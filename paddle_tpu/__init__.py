@@ -8,7 +8,6 @@ whole-program XLA compilation, jax.grad autodiff, SPMD parallelism over
 jax.sharding meshes, Pallas kernels for the hot paths.
 """
 
-from . import jax_compat    # noqa: F401  must run before any jax-using module
 from . import observability
 from . import initializer
 from .core import (framework, unique_name)

@@ -28,14 +28,15 @@ class Place:
         return hash((type(self).__name__, self.device_id))
 
     def jax_device(self):
-        """Resolve to a concrete jax.Device (best effort)."""
-        devs = jax.devices()
-        if self._kind == "cpu":
-            try:
-                devs = jax.devices("cpu")
-            except RuntimeError:
-                pass
-        return devs[min(self.device_id, len(devs) - 1)]
+        """Resolve to the concrete jax.Device this place names. An id
+        past the last device raises — a clamp would quietly run
+        everything on one chip."""
+        devs = jax.devices("cpu") if self._kind == "cpu" else jax.devices()
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r} names device {self.device_id} but jax has "
+                f"{len(devs)} {devs[0].platform} device(s)")
+        return devs[self.device_id]
 
 
 class CPUPlace(Place):

@@ -12,7 +12,7 @@ scrape target on every host, not a metrics SDK.
   escaped per the format spec).
 - ``GET /healthz`` — JSON ``{"status": "ok", ...health_fn()}``; any
   exception from health_fn turns into ``{"status": "error"}`` + HTTP
-  500, so a wedged component reads as unhealthy instead of silent.
+  500, so a hung component reads as unhealthy instead of silent.
 - ``GET /slo`` — JSON from ``slo_fn()`` (the serving SLO digest
   snapshot), ``{}`` when the component has none.
 - ``GET /memory`` — JSON HBM-ledger snapshot (``memory_fn()``; default

@@ -165,7 +165,7 @@ METRIC_SPECS = [
     ("serving.kernel.fallback", "counter",
      "paged_attention dispatches that took the pure-JAX reference path "
      "(unlabeled aggregate plus a reason label — pinned_off, "
-     "unsupported, vmap_trace, unsupported_under_shard_map — and a "
+     "unsupported, unsupported_under_shard_map — and a "
      "version=reference label mirroring serving.kernel.traced's)"),
     ("serving.kernel.version", "gauge",
      "kernel generation the LAST paged_attention dispatch took: 1 = "
@@ -710,8 +710,8 @@ class MetricsRegistry:
                             sorted(metrics, key=lambda m: m.name)]}
 
     def to_json(self, indent=None):
-        """One-line JSON by default: perf/ artifacts are parsed line-wise
-        (tools/bench_watch.py _artifact_ok reads the LAST line)."""
+        """One-line JSON by default: perf/ artifacts are parsed
+        line-wise (readers take the LAST line)."""
         return json.dumps(self.to_dict(), indent=indent, sort_keys=True)
 
     def to_prometheus(self):

@@ -207,8 +207,10 @@ class FlightRecorder:
 
     def __init__(self, capacity=256, out_dir=None):
         self.capacity = max(1, int(capacity))
+        # no directory named: dumps go to a fresh temp dir made at the
+        # first dump, never into the working directory
         self.out_dir = out_dir if out_dir is not None else \
-            os.environ.get("PADDLE_TPU_FLIGHT_DIR", ".")
+            os.environ.get("PADDLE_TPU_FLIGHT_DIR")
         self._entries = collections.deque(maxlen=self.capacity)
         self._recorded = 0
         self._lock = threading.Lock()
@@ -284,6 +286,9 @@ class FlightRecorder:
         if step is None:
             step = entries[-1]["step"] if entries else 0
         if path is None:
+            if self.out_dir is None:
+                import tempfile
+                self.out_dir = tempfile.mkdtemp(prefix="paddle_tpu_flight_")
             os.makedirs(self.out_dir, exist_ok=True)
             path = os.path.join(self.out_dir, f"flight-{int(step):08d}.json")
             # repeated faults at one step (e.g. GuardedTrainer retries of

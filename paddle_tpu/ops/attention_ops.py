@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from . import register
 
-_flash_warned = False
 _ring_seg_warned = False
 
 
@@ -29,10 +28,7 @@ def _use_pallas():
         return True
     if os.environ.get("PADDLE_TPU_DISABLE_FLASH") == "1":
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _xla_attention(q, k, v, bias=None, scale=None, causal=False):
@@ -51,6 +47,25 @@ def _xla_attention(q, k, v, bias=None, scale=None, causal=False):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def _active_mesh():
+    """The mesh the Executor activated (the `with mesh:` context
+    core/compiler._run_data_parallel opens) while its axes are still
+    GSPMD's to partition, or None. Inside someone else's shard_map (the
+    pipeline forward, parallel/pipeline.py, is a full-mesh one under the
+    same `with mesh:`) the axes are already Manual: that caller owns the
+    partitioning, the operands here are its local shards, and a second
+    shard_map over the same mesh is an error — so there is no mesh left
+    for this op to wrap. The legacy mesh context is only readable from a
+    private jax module; it is imported without a guard so a jax that
+    moves it fails here instead of silently dropping the mesh paths
+    below."""
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    from jax._src import mesh as mesh_lib
+    mesh = mesh_lib.thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
+
+
 def _active_sp_mesh(q, k, bias):
     """The executor-activated mesh, when sequence parallelism applies:
     mesh has an 'sp' axis > 1, BOTH time axes divide it (cross-attention
@@ -59,12 +74,8 @@ def _active_sp_mesh(q, k, bias):
     dense paths, never crashes."""
     if os.environ.get("PADDLE_TPU_DISABLE_RING") == "1":
         return None
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
-    if mesh.empty or "sp" not in mesh.axis_names:
+    mesh = _active_mesh()
+    if mesh is None or "sp" not in mesh.axis_names:
         return None
     sp = mesh.shape["sp"]
     if sp <= 1 or q.shape[2] % sp != 0 or k.shape[2] % sp != 0:
@@ -76,6 +87,50 @@ def _active_sp_mesh(q, k, bias):
         if name in mesh.axis_names and dim % mesh.shape[name] != 0:
             return None
     return mesh
+
+
+def _flash_on_mesh(q, k, v, bias, scale, causal, segment_ids, mesh):
+    """The flash kernel under an executor-activated mesh. GSPMD cannot
+    partition a Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), so the call is
+    wrapped here: attention is independent per (batch, head), so batch
+    shards over 'dp' and heads over 'tp' (parallel/mesh.make_mesh's axis
+    names, as in _active_sp_mesh) where the mesh has those axes and they
+    divide; every other axis sees the operands replicated."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from .pallas.flash import flash_attention
+
+    def ax(name, dim):
+        ok = (name in mesh.axis_names and mesh.shape[name] > 1
+              and dim % mesh.shape[name] == 0)
+        return name if ok else None
+
+    b, h = q.shape[:2]
+    b_ax, h_ax = ax("dp", b), ax("tp", h)
+    qkv = P(b_ax, h_ax, None, None)
+    operands, specs = [q, k, v], [qkv, qkv, qkv]
+    if bias is not None:
+        bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
+        operands.append(bias)
+        specs.append(P(b_ax if bias.shape[0] == b else None,
+                       h_ax if bias.shape[1] == h else None, None, None))
+    seg = segment_ids
+    paired = isinstance(seg, (tuple, list))
+    if seg is not None:
+        for ids in (seg if paired else (seg,)):
+            operands.append(ids)
+            specs.append(P(b_ax, None))
+
+    def local(q_, k_, v_, *rest):
+        rest = list(rest)
+        bias_ = rest.pop(0) if bias is not None else None
+        seg_ = (tuple(rest) if paired else rest[0]) if rest else None
+        return flash_attention(q_, k_, v_, bias=bias_, scale=scale,
+                               causal=causal, segment_ids=seg_)
+
+    return shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                     out_specs=qkv, check_vma=False)(*operands)
 
 
 def dot_product_attention(q, k, v, bias=None, scale=None, causal=False,
@@ -107,23 +162,15 @@ def dot_product_attention(q, k, v, bias=None, scale=None, causal=False,
         return ring_attention_sharded(q, k, v, sp_mesh, causal=causal,
                                       scale=scale, bias=bias)
     if _use_pallas():
-        try:
-            from .pallas.flash import flash_attention
-            return flash_attention(q, k, v, bias=bias, scale=scale,
-                                   causal=causal, segment_ids=segment_ids)
-        except Exception as e:
-            # Never degrade silently: on TPU a dead flash kernel means the
-            # hot path quietly became O(T^2) (VERDICT r1 weak #7).
-            if os.environ.get("PADDLE_TPU_STRICT_FLASH") == "1":
-                raise
-            global _flash_warned
-            if not _flash_warned:
-                warnings.warn(
-                    f"Pallas flash attention failed ({e!r}); falling back "
-                    "to the O(T^2) XLA attention path. Set "
-                    "PADDLE_TPU_STRICT_FLASH=1 to make this fatal.",
-                    RuntimeWarning, stacklevel=2)
-                _flash_warned = True
+        # no fallback: a flash kernel that fails to build on TPU must
+        # stop the program, not quietly become the O(T^2) XLA path
+        mesh = _active_mesh()
+        if mesh is not None and mesh.size > 1:
+            return _flash_on_mesh(q, k, v, bias, scale, causal,
+                                  segment_ids, mesh)
+        from .pallas.flash import flash_attention
+        return flash_attention(q, k, v, bias=bias, scale=scale,
+                               causal=causal, segment_ids=segment_ids)
     if segment_ids is not None:
         from .pallas.flash import segment_mask_bias
         seg_b = (segment_mask_bias(*segment_ids)

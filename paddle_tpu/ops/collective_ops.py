@@ -10,7 +10,6 @@ mapped context (single-chip program) they degrade to identity, mirroring how
 a 1-GPU NCCL ring is a no-op.
 """
 
-import jax
 from jax import lax
 
 from . import register
@@ -18,14 +17,6 @@ from . import register
 
 def _axis(ctx):
     return ctx.attr("ring_id_axis", ctx.attr("axis_name", "dp"))
-
-
-def _in_mapped_context(axis):
-    try:
-        jax.core.get_axis_env().axis_size(axis) if hasattr(jax.core, "get_axis_env") else lax.axis_index(axis)
-        return True
-    except (NameError, Exception):
-        return False
 
 
 def _maybe(fn, x, axis):

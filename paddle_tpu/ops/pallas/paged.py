@@ -5,22 +5,23 @@ materializes a dense (B, H, M*bs, D) gather of every request's FULL
 block table on every fused step — each decode iteration pays
 O(max_blocks) HBM traffic per lane regardless of how many tokens the
 lane actually holds. The kernels here (per the *Ragged Paged Attention*
-TPU paper, PAPERS.md) walk the block table INSIDE the kernel instead,
-in two generations:
+TPU paper, PAPERS.md) walk the block table INSIDE the kernel instead:
+the grid is (lane, table column) and each K/V block arrives through a
+BlockSpec whose index_map reads the scalar-prefetched table, so the
+Pallas pipeline issues (and double-buffers) the HBM->VMEM copies. Two
+generations share that walk:
 
-* **v1** (`ragged_paged_attention`): per lane, a DMA loop copies only
-  the table's live blocks into an (M, H_kv, bs, D) VMEM scratch and
-  STOPS past the lane's highest live block, then runs the reference's
-  exact op sequence on the VMEM-resident gather. f32 and int8 pools are
-  pinned BITWISE against the reference under jit in interpret mode —
-  the price is VMEM scratch proportional to the table width M.
-* **v2** (`ragged_paged_attention_v2`): a double-buffered
-  block-STREAMING walk. VMEM scratch holds O(2 blocks) of K/V —
-  independent of M, so context length is unbounded at fixed VMEM — and
-  each streamed block folds into a flash-style online-softmax
-  accumulator (running max, rescaled sum, rescaled PV partial). The
-  next block's `make_async_copy` is issued BEFORE the current block's
-  compute, so HBM latency hides behind the MXU work. Online softmax is
+* **v1** (`ragged_paged_attention`): every live block is copied into
+  an (H_kv, M*bs, D) VMEM scratch as it arrives; the last grid step
+  runs the reference's exact op sequence on the VMEM-resident gather.
+  f32 and int8 pools are pinned BITWISE against the reference under jit
+  in interpret mode — the price is VMEM scratch proportional to the
+  table width M.
+* **v2** (`ragged_paged_attention_v2`): each arriving block folds
+  straight into a flash-style online-softmax accumulator (running max,
+  rescaled sum, rescaled PV partial, all f32 VMEM scratch). VMEM holds
+  the pipeline's two block windows plus the carry — independent of M,
+  so context length is unbounded at fixed VMEM. Online softmax is
   mathematically EXACT (every rescale is an identity in real
   arithmetic) but reorders the floating-point reductions the reference
   performs in one pass, so v2 is pinned allclose-at-f32-tightness plus
@@ -29,42 +30,50 @@ in two generations:
 
 Both kernels share the serving contract:
 
-* the K/V pools stay in HBM (`memory_space=ANY`); the block table and
-  query positions ride scalar prefetch (SMEM), so block indices are
-  available for DMA address computation the way jax's own
+* the block table and query positions ride scalar prefetch (SMEM), so
+  block indices are available to the index_maps the way jax's own
   paged-attention kernel does it;
-* the NULL block (block 0 — table padding, masked-lane writes) is never
-  read: padding entries and idle lanes contribute exactly nothing, even
-  if block 0 holds garbage (pinned by NaN-poison tests);
+* per-lane early stop: past a lane's highest live block the index_map
+  repeats the last live block index, so that the pipeline can skip a
+  copy whose block index did not change, and the step's compute is
+  predicated off (the HBM bytes this saves are not measured: PERF.md
+  section 7);
+* the NULL block (block 0 — table padding, masked-lane writes) never
+  enters the arithmetic: padding entries and idle lanes contribute
+  exactly nothing, even if block 0 holds garbage (pinned by NaN-poison
+  tests);
 * chunked prefill (C > 1) and decode (C = 1) are ONE kernel — the
   engine's single fused-step signature survives unchanged;
 * bf16 pools are welcome: scores and softmax accumulate in f32
   (EQuARX-style reduced-precision hot path with full-precision
   accumulation);
 * int8 pools (quantized serving, ISSUE 14) fuse the DEQUANT into the
-  gather: the DMA loop copies the int8 codes plus their (H_kv, bs) f32
+  walk: the pipeline copies the int8 codes plus their (H_kv, bs) f32
   scale rows — roughly HALF the bytes a bf16 pool moves per block —
-  and the dequant multiply happens on the VMEM-resident data right
+  and the dequant multiply happens on the VMEM-resident block right
   where the value path consumes it;
 * grouped-query attention (ISSUE 16): pools may carry H_kv < H heads
   (H % H_kv == 0). Query head j attends KV head j // (H/H_kv) — the
   contiguous-group convention, so Megatron column-sharded projections
-  stay head-aligned. v1 repeats the gathered KV rows across each
-  group (a pure copy, so the bitwise pin extends to GQA); v2 batches
-  the einsums as (H_kv, group, ...) against the un-repeated blocks and
-  never materializes the repeat at all.
+  stay head-aligned. Both kernels repeat the VMEM-resident KV rows
+  across each group (a pure copy, so v1's bitwise pin extends to GQA);
+  HBM traffic stays at H_kv heads.
 
-VMEM budget: v1 scratch holds one lane's full K+V working set,
-2 * M * bs * H_kv * D * itemsize — the full-KV-resident discipline of
-flash.py's default forward. v2 holds 2 * 2 * bs * H_kv * D * itemsize
-whatever M is; the dispatcher (serving/kv_cache.paged_attention) routes
-tables past the v1 ceiling to v2 automatically.
+Why BlockSpecs and not hand-rolled `make_async_copy`: Mosaic refuses a
+DMA slice of an array whose minor dim is under one 128-lane tile
+("Slice shape along dimension 3 must be aligned to tiling (128), but is
+64"), and head_dim 64 is the GPT-2 geometry. The pipeline's own copies
+take any block whose trailing dims equal the array's.
+
+VMEM budget: v1's scratch holds one lane's full K+V working set,
+2 * H_kv * M*bs * D elements (minor dim padded to 128 lanes) — the
+full-KV-resident discipline of flash.py's default forward. v2 holds
+two block windows per pool whatever M is; the dispatcher
+(serving/kv_cache.paged_attention) routes tables past the v1 ceiling
+to v2 automatically.
 
 Off-TPU the kernels run under the Pallas interpreter (same policy as
-flash.py) so the CPU suite exercises the real kernel code. All Pallas
-APIs used here (PrefetchScalarGridSpec, memory_space=ANY,
-make_async_copy, SemaphoreType.DMA) exist and interpret correctly on
-this container's jax 0.4.37 — no jax_compat shim needed.
+flash.py) so the CPU suite exercises the real kernel code.
 """
 
 import functools
@@ -89,10 +98,7 @@ V2_TRACE_COUNT = 0
 
 
 def _interpret():
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    return jax.default_backend() != "tpu"
 
 
 def _validate_paged_args(q, k_pool, v_pool, block_table, q_positions,
@@ -131,107 +137,201 @@ def _validate_paged_args(q, k_pool, v_pool, block_table, q_positions,
     return b, h, c, d, n, hp, bs, m, quantized
 
 
-def _paged_kernel(tbl_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref,
-                  *rest, bs, m, h, hp, d, quantized=False):
-    """One grid step = one request lane, all heads — dense AND int8
-    pools share this walk (selected at trace time by `quantized`, so
-    the early-stop arithmetic, the NULL guard, the zero-fill the
+def _n_live(pos_ref, b, c, bs, m):
+    """A lane's live-block count, from its highest query position
+    (scalar reads; C is static and small). Always >= 1."""
+    max_pos = pos_ref[b, 0]
+    for ci in range(1, c):
+        max_pos = jnp.maximum(max_pos, pos_ref[b, ci])
+    return jnp.minimum(max_pos // bs + 1, m)
+
+
+def _block_is_live(tbl_ref, pos_ref, b, j, bs, m):
+    """Does grid step (b, j) hold a block to attend? Not past the
+    lane's last live block, and not table padding / an idle lane's
+    NULL_BLOCK — whatever the pipeline delivered for those steps is
+    never touched."""
+    return ((j < _n_live(pos_ref, b, pos_ref.shape[1], bs, m))
+            & (tbl_ref[b, j] != NULL_BLOCK))
+
+
+def _page_spec(block_shape, c, bs, m):
+    """BlockSpec for one pool: grid step (b, j) sees pool block
+    table[b, j]. Past the lane's last live block the index repeats, so
+    the pipeline issues no further copies for that lane (early stop)."""
+    zeros = (0,) * (len(block_shape) - 1)
+
+    def index_map(b, j, tbl, pos):
+        last = _n_live(pos, b, c, bs, m) - 1
+        return (tbl[b, jnp.minimum(j, last)],) + zeros
+
+    return pl.BlockSpec((1,) + tuple(block_shape[1:]), index_map)
+
+
+def _pos_matrix(pos_ref, b, c, shape, axis):
+    """int32 array of `shape` holding pos_ref[b, i] at index i along
+    `axis` — the lane's query positions as a vector operand (SMEM
+    scalars cannot be stacked into a vector directly)."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    out = jnp.full(shape, pos_ref[b, 0], jnp.int32)
+    for ci in range(1, c):
+        out = jnp.where(idx == ci, pos_ref[b, ci], out)
+    return out
+
+
+def _dequant(codes, scales, dtype):
+    """int8 block (H_kv, bs, D) times its (H_kv, bs) f32 row scales —
+    the reference's dequant expression, op for op."""
+    return (codes.astype(jnp.float32) * scales[..., None]).astype(dtype)
+
+
+def _repeat_heads(x, g):
+    """(H_kv, ...) -> (H_kv * g, ...): query head j reads KV head
+    j // g. A leading-dim copy."""
+    if g == 1:
+        return x
+    return jnp.broadcast_to(x[:, None], (x.shape[0], g) + x.shape[1:]
+                            ).reshape((x.shape[0] * g,) + x.shape[1:])
+
+
+def _mxu_precision(dtype):
+    """Matmul precision for in-kernel dots on `dtype` operands. bf16
+    operands ride the MXU natively (every product is exact in the f32
+    accumulator, so DEFAULT loses nothing) — and must say so, because
+    Mosaic refuses bf16 operands under a process-wide "highest" matmul
+    precision ("Bad lhs type"). f32 operands follow the process
+    setting."""
+    return None if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+
+
+def _padded_bytes(shape, dtype):
+    """VMEM bytes of one buffer: minor dim padded to 128 lanes, second
+    minor to the dtype's sublane tile (8 rows of 32 bits)."""
+    item = np.dtype(dtype).itemsize
+    dims = list(shape)
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        sub = 8 * (4 // item)
+        dims[-2] = -(-dims[-2] // sub) * sub
+    return int(np.prod(dims)) * item
+
+
+def _compiler_params(vmem_bytes):
+    """Lanes are independent, table columns carry scratch state. The
+    VMEM limit is stated (Mosaic's default scope is smaller than v1's
+    gather at long tables) with headroom for the pipeline windows and
+    value temporaries."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(max(2 * vmem_bytes + (8 << 20),
+                                     32 << 20), 100 << 20)))
+
+
+def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m, h,
+                  hp, d, quantized=False):
+    """Grid step (b, j): lane b, table column j, all heads — dense AND
+    int8 pools share this walk (selected at trace time by `quantized`,
+    so the early-stop arithmetic, the NULL guard, the zero-fill the
     bitwise pin depends on, and the mask/softmax tail exist exactly
     once).
 
     tbl_ref (B, M) / pos_ref (B, C): scalar-prefetched SMEM.
-    q_ref (1, H, C, D) VMEM; k/v_pool_ref (N, H_kv, bs, D) HBM (ANY).
-    gk/gv scratch (M, H_kv, bs, D) VMEM in pool dtype — the lane's
-    gathered view, laid out exactly like the reference's `pool[table]`
-    row so the value-path math below can mirror it op for op. Quantized
-    adds the (N, H_kv, bs) f32 scale pools in HBM and (M, H_kv, bs)
-    scale scratch. GQA (hp < h) repeats the gathered (and dequantized)
-    rows across each query-head group — a pure copy, identical to the
-    reference's repeat of its dense gather, so the bitwise pin holds."""
+    q_ref (1, H, C, D); k/v_ref (1, H_kv, bs, D): pool block
+    table[b, j], delivered by the pipeline. gk/gv scratch
+    (H_kv, M*bs, D) VMEM — the lane's gathered view, rows in
+    logical-position order exactly like the reference's dense gather, so
+    the value-path math below can mirror it op for op. Quantized adds
+    the (1, H_kv, bs) f32 scale blocks; the dequant happens as each
+    block lands (gk scratch f32, gv scratch in the output dtype — the
+    reference's dequant expression per row). GQA (hp < h) repeats the
+    gathered rows across each query-head group — a pure copy, identical
+    to the reference's repeat of its dense gather, so the bitwise pin
+    holds."""
     if quantized:
-        (ks_pool_ref, vs_pool_ref, o_ref,
-         gk_ref, gv_ref, gks_ref, gvs_ref, sem_ref) = rest
+        ks_ref, vs_ref, o_ref, gk_ref, gv_ref = rest
     else:
-        o_ref, gk_ref, gv_ref, sem_ref = rest
-    b = pl.program_id(0)
+        o_ref, gk_ref, gv_ref = rest
+    b, j = pl.program_id(0), pl.program_id(1)
+    c = pos_ref.shape[1]
     t = m * bs
 
     # the skipped tail must hold zeros, not stale VMEM: its (masked)
     # probabilities are exactly 0 and 0 * 0 keeps the PV partial sums
-    # bitwise-identical to the reference's 0 * null-block terms (for
-    # int8, zero codes AND zero scales dequantize to exact 0.0)
-    gk_ref[...] = jnp.zeros_like(gk_ref)
-    gv_ref[...] = jnp.zeros_like(gv_ref)
-    if quantized:
-        gks_ref[...] = jnp.zeros_like(gks_ref)
-        gvs_ref[...] = jnp.zeros_like(gvs_ref)
+    # bitwise-identical to the reference's 0 * null-block terms
+    @pl.when(j == 0)
+    def _zero():
+        gk_ref[...] = jnp.zeros_like(gk_ref)
+        gv_ref[...] = jnp.zeros_like(gv_ref)
 
-    # per-lane early stop: the highest live block index comes from the
-    # lane's query positions (scalar reads; C is static and small)
-    c = pos_ref.shape[1]
-    max_pos = pos_ref[b, 0]
-    for ci in range(1, c):
-        max_pos = jnp.maximum(max_pos, pos_ref[b, ci])
-    n_live = jnp.minimum(max_pos // bs + 1, m)
-
-    def fetch(j, carry):
-        blk = tbl_ref[b, j]
-
-        def do_copy(_):
-            # all of one block's pieces in flight together; the NULL
-            # guard below means block 0 is NEVER the DMA source
-            copies = [
-                pltpu.make_async_copy(k_pool_ref.at[blk], gk_ref.at[j],
-                                      sem_ref.at[0]),
-                pltpu.make_async_copy(v_pool_ref.at[blk], gv_ref.at[j],
-                                      sem_ref.at[1])]
-            if quantized:
-                copies += [
-                    pltpu.make_async_copy(ks_pool_ref.at[blk],
-                                          gks_ref.at[j], sem_ref.at[2]),
-                    pltpu.make_async_copy(vs_pool_ref.at[blk],
-                                          gvs_ref.at[j], sem_ref.at[3])]
-            for cp in copies:
-                cp.start()
-            for cp in copies:
-                cp.wait()
-            return 0
-
-        # table padding and idle lanes route to NULL_BLOCK: skip the
-        # copy outright (contributes nothing, reads nothing)
-        jax.lax.cond(blk != NULL_BLOCK, do_copy, lambda _: 0, 0)
-        return carry
-
-    jax.lax.fori_loop(0, n_live, fetch, 0)
+    @pl.when(_block_is_live(tbl_ref, pos_ref, b, j, bs, m))
+    def _gather():
+        k, v = k_ref[0], v_ref[0]                     # (H_kv, bs, D)
+        if quantized:
+            k = _dequant(k, ks_ref[0], gk_ref.dtype)
+            v = _dequant(v, vs_ref[0], gv_ref.dtype)
+        rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
+        gk_ref[:, rows, :] = k
+        gv_ref[:, rows, :] = v
 
     # ---- value path: the reference body on the VMEM-resident gather --
-    # (same moveaxis/reshape, same einsums batched over H, same mask
-    # constant, same jax.nn.softmax — the bitwise pin lives here; the
-    # int8 dequant slots in exactly where the reference branch does it)
-    q = q_ref[0]                                          # (H, C, D)
-    gk = jnp.moveaxis(gk_ref[...], 1, 0).reshape(hp, t, d)
-    gv = jnp.moveaxis(gv_ref[...], 1, 0).reshape(hp, t, d)
+    # (same einsums batched over H, same mask constant, same
+    # jax.nn.softmax — the bitwise pin lives here)
+    @pl.when(j == m - 1)
+    def _attend():
+        q = q_ref[0]                                      # (H, C, D)
+        gk = _repeat_heads(gk_ref[...], h // hp)
+        gv = _repeat_heads(gv_ref[...], h // hp)
+        s = jnp.einsum("hcd,htd->hct", q.astype(gk.dtype), gk,
+                       precision=_mxu_precision(gk.dtype),
+                       preferred_element_type=jnp.float32) / np.sqrt(d)
+        key_pos = jax.lax.broadcasted_iota(jnp.int32, (c, t), 1)
+        mask = (key_pos <= _pos_matrix(pos_ref, b, c, (c, t), 0))[None]
+        s = jnp.where(mask, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
+        o_ref[0] = jnp.einsum(
+            "hct,htd->hcd", p, gv, precision=_mxu_precision(gv.dtype),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _paged_call(name, kernel, q, pools, scales, block_table,
+                q_positions, scratch, out_dtype, vmem_bytes, interpret):
+    """The pallas_call both generations share: grid (lane, table
+    column), table + positions scalar-prefetched, q/out one lane per
+    block, every pool one table-addressed block per step."""
+    b, h, c, d = q.shape
+    _n, hp, bs, _d = pools[0].shape
+    m = block_table.shape[1]
+    lane_spec = pl.BlockSpec((1, h, c, d),
+                             lambda b_, j, tbl, pos: (b_, 0, 0, 0))
+    in_specs = [lane_spec]
+    in_specs += [_page_spec(p.shape, c, bs, m) for p in pools + scales]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,          # block_table, q_positions
+        grid=(b, m),
+        in_specs=in_specs,
+        out_specs=lane_spec,
+        scratch_shapes=[pltpu.VMEM(shp, dt) for shp, dt in scratch],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, bs=bs, m=m, h=h, hp=hp, d=d,
+                          quantized=bool(scales)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, c, d), out_dtype),
+        compiler_params=_compiler_params(vmem_bytes),
+        name=name,
+        interpret=interpret,
+    )(block_table.astype(jnp.int32), q_positions.astype(jnp.int32),
+      q, *pools, *scales)
+
+
+def _v1_scratch_shapes(hp, bs, d, m, pool_dtype, out_dtype, quantized):
+    """v1's VMEM gather: K and V at full table width. Quantized pools
+    are dequantized as they land, so K sits in f32 and V in the output
+    dtype; dense pools sit in the pool dtype."""
     if quantized:
-        ks = jnp.moveaxis(gks_ref[...], 1, 0).reshape(hp, t)
-        vs = jnp.moveaxis(gvs_ref[...], 1, 0).reshape(hp, t)
-        gk = gk.astype(jnp.float32) * ks[..., None]
-        gv = (gv.astype(jnp.float32) * vs[..., None]).astype(
-            o_ref.dtype)
-    if hp < h:
-        # GQA: query head j reads KV head j // group — repeat the
-        # gathered rows per group (pure copies, so the einsums below
-        # see exactly the values a repeat-KV dense pool would hold)
-        gk = jnp.repeat(gk, h // hp, axis=0)
-        gv = jnp.repeat(gv, h // hp, axis=0)
-    s = jnp.einsum("hcd,htd->hct", q.astype(jnp.float32),
-                   gk.astype(jnp.float32),
-                   preferred_element_type=jnp.float32) / np.sqrt(d)
-    pos = jnp.stack([pos_ref[b, ci] for ci in range(c)])  # (C,)
-    key_pos = jax.lax.broadcasted_iota(jnp.int32, (c, t), 1)
-    mask = key_pos[None] <= pos[None, :, None]
-    s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
-    o_ref[0] = jnp.einsum("hct,htd->hcd", p, gv).astype(o_ref.dtype)
+        return [((hp, m * bs, d), jnp.float32),
+                ((hp, m * bs, d), out_dtype)]
+    return [((hp, m * bs, d), pool_dtype)] * 2
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_table, q_positions,
@@ -259,92 +359,43 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_positions,
         q, k_pool, v_pool, block_table, q_positions, k_scale, v_scale)
     if interpret is None:
         interpret = _interpret()
-
-    lane_spec = pl.BlockSpec((1, h, c, d),
-                             lambda b_, tbl, pos: (b_, 0, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    if quantized:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,      # block_table, q_positions
-            grid=(b,),
-            in_specs=[lane_spec,
-                      any_spec, any_spec,       # k/v pools stay in HBM
-                      any_spec, any_spec],      # scale pools too
-            out_specs=lane_spec,
-            scratch_shapes=[
-                pltpu.VMEM((m, hp, bs, d), jnp.int8),
-                pltpu.VMEM((m, hp, bs, d), jnp.int8),
-                pltpu.VMEM((m, hp, bs), jnp.float32),
-                pltpu.VMEM((m, hp, bs), jnp.float32),
-                pltpu.SemaphoreType.DMA((4,)),
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_paged_kernel, bs=bs, m=m, h=h, hp=hp,
-                              d=d, quantized=True),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, c, d), q.dtype),
-            interpret=interpret,
-        )(block_table.astype(jnp.int32), q_positions.astype(jnp.int32),
-          q, k_pool, v_pool, k_scale, v_scale)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_table, q_positions
-        grid=(b,),
-        in_specs=[
-            lane_spec,
-            any_spec,                               # k pool stays in HBM
-            any_spec,                               # v pool stays in HBM
-        ],
-        out_specs=lane_spec,
-        scratch_shapes=[
-            pltpu.VMEM((m, hp, bs, d), k_pool.dtype),
-            pltpu.VMEM((m, hp, bs, d), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, m=m, h=h, hp=hp, d=d),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, c, d), v_pool.dtype),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), q_positions.astype(jnp.int32),
-      q, k_pool, v_pool)
+    out_dtype = q.dtype if quantized else v_pool.dtype
+    scratch = _v1_scratch_shapes(hp, bs, d, m, k_pool.dtype, out_dtype,
+                                 quantized)
+    # the gather, its head-repeated f32 view, and the (H, C, T) scores
+    vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
+            * (h // hp) + 3 * _padded_bytes((h, c, m * bs), jnp.float32))
+    return _paged_call("paged_attention_v1", _paged_kernel, q,
+                       [k_pool, v_pool],
+                       [k_scale, v_scale] if quantized else [],
+                       block_table, q_positions, scratch, out_dtype,
+                       vmem, interpret)
 
 
 # ---------------------------------------------------------------------------
-# kernel v2: double-buffered block streaming + online softmax
+# kernel v2: block streaming + online softmax
 # ---------------------------------------------------------------------------
 
-def _v2_scratch_shapes(hp, bs, d, pool_dtype, quantized):
+def _v2_scratch_shapes(h, c, d):
     """The v2 VMEM scratch contract, exposed for the white-box test:
-    every buffer's leading dim is 2 (the double-buffer slots) and NO
-    dimension depends on the table width M — that independence IS the
-    unbounded-context claim. Returns [(shape, dtype), ...] for the K
-    window, the V window, and (quantized only) their scale windows."""
-    shapes = [((2, hp, bs, d), pool_dtype),
-              ((2, hp, bs, d), pool_dtype)]
-    if quantized:
-        shapes += [((2, hp, bs), jnp.float32),
-                   ((2, hp, bs), jnp.float32)]
-    return shapes
+    the online-softmax carry (running max, exp-sum, PV partial) — NO
+    dimension depends on the table width M, and the K/V windows are the
+    pipeline's own two block-sized buffers. That independence IS the
+    unbounded-context claim. Returns [(shape, dtype), ...]."""
+    return [((h, c, 1), jnp.float32), ((h, c, 1), jnp.float32),
+            ((h, c, d), jnp.float32)]
 
 
-def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref,
-                     *rest, bs, m, h, hp, d, quantized=False):
-    """One grid step = one request lane, all heads, streaming the
-    lane's live blocks through a 2-slot VMEM window.
-
-    The walk: block 0's DMA is issued up front; each loop iteration
-    first issues block j+1's copy into the OTHER slot, then waits on
-    block j's and folds it into the online-softmax carry
+def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m,
+                     h, hp, d, quantized=False):
+    """Grid step (b, j): lane b, table column j, all heads. Block
+    table[b, j] arrives through the pipeline (the NEXT block's copy is
+    already in flight while this one computes — the two-window overlap)
+    and folds into the online-softmax carry held in VMEM scratch
     (m: running row max, l: rescaled exp-sum, acc: rescaled PV partial,
-    all f32). NULL blocks (padding, idle lanes) are skipped on both the
-    issue and the wait side, and their mask zeroes the whole block's
-    probabilities — a skipped slot's stale-but-finite contents multiply
-    by exact 0 (both slots are zero-filled once at entry, so "stale"
-    can only ever mean a previous LIVE block's values, never
-    uninitialized VMEM or the NULL block's poison).
+    all f32). NULL blocks (padding, idle lanes) and columns past the
+    lane's last live block are predicated off whole: nothing they hold
+    — garbage, NaN poison — is ever multiplied.
 
     Two traps the masking dodges, pinned by tests:
     * NEG_INF is finite (-1e9), so on an all-masked prefix
@@ -355,128 +406,67 @@ def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, k_pool_ref, v_pool_ref,
       `where(l > 0, l, 1)` lands an exact 0 output instead of NaN (the
       engine's non-finite-logits guard sums every lane's logps)."""
     if quantized:
-        (ks_pool_ref, vs_pool_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf,
-         sem_k, sem_v, sem_ks, sem_vs) = rest
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
-        o_ref, kbuf, vbuf, sem_k, sem_v = rest
-    b = pl.program_id(0)
-    g = h // hp
+        o_ref, m_ref, l_ref, acc_ref = rest
+    b, j = pl.program_id(0), pl.program_id(1)
     c = pos_ref.shape[1]
 
-    # zero-fill BOTH slots once: a skipped (NULL) block leaves its slot
-    # untouched, and 0-probability times a finite stale value is an
-    # exact 0 — times uninitialized VMEM (or a NaN-poisoned NULL block,
-    # had we copied it) it would be NaN
-    kbuf[...] = jnp.zeros_like(kbuf)
-    vbuf[...] = jnp.zeros_like(vbuf)
-    if quantized:
-        ksbuf[...] = jnp.zeros_like(ksbuf)
-        vsbuf[...] = jnp.zeros_like(vsbuf)
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    max_pos = pos_ref[b, 0]
-    for ci in range(1, c):
-        max_pos = jnp.maximum(max_pos, pos_ref[b, ci])
-    n_live = jnp.minimum(max_pos // bs + 1, m)
-
-    def _copies(j, slot):
-        blk = tbl_ref[b, j]
-        copies = [
-            pltpu.make_async_copy(k_pool_ref.at[blk], kbuf.at[slot],
-                                  sem_k.at[slot]),
-            pltpu.make_async_copy(v_pool_ref.at[blk], vbuf.at[slot],
-                                  sem_v.at[slot])]
+    @pl.when(_block_is_live(tbl_ref, pos_ref, b, j, bs, m))
+    def _fold():
+        kb, vb = k_ref[0], v_ref[0]                   # (H_kv, bs, D)
         if quantized:
-            copies += [
-                pltpu.make_async_copy(ks_pool_ref.at[blk],
-                                      ksbuf.at[slot], sem_ks.at[slot]),
-                pltpu.make_async_copy(vs_pool_ref.at[blk],
-                                      vsbuf.at[slot], sem_vs.at[slot])]
-        return blk, copies
-
-    def _issue(j):
-        blk, copies = _copies(j, jax.lax.rem(j, 2))
-
-        def go(_):
-            for cp in copies:
-                cp.start()
-            return 0
-
-        jax.lax.cond(blk != NULL_BLOCK, go, lambda _: 0, 0)
-        return 0
-
-    def _wait(j):
-        blk, copies = _copies(j, jax.lax.rem(j, 2))
-
-        def go(_):
-            for cp in copies:
-                cp.wait()
-            return 0
-
-        jax.lax.cond(blk != NULL_BLOCK, go, lambda _: 0, 0)
-        return 0
-
-    # warm-up: block 0 in flight before the loop (n_live >= 1 always)
-    _issue(0)
-
-    q = q_ref[0].reshape(hp, g, c, d).astype(jnp.float32)
-    pos = jnp.stack([pos_ref[b, ci] for ci in range(c)])      # (C,)
-
-    def body(j, carry):
-        m_run, l_run, acc = carry
-        # the NEXT block's DMA goes out before this block's compute —
-        # that overlap is the whole point of the 2-slot window
-        jax.lax.cond(j + 1 < n_live,
-                     lambda _: _issue(j + 1), lambda _: 0, 0)
-        _wait(j)
-        slot = jax.lax.rem(j, 2)
-        blk = tbl_ref[b, j]
-        kb = kbuf[slot]                               # (H_kv, bs, D)
-        vb = vbuf[slot]
-        if quantized:
-            kb = kb.astype(jnp.float32) * ksbuf[slot][..., None]
-            vb = vb.astype(jnp.float32) * vsbuf[slot][..., None]
-        s = jnp.einsum("kgcd,kbd->kgcb", q,
-                       kb.astype(jnp.float32),
+            kb = _dequant(kb, ks_ref[0], jnp.float32)
+            vb = _dequant(vb, vs_ref[0], jnp.float32)
+        kb = _repeat_heads(kb.astype(jnp.float32), h // hp)
+        vb = _repeat_heads(vb.astype(jnp.float32), h // hp)
+        s = jnp.einsum("hcd,hbd->hcb", q_ref[0].astype(jnp.float32), kb,
                        preferred_element_type=jnp.float32) / np.sqrt(d)
         key_pos = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (c, bs), 1)
-        mask = ((key_pos <= pos[:, None])
-                & (blk != NULL_BLOCK))[None, None]    # (1, 1, C, bs)
+        mask = (key_pos <= _pos_matrix(pos_ref, b, c, (c, bs), 0))[None]
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m_run, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # on an all-masked prefix both maxes sit at the finite NEG_INF,
-        # so m_run - m_new == 0 and corr == 1 exactly — the carry stays
+        # so m_prev - m_new == 0 and corr == 1 exactly — the carry stays
         # untouched instead of decaying through exp(-1e9)
-        corr = jnp.exp(m_run - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-        l_new = l_run * corr + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("kgcb,kbd->kgcd", p, vb.astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * corr[..., None] + pv
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1,
+                                                 keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "hcb,hbd->hcd", p, vb, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    m0 = jnp.full((hp, g, c), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((hp, g, c), jnp.float32)
-    acc0 = jnp.zeros((hp, g, c, d), jnp.float32)
-    _, l_f, acc_f = jax.lax.fori_loop(0, n_live, body, (m0, l0, acc0))
-    # idle lanes (every key masked) land l == 0: divide by 1 and output
-    # an exact 0 — never NaN
-    l_safe = jnp.where(l_f > 0.0, l_f, 1.0)
-    o_ref[0] = (acc_f / l_safe[..., None]).reshape(h, c, d).astype(
-        o_ref.dtype)
+    @pl.when(j == m - 1)
+    def _flush():
+        # idle lanes (every key masked) land l == 0: divide by 1 and
+        # output an exact 0 — never NaN
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(
+            o_ref.dtype)
 
 
 def ragged_paged_attention_v2(q, k_pool, v_pool, block_table,
                               q_positions, k_scale=None, v_scale=None,
                               interpret=None):
-    """Paged attention kernel v2: double-buffered block streaming with
-    a flash-style online softmax. Identical call contract to
+    """Paged attention kernel v2: block streaming with a flash-style
+    online softmax. Identical call contract to
     `ragged_paged_attention` (v1); the difference is the resource
-    shape — VMEM scratch is O(2 blocks) regardless of the table width
-    (`_v2_scratch_shapes`), and scores/softmax/PV accumulate in f32 for
-    EVERY pool dtype, with the output cast once at the end. v2 is
-    mathematically exact vs the reference but reorders its fp
-    reductions (per-block partial sums + rescales), so the tier-1 pin
-    is tight-allclose + argmax-identical rather than v1's bitwise."""
+    shape — VMEM is two block windows per pool plus the carry
+    (`_v2_scratch_shapes`) regardless of the table width, and
+    scores/softmax/PV accumulate in f32 for EVERY pool dtype, with the
+    output cast once at the end. v2 is mathematically exact vs the
+    reference but reorders its fp reductions (per-block partial sums +
+    rescales), so the tier-1 pin is tight-allclose + argmax-identical
+    rather than v1's bitwise."""
     global TRACE_COUNT, V2_TRACE_COUNT
     TRACE_COUNT += 1
     V2_TRACE_COUNT += 1
@@ -484,29 +474,13 @@ def ragged_paged_attention_v2(q, k_pool, v_pool, block_table,
         q, k_pool, v_pool, block_table, q_positions, k_scale, v_scale)
     if interpret is None:
         interpret = _interpret()
-
     out_dtype = q.dtype if quantized else v_pool.dtype
-    lane_spec = pl.BlockSpec((1, h, c, d),
-                             lambda b_, tbl, pos: (b_, 0, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    scratch = [pltpu.VMEM(shp, dt) for shp, dt in _v2_scratch_shapes(
-        hp, bs, d, k_pool.dtype, quantized)]
-    # one 2-slot semaphore array per streamed pool (k, v[, scales])
-    scratch += [pltpu.SemaphoreType.DMA((2,))
-                for _ in range(4 if quantized else 2)]
-    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quantized else [])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_table, q_positions
-        grid=(b,),
-        in_specs=[lane_spec] + [any_spec] * len(pools),
-        out_specs=lane_spec,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel_v2, bs=bs, m=m, h=h, hp=hp,
-                          d=d, quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, c, d), out_dtype),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), q_positions.astype(jnp.int32),
-      q, *pools)
+    scratch = _v2_scratch_shapes(h, c, d)
+    # carry + the f32 head-repeated views of one K and one V block
+    vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
+            + 4 * _padded_bytes((h, bs, d), jnp.float32))
+    return _paged_call("paged_attention_v2", _paged_kernel_v2, q,
+                       [k_pool, v_pool],
+                       [k_scale, v_scale] if quantized else [],
+                       block_table, q_positions, scratch, out_dtype,
+                       vmem, interpret)

@@ -33,7 +33,7 @@ class PreemptionHandler:
         """The signal handler body: flag only, no I/O, no locks."""
         self._flag.set()
         if signum == signal.SIGINT and self._installed:
-            # let a second ^C interrupt a wedged drain/save
+            # let a second ^C interrupt a stuck drain/save
             old = self._old.get(signal.SIGINT)
             if old is not None:
                 signal.signal(signal.SIGINT, old)

@@ -87,7 +87,7 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
                      reduce_fn, pools, tokens, positions, valid, tables,
                      per_column=False, sampling=False, mask=None,
                      rng=None, temperature=None, do_sample=None,
-                     top_k=None, top_p=None):
+                     top_k=None, top_p=None, in_shard_map=False):
     """The ONE fused prefill/decode step body (build_kv_step's math over
     (S, C) ragged lanes with paged KV), shared by the single-device and
     tensor-parallel fused steps exactly like gpt._prefill_forward:
@@ -99,7 +99,9 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
     onto the shared KV heads), and `reduce_fn`
     finishes the row-parallel o-proj / ffn-down contractions (identity
     single-device; one psum per sub-block under tp — the partial sums
-    those matmuls leave are the ONLY cross-shard state the step has).
+    those matmuls leave are the ONLY cross-shard state the step has);
+    `in_shard_map` is that same fact handed on to the paged_attention
+    dispatcher, which cannot see it from inside the trace.
 
     `per_column=False` (plain serving): each lane's LAST valid column
     is gathered before the lm-head projection — one (S, H) @ (H, V)
@@ -156,7 +158,8 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
             kp = write_block_kv(kp, k, bidx, off)
             vp = write_block_kv(vp, v, bidx, off)
         o = paged_attention(q.transpose(0, 2, 1, 3), kp, vp,
-                            tables, pos, k_scale=ks, v_scale=vs)
+                            tables, pos, k_scale=ks, v_scale=vs,
+                            in_shard_map=in_shard_map)
         o = o.transpose(0, 2, 1, 3).reshape(s, c, h_count * d)
         x = x + (reduce_fn(o @ w(lp, "wo")) + lp["bo"]).astype(x.dtype)
         hn = _ln(x, lp["ln2_s"], lp["ln2_b"])
@@ -375,7 +378,8 @@ class GPTServingModel:
             return _fused_step_body(
                 lp_all, cfg, block_size, h_loc, kv_loc, d,
                 lambda z: jax.lax.psum(z, axis),
-                pools, tokens, positions, valid, tables)
+                pools, tokens, positions, valid, tables,
+                in_shard_map=True)
 
         param_specs = jax.tree_util.tree_map(
             lambda ns: ns.spec, shardings)

@@ -69,7 +69,7 @@ class Constraint:
         returned array is cached and shared — callers must not mutate
         it. When NO token is allowed and the state is not accepting
         (an exhausted constraint), eos becomes the only escape so the
-        lane can retire instead of wedging."""
+        lane can retire instead of stalling."""
         key = (state, eos_id)
         row = self._row_cache.get(key)
         if row is not None:

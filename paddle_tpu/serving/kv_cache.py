@@ -103,7 +103,7 @@ KV_QMAX = 127.0         # symmetric int8 range; -128 is never produced,
 # so a silent fallback can never masquerade as a kernel win.
 # FALLBACK_REASONS mirrors the `serving.kernel.fallback{reason=...}`
 # labeled series so tests and get_stats can tell a deliberate pin
-# (pinned_off) from a degradation (unsupported, vmap_trace).
+# (pinned_off) from a degradation (unsupported).
 KERNEL_DISPATCHES = 0
 FALLBACK_DISPATCHES = 0
 FALLBACK_REASONS = {}
@@ -130,9 +130,9 @@ def gather_block_kv_pair(k_pool, v_pool, block_table):
     The two dense (B, H, M*bs, D) materializations themselves are the
     reference's inherent O(M*bs) HBM cost per lane per step — every
     decode iteration copies each request's FULL table width regardless
-    of its true length. That is exactly the traffic the Pallas kernel
-    (ops/pallas/paged.py) removes by walking the table in-kernel with a
-    per-lane early stop."""
+    of its true length. That is the traffic the Pallas kernel
+    (ops/pallas/paged.py) is built to remove by walking the table
+    in-kernel with a per-lane early stop (not measured on the chip)."""
     b, m = block_table.shape
     n, h, bs, d = k_pool.shape
     flat = block_table.reshape(-1)              # ONE index plan
@@ -339,38 +339,12 @@ def paged_kernel_supported(q, k_pool, v_pool, k_scale=None,
     return k_pool.dtype in (jnp.float32, jnp.bfloat16)
 
 
-def _transform_trace_kind(*operands):
-    """'vmap' / 'shard_map' when any operand is mid-transform trace,
-    else None. Raising inside such a trace surfaces as an opaque
-    transform-internals stack, so the dispatcher degrades to the
-    reference there instead (vmap additionally because batching a
-    PrefetchScalarGridSpec pallas_call is outside the kernel's TPU
-    contract — the CPU interpreter happens to cope, the compiled path
-    is unvalidated). shard_map traces with QUALIFYING operands still
-    take the kernel: that is the tensor-parallel serving hot path."""
-    from jax.interpreters import batching
-    for x in operands:
-        if isinstance(x, batching.BatchTracer):
-            return "vmap"
-        if type(x).__name__ == "ShardMapTracer":
-            return "shard_map"
-    # jit(shard_map(...)) — the tp serving hot path — hands the body
-    # plain DynamicJaxprTracers, not ShardMapTracers; what marks the
-    # context is the mesh axis bound in the axis env (the same state
-    # psum resolves against). The probe-by-name API is version-fenced,
-    # so degrade to None (plain-jit behavior) when it's absent.
-    nonempty = getattr(jax.core, "nonempty_axis_env_DO_NOT_USE", None)
-    if nonempty is not None and nonempty():
-        return "shard_map"
-    return None
-
-
 def _record_dispatch(kernel, reason=None, version=None):
     """Trace-time metrics: dispatch counters + the interpret-mode gauge
     land in the global registry so GenerationServer.get_stats() and the
     trace_report serving summary can prove the kernel engaged.
     Fallbacks carry a `reason` label (pinned_off / unsupported /
-    vmap_trace / unsupported_under_shard_map) on top of the unlabeled
+    unsupported_under_shard_map) on top of the unlabeled
     aggregate, so a dashboard can tell an operator pin from a silent
     degradation. Kernel dispatches carry the kernel GENERATION: a
     `version` label on `serving.kernel.traced` (and "reference" on the
@@ -417,7 +391,7 @@ def kernel_dispatch_stats():
 
 
 def paged_attention(q, k_pool, v_pool, block_table, q_positions,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, *, in_shard_map=False):
     """Paged attention dispatcher — the frozen serving contract.
 
     Routes to the Pallas ragged paged attention kernel
@@ -425,45 +399,36 @@ def paged_attention(q, k_pool, v_pool, block_table, q_positions,
     per-lane early stop, NULL block never read, bf16 KV with f32
     accumulation, int8 KV with the dequant fused into the VMEM gather)
     whenever `PADDLE_TPU_PAGED_KERNEL` allows it and the operands
-    qualify; otherwise falls back to `paged_attention_reference`, the
-    documented pure-JAX spec. int8 pools ride the SAME auto mode: the
-    scale pools travel as two extra operands and the decision happens
-    at TRACE time (shapes/dtypes are static under jit), so a compiled
-    fused step pays zero dispatch overhead.
+    qualify. `paged_attention_reference`, the documented pure-JAX spec,
+    runs only when the operator pinned it (mode off) or the operands do
+    not qualify — each with a labeled `serving.kernel.fallback` reason.
+    int8 pools ride the SAME auto mode: the scale pools travel as two
+    extra operands and the decision happens at TRACE time
+    (shapes/dtypes are static under jit), so a compiled fused step pays
+    zero dispatch overhead.
 
-    Transform traces degrade instead of dying: under a vmap trace the
-    kernel is never taken (batched pallas_call is outside its TPU
-    contract), and unsupported operands inside a vmap/shard_map trace
-    fall back with a labeled `serving.kernel.fallback` reason even in
-    force mode — a ValueError mid-transform-trace would surface as
-    transform internals, not as this dispatcher's message. Plain
-    force-mode misuse (no transform) still raises loudly."""
+    `in_shard_map` is a fact only the caller has: the tensor-parallel
+    fused step (GPTServingModel.build_fused_step) wraps this body in a
+    shard_map and says so. There, force mode with non-qualifying
+    operands falls back (reason unsupported_under_shard_map) instead of
+    raising — a ValueError mid-shard_map-trace surfaces as transform
+    internals, not as this dispatcher's message. Plain force-mode
+    misuse still raises loudly."""
     mode = paged_kernel_mode()
-    supported = paged_kernel_supported(q, k_pool, v_pool, k_scale,
-                                       v_scale)
-    transform = _transform_trace_kind(q, k_pool, v_pool, block_table,
-                                      q_positions)
-    # a deliberate operator pin dominates every other reason: off mode
-    # under a vmap trace is still pinned_off, so a dashboard alerting
-    # on non-pinned_off fallbacks never pages on the pin itself
     if mode == "off":
         _record_dispatch(kernel=False, reason="pinned_off")
         return paged_attention_reference(q, k_pool, v_pool, block_table,
                                          q_positions, k_scale, v_scale)
-    if transform == "vmap":
-        _record_dispatch(kernel=False, reason="vmap_trace")
-        return paged_attention_reference(q, k_pool, v_pool, block_table,
-                                         q_positions, k_scale, v_scale)
-    if not supported:
-        if mode == "force" and transform is None:
+    if not paged_kernel_supported(q, k_pool, v_pool, k_scale, v_scale):
+        if mode == "force" and not in_shard_map:
             raise ValueError(
                 "PADDLE_TPU_PAGED_KERNEL=1 but operands do not qualify "
                 f"(q {q.shape} {q.dtype}, pools {k_pool.shape} "
                 f"{k_pool.dtype}/{v_pool.dtype}, scales "
                 f"{'present' if k_scale is not None else 'absent'})")
         _record_dispatch(kernel=False,
-                         reason=f"unsupported_under_{transform}"
-                         if transform else "unsupported")
+                         reason="unsupported_under_shard_map"
+                         if in_shard_map else "unsupported")
         return paged_attention_reference(q, k_pool, v_pool, block_table,
                                          q_positions, k_scale, v_scale)
     from ..ops.pallas.paged import (ragged_paged_attention,

@@ -112,7 +112,7 @@ class _Entry:
         # counts the children currently spilled — an entry whose only
         # children are host-tier is still spill-eligible (the chain
         # stays walkable either way), which is what lets a whole chain
-        # drain to host leaf-first instead of wedging after one leaf.
+        # drain to host leaf-first instead of stalling after one leaf.
         self.tier = "device"
         self.host_block = None
         self.host_children = 0

@@ -18,7 +18,7 @@ into the existing death taxonomy:
 - RPC timeout: the worker is HUNG-suspect — the proxy stops issuing
   step RPCs, its cached progress mark freezes with work pending, and
   the watchdog's stale-heartbeat verdict fires exactly as it does for
-  an in-process stall (teardown then SIGKILLs the wedged pid);
+  an in-process stall (teardown then SIGKILLs the hung pid);
 - a worker-side engine fault (NonFiniteError) travels back
   structurally (var/step/bad_vars/bad_rids) and is re-raised so the
   poison-quarantine lineage accounting sees the same exception shape
@@ -48,7 +48,7 @@ import numpy as np
 from .transport import RpcClient, RpcTimeout, TransportError
 
 # every live worker Popen, for the `proc` test fixture's
-# kill-on-teardown sweep — a wedged worker must never outlive its test
+# kill-on-teardown sweep — a hung worker must never outlive its test
 _LIVE_WORKERS = []
 _LIVE_LOCK = threading.Lock()
 
@@ -361,7 +361,7 @@ class WorkerProxy:
 
     def step(self):
         if self._closed or self._suspect_hung:
-            # hung-suspect: stop calling a wedged worker — the cached
+            # hung-suspect: stop calling a hung worker — the cached
             # progress mark freezes with work pending and the watchdog
             # takes it from here
             return False
@@ -556,6 +556,26 @@ def _repo_root():
         os.path.dirname(os.path.abspath(__file__))))
 
 
+def _check_one_process_per_chip(wenv):
+    """A TPU chip belongs to the first process that initializes the
+    backend: a parent that has touched jax holds it, and a child that
+    needs it then fails or hangs. Refuse that spawn here, by name,
+    instead of waiting out the ready-handshake timeout. A child pinned
+    to the cpu platform needs no chip."""
+    if wenv.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    import jax
+    from jax._src import xla_bridge
+    if (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu"):
+        raise RuntimeError(
+            "one process per chip: this process has initialized the "
+            "TPU backend and holds the chip, so a subprocess worker "
+            "could never reach it. On a one-chip machine run replicas "
+            "in-process (make_checkpoint_spawn); a subprocess fleet "
+            "needs a parent that stays off jax and one chip per worker")
+
+
 def spawn_worker(spec, *, chaos=None, spawn_timeout_s=180.0,
                  rpc_timeout_s=30.0, retries=3, backoff_s=0.02,
                  env=None):
@@ -565,11 +585,12 @@ def spawn_worker(spec, *, chaos=None, spawn_timeout_s=180.0,
     crash-loop breaker counts that exactly like a failed in-process
     spawn."""
     from .worker import READY_PREFIX
+    wenv = dict(os.environ if env is None else env)
+    _check_one_process_per_chip(wenv)
     fd, spec_path = tempfile.mkstemp(prefix="ptworker_",
                                      suffix=".json")
     with os.fdopen(fd, "w") as f:
         json.dump(spec, f)
-    wenv = dict(os.environ if env is None else env)
     pypath = wenv.get("PYTHONPATH", "")
     root = _repo_root()
     if root not in pypath.split(os.pathsep):

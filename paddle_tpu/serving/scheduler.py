@@ -947,7 +947,7 @@ class ContinuousBatchingScheduler:
                 got = self._cache.allocate(1)
             if got is None:
                 # last resort: park THIS lane — its host pledge
-                # guarantees the space, and parked beats wedged
+                # guarantees the space, and parked beats stuck
                 if not slot.prefilling:
                     self._preempt_slot(sid)
                 return False

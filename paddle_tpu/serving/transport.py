@@ -32,7 +32,7 @@ dead/hung/slow taxonomy, serving/remote.py):
 - connection refused/reset/EOF → bounded exponential-backoff retries,
   then ``TransportError``  → the replica is DEAD;
 - socket timeout → ``RpcTimeout`` immediately (no retry — re-calling a
-  wedged worker just blocks again) → the replica is HUNG-suspect;
+  hung worker just blocks again) → the replica is HUNG-suspect;
 - worker-side exception → ``RemoteError`` carrying the peer's exception
   type + message (re-raised as the matching builtin when unambiguous).
 """

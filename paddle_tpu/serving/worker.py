@@ -391,7 +391,7 @@ class WorkerHost:
             srv.run_until_idle()
         try:
             srv.close(drain=False)
-        except Exception:       # noqa: BLE001 — exit must not wedge
+        except Exception:       # noqa: BLE001 — exit must not hang
             pass
         if exit:
             self.exit_event.set()
@@ -471,6 +471,8 @@ def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     with open(argv[0]) as f:
         spec = json.load(f)
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     server = build_server(spec)
     host = WorkerHost(server)
     http_port = None

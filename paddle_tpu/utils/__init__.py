@@ -2,7 +2,6 @@
 determinism, memory introspection, self-test, model stats."""
 
 from . import debugger
-from . import device_lock
 from . import image_util
 from . import plot
 from . import show_pb

@@ -257,7 +257,7 @@ def test_evict_spills_chain_and_claim_materializes():
 
     # leaf-first drain: the child spills, THEN the parent (its only
     # child is host-tier, so it is spill-eligible — the chain drains
-    # instead of wedging after one leaf)
+    # instead of stalling after one leaf)
     assert idx.evict_lru() == blocks[1]
     assert idx.evict_lru() == blocks[0]
     assert idx.counts["spills"] == 2 and idx.host_entry_count() == 2

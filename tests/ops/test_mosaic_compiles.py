@@ -1,0 +1,117 @@
+"""The Pallas kernels COMPILE for a TPU v5e — checked here, without a
+chip. libtpu can describe a topology it has no devices for, and jax can
+lower and compile against it ahead of time, so a Mosaic refusal (an
+unaligned DMA slice, a matmul it cannot type) fails tier-1 instead of
+the first chip run. Whether the compiled kernel is RIGHT is
+`tests_tpu/`'s question, on the chip.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from paddle_tpu.ops import attention_ops
+from paddle_tpu.ops.pallas import flash, paged
+
+pytestmark = pytest.mark.pallas
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:      # noqa: BLE001 — no libtpu, no test
+        pytest.skip(f"no compile-only TPU topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    return v5e_2x2[0]
+
+
+def _compile(dev, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=SingleDeviceSharding(dev))
+            for s, d in shapes]
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("kgrid", ["0", "1"], ids=["loop", "kgrid"])
+def test_flash_fwd_bwd_compile_at_gpt_geometry(v5e, kgrid, monkeypatch):
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    monkeypatch.setenv("PT_FLASH_KGRID", kgrid)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash.flash_attention(
+            *a, causal=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    calls = _compile(v5e, grads, *[((1, 12, 1024, 64), jnp.float32)] * 3)
+    suffix = "_kgrid" if kgrid == "1" else ""
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert any(f"{name}{suffix})" in c for c in calls), (name, calls)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("version", ["v1", "v2"])
+def test_paged_kernels_compile_at_smoke_geometry(v5e, version, pool):
+    """GPT 12x64 heads, the engine's defaults: 4 slots x 4-token chunks,
+    16-token blocks, a 64-block table — head_dim 64 is HALF a lane
+    tile, the geometry hand-rolled DMAs could not slice."""
+    fn = (paged.ragged_paged_attention if version == "v1"
+          else paged.ragged_paged_attention_v2)
+    s, h, c, d, bs, m = 4, 12, 4, 64, 16, 64
+    n = 1 + s * m
+    pdt = jnp.int8 if pool == "int8" else jnp.bfloat16
+    shapes = [((s, h, c, d), jnp.bfloat16), ((n, h, bs, d), pdt),
+              ((n, h, bs, d), pdt), ((s, m), jnp.int32),
+              ((s, c), jnp.int32)]
+    if pool == "int8":
+        shapes += [((n, h, bs), jnp.float32)] * 2
+    calls = _compile(
+        v5e, lambda *a: fn(*a[:5], *a[5:], interpret=False), *shapes)
+    assert len(calls) == 1 and f"paged_attention_{version}" in calls[0]
+
+
+@pytest.mark.parametrize("nested", [False, True],
+                         ids=["gspmd", "in_callers_shard_map"])
+def test_flash_compiles_under_an_executor_mesh(v5e_2x2, nested,
+                                               monkeypatch):
+    """`dot_product_attention` under a four-chip `with mesh:`, forward
+    and backward. Left to GSPMD the lowering dies ("Mosaic kernels
+    cannot be automatically partitioned"), so the op wraps the kernel in
+    a shard_map; inside a caller's own full-mesh shard_map (the
+    pipeline forward) it must not wrap again. The CPU tests of both
+    (tests/parallel/test_flash_on_mesh.py) run the interpreted kernel,
+    which is plain XLA — only a TPU lowering sees the difference."""
+    import numpy as np
+
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH", "1")
+    mesh = Mesh(np.array(v5e_2x2).reshape(2, 2), ("dp", "pp"))
+    spec = P("dp")
+
+    def loss(q):
+        o = attention_ops.dot_product_attention(q, q, q, causal=True)
+        return o.astype(jnp.float32).sum()
+
+    def fn(q):
+        if not nested:
+            return jax.grad(loss)(q)
+        return jax.shard_map(jax.grad(loss), mesh=mesh, in_specs=spec,
+                             out_specs=spec, check_vma=False)(q)
+
+    arg = jax.ShapeDtypeStruct((4, 12, 1024, 64), jnp.float32,
+                               sharding=NamedSharding(mesh, spec))
+    with mesh:
+        text = jax.jit(fn).trace(arg).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert any(name in c for c in calls), (name, calls)

@@ -224,37 +224,57 @@ def test_bad_env_value_raises(monkeypatch):
         kvc.paged_kernel_mode()
 
 
-@pytest.mark.parametrize("env", [None, "1"], ids=["auto", "force"])
-def test_dispatch_vmap_trace_falls_back_with_reason(monkeypatch, env):
-    """ISSUE 9 satellite: a vmap trace must never take the kernel —
-    batching a PrefetchScalarGridSpec pallas_call is outside its TPU
-    contract (the CPU interpreter happens to cope, the compiled path
-    is unvalidated) — and must not raise mid-trace even under force.
-    The fallback lands with the distinct vmap_trace reason label so a
-    dashboard can tell this degradation from an operator pin."""
+def test_dispatch_takes_the_shard_map_fact_as_an_argument(monkeypatch):
+    """The dispatcher does not inspect tracers: whether it runs inside
+    a shard_map is a fact its caller passes (`in_shard_map=`, set by
+    GPTServingModel.build_fused_step's tensor-parallel branch). With
+    it, force mode + non-qualifying operands falls back under the
+    unsupported_under_shard_map label; without it the same call
+    raises. A plain jit(vmap(...)) trace gets no special treatment —
+    it takes the kernel like any other trace."""
     from paddle_tpu.observability.metrics import global_registry
-    if env is None:
-        monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
-    else:
-        monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", env)
+    monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
     q, k_pool, v_pool, tables, pos = make_case(b=2, c=1, m=3, seed=8)
-    qq = jnp.stack([q, q + 1])
-    k0, f0 = kvc.KERNEL_DISPATCHES, kvc.FALLBACK_DISPATCHES
+    k16, v16 = k_pool.astype(jnp.float16), v_pool.astype(jnp.float16)
     reason = global_registry().counter(
-        "serving.kernel.fallback").labels(reason="vmap_trace")
+        "serving.kernel.fallback").labels(
+        reason="unsupported_under_shard_map")
     r0 = reason.value()
-    out = jax.jit(jax.vmap(
-        lambda a: kvc.paged_attention(a, k_pool, v_pool, tables,
-                                      pos)))(qq)
-    assert kvc.KERNEL_DISPATCHES == k0      # kernel NOT taken
-    assert kvc.FALLBACK_DISPATCHES == f0 + 1
+    with pytest.raises(ValueError, match="do not qualify"):
+        kvc.paged_attention(q, k16, v16, tables, pos)
+    out = kvc.paged_attention(q, k16, v16, tables, pos,
+                              in_shard_map=True)
     assert reason.value() == r0 + 1
-    assert kvc.kernel_dispatch_stats()["fallback_reasons"][
-        "vmap_trace"] >= 1
-    ref = jax.jit(jax.vmap(
-        lambda a: kvc.paged_attention_reference(
-            a, k_pool, v_pool, tables, pos)))(qq)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(kvc.paged_attention_reference(
+            q, k16, v16, tables, pos)))
+    k0, f0 = kvc.KERNEL_DISPATCHES, kvc.FALLBACK_DISPATCHES
+    jax.jit(jax.vmap(lambda a: kvc.paged_attention(
+        a, k_pool, v_pool, tables, pos)))(jnp.stack([q, q + 1]))
+    assert (kvc.KERNEL_DISPATCHES, kvc.FALLBACK_DISPATCHES) == \
+        (k0 + 1, f0)
+
+
+def test_no_module_sniffs_jax_tracer_internals():
+    """The jax 0.9 upgrade removed `jax.interpreters.batching.
+    BatchTracer` and took every serving path down with it. Nothing
+    under paddle_tpu/ may reach into jax.interpreters or the axis-env
+    probes again."""
+    import os
+    import re
+    root = os.path.dirname(os.path.abspath(kvc.__file__))
+    root = os.path.dirname(root)
+    pat = re.compile(r"jax\.interpreters|from jax import interpreters"
+                     r"|nonempty_axis_env|get_axis_env")
+    hits = []
+    for dirpath, _dirs, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(dirpath, f)
+                with open(path) as fh:
+                    if pat.search(fh.read()):
+                        hits.append(os.path.relpath(path, root))
+    assert not hits, hits
 
 
 def test_dispatch_fallback_reason_labels(monkeypatch):

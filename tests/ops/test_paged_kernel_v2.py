@@ -194,18 +194,21 @@ def test_v2_null_block_poison_stays_finite():
 # white-box: the O(2-block) VMEM contract
 # ---------------------------------------------------------------------------
 
-def test_v2_scratch_is_two_slots_and_m_independent():
-    """The streaming claim, pinned structurally: every v2 VMEM buffer
-    leads with exactly 2 slots and no dimension involves the table
-    width M (the function cannot even be passed one). v1's scratch by
+def test_v2_scratch_is_m_independent():
+    """The streaming claim, pinned structurally: v2's VMEM scratch is
+    the online-softmax carry only — no dimension involves the table
+    width M (the function cannot even be passed one); the K/V windows
+    are the pipeline's two block-sized buffers. v1's scratch by
     contrast scales linearly with M."""
-    dense = paged._v2_scratch_shapes(2, 8, 16, jnp.bfloat16, False)
-    assert dense == [((2, 2, 8, 16), jnp.bfloat16)] * 2
-    quant = paged._v2_scratch_shapes(3, 4, 8, jnp.int8, True)
-    assert quant == [((2, 3, 4, 8), jnp.int8)] * 2 + \
-        [((2, 3, 4), jnp.float32)] * 2
-    for shape, _dt in dense + quant:
-        assert shape[0] == 2
+    h, c, d = 4, 2, 16
+    assert paged._v2_scratch_shapes(h, c, d) == [
+        ((h, c, 1), jnp.float32), ((h, c, 1), jnp.float32),
+        ((h, c, d), jnp.float32)]
+    narrow = paged._v1_scratch_shapes(2, 8, d, 6, jnp.bfloat16,
+                                      jnp.bfloat16, False)
+    wide = paged._v1_scratch_shapes(2, 8, d, 24, jnp.bfloat16,
+                                    jnp.bfloat16, False)
+    assert [s[0][1] for s in wide] == [4 * s[0][1] for s in narrow]
     # and the dispatcher's v1 estimate DOES scale with M — the gap auto
     # mode routes on
     _q, k_pool, _v, tables, _p = make_case(m=6)

@@ -268,9 +268,10 @@ def test_force_mode_unsupported_under_shard_map_falls_back(monkeypatch):
     """ISSUE 9 satellite: force mode + non-qualifying operands INSIDE a
     jit(shard_map) trace must fall back with the distinct
     unsupported_under_shard_map reason label instead of raising
-    mid-trace. The tracers there are plain DynamicJaxprTracers, not
-    ShardMapTracers — the mesh axis bound in the axis env (what psum
-    resolves against) is what marks the context."""
+    mid-trace. The dispatcher cannot see the shard_map from inside the
+    trace; the caller that wrapped it says so (`in_shard_map=True`,
+    what build_fused_step's tensor-parallel branch passes)."""
+    import functools
     from jax import shard_map
 
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
@@ -283,7 +284,8 @@ def test_force_mode_unsupported_under_shard_map_falls_back(monkeypatch):
         reason="unsupported_under_shard_map")
     r0 = reason.value()
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
-    fn = shard_map(kvc.paged_attention, mesh=mesh,
+    fn = shard_map(functools.partial(kvc.paged_attention,
+                                     in_shard_map=True), mesh=mesh,
                    in_specs=(P(None, "tp"), P(None, "tp"),
                              P(None, "tp"), P(), P()),
                    out_specs=P(None, "tp"), check_vma=False)

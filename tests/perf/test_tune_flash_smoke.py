@@ -1,12 +1,10 @@
 """CPU smoke of tools/tune_flash.py — the FULL tuner code path.
 
-The r4 hardware window burned 25 minutes on a tune_flash invocation that
-had never been smoke-tested end-to-end (perf/watch_log.txt 04:47:46:
-rc=1 in 1510s, empty artifact). This test runs the tuner main() as a
-subprocess — argparse, device init (cpu-pinned, under bench.py's
-watchdog), the fwd AND --backward sweep, winner selection, and the
-persist gate — on interpreter-sized shapes so the path can never again
-crash only on hardware.
+The tuner once failed on hardware in a path no test had run
+end-to-end and left an empty artifact. This test runs the tuner main()
+as a subprocess — argparse, device init (cpu-pinned), the fwd AND
+--backward sweep, winner selection, and the persist gate — on
+interpreter-sized shapes so the path cannot crash only on hardware.
 """
 
 import json
@@ -33,7 +31,7 @@ def _run_tuner(tmp_path, *extra):
 
 
 def test_tuner_backward_full_path(tmp_path):
-    """The exact watcher configuration (--backward) end-to-end on cpu."""
+    """The full configuration (--backward) end-to-end on cpu."""
     r = _run_tuner(tmp_path, "--backward")
     assert r.returncode == 0, (r.stdout, r.stderr)
     assert "best: " in r.stdout, (r.stdout, r.stderr)
@@ -45,7 +43,7 @@ def test_tuner_backward_full_path(tmp_path):
 
 def test_tuner_failure_writes_structured_record(tmp_path):
     """When no config can run, stdout carries a parseable failure record
-    — never a 0-byte artifact (the r4 failure shape)."""
+    — never a 0-byte artifact."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PADDLE_TPU_FLASH_TUNED_FILE"] = str(tmp_path / "tuned.json")

@@ -20,22 +20,14 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 # chip peak table shared with the end-to-end bench
-from bench import PEAK_TFLOPS  # noqa: E402
+from bench import _peak_flops  # noqa: E402
 
 
 def _peak(kind):
     if "cpu" in kind.lower():
         return None      # no meaningful MXU peak, even with the env var
                          # still exported from an earlier TPU session
-    env = os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        return float(env) * 1e12     # malformed value raises, by design
-    kind = kind.lower()
-    best = None
-    for sub, tf in PEAK_TFLOPS:      # table lookup only: an unknown chip
-        if sub in kind:              # shows '?', never a guessed peak
-            best = tf
-    return best * 1e12 if best else None
+    return _peak_flops(kind)    # an unknown chip is an error there
 
 
 def _time(fn, *args, steps=20):
@@ -60,11 +52,9 @@ def main():
     args = ap.parse_args()
 
     import jax
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    from paddle_tpu.utils import device_lock
-    device_lock.ensure_device_lock()    # no-op on cpu; blocks, not wedges
     import jax.numpy as jnp
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     dtype = jnp.dtype(args.dtype)
     dev = jax.devices()[0]

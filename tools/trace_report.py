@@ -16,9 +16,8 @@ Usage:
 
 --demo runs a tiny cached 3-step training loop on CPU, writes
 `trace_sample.timeline.json` + `metrics_sample.json` into --out-dir,
-then reports on them — the zero-to-trace smoke path, also invoked by
-tools/bench_watch.py so every hardware window refreshes the committed
-sample under perf/.
+then reports on them — the zero-to-trace smoke path; the committed
+sample under perf/ comes from it.
 """
 
 import argparse
@@ -653,8 +652,7 @@ def run_demo(out_dir):
     dump["fleet_stats"] = fleet_stats
     dump["signals_sample"] = signals_sample
     with open(metrics_path, "w") as f:
-        # single line: perf/ artifacts are parsed line-wise by
-        # tools/bench_watch.py's _artifact_ok
+        # single line: perf/ artifacts are parsed line-wise
         json.dump(dump, f, sort_keys=True)
         f.write("\n")
     return trace_base + ".timeline.json", metrics_path
