@@ -36,6 +36,7 @@ from .framework import (Program, Variable, grad_var_name, BACKWARD_MARKER,
                         default_main_program)
 from .. import ops as ops_registry
 from ..observability import ComponentStats
+from ..observability.tracing import get_recorder
 
 
 def _canon_host(name, a):
@@ -801,18 +802,23 @@ class Executor:
             feed_var_name="feed", fetch_var_name="fetch", return_numpy=True,
             use_program_cache=True):
         t_step0 = time.perf_counter()
-        fetches, guard = self._dispatch(program, feed, fetch_list, scope,
-                                        use_program_cache)
-        # sentinel check BEFORE conversion: sync semantics put the
-        # NonFiniteError in the caller's hands, not in the fetch copies
-        self._check_guard(guard)
-        with self._stats.span("executor.fetch", "executor.span.fetch_ms"):
-            if return_numpy:
-                out = [np.asarray(f) for f in fetches]
-            else:
-                out = list(fetches)
-        self._stats.observe("executor.step_ms",
-                            (time.perf_counter() - t_step0) * 1e3)
+        # the parent of key_build / trace / compile / execute / fetch:
+        # one step's host time as one duration
+        with get_recorder().span("executor.run", cat="executor"):
+            fetches, guard = self._dispatch(program, feed, fetch_list,
+                                            scope, use_program_cache)
+            # sentinel check BEFORE conversion: sync semantics put the
+            # NonFiniteError in the caller's hands, not in the fetch
+            # copies
+            self._check_guard(guard)
+            with self._stats.span("executor.fetch",
+                                  "executor.span.fetch_ms"):
+                if return_numpy:
+                    out = [np.asarray(f) for f in fetches]
+                else:
+                    out = list(fetches)
+            self._stats.observe("executor.step_ms",
+                                (time.perf_counter() - t_step0) * 1e3)
         return out
 
     def run_async(self, program=None, feed=None, fetch_list=None,

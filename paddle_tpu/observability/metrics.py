@@ -143,7 +143,14 @@ METRIC_SPECS = [
     ("serving.iterations", "counter",
      "scheduler iterations (one fused prefill/decode step each)"),
     ("serving.step_ms", "histogram",
-     "wall ms of one serving iteration (plan + fused step + commit)"),
+     "wall ms of one serving iteration from the step's feed to the end "
+     "of commit (plan() is not in it)"),
+    ("serving.valid_columns", "counter",
+     "columns of the fused step's (slots, chunk) grid that carried a "
+     "token"),
+    ("serving.padded_columns", "counter",
+     "columns of the fused step's (slots, chunk) grid that were "
+     "padding: computed, and thrown away"),
     ("serving.generated_tokens", "counter",
      "tokens emitted across all requests (tokens/s numerator)"),
     ("serving.prefill_tokens", "counter",

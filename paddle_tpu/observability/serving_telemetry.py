@@ -857,6 +857,16 @@ class ServingTelemetry:
             st.slot = slot
             st.blocks = int(blocks)
             st.queue_wait_ms = float(queue_wait_ms)
+        if st.sampled and self._rec.enabled:
+            # the tree lands only at retirement; the admission lands
+            # now, so a short capture holds every admission that fell in
+            # it, on the track and under the ids the tree will carry
+            self._rec.instant(
+                "serving.admit", cat="serving.request",
+                args=dict(self._base_args(st), slot=slot,
+                          iteration=iteration, blocks=st.blocks,
+                          queue_wait_ms=st.queue_wait_ms),
+                ts=st.admit_perf, track=f"serving slot {slot}")
 
     def on_prefill_chunk(self, rid, iteration, ntokens):
         # lock-free: dict.get is GIL-atomic and every mutation of an
@@ -924,19 +934,24 @@ class ServingTelemetry:
             self._traced_local += 1
 
     # -- span-tree emission ------------------------------------------------
-    def _emit_tree(self, st, end_iteration, outcome, reason, prompt_len,
-                   generated):
-        rec = self._rec
-        end = time.perf_counter()
-        track = (f"serving slot {st.slot}" if st.slot is not None
-                 else "serving queue")
-        # fleet correlation rides EVERY span of the tree: the merged
+    @staticmethod
+    def _base_args(st):
+        # fleet correlation rides EVERY event of a request: the merged
         # fleet dump is queried by trace_id, and a child span must be
         # attributable without walking back to its root
         base = {"rid": st.rid}
         if st.trace_id is not None:
             base["trace_id"] = st.trace_id
             base["hop"] = st.hop
+        return base
+
+    def _emit_tree(self, st, end_iteration, outcome, reason, prompt_len,
+                   generated):
+        rec = self._rec
+        end = time.perf_counter()
+        track = (f"serving slot {st.slot}" if st.slot is not None
+                 else "serving queue")
+        base = self._base_args(st)
         root_args = dict(base, outcome=outcome, finish_reason=reason,
                          prompt_len=prompt_len, generated=generated,
                          admit_iteration=st.admit_iteration,

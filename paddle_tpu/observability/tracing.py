@@ -9,8 +9,20 @@ because ops/__init__.py wraps every dispatch in jax.named_scope, the XLA
 HLO op names in that device trace line up with the framework spans here.
 
 The recorder is OFF by default: a disabled span() costs one attribute
-check, so the Executor can call it unconditionally on the hot path.
+check and returns one shared no-op context manager, so the Executor and
+the serving engine call it unconditionally on the hot path.
 profiler.start_profiler() (or recorder.start()) turns it on.
+
+One API, two sinks, one clock. While enabled, span() also enters a
+`jax.profiler.TraceAnnotation` of the same name (scalar args as its
+stats), so every span is a host event in the profiler's trace too, on
+the timeline of the device planes, and an idle gap of the device can be
+laid over what the host was doing in it. start() emits one
+`trace.clock_anchor` annotation carrying the `perf_counter` reading
+taken inside it (and the same instant as recorder time), so events
+recorded retroactively (complete(), instant(): the request trees) map
+onto the profiler's clock by one offset. With no profiler session
+running an annotation costs a flag check.
 
 The event buffer is a bounded ring (drop-oldest): a long-lived
 GenerationServer with tracing on keeps the most recent
@@ -25,16 +37,19 @@ per decode slot instead of interleaving on the engine thread's row.
 """
 
 import collections
-import contextlib
 import json
 import os
 import threading
 import time
 import warnings
 
-__all__ = ["TraceRecorder", "get_recorder", "DEFAULT_MAX_EVENTS"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["TraceRecorder", "get_recorder", "DEFAULT_MAX_EVENTS",
+           "CLOCK_ANCHOR"]
 
 DEFAULT_MAX_EVENTS = 200_000
+CLOCK_ANCHOR = "trace.clock_anchor"
 
 
 def _default_max_events():
@@ -54,6 +69,64 @@ def _default_max_events():
             RuntimeWarning, stacklevel=2)
         return DEFAULT_MAX_EVENTS
     return n
+
+
+class _NoSpan:
+    """What a disabled span() returns: one shared object, no state."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def _scalars(args):
+    """The args a profiler annotation can carry as stats."""
+    return {k: int(v) if isinstance(v, bool) else v
+            for k, v in args.items()
+            if isinstance(v, (int, float, str))}
+
+
+class _Span:
+    """One live span: the profiler's annotation outside, the recorder's
+    own stamps just inside it, so the two sinks agree to the microsecond
+    on name, start and duration. A dict of args is known as the span
+    opens; a callable is called once as the span closes (it can report
+    what the region found), and not at all if the region raised."""
+
+    __slots__ = ("_rec", "_name", "_cat", "_args", "_ann", "_t0")
+
+    def __init__(self, rec, name, cat, args):
+        self._rec, self._name, self._cat, self._args = rec, name, cat, args
+
+    def __enter__(self):
+        args = self._args
+        self._ann = (TraceAnnotation(self._name, **_scalars(args))
+                     if type(args) is dict and args
+                     else TraceAnnotation(self._name))
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return None
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        args = self._args
+        if callable(args):
+            args = args() if exc_type is None else None
+            if args:
+                self._ann.set_metadata(**_scalars(args))
+        self._ann.__exit__(exc_type, exc, tb)
+        rec = self._rec
+        if rec._enabled:        # capture may have stopped mid-span: drop
+            rec._emit(self._name, self._cat, (self._t0 - rec._t0) * 1e6,
+                      (t1 - self._t0) * 1e6, args)
+        return False
 
 
 class TraceRecorder:
@@ -105,6 +178,12 @@ class TraceRecorder:
                         else float(origin))
             self._epoch0 = time.time()
             self._enabled = True
+        # lands in the profiler's trace only if its session is already
+        # running: start the profiler first, then the recorder
+        with TraceAnnotation(CLOCK_ANCHOR) as anchor:
+            now = time.perf_counter()
+            anchor.set_metadata(perf_counter_ns=int(now * 1e9),
+                                recorder_ts_us=(now - self._t0) * 1e6)
 
     def stop(self):
         self._enabled = False
@@ -119,20 +198,15 @@ class TraceRecorder:
             return list(self._events)
 
     # -- recording ----------------------------------------------------------
-    @contextlib.contextmanager
     def span(self, name, cat="host", args=None):
-        """Time a region into a complete event. No-op while disabled."""
+        """Time a region into a complete event and a profiler annotation
+        of the same name. `args` is a dict, or a callable returning one:
+        it is called once, as the span closes, and only while enabled.
+        Disabled, this returns the shared no-op context manager and
+        touches nothing else."""
         if not self._enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            if self._enabled:   # capture may have stopped mid-span: drop
-                self._emit(name, cat, (t0 - self._t0) * 1e6,
-                           (t1 - t0) * 1e6, args)
+            return _NO_SPAN
+        return _Span(self, name, cat, args)
 
     def complete(self, name, start, end, cat="host", args=None, track=None):
         """Record a complete event from explicit perf_counter stamps.

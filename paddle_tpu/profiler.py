@@ -57,7 +57,6 @@ def start_profiler(state="All", tracer_option="Default",
     _profiler_state = state
     _trace_dir = trace_dir
     _timings.clear()
-    tracing.get_recorder().start()
     _jax_trace_active = False
     if state in ("GPU", "All"):
         try:
@@ -65,6 +64,8 @@ def start_profiler(state="All", tracer_option="Default",
             _jax_trace_active = True
         except Exception:
             _jax_trace_active = False
+    # after the device trace: the recorder's clock anchor has to land in it
+    tracing.get_recorder().start()
 
 
 def stop_profiler(sorted_key=None, profile_path='/tmp/profile'):
@@ -156,15 +157,15 @@ def profiler(state="All", sorted_key=None, profile_path='/tmp/profile',
 
 @contextlib.contextmanager
 def record_event(name):
-    """Host-side timing of a region: feeds the report table, the Chrome
-    trace (when capturing), and the XLA device trace annotation. The
-    record lands even when the region raises — the trace recorder emits
-    its event in a finally, and the table must not disagree with it."""
+    """Host-side timing of a region: feeds the report table and, when
+    capturing, the Chrome trace and the XLA device trace (the recorder's
+    span writes both). The record lands even when the region raises —
+    the trace recorder emits its event on exit, and the table must not
+    disagree with it."""
     start = time.time()
     t0 = time.perf_counter()
     try:
-        with jax.profiler.TraceAnnotation(name), \
-                tracing.get_recorder().span(name, cat="user"):
+        with tracing.get_recorder().span(name, cat="user"):
             yield
     finally:
         _timings.append((name, time.perf_counter() - t0, start,

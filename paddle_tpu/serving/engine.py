@@ -828,6 +828,10 @@ class GenerationServer:
                                       _help("serving.iterations")),
             "step_ms": reg.histogram("serving.step_ms",
                                      _help("serving.step_ms")),
+            "valid_columns": reg.counter(
+                "serving.valid_columns", _help("serving.valid_columns")),
+            "padded_columns": reg.counter(
+                "serving.padded_columns", _help("serving.padded_columns")),
             "queue_depth": reg.gauge("serving.queue_depth",
                                      _help("serving.queue_depth")),
             "active_slots": reg.gauge("serving.active_slots",
@@ -1054,15 +1058,23 @@ class GenerationServer:
         pump the engine deterministically without the worker thread."""
         with self._step_lock:
             tel = self._tel
-            if tel is not None:
-                # before plan(): the iteration's deadline cancels fire
-                # inside plan and must land on THIS iteration's flight
-                # entry (plan() increments the counter if non-idle)
-                tel.begin_iteration(self._sched.iteration + 1)
-            admitted0 = self._sched.counts["admitted"]
+            rec = get_recorder()
             it0 = self._sched.iteration
-            plan = self._sched.plan()
-            self._publish_gauges()
+            # leaf spans (docs/serving.md): plan, feed, dispatch, fetch,
+            # commit, account tile the body of a non-idle iteration on
+            # this thread, so every idle gap of the device has a name
+            with rec.span("serving.plan", cat="serving",
+                          args=lambda: {"iteration": self._sched.iteration,
+                                        "idle": plan is None}):
+                if tel is not None:
+                    # before plan(): the iteration's deadline cancels
+                    # fire inside plan and must land on THIS iteration's
+                    # flight entry (plan() increments the counter if
+                    # non-idle)
+                    tel.begin_iteration(it0 + 1)
+                admitted0 = self._sched.counts["admitted"]
+                plan = self._sched.plan()
+                self._publish_gauges()
             if plan is None:
                 it = self._sched.iteration
                 if self._chaos is not None and it > it0:
@@ -1097,154 +1109,187 @@ class GenerationServer:
             # pre-step occupancy rides the plan (built inside plan()'s
             # slot loop — no second scheduler-lock round-trip)
             lanes = plan.lanes_detail
-            rec = get_recorder()
+            leaf = {"iteration": it} if rec.enabled else None
             t0 = time.perf_counter()
             with rec.span("serving.iteration", cat="serving",
-                          args={"iteration": it,
-                                "lanes": len(plan.slot_ids),
-                                "prefill_tokens": plan.prefill_tokens}):
-                if self._chaos is not None:
-                    # content-addressed poison: a STANDING plan keyed
-                    # to a request's prompt bytes, so the fault follows
-                    # the request's failover replay onto every replica
-                    # it lands on (the quarantine cascade seed). Each
-                    # plan entry applies (and counts) at most once per
-                    # ENGINE — the fault kills the server the same
-                    # iteration, so fired == replica deaths caused,
-                    # never inflated by a lane sitting poisoned across
-                    # iterations
-                    for pi, (pp, pl) in enumerate(
-                            self._chaos.prompt_poison_plan()):
-                        if pi in self._prompt_poison_fired:
-                            continue
-                        blk = self._sched.lane_block_for_prompt(pp)
-                        if blk is not None:
-                            self._nan_block(pl, blk)
-                            self._prompt_poison_fired.add(pi)
-                            self._chaos.prompt_poison_applied()
-                    poison_layer = self._chaos.serving_poison_at(it)
-                    if poison_layer is not None:
-                        if self._poison_kv(poison_layer, lanes):
-                            self._chaos.serving_poison_applied()
-                        else:
-                            # no lane past pos 0 yet: its block would be
-                            # fully overwritten by its own prefill write
-                            # this iteration — defer, don't no-op
-                            self._chaos.poison_serving_at(
-                                it + 1, poison_layer)
-                # speculative mode: the draft step runs EVERY iteration
-                # (its KV must track prefill chunks too, not just
-                # decode lanes) and its proposals land in plan.tokens
-                # columns 1..q-1 before the fused step verifies them
-                draft_logps = None
-                if self._draft is not None:
-                    draft_logps = self._run_draft(plan)
-                args = (jnp.asarray(plan.tokens),
-                        jnp.asarray(plan.positions),
-                        jnp.asarray(plan.valid),
-                        jnp.asarray(plan.tables))
-                if self._strategies:
-                    # mask/rng/temperature/do_sample/top_k/top_p are
-                    # DATA with constant shapes — the signature set
-                    # below still collapses to one entry
-                    args = args + self._strategies_args(plan, it)
-                self._signatures.add(
-                    tuple((a.shape, str(a.dtype)) for a in args))
-                # the cache object always holds the LIVE device pools:
-                # the functional update replaces them in place of the
-                # consumed ones (keeping both would pin 2x the KV HBM)
-                if self._kernel_engaged is None:
-                    # first fused call is about to TRACE: serialize it
-                    # against other servers' first traces and snapshot
-                    # the dispatch mode + counters right around it, so
-                    # the delta covers exactly THIS trace
-                    with GenerationServer._first_trace_lock:
-                        self._kernel_mode = _kvc.paged_kernel_mode()
-                        k0, f0 = (_kvc.KERNEL_DISPATCHES,
-                                  _kvc.FALLBACK_DISPATCHES)
-                        v0 = dict(_kvc.KERNEL_VERSIONS)
-                        out = self._fused(self.cache.pools, *args)
-                        self._kernel_counts = (
-                            _kvc.KERNEL_DISPATCHES - k0,
-                            _kvc.FALLBACK_DISPATCHES - f0)
-                        # which kernel GENERATION this trace's
-                        # dispatches took (None if none engaged)
-                        dv = [v for v in ("v1", "v2")
-                              if _kvc.KERNEL_VERSIONS.get(v, 0)
-                              > v0.get(v, 0)]
-                        self._kernel_version = (
-                            dv[0] if len(dv) == 1 else
-                            ("mixed" if dv else None))
-                    self._check_kernel_engagement()
-                else:
-                    out = self._fused(self.cache.pools, *args)
-                # plain mode: (pools, ids (S,), logps (S,)) from the
-                # last-column step; spec mode adds fed_logps and every
-                # output is per-column (S, C)
-                self.cache.pools = out[0]
-                nxt, logps = np.asarray(out[1]), np.asarray(out[2])
-                if nxt.ndim == 1:
-                    # commit() reads per-column arrays; a broadcast
-                    # VIEW puts the last-valid-column value at every
-                    # column (a prefill lane reads col n-1, a decode
-                    # lane col 0 — both ARE that value), zero copies
-                    s, c = plan.tokens.shape
-                    nxt = np.broadcast_to(nxt[:, None], (s, c))
-                    logps = np.broadcast_to(logps[:, None], (s, c))
-                # target-logp-of-fed-token only matters to the
-                # rejection-sampled acceptance; don't pay its host
-                # transfer otherwise
-                fed = (np.asarray(out[3])
-                       if self._spec is not None
-                       and self._spec.mode == "rejection" else None)
-                # full logp rows (last output when the strategies step
-                # is compiled in): fork-time host sampling and beam
-                # re-ranking read them — transferred only when this
-                # plan actually has a group that needs them
-                rows = None
-                if self._strategies and plan.needs_rows:
-                    rows = np.asarray(
-                        out[4] if self._spec is not None else out[3])
-            # non-finite logits guard: one reduce on the hot path (a
-            # NaN/Inf anywhere makes the sum non-finite; idle lanes
-            # hold finite garbage); the per-slot triage only runs on a
-            # trip, BEFORE commit() streams garbage tokens to clients.
-            # math.isfinite on the extracted scalar beats np.isfinite's
-            # ufunc dispatch on this every-iteration path. The
-            # fail-stop is a safety feature and runs regardless of
-            # telemetry — only the flight-recorder dump needs it
-            if plan.slot_ids and not math.isfinite(float(logps.sum())):
-                if not np.all(np.isfinite(logps[plan.slot_ids])):
-                    self._on_engine_fault(plan, it, logps, lanes)
-            retired = self._sched.commit(plan, nxt, logps,
-                                         fed_logps=fed,
-                                         draft_logps=draft_logps,
-                                         rows=rows)
-            self._m["iterations"].inc()
-            step_ms = (time.perf_counter() - t0) * 1e3
-            self._m["step_ms"].observe(step_ms)
-            self._publish_gauges()
-            if tel is not None:
-                st = self._sched
-                # hot path: one ITER_FIELDS-order tuple per iteration
-                # (tuples of scalars are GC-untracked; per-iteration
-                # dicts next to a ~0.25 ms fused step kept promoting
-                # ring garbage into the older GC generations)
-                tel.end_iteration(it, (
-                    round(step_ms, 3),              # step_ms
-                    tuple(plan.slot_ids),           # lanes
-                    tuple(plan.emitting),           # emitting
-                    plan.prefill_tokens,
-                    st.counts["admitted"] - admitted0,
-                    tuple(r.request_id for r in retired),
-                    plan.queue_depth,
-                    len(plan.slot_ids),             # active_slots
-                    self.cache.num_free,            # blocks_free
-                    self.cache.num_used,            # blocks_in_use
-                    st.watermark_blocks,
-                    lanes,                          # lanes_detail
-                    self._kernel_info()))
+                          args=lambda: self._iteration_record(plan, it)):
+                with rec.span("serving.feed", cat="serving", args=leaf):
+                    if self._chaos is not None:
+                        self._apply_step_chaos(it, lanes)
+                    # speculative mode: the draft step runs EVERY
+                    # iteration (its KV must track prefill chunks too,
+                    # not just decode lanes) and its proposals land in
+                    # plan.tokens columns 1..q-1 before the fused step
+                    # verifies them: they are part of what is fed
+                    draft_logps = None
+                    if self._draft is not None:
+                        with rec.span("serving.draft", cat="serving",
+                                      args=leaf):
+                            draft_logps = self._run_draft(plan)
+                    args = (jnp.asarray(plan.tokens),
+                            jnp.asarray(plan.positions),
+                            jnp.asarray(plan.valid),
+                            jnp.asarray(plan.tables))
+                    if self._strategies:
+                        # mask/rng/temperature/do_sample/top_k/top_p are
+                        # DATA with constant shapes — the signature set
+                        # below still collapses to one entry
+                        args = args + self._strategies_args(plan, it)
+                    self._signatures.add(
+                        tuple((a.shape, str(a.dtype)) for a in args))
+                with rec.span("serving.dispatch", cat="serving",
+                              args=leaf):
+                    out = self._dispatch_fused(args)
+                    # the cache object always holds the LIVE device
+                    # pools: the functional update replaces them in
+                    # place of the consumed ones (keeping both would pin
+                    # 2x the KV HBM)
+                    self.cache.pools = out[0]
+                with rec.span("serving.fetch", cat="serving", args=leaf):
+                    nxt, logps, fed, rows = self._fetch_outputs(out, plan)
+            with rec.span("serving.commit", cat="serving", args=leaf):
+                # non-finite logits guard: one reduce on the hot path (a
+                # NaN/Inf anywhere makes the sum non-finite; idle lanes
+                # hold finite garbage); the per-slot triage only runs on
+                # a trip, BEFORE commit() streams garbage tokens to
+                # clients. math.isfinite on the extracted scalar beats
+                # np.isfinite's ufunc dispatch on this every-iteration
+                # path. The fail-stop is a safety feature and runs
+                # regardless of telemetry — only the flight-recorder
+                # dump needs it
+                if plan.slot_ids and \
+                        not math.isfinite(float(logps.sum())):
+                    if not np.all(np.isfinite(logps[plan.slot_ids])):
+                        self._on_engine_fault(plan, it, logps, lanes)
+                retired = self._sched.commit(plan, nxt, logps,
+                                             fed_logps=fed,
+                                             draft_logps=draft_logps,
+                                             rows=rows)
+            with rec.span("serving.account", cat="serving", args=leaf):
+                self._m["iterations"].inc()
+                self._m["valid_columns"].inc(plan.valid_columns)
+                self._m["padded_columns"].inc(plan.padded_columns)
+                step_ms = (time.perf_counter() - t0) * 1e3
+                self._m["step_ms"].observe(step_ms)
+                self._publish_gauges()
+                if tel is not None:
+                    st = self._sched
+                    # hot path: one ITER_FIELDS-order tuple per
+                    # iteration (tuples of scalars are GC-untracked;
+                    # per-iteration dicts next to a ~0.25 ms fused step
+                    # kept promoting ring garbage into the older GC
+                    # generations)
+                    tel.end_iteration(it, (
+                        round(step_ms, 3),              # step_ms
+                        tuple(plan.slot_ids),           # lanes
+                        tuple(plan.emitting),           # emitting
+                        plan.prefill_tokens,
+                        st.counts["admitted"] - admitted0,
+                        tuple(r.request_id for r in retired),
+                        plan.queue_depth,
+                        len(plan.slot_ids),             # active_slots
+                        self.cache.num_free,            # blocks_free
+                        self.cache.num_used,            # blocks_in_use
+                        st.watermark_blocks,
+                        lanes,                          # lanes_detail
+                        self._kernel_info()))
             return True
+
+    def _iteration_record(self, plan, it):
+        """The `serving.iteration` span's args, built only while a
+        capture is live: what the fused step was handed. `lanes_qc` is
+        each lane's (queries, context) — columns fed this iteration, and
+        the tokens its attention reads once they are written."""
+        cols = plan.valid.sum(axis=1)
+        return {"iteration": it, "lanes": len(plan.slot_ids),
+                "prefill_tokens": plan.prefill_tokens,
+                "valid_columns": plan.valid_columns,
+                "padded_columns": plan.padded_columns,
+                "lanes_qc": [[int(cols[sid]),
+                              int(plan.positions[sid, cols[sid] - 1]) + 1]
+                             for sid in plan.slot_ids]}
+
+    def _apply_step_chaos(self, it, lanes):
+        """Injected KV poison, applied before the step is fed."""
+        # content-addressed poison: a STANDING plan keyed to a request's
+        # prompt bytes, so the fault follows the request's failover
+        # replay onto every replica it lands on (the quarantine cascade
+        # seed). Each plan entry applies (and counts) at most once per
+        # ENGINE — the fault kills the server the same iteration, so
+        # fired == replica deaths caused, never inflated by a lane
+        # sitting poisoned across iterations
+        for pi, (pp, pl) in enumerate(self._chaos.prompt_poison_plan()):
+            if pi in self._prompt_poison_fired:
+                continue
+            blk = self._sched.lane_block_for_prompt(pp)
+            if blk is not None:
+                self._nan_block(pl, blk)
+                self._prompt_poison_fired.add(pi)
+                self._chaos.prompt_poison_applied()
+        poison_layer = self._chaos.serving_poison_at(it)
+        if poison_layer is not None:
+            if self._poison_kv(poison_layer, lanes):
+                self._chaos.serving_poison_applied()
+            else:
+                # no lane past pos 0 yet: its block would be fully
+                # overwritten by its own prefill write this iteration —
+                # defer, don't no-op
+                self._chaos.poison_serving_at(it + 1, poison_layer)
+
+    def _dispatch_fused(self, args):
+        """Launch the fused step on the live pools; returns its outputs
+        (device arrays, not waited for)."""
+        if self._kernel_engaged is not None:
+            return self._fused(self.cache.pools, *args)
+        # first fused call is about to TRACE: serialize it against other
+        # servers' first traces and snapshot the dispatch mode +
+        # counters right around it, so the delta covers exactly THIS
+        # trace
+        with GenerationServer._first_trace_lock:
+            self._kernel_mode = _kvc.paged_kernel_mode()
+            k0, f0 = (_kvc.KERNEL_DISPATCHES, _kvc.FALLBACK_DISPATCHES)
+            v0 = dict(_kvc.KERNEL_VERSIONS)
+            out = self._fused(self.cache.pools, *args)
+            self._kernel_counts = (_kvc.KERNEL_DISPATCHES - k0,
+                                   _kvc.FALLBACK_DISPATCHES - f0)
+            # which kernel GENERATION this trace's dispatches took (None
+            # if none engaged)
+            dv = [v for v in ("v1", "v2")
+                  if _kvc.KERNEL_VERSIONS.get(v, 0) > v0.get(v, 0)]
+            self._kernel_version = (dv[0] if len(dv) == 1 else
+                                    ("mixed" if dv else None))
+        self._check_kernel_engagement()
+        return out
+
+    def _fetch_outputs(self, out, plan):
+        """Bring the step's host-side outputs over (this waits for the
+        device): (ids, logps, fed logps or None, logp rows or None)."""
+        # plain mode: (pools, ids (S,), logps (S,)) from the last-column
+        # step; spec mode adds fed_logps and every output is per-column
+        # (S, C)
+        nxt, logps = np.asarray(out[1]), np.asarray(out[2])
+        if nxt.ndim == 1:
+            # commit() reads per-column arrays; a broadcast VIEW puts
+            # the last-valid-column value at every column (a prefill
+            # lane reads col n-1, a decode lane col 0 — both ARE that
+            # value), zero copies
+            s, c = plan.tokens.shape
+            nxt = np.broadcast_to(nxt[:, None], (s, c))
+            logps = np.broadcast_to(logps[:, None], (s, c))
+        # target-logp-of-fed-token only matters to the rejection-sampled
+        # acceptance; don't pay its host transfer otherwise
+        fed = (np.asarray(out[3])
+               if self._spec is not None
+               and self._spec.mode == "rejection" else None)
+        # full logp rows (last output when the strategies step is
+        # compiled in): fork-time host sampling and beam re-ranking read
+        # them — transferred only when this plan actually has a group
+        # that needs them
+        rows = None
+        if self._strategies and plan.needs_rows:
+            rows = np.asarray(
+                out[4] if self._spec is not None else out[3])
+        return nxt, logps, fed, rows
 
     def _run_draft(self, plan):
         """One draft-step call: sync the draft KV with this iteration's

@@ -195,12 +195,13 @@ class IterationPlan:
     __slots__ = ("tokens", "positions", "valid", "tables", "slot_ids",
                  "emitting", "prefill_tokens", "decode_cols", "limits",
                  "lanes_detail", "queue_depth", "sample_ctl",
-                 "guided_lanes", "needs_rows")
+                 "guided_lanes", "needs_rows", "valid_columns")
 
     def __init__(self, tokens, positions, valid, tables, slot_ids,
                  emitting, prefill_tokens, decode_cols=None,
                  limits=None, lanes_detail=None, queue_depth=None,
-                 sample_ctl=None, guided_lanes=None, needs_rows=False):
+                 sample_ctl=None, guided_lanes=None, needs_rows=False,
+                 valid_columns=0):
         self.tokens = tokens                # (S, C) int32
         self.positions = positions          # (S, C) int32
         self.valid = valid                  # (S, C) bool
@@ -226,6 +227,13 @@ class IterationPlan:
         # or a pending group fork) — the engine only materializes the
         # (S, [C,] V) rows output host-side when asked
         self.needs_rows = needs_rows
+        # columns of the (S, C) grid that carry a token this iteration;
+        # the rest of the grid is padding the fused step computes anyway
+        self.valid_columns = valid_columns
+
+    @property
+    def padded_columns(self):
+        return self.tokens.size - self.valid_columns
 
 
 class ContinuousBatchingScheduler:
@@ -1149,7 +1157,7 @@ class ContinuousBatchingScheduler:
             decode_cols = np.zeros((s,), np.int32)
             limits = np.zeros((s,), np.int32)
             slot_ids, emitting = [], set()
-            prefill_tokens = 0
+            prefill_tokens = valid_columns = 0
             lanes = [] if self._tel is not None else None
             do_sample = np.zeros((s,), bool)
             temperature = np.ones((s,), np.float32)
@@ -1170,6 +1178,7 @@ class ContinuousBatchingScheduler:
                 if lanes is not None:
                     lanes.append(_lane_tuple(sid, slot))
                 n = _plan_cols(slot)        # == the pre-pass's count
+                valid_columns += n
                 if slot.prefilling:
                     tokens[sid, :n] = req.prompt[slot.pos:slot.pos + n]
                     prefill_tokens += n
@@ -1227,7 +1236,7 @@ class ContinuousBatchingScheduler:
                 sample_ctl=(do_sample, temperature, top_k_arr,
                             top_p_arr, rng_keys),
                 guided_lanes=tuple(guided_lanes),
-                needs_rows=needs_rows)
+                needs_rows=needs_rows, valid_columns=valid_columns)
 
     def _accept(self, plan, sid, ids, logps, fed_logps, draft_logps):
         """One decode lane's committed (token, logp) list + position

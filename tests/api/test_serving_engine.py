@@ -28,7 +28,8 @@ from paddle_tpu.inference import decoding as dec
 from paddle_tpu.models import gpt
 from paddle_tpu.robustness import ChaosInjector
 from paddle_tpu.serving import (DeadlineExceeded, GenerationServer,
-                                GPTServingModel, PagedKVCache)
+                                GPTServingModel, PagedKVCache,
+                                SpecDecodeConfig)
 
 pytestmark = pytest.mark.serving
 
@@ -416,3 +417,127 @@ def test_generation_not_enabled_raises(tmp_path, tiny_gpt):
     pred = inference.create_predictor(str(tmp_path / "g2"))
     with pytest.raises(RuntimeError, match="enable_generation"):
         pred.generation_server()
+
+
+# ---------------------------------------------------------------------------
+# host phases of step() as leaf spans (docs/serving.md)
+# ---------------------------------------------------------------------------
+
+LEAVES = ("serving.plan", "serving.feed", "serving.dispatch",
+          "serving.fetch", "serving.commit", "serving.account")
+
+
+def _traced_run(params, cfg, prompts, **kw):
+    from paddle_tpu.observability.metrics import global_registry
+    from paddle_tpu.observability.tracing import get_recorder
+    reg = global_registry()
+    names = ("serving.valid_columns", "serving.padded_columns",
+             "serving.iterations")
+    before = {n: reg.counter(n).value() for n in names}
+    wait0 = reg.histogram("serving.queue_wait_ms").summary()
+    rec = get_recorder()
+    rec.start()
+    try:
+        srv = _server(params, cfg, **kw)
+        futs = [srv.submit(p, max_new_tokens=n) for p, n in prompts]
+        srv.run_until_idle()
+        for f in futs:
+            f.result(timeout=30)
+    finally:
+        rec.stop()
+    events = rec.events()
+    rec.clear()
+    delta = {n: reg.counter(n).value() - before[n] for n in names}
+    wait1 = reg.histogram("serving.queue_wait_ms").summary()
+    delta["queue_wait_ms"] = wait1["sum"] - wait0["sum"]
+    delta["admissions"] = wait1["count"] - wait0["count"]
+    return srv, events, delta
+
+
+def test_leaf_spans_tile_every_iteration(tiny_gpt):
+    cfg, _scope, params = tiny_gpt
+    prompts = [([5, 6, 7, 8, 9, 10], 4), ([3, 4], 6), ([1], 2),
+               ([2, 2, 2], 3)]         # 4 requests on 3 slots: one queues
+    srv, events, delta = _traced_run(params, cfg, prompts)
+    s, c = srv._sched.num_slots, srv._sched.chunk
+    iters = {e["args"]["iteration"]: e for e in events
+             if e["name"] == "serving.iteration"}
+    assert len(iters) == delta["serving.iterations"] >= 6
+    by_iter = {}
+    for e in events:
+        if e["name"] in LEAVES and not e["args"].get("idle"):
+            by_iter.setdefault(e["args"]["iteration"], []).append(e)
+    assert set(by_iter) == set(iters)
+    valid = padded = 0
+    for it, leaves in by_iter.items():
+        leaves.sort(key=lambda e: e["ts"])
+        # one of each, in order, on one thread, none overlapping
+        assert tuple(e["name"] for e in leaves) == LEAVES, (it, leaves)
+        assert len({e["tid"] for e in leaves}) == 1
+        for a, b in zip(leaves, leaves[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 0.002, (a, b)
+        # serving.iteration: from the start of feed to the end of fetch
+        # (the span engine.iter_ms_p50 reads; its extent must not move)
+        whole, feed, fetch = iters[it], leaves[1], leaves[3]
+        assert whole["tid"] == feed["tid"]
+        assert 0 <= feed["ts"] - whole["ts"] < 200
+        assert 0 <= (whole["ts"] + whole["dur"]) \
+            - (fetch["ts"] + fetch["dur"]) + 0.002 < 200
+        # the iteration record: the (S, C) grid and who filled it
+        args = whole["args"]
+        assert args["valid_columns"] + args["padded_columns"] == s * c
+        assert args["lanes"] == len(args["lanes_qc"])
+        assert sum(q for q, _ctx in args["lanes_qc"]) \
+            == args["valid_columns"]
+        assert all(1 <= q <= c and ctx >= q for q, ctx in args["lanes_qc"])
+        valid += args["valid_columns"]
+        padded += args["padded_columns"]
+    assert delta["serving.valid_columns"] == valid
+    assert delta["serving.padded_columns"] == padded
+    assert valid + padded == len(iters) * s * c
+    # every fed token is a column: prompts, and each generated token
+    # but a request's last, which is never fed back
+    assert valid == sum(len(p) + n - 1 for p, n in prompts)
+    # the idle poll that ends run_until_idle plans and does nothing else
+    idle = [e for e in events if e["args"].get("idle")]
+    assert [e["name"] for e in idle] == ["serving.plan"]
+
+
+def test_draft_step_is_a_child_of_feed(tiny_gpt):
+    cfg, _scope, params = tiny_gpt
+    spec = SpecDecodeConfig(GPTServingModel(params, cfg), k=2)
+    _srv, events, _delta = _traced_run(params, cfg, [([5, 6, 7], 5)],
+                                       spec=spec)
+    drafts = [e for e in events if e["name"] == "serving.draft"]
+    feeds = {e["args"]["iteration"]: e for e in events
+             if e["name"] == "serving.feed"}
+    assert drafts and len(drafts) == len(feeds)
+    for d in drafts:
+        f = feeds[d["args"]["iteration"]]
+        assert f["ts"] <= d["ts"] and \
+            d["ts"] + d["dur"] <= f["ts"] + f["dur"] + 0.002
+
+
+def test_admit_instant_shares_ids_with_the_request_tree(tiny_gpt):
+    cfg, _scope, params = tiny_gpt
+    prompts = [([5, 6, 7, 8], 3), ([3, 4], 2), ([1], 2), ([9, 9], 2)]
+    _srv, events, delta = _traced_run(params, cfg, prompts)
+    admits = [e for e in events if e["name"] == "serving.admit"]
+    roots = {e["args"]["rid"]: e for e in events
+             if e["name"].startswith("request ")}
+    assert len(admits) == len(prompts) == delta["admissions"]
+    assert {e["args"]["rid"] for e in admits} == set(roots)
+    for e in admits:
+        root = roots[e["args"]["rid"]]
+        assert e["ph"] == "i" and e["cat"] == "serving.request"
+        assert e["tid"] == root["tid"] == f"serving slot {e['args']['slot']}"
+        assert e["args"]["slot"] == root["args"]["slot"]
+        assert e["args"]["iteration"] == root["args"]["admit_iteration"]
+        assert e["args"]["blocks"] >= 1
+        # admission closes the tree's queue span
+        queue = next(q for q in events if q["name"] == "queue"
+                     and q["args"]["rid"] == e["args"]["rid"])
+        assert abs(queue["ts"] + queue["dur"] - e["ts"]) < 1.0
+    # what the histogram observed is what the instants carry
+    assert sum(e["args"]["queue_wait_ms"] for e in admits) \
+        == pytest.approx(delta["queue_wait_ms"], abs=1e-3)
