@@ -231,6 +231,11 @@ METRIC_SPECS = [
     ("serving.spec.accept_rate", "gauge",
      "process-cumulative accepted/proposed ratio across all "
      "speculative schedulers"),
+    ("serving.kv.pool_donations", "counter",
+     "fused steps whose KV pools XLA took over and rewrote in place "
+     "(the array that was pools[0]['k'] before the call is deleted "
+     "after it); equals serving.iterations when donation works, and "
+     "stays behind it when a step copied the pools instead"),
     ("serving.kv.quant.pool_bytes", "gauge",
      "TRUE footprint of a quantized KV block pool: int8 codes plus the "
      "f32 per-row scale pools, across k+v and every layer (label: "

@@ -484,3 +484,44 @@ def ragged_paged_attention_v2(q, k_pool, v_pool, block_table,
                        [k_scale, v_scale] if quantized else [],
                        block_table, q_positions, scratch, out_dtype,
                        vmem, interpret)
+
+
+# ---------------------------------------------------------------------------
+# reading whole blocks out of a pool, where the pool lies
+# ---------------------------------------------------------------------------
+
+def _copy_block_kernel(blk_ref, pool_ref, o_ref):
+    del blk_ref                     # read by the index_map alone
+    o_ref[...] = pool_ref[...]
+
+
+def gather_pool_blocks(pool, blocks, interpret=None):
+    """pool[blocks] for whole blocks: pool (N, H, bs, ...), blocks (G,)
+    int32 -> (G, H, bs, ...). One block a grid step, the id scalar-
+    prefetched into the pool's index_map as the attention kernels do
+    it, so the pool is read in the row-major layout those kernels read
+    it in. For an XLA gather of the same blocks the TPU's compiler
+    re-laid the whole pool out first, 52 MB to fetch 1.6 (PERF.md
+    section 6, PR 26). NULL may repeat among `blocks`; each repeat
+    reads it again."""
+    if interpret is None:
+        interpret = _interpret()
+    g = blocks.shape[0]
+    block = (1,) + tuple(pool.shape[1:])
+    rest = (0,) * (pool.ndim - 1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # blocks
+        grid=(g,),
+        in_specs=[pl.BlockSpec(block, lambda i, blk: (blk[i],) + rest)],
+        out_specs=pl.BlockSpec(block, lambda i, blk: (i,) + rest),
+    )
+    return pl.pallas_call(
+        _copy_block_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((g,) + tuple(pool.shape[1:]),
+                                       pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        name="gather_pool_blocks",
+        interpret=interpret,
+    )(blocks.astype(jnp.int32), pool)

@@ -573,7 +573,7 @@ class GenerationServer:
         self._chaos = chaos
         self._prompt_poison_fired = set()   # plan entries this engine
         #                                     already applied (chaos)
-        self._fault = None          # first engine fault (NonFiniteError)
+        self._fault = None          # what fail-stopped the engine
         self._exporter = None
         self._sched = ContinuousBatchingScheduler(
             self.cache, num_slots=num_slots, chunk=chunk,
@@ -602,7 +602,7 @@ class GenerationServer:
             self.cache.attach_sibling(self._draft_cache)
             from .spec_decode import build_draft_step
             self._draft = jax.jit(build_draft_step(
-                dm, self.block_size, spec.k))
+                dm, self.block_size, spec.k), donate_argnums=(0,))
         # host KV tier (tiered cache): a numpy block pool in host RAM
         # that eviction spills to and preemption parks in. Enabled
         # AFTER the draft sibling attaches so the tier mirrors onto the
@@ -643,7 +643,11 @@ class GenerationServer:
             if self._strategies:
                 step_kw["sampling"] = True
             fused = model.build_fused_step(self.block_size, **step_kw)
-        self._fused = jax.jit(fused)
+        # argument 0 is the pools: the step rewrites them in place (XLA
+        # aliases each pool's output to its input), so the call consumes
+        # them and _dispatch_fused stores the new set before anyone can
+        # look (docs/serving.md "Who owns the pools")
+        self._fused = jax.jit(fused, donate_argnums=(0,))
         self._signatures = set()
         # HBM ledger (observability/compile_insight.py): the serving
         # side of get_stats()["memory"] / the /memory endpoint — block
@@ -838,6 +842,9 @@ class GenerationServer:
                                       _help("serving.active_slots")),
             "blocks_in_use": reg.gauge("serving.blocks_in_use",
                                        _help("serving.blocks_in_use")),
+            "pool_donations": reg.counter(
+                "serving.kv.pool_donations",
+                _help("serving.kv.pool_donations")),
         }
         self._worker = None
         if start:
@@ -1140,11 +1147,6 @@ class GenerationServer:
                 with rec.span("serving.dispatch", cat="serving",
                               args=leaf):
                     out = self._dispatch_fused(args)
-                    # the cache object always holds the LIVE device
-                    # pools: the functional update replaces them in
-                    # place of the consumed ones (keeping both would pin
-                    # 2x the KV HBM)
-                    self.cache.pools = out[0]
                 with rec.span("serving.fetch", cat="serving", args=leaf):
                     nxt, logps, fed, rows = self._fetch_outputs(out, plan)
             with rec.span("serving.commit", cat="serving", args=leaf):
@@ -1236,11 +1238,39 @@ class GenerationServer:
                 # defer, don't no-op
                 self._chaos.poison_serving_at(it + 1, poison_layer)
 
+    def _call_donating(self, fn, cache, args):
+        """Call a jitted step that donates `cache.pools` (its argument
+        0) and returns the rewritten pools first, and store those: one
+        step under the cache's lock, so that no reader on another
+        thread finds the consumed set. Returns (outputs, whether the
+        old pools were consumed)."""
+        k0 = None
+        try:
+            with cache.pools_lock:
+                k0 = cache.pools[0]["k"]
+                out = fn(cache.pools, *args)
+                cache.pools = out[0]
+        except Exception as e:
+            if k0 is not None and k0.is_deleted():
+                # the buffers went with the call: there is no KV left
+                # to serve from. Stop now and with this error, not at
+                # the next step with "Array has been deleted"
+                self._fail_stop(e)
+            raise
+        return out, k0.is_deleted()
+
+    def _call_fused(self, args):
+        out, donated = self._call_donating(self._fused, self.cache, args)
+        if donated:
+            self._m["pool_donations"].inc()
+        return out
+
     def _dispatch_fused(self, args):
-        """Launch the fused step on the live pools; returns its outputs
-        (device arrays, not waited for)."""
+        """Launch the fused step on the live pools and store the pools
+        it returns; returns its outputs (device arrays, not waited
+        for)."""
         if self._kernel_engaged is not None:
-            return self._fused(self.cache.pools, *args)
+            return self._call_fused(args)
         # first fused call is about to TRACE: serialize it against other
         # servers' first traces and snapshot the dispatch mode +
         # counters right around it, so the delta covers exactly THIS
@@ -1249,7 +1279,7 @@ class GenerationServer:
             self._kernel_mode = _kvc.paged_kernel_mode()
             k0, f0 = (_kvc.KERNEL_DISPATCHES, _kvc.FALLBACK_DISPATCHES)
             v0 = dict(_kvc.KERNEL_VERSIONS)
-            out = self._fused(self.cache.pools, *args)
+            out = self._call_fused(args)
             self._kernel_counts = (_kvc.KERNEL_DISPATCHES - k0,
                                    _kvc.FALLBACK_DISPATCHES - f0)
             # which kernel GENERATION this trace's dispatches took (None
@@ -1305,14 +1335,13 @@ class GenerationServer:
                 # the draft's sync pass feeds ONLY the committed token;
                 # the verify columns belong to the target step
                 valid_d[sid, 1:] = False
-        dpools, props, dlps = self._draft(
-            self._draft_cache.pools, jnp.asarray(plan.tokens),
-            jnp.asarray(plan.positions), jnp.asarray(valid_d),
-            jnp.asarray(plan.tables), jnp.asarray(spec_go),
-            jnp.asarray(plan.limits))
+        (_, props, dlps), _ = self._call_donating(
+            self._draft, self._draft_cache,
+            (jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
+             jnp.asarray(valid_d), jnp.asarray(plan.tables),
+             jnp.asarray(spec_go), jnp.asarray(plan.limits)))
         self._draft_signatures.add(
             (plan.tokens.shape, plan.tables.shape))
-        self._draft_cache.pools = dpools
         props = np.asarray(props)
         for sid in plan.slot_ids:
             q = int(plan.decode_cols[sid])
@@ -1460,11 +1489,16 @@ class GenerationServer:
         # requests that were in the blast center — innocent bystanders
         # fail over without a strike (serving/router.py)
         err.bad_rids = bad_rids
+        self._fail_stop(err)
+        raise err
+
+    def _fail_stop(self, err):
+        """The engine cannot go on: record `err` as its fault, refuse
+        new work, and fail every outstanding request with it."""
         self._fault = err
         with self._rid_lock:
             self._closed = True
         self._sched.cancel_all(err)
-        raise err
 
     def run_until_idle(self, max_iterations=100000):
         """Pump step() until no lane has work (manual-drive mode)."""
@@ -1529,15 +1563,22 @@ class GenerationServer:
             if self._prefix is not None else 0)
 
     def _serve(self):
-        from ..robustness.guard import NonFiniteError
         while True:
             try:
                 did = self.step()
-            except NonFiniteError:
-                # _on_engine_fault already dumped the flight recorder,
-                # failed every future, and closed the server: the
-                # worker just exits (clients observe the error on their
-                # futures; get_stats()["engine_fault"] records it)
+            except Exception as e:
+                if self._fault is None:
+                    # nobody can pump this engine again once its thread
+                    # is gone: fail the futures with what killed it,
+                    # where they used to wait for ever
+                    self._fail_stop(e)
+                    raise
+                # fail-stopped by the step itself (_on_engine_fault
+                # after non-finite logits, or a fused call that died
+                # with the pools): every future is failed and the
+                # server closed, so the worker just exits (clients
+                # observe the error on their futures;
+                # get_stats()["engine_fault"] records it)
                 return
             if did:
                 continue

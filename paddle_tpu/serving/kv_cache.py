@@ -77,6 +77,7 @@ dtype follows the query dtype (the model's activation dtype).
 """
 
 import os
+import threading
 
 import numpy as np
 
@@ -441,12 +442,65 @@ def paged_attention(q, k_pool, v_pool, block_table, q_positions,
               k_scale=k_scale, v_scale=v_scale)
 
 
+def _plan_block_writes(block_idx, offset, block_size):
+    """Which whole blocks a step's token writes touch (the same few
+    small operations for every pool of a step; XLA keeps one copy).
+
+    block_idx, offset: (B, C) int32, the (block, row) each column's
+    K/V goes to, masked columns routed to (NULL_BLOCK, 0). The
+    scheduler's contract: a lane's live columns are a prefix of its C
+    columns and hold consecutive positions (`plan()`:
+    `positions[sid, :n] = arange(pos, pos + n)`), so they lie in at
+    most J = (C + bs - 2) // bs + 1 blocks: the one column 0 writes,
+    and each later one from the column whose row wraps to 0.
+
+    Returns (blocks (B, J) int32 — the blocks lane b writes, NULL
+    where it writes fewer; src (B, J, bs) int32 — the column whose
+    token lands in that row; hit (B, J, bs) bool — whether any does)."""
+    block_idx = jnp.asarray(block_idx, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    c = block_idx.shape[1]
+    bs = int(block_size)
+    j = jnp.arange((c + bs - 2) // bs + 1, dtype=jnp.int32)
+    first = jnp.where(j == 0, 0, j * bs - offset[:, :1])        # (B, J)
+    blocks = jnp.where(
+        first < c,
+        jnp.take_along_axis(block_idx, jnp.minimum(first, c - 1), axis=1),
+        NULL_BLOCK)
+    rows = jnp.arange(bs, dtype=jnp.int32)
+    match = ((block_idx[:, None, None, :] == blocks[:, :, None, None])
+             & (offset[:, None, None, :] == rows[None, None, :, None]))
+    return blocks, jnp.argmax(match, axis=-1).astype(jnp.int32), \
+        match.any(axis=-1)
+
+
 def write_block_kv(pool, vals, block_idx, offset):
-    """Scatter vals (B, C, H, D) into pool (N, H, bs, D) at
-    (block_idx (B, C), :, offset (B, C), :). Masked tokens should be
-    routed to (NULL_BLOCK, 0) by the caller. The pool dtype wins (same
-    contract as decoding.update_kv_cache)."""
-    return pool.at[block_idx, :, offset, :].set(vals.astype(pool.dtype))
+    """Write vals (B, C, H, D) into pool (N, H, bs, D) at
+    (block_idx (B, C), :, offset (B, C), :); a scale pool (N, H, bs)
+    takes vals (B, C, H) the same way. Masked tokens should be routed
+    to (NULL_BLOCK, 0) by the caller, and a lane's live columns hold
+    consecutive positions (_plan_block_writes). The pool dtype wins
+    (same contract as decoding.update_kv_cache).
+
+    The touched blocks are read, overlaid and written back whole, all
+    in the row-major layout the Pallas kernels read the pool in, so the
+    step re-lays a pool out once for all three; a scatter of single
+    rows, and an XLA gather of blocks too, each had XLA:TPU re-lay the
+    whole pool out once more (PERF.md section 6, PR 26). Lanes never
+    share a block they write (copy-on-write comes first), so only NULL
+    repeats among the indices, and NULL holds garbage by design."""
+    from ..ops.pallas.paged import gather_pool_blocks
+    blocks, src, hit = _plan_block_writes(block_idx, offset,
+                                          pool.shape[2])
+    tail = (1,) * (vals.ndim - 2)
+    upd = jnp.take_along_axis(vals[:, None],
+                              src.reshape(src.shape + tail), axis=2)
+    upd = jnp.swapaxes(upd, 2, 3)                   # (B, J, H, bs, ...)
+    hit = hit.reshape(hit.shape[:2] + (1, hit.shape[2]) + tail[1:])
+    flat = blocks.reshape(-1)
+    cur = gather_pool_blocks(pool, flat).reshape(upd.shape)
+    new = jnp.where(hit, upd.astype(pool.dtype), cur)
+    return pool.at[flat].set(new.reshape((-1,) + new.shape[2:]))
 
 
 def write_block_kv_quant(pool, scale_pool, vals, block_idx, offset):
@@ -459,9 +513,8 @@ def write_block_kv_quant(pool, scale_pool, vals, block_idx, offset):
     dense write — the NULL block's codes/scales are garbage by design
     and the kernel/reference never read them."""
     q, s = quantize_kv_rows(vals)
-    pool = pool.at[block_idx, :, offset, :].set(q)
-    scale_pool = scale_pool.at[block_idx, :, offset].set(s)
-    return pool, scale_pool
+    return (write_block_kv(pool, q, block_idx, offset),
+            write_block_kv(scale_pool, s, block_idx, offset))
 
 
 # ---------------------------------------------------------------------------
@@ -680,6 +733,14 @@ class PagedKVCache:
             return layer
 
         self.pools = [make_layer() for _ in range(self.num_layers)]
+        # every jitted rewriter of the pools DONATES them (the engine's
+        # fused and draft steps, cow_copy, adopt_block_from,
+        # deserialize_block, swap_in_block): the old arrays are dead the
+        # moment the call returns. So whoever touches `pools` holds this
+        # lock from reading the attribute to storing the result, takes
+        # the list fresh each time, and keeps no element of it. Sibling
+        # caches share the primary's lock (attach_sibling).
+        self.pools_lock = threading.RLock()
         # LIFO free list; block 0 (NULL) is never handed out
         self._free = list(range(self.num_blocks - 1, 0, -1))
         # host-side refcounts: block -> live references (absent = free).
@@ -857,6 +918,7 @@ class PagedKVCache:
         """Register a cache whose pools share this cache's block ids
         (the spec-decode draft pools): cow_copy keeps them consistent."""
         self._siblings.append(sibling)
+        sibling.pools_lock = self.pools_lock    # one lock per engine
         self._cow_fn = None         # pytree layout changed: rebuild
         if self.host is not None:
             # host tier already on: the new sibling needs its own host
@@ -886,13 +948,14 @@ class PagedKVCache:
                     [{name: a.at[d].set(a[s]) for name, a in p.items()}
                      for p in pools]
                     for pools in pool_sets]
-            self._cow_fn = jax.jit(_copy)
+            self._cow_fn = jax.jit(_copy, donate_argnums=(0,))
         holders = [self] + self._siblings
-        new_sets = self._cow_fn([h.pools for h in holders],
-                                jnp.asarray(src, jnp.int32),
-                                jnp.asarray(dst, jnp.int32))
-        for h, pools in zip(holders, new_sets):
-            h.pools = pools
+        with self.pools_lock:
+            new_sets = self._cow_fn([h.pools for h in holders],
+                                    jnp.asarray(src, jnp.int32),
+                                    jnp.asarray(dst, jnp.int32))
+            for h, pools in zip(holders, new_sets):
+                h.pools = pools
         self.cow_copies += 1
 
     def adopt_block_from(self, src_cache, src_block, dst_block):
@@ -950,10 +1013,18 @@ class PagedKVCache:
                         sp[name][s].astype(dp[name].dtype))
                      for name in dp}
                     for sp, dp in zip(src_pools, dst_pools)]
-            self._xfer_fn = jax.jit(_xfer)
-        self.pools = self._xfer_fn(src_cache.pools, self.pools,
-                                   jnp.asarray(src_block, jnp.int32),
-                                   jnp.asarray(dst_block, jnp.int32))
+            # the destination is rewritten in place; the source is
+            # only read and stays its owner's
+            self._xfer_fn = jax.jit(_xfer, donate_argnums=(1,))
+        # both caches' locks, in one order whoever calls (two handoffs
+        # in opposite directions must not wait on each other)
+        first, second = sorted((src_cache.pools_lock, self.pools_lock),
+                               key=id)
+        with first, second:
+            self.pools = self._xfer_fn(
+                src_cache.pools, self.pools,
+                jnp.asarray(src_block, jnp.int32),
+                jnp.asarray(dst_block, jnp.int32))
 
     # -- wire handoff (out-of-process fleet, serving/transport.py) ---------
     def wire_geometry(self):
@@ -974,9 +1045,14 @@ class PagedKVCache:
         rows when quantized. This is the byte payload of a
         cross-process ``adopt_block_from``; deserialize_block is the
         receiving half."""
-        names = sorted(self.pools[0].keys())
-        arrays = [np.asarray(layer[name][block])
-                  for layer in self.pools for name in names]
+        with self.pools_lock:
+            names = sorted(self.pools[0].keys())
+            # each slice is a device array of its own, queued before
+            # whatever step consumes the pools next: the host copies
+            # below need no lock
+            rows = [layer[name][block]
+                    for layer in self.pools for name in names]
+        arrays = [np.asarray(r) for r in rows]
         return {"geometry": self.wire_geometry(), "names": names}, arrays
 
     def deserialize_block(self, dst_block, meta, arrays):
@@ -1035,9 +1111,10 @@ class PagedKVCache:
                         row[name].astype(layer[name].dtype))
                      for name in layer}
                     for layer, row in zip(pools, rows)]
-            self._wire_in_fn = jax.jit(_write)
-        self.pools = self._wire_in_fn(
-            self.pools, rows, jnp.asarray(dst_block, jnp.int32))
+            self._wire_in_fn = jax.jit(_write, donate_argnums=(0,))
+        with self.pools_lock:
+            self.pools = self._wire_in_fn(
+                self.pools, rows, jnp.asarray(dst_block, jnp.int32))
 
     # -- host spill tier ---------------------------------------------------
     def enable_host_tier(self, num_blocks):
@@ -1081,9 +1158,10 @@ class PagedKVCache:
             self._spill_fn = jax.jit(_extract)
         holders = [h for h in [self] + self._siblings
                    if h.host is not None]
-        rows_sets = jax.device_get(
-            self._spill_fn([h.pools for h in holders],
-                           jnp.asarray(block, jnp.int32)))
+        with self.pools_lock:       # reads only: nothing is donated
+            rows_sets = self._spill_fn([h.pools for h in holders],
+                                       jnp.asarray(block, jnp.int32))
+        rows_sets = jax.device_get(rows_sets)
         for h, rows in zip(holders, rows_sets):
             for layer, r in zip(h.host.pools, rows):
                 for name, arr in r.items():
@@ -1110,18 +1188,19 @@ class PagedKVCache:
                       for name in p}
                      for p, rows in zip(pools, rset)]
                     for pools, rset in zip(pool_sets, rows_sets)]
-            self._swap_in_fn = jax.jit(_inject)
+            self._swap_in_fn = jax.jit(_inject, donate_argnums=(0,))
         holders = [h for h in [self] + self._siblings
                    if h.host is not None]
         rows_sets = [
             [{name: arr[host_block] for name, arr in layer.items()}
              for layer in h.host.pools]
             for h in holders]
-        new_sets = self._swap_in_fn([h.pools for h in holders],
-                                    rows_sets,
-                                    jnp.asarray(dst_block, jnp.int32))
-        for h, pools in zip(holders, new_sets):
-            h.pools = pools
+        with self.pools_lock:
+            new_sets = self._swap_in_fn([h.pools for h in holders],
+                                        rows_sets,
+                                        jnp.asarray(dst_block, jnp.int32))
+            for h, pools in zip(holders, new_sets):
+                h.pools = pools
         self.host_swap_ins += 1
 
     def host_pool_bytes(self):

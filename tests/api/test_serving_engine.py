@@ -541,3 +541,64 @@ def test_admit_instant_shares_ids_with_the_request_tree(tiny_gpt):
     # what the histogram observed is what the instants carry
     assert sum(e["args"]["queue_wait_ms"] for e in admits) \
         == pytest.approx(delta["queue_wait_ms"], abs=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# every pool rewriter in one server (ISSUE 26): each donates the pools,
+# so a reference kept across any of them would read a deleted array
+# ---------------------------------------------------------------------------
+
+def test_every_pool_rewriter_in_one_server_keeps_reference_ids(tiny_gpt):
+    """Prefix cache, host tier, a fork group and a speculative draft in
+    one server, pumped through admission, COW, spill, swap-in and
+    retirement: the ids are the dense per-token loop's, and every
+    iteration's fused step consumed the pools it was handed."""
+    from paddle_tpu.observability.metrics import global_registry
+    from paddle_tpu.serving import SamplingParams
+    cfg, _scope, params = tiny_gpt
+    rng = np.random.default_rng(26)
+    a, b, c = (rng.integers(3, cfg.vocab_size, n).astype(np.int32)
+               for n in (17, 16, 11))
+    want = {k: _reference_greedy(params, cfg, p, 6)
+            for k, p in (("a", a), ("b", b), ("c", c))}
+    reg = global_registry()
+    before = [reg.counter(n).value() for n in
+              ("serving.kv.pool_donations", "serving.iterations")]
+
+    chaos = ChaosInjector()
+    srv = _server(params, cfg, num_slots=4, num_blocks=48,
+                  prefix_cache=True, host_kv_blocks=8, chaos=chaos,
+                  spec=SpecDecodeConfig(GPTServingModel(params, cfg), k=3))
+
+    def run(*prompts):
+        futs = [srv.submit(p, max_new_tokens=6) for p in prompts]
+        srv.run_until_idle()
+        return [list(f.result(timeout=5).token_ids) for f in futs]
+
+    # admission, chunked prefill, retirement: a's and b's chunks are
+    # indexed as they go
+    assert run(a, b) == [want["a"], want["b"]]
+    # a's chain goes to the host tier; the hit below swaps it back in
+    chaos.spill_chain_at(srv._sched.iteration + 1, 2)
+    # b is covered whole by its two cached blocks, so its last token is
+    # fed again into a shared block: copy-on-write. The group forks
+    # three lanes off one prefill of c
+    group = srv.submit(c, max_new_tokens=6,
+                       sampling=SamplingParams(n=3, temperature=0.0))
+    assert run(a, b) == [want["a"], want["b"]]
+    lanes = group.result(timeout=5).lanes
+    assert [list(l.token_ids) for l in lanes] == [want["c"]] * 3
+
+    st = srv.get_stats()
+    assert chaos.fired["spill"] == 2
+    assert st["kv_tier"]["spills"] >= 2 and st["kv_tier"]["swap_ins"] >= 2
+    assert st["prefix"]["hits"] >= 4 and st["prefix"]["cow_copies"] >= 1
+    assert st["group.forks"] == 2
+    assert st["spec"]["proposed"] > 0
+    assert st["fused_step_signatures"] == 1
+    assert srv.cache.num_free == \
+        srv.cache.usable_blocks - st["prefix"]["entries"]
+    donated, iterations = (reg.counter(n).value() - v for n, v in zip(
+        ("serving.kv.pool_donations", "serving.iterations"), before))
+    assert donated == iterations == st["iteration"]
+    srv.close()

@@ -115,3 +115,95 @@ def test_flash_compiles_under_an_executor_mesh(v5e_2x2, nested,
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert any(name in c for c in calls), (name, calls)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "bf16_tp4", "bf16_d128"])
+def test_kv_write_and_walk_re_lay_a_pool_once_in_and_once_out(
+        v5e_2x2, pool):
+    """One layer of the fused step at the GPT-2 XL cell's geometry (16
+    lanes x 16-token chunks, 25 heads x 64, 1,025 blocks of 16): the
+    write and the attention kernel over donated pools. A TPU keeps a
+    pool whose minor dim is 64 with the block dim minor, the kernels
+    read it row-major, and with a scatter of single rows XLA:TPU wanted
+    a third layout: three whole-pool copies a pool a step (PERF.md
+    section 6, PR 26). Writing whole blocks leaves the two that a
+    row-major kernel operand costs, in and out, and the step writes into
+    the pools it was given. At head_dim 128 the device's layout is the
+    kernels' and nothing is copied."""
+    import re
+
+    import numpy as np
+
+    from paddle_tpu.serving import kv_cache as kvc
+
+    s, h, c, d, bs, m = 16, 25, 16, 64, 16, 64
+    if pool == "bf16_d128":
+        h, d = 8, 128
+    n = 1 + s * m
+    pdt = jnp.int8 if pool == "int8" else jnp.bfloat16
+    tp = 4 if pool == "bf16_tp4" else 1
+    if tp == 1:
+        rep = by_head = by_head3 = cols = SingleDeviceSharding(v5e_2x2[0])
+    else:
+        # the mesh step: pools and q sharded by heads (24 here, for 4
+        # chips), the body under shard_map as build_fused_step has it
+        h = 24
+        mesh = Mesh(np.array(v5e_2x2), ("tp",))
+        rep = NamedSharding(mesh, P())
+        by_head = NamedSharding(mesh, P(None, "tp", None, None))
+        by_head3 = NamedSharding(mesh, P(None, "tp", None))
+        cols = NamedSharding(mesh, P(None, None, "tp", None))
+
+    def struct(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    layer = {"k": struct((n, h, bs, d), pdt, by_head),
+             "v": struct((n, h, bs, d), pdt, by_head)}
+    if pool == "int8":
+        layer["k_scale"] = struct((n, h, bs), jnp.float32, by_head3)
+        layer["v_scale"] = struct((n, h, bs), jnp.float32, by_head3)
+
+    def step(p, q, k, v, tables, pos, bidx, off):
+        if pool == "int8":
+            kp, ks = kvc.write_block_kv_quant(p["k"], p["k_scale"], k,
+                                              bidx, off)
+            vp, vs = kvc.write_block_kv_quant(p["v"], p["v_scale"], v,
+                                              bidx, off)
+            new = {"k": kp, "v": vp, "k_scale": ks, "v_scale": vs}
+        else:
+            ks = vs = None
+            new = {"k": kvc.write_block_kv(p["k"], k, bidx, off),
+                   "v": kvc.write_block_kv(p["v"], v, bidx, off)}
+        return new, paged.ragged_paged_attention(
+            q, new["k"], new["v"], tables, pos, k_scale=ks, v_scale=vs,
+            interpret=False)
+
+    args = [layer, struct((s, h, c, d), jnp.bfloat16, by_head),
+            struct((s, c, h, d), jnp.bfloat16, cols),
+            struct((s, c, h, d), jnp.bfloat16, cols),
+            struct((s, m), jnp.int32, rep), struct((s, c), jnp.int32, rep),
+            struct((s, c), jnp.int32, rep), struct((s, c), jnp.int32, rep)]
+    if tp > 1:
+        step = jax.shard_map(
+            step, mesh=mesh,
+            in_specs=({"k": by_head.spec, "v": by_head.spec}, by_head.spec,
+                      cols.spec, cols.spec, P(), P(), P(), P()),
+            out_specs=({"k": by_head.spec, "v": by_head.spec},
+                       by_head.spec),
+            check_vma=False)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged, "_interpret", lambda: False)
+        text = jax.jit(step, donate_argnums=(0,)).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    header = text[:text.index("\n")]
+    assert len(re.findall(r"(?:may|must)-alias", header)) == len(layer)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert sum("gather_pool_blocks" in ln for ln in calls) == len(layer)
+    assert sum("paged_attention_v1" in ln for ln in calls) == 1
+    # (an int8 pool's f32 scale pools, 3% of its codes, are not counted)
+    whole_pool = re.compile(
+        rf"= \w+\[{n},{h // tp},{bs},{d}\]\{{([\d,]+)\S* copy\(")
+    layouts = [whole_pool.search(ln).group(1) for ln in text.splitlines()
+               if whole_pool.search(ln)]
+    want = [] if d == 128 else ["0,3,2,1"] * 2 + ["3,2,1,0"] * 2
+    assert sorted(layouts) == want, layouts

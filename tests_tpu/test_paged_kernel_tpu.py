@@ -130,3 +130,73 @@ def test_dispatcher_takes_the_kernel_on_tpu(monkeypatch):
         err = float(np.max(np.abs(np.asarray(got, np.float32)
                                   - np.asarray(want, np.float32))))
         assert err <= TOL["bfloat16"], err
+
+
+# ---------------------------------------------------------------------------
+# the write: whole blocks, in place (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+def _write_case(cache, b, c, seed):
+    """Lanes of a step: one idle, one whose chunk straddles two blocks,
+    the rest anywhere; columns past each lane's count masked to
+    (NULL, 0) as the fused step routes them."""
+    rng = np.random.default_rng(seed)
+    bs = cache.block_size
+    m = (cache.num_blocks - 1) // b
+    tables = rng.permutation(np.arange(1, cache.num_blocks))[
+        :b * m].reshape(b, m)
+    pos0 = rng.integers(0, m * bs - c, b)
+    pos0[1] = bs - 1                        # wraps after one column
+    count = rng.integers(1, c + 1, b)
+    count[0], count[1] = 0, c
+    valid = np.arange(c)[None] < count[:, None]
+    pos = np.where(valid, pos0[:, None] + np.arange(c)[None], 0)
+    bidx = np.where(valid, np.take_along_axis(tables, pos // bs, 1), NULL)
+    return (bidx.astype(np.int32), np.where(valid, pos % bs, 0).astype(
+        np.int32))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "bf16", "int8"])
+@pytest.mark.parametrize("geom", [(16, 16, 16, 64, 25), (4, 1, 16, 64, 12),
+                                  (3, 4, 32, 128, 8), (2, 16, 8, 64, 4)],
+                         ids=lambda g: "b{}c{}bs{}d{}h{}".format(*g))
+def test_block_write_matches_row_scatter_tpu(geom, kv_dtype):
+    """`write_block_kv` / `_quant` on the chip, over a `PagedKVCache`'s
+    pools, donated: every real block equals what a scatter of single
+    rows gives, and the pools handed in are consumed."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving import kv_cache as kvc
+
+    b, c, bs, d, h = geom
+    cache = kvc.PagedKVCache(1, h, d, 1 + 6 * b, block_size=bs,
+                             dtype=jnp.float32, kv_dtype=kv_dtype)
+    layer = cache.pools[0]
+    rng = np.random.default_rng(sum(geom))
+    seeded = {name: jnp.asarray(
+        rng.integers(-100, 100, a.shape), a.dtype) for name, a in
+        layer.items()}
+    vals = jnp.asarray(rng.standard_normal((b, c, h, d)), jnp.float32)
+    bidx, off = _write_case(cache, b, c, seed=sum(geom) + 1)
+
+    def write(p, vals):
+        if cache.quantized:
+            k, ks = kvc.write_block_kv_quant(p["k"], p["k_scale"], vals,
+                                             bidx, off)
+            return dict(p, k=k, k_scale=ks)
+        return dict(p, k=kvc.write_block_kv(p["k"], vals, bidx, off))
+
+    def rows(p, vals):
+        if cache.quantized:
+            q, s = kvc.quantize_kv_rows(vals)
+            return dict(p, k=p["k"].at[bidx, :, off, :].set(q),
+                        k_scale=p["k_scale"].at[bidx, :, off].set(s))
+        return dict(p, k=p["k"].at[bidx, :, off, :].set(
+            vals.astype(p["k"].dtype)))
+
+    want = jax.jit(rows)(seeded, vals)
+    got = jax.jit(write, donate_argnums=(0,))(seeded, vals)
+    assert all(a.is_deleted() for a in seeded.values())
+    for name in layer:
+        np.testing.assert_array_equal(np.asarray(got[name])[1:],
+                                      np.asarray(want[name])[1:], name)
