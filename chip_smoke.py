@@ -271,7 +271,7 @@ def _serve_and_check(sz, scope, rows, mesh, rehearsal):
     if mesh is not None:
         tp = mesh.devices.size
         _check(st["mesh"]["tp"] == tp, f"mesh stats {st['mesh']}")
-        shards = srv.cache.pools[0]["k"].addressable_shards
+        shards = srv.cache.pools[0]["kv"].addressable_shards
         _check(len(shards) == tp and shards[0].data.shape[1]
                == cfg.num_heads // tp,
                f"KV pool is not head-sharded over {tp} devices")

@@ -233,7 +233,7 @@ METRIC_SPECS = [
      "speculative schedulers"),
     ("serving.kv.pool_donations", "counter",
      "fused steps whose KV pools XLA took over and rewrote in place "
-     "(the array that was pools[0]['k'] before the call is deleted "
+     "(the array that was pools[0]['kv'] before the call is deleted "
      "after it); equals serving.iterations when donation works, and "
      "stays behind it when a step copied the pools instead"),
     ("serving.kv.quant.pool_bytes", "gauge",

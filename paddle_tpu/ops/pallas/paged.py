@@ -6,21 +6,29 @@ block table on every fused step — each decode iteration pays
 O(max_blocks) HBM traffic per lane regardless of how many tokens the
 lane actually holds. The kernels here (per the *Ragged Paged Attention*
 TPU paper, PAPERS.md) walk the block table INSIDE the kernel instead:
-the grid is (lane, table column) and each K/V block arrives through a
+the grid is (lane, table column) and each block arrives through a
 BlockSpec whose index_map reads the scalar-prefetched table, so the
-Pallas pipeline issues (and double-buffers) the HBM->VMEM copies. Two
-generations share that walk:
+Pallas pipeline issues (and double-buffers) the HBM->VMEM copies.
+
+A layer's pool is ONE array (N, H_kv, bs, 2*D): the K row of a token in
+lanes [0, D), its V row in [D, 2*D) (`serving/kv_cache.fuse_kv`). At
+head_dim 64 the minor dim is the 128 lanes of a TPU tile, so the
+device's own layout of the pool is the row-major one these kernels
+read and no step re-lays a pool out (PERF.md section 6, PR 29); a grid
+step is one DMA, not two. Two generations share that walk:
 
 * **v1** (`ragged_paged_attention`): every live block is copied into
-  an (H_kv, M*bs, D) VMEM scratch as it arrives; the last grid step
-  runs the reference's exact op sequence on the VMEM-resident gather.
+  an (H_kv, M*bs, 2*D) VMEM scratch as it arrives; the last grid step
+  takes K and V out of it with two lane slices and runs the reference's
+  exact op sequence on the VMEM-resident gather.
   f32 and int8 pools are pinned BITWISE against the reference under jit
   in interpret mode — the price is VMEM scratch proportional to the
   table width M.
 * **v2** (`ragged_paged_attention_v2`): each arriving block folds
   straight into a flash-style online-softmax accumulator (running max,
-  rescaled sum, rescaled PV partial, all f32 VMEM scratch). VMEM holds
-  the pipeline's two block windows plus the carry — independent of M,
+  rescaled sum, rescaled PV partial, all f32 VMEM scratch), split into
+  its K and V lanes as it lands. VMEM holds the pipeline's two block
+  windows plus the carry — independent of M,
   so context length is unbounded at fixed VMEM. Online softmax is
   mathematically EXACT (every rescale is an identity in real
   arithmetic) but reorders the floating-point reductions the reference
@@ -48,8 +56,9 @@ Both kernels share the serving contract:
   (EQuARX-style reduced-precision hot path with full-precision
   accumulation);
 * int8 pools (quantized serving, ISSUE 14) fuse the DEQUANT into the
-  walk: the pipeline copies the int8 codes plus their (H_kv, bs) f32
-  scale rows — roughly HALF the bytes a bf16 pool moves per block —
+  walk: the pipeline copies the int8 codes (K and V of a block in
+  one (H_kv, bs, 2*D) window) plus their two (H_kv, bs) f32 scale
+  rows — roughly HALF the bytes a bf16 pool moves per block —
   and the dequant multiply happens on the VMEM-resident block right
   where the value path consumes it;
 * grouped-query attention (ISSUE 16): pools may carry H_kv < H heads
@@ -66,9 +75,14 @@ DMA slice of an array whose minor dim is under one 128-lane tile
 take any block whose trailing dims equal the array's.
 
 VMEM budget: v1's scratch holds one lane's full K+V working set,
-2 * H_kv * M*bs * D elements (minor dim padded to 128 lanes) — the
-full-KV-resident discipline of flash.py's default forward. v2 holds
-two block windows per pool whatever M is; the dispatcher
+H_kv * M*bs * 2*D elements in the pool's dtype (f32 for int8 pools,
+which are dequantized as they land) — the full-KV-resident discipline
+of flash.py's default forward. At head_dim 64 the 2*D minor dim is
+exactly the 128 lanes, so nothing is padded and the dispatcher's
+estimate (`v1_scratch_bytes`) is what Mosaic allocates: 6.5 MB for 25
+heads x 1,024 tokens of bf16, where two 64-lane scratches padded to
+13. The K and V slices `_attend` takes are temporaries of the same
+size again. v2 holds two block windows whatever M is; the dispatcher
 (serving/kv_cache.paged_attention) routes tables past the v1 ceiling
 to v2 automatically.
 
@@ -101,24 +115,24 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def _validate_paged_args(q, k_pool, v_pool, block_table, q_positions,
+def _validate_paged_args(q, kv_pool, block_table, q_positions,
                          k_scale, v_scale):
     """Shared v1/v2 operand validation. Returns
     (b, h, c, d, n, hp, bs, m, quantized); `hp` is the pool (KV) head
     count — equal to h for MHA, a divisor of h for GQA."""
     b, h, c, d = q.shape
-    n, hp, bs, dp = k_pool.shape
-    if (dp != d or hp > h or h % hp != 0
-            or v_pool.shape != k_pool.shape):
+    n, hp, bs, dp = kv_pool.shape
+    if dp != 2 * d or hp > h or h % hp != 0:
         raise ValueError(
-            f"pool shapes {k_pool.shape}/{v_pool.shape} do not match "
-            f"q {q.shape} (GQA needs q heads a multiple of pool heads)")
+            f"pool {kv_pool.shape} and q {q.shape} do not match (a "
+            f"fused pool is (N, H_kv, bs, 2 * head_dim), K beside V; "
+            f"GQA needs q heads a multiple of pool heads)")
     m = block_table.shape[1]
     if block_table.shape[0] != b or q_positions.shape != (b, c):
         raise ValueError(
             f"table {block_table.shape} / positions {q_positions.shape} "
             f"do not match q {q.shape}")
-    quantized = k_pool.dtype == jnp.int8
+    quantized = kv_pool.dtype == jnp.int8
     if quantized:
         if k_scale is None or v_scale is None:
             raise ValueError(
@@ -128,11 +142,11 @@ def _validate_paged_args(q, k_pool, v_pool, block_table, q_positions,
                 or v_scale.shape != (n, hp, bs)):
             raise ValueError(
                 f"scale pools {k_scale.shape}/{v_scale.shape} do not "
-                f"match data pools {k_pool.shape} (want {(n, hp, bs)})")
+                f"match data pool {kv_pool.shape} (want {(n, hp, bs)})")
     elif k_scale is not None or v_scale is not None:
         raise ValueError(
-            f"scale pools passed with non-int8 pools "
-            f"({k_pool.dtype}) — scales only mean something for "
+            f"scale pools passed with a non-int8 pool "
+            f"({kv_pool.dtype}) — scales only mean something for "
             f"quantized KV")
     return b, h, c, d, n, hp, bs, m, quantized
 
@@ -179,10 +193,14 @@ def _pos_matrix(pos_ref, b, c, shape, axis):
     return out
 
 
-def _dequant(codes, scales, dtype):
-    """int8 block (H_kv, bs, D) times its (H_kv, bs) f32 row scales —
-    the reference's dequant expression, op for op."""
-    return (codes.astype(jnp.float32) * scales[..., None]).astype(dtype)
+def _dequant(codes, k_scales, v_scales):
+    """int8 block (H_kv, bs, 2*D) times its (H_kv, bs) f32 row scales,
+    the K scale over lanes [0, D) and the V scale over [D, 2*D) — per
+    element the reference's dequant product, in f32."""
+    d = codes.shape[-1] // 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, codes.shape, 2)
+    scales = jnp.where(lane < d, k_scales[..., None], v_scales[..., None])
+    return codes.astype(jnp.float32) * scales
 
 
 def _repeat_heads(x, g):
@@ -227,8 +245,8 @@ def _compiler_params(vmem_bytes):
                                      32 << 20), 100 << 20)))
 
 
-def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m, h,
-                  hp, d, quantized=False):
+def _paged_kernel(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h, hp,
+                  d, quantized=False):
     """Grid step (b, j): lane b, table column j, all heads — dense AND
     int8 pools share this walk (selected at trace time by `quantized`,
     so the early-stop arithmetic, the NULL guard, the zero-fill the
@@ -236,21 +254,22 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m, h,
     once).
 
     tbl_ref (B, M) / pos_ref (B, C): scalar-prefetched SMEM.
-    q_ref (1, H, C, D); k/v_ref (1, H_kv, bs, D): pool block
-    table[b, j], delivered by the pipeline. gk/gv scratch
-    (H_kv, M*bs, D) VMEM — the lane's gathered view, rows in
-    logical-position order exactly like the reference's dense gather, so
-    the value-path math below can mirror it op for op. Quantized adds
-    the (1, H_kv, bs) f32 scale blocks; the dequant happens as each
-    block lands (gk scratch f32, gv scratch in the output dtype — the
-    reference's dequant expression per row). GQA (hp < h) repeats the
+    q_ref (1, H, C, D); kv_ref (1, H_kv, bs, 2*D): pool block
+    table[b, j], delivered by the pipeline, K in lanes [0, D) and V in
+    [D, 2*D). g scratch (H_kv, M*bs, 2*D) VMEM — the lane's gathered
+    view, rows in logical-position order exactly like the reference's
+    dense gather, so the value-path math below can mirror it op for op
+    once K and V are sliced out of it. Quantized adds the two
+    (1, H_kv, bs) f32 scale blocks; the dequant product happens as each
+    block lands (g scratch f32; V is cast to the output dtype where the
+    reference casts it, after the gather). GQA (hp < h) repeats the
     gathered rows across each query-head group — a pure copy, identical
     to the reference's repeat of its dense gather, so the bitwise pin
     holds."""
     if quantized:
-        ks_ref, vs_ref, o_ref, gk_ref, gv_ref = rest
+        ks_ref, vs_ref, o_ref, g_ref = rest
     else:
-        o_ref, gk_ref, gv_ref = rest
+        o_ref, g_ref = rest
     b, j = pl.program_id(0), pl.program_id(1)
     c = pos_ref.shape[1]
     t = m * bs
@@ -260,18 +279,14 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m, h,
     # bitwise-identical to the reference's 0 * null-block terms
     @pl.when(j == 0)
     def _zero():
-        gk_ref[...] = jnp.zeros_like(gk_ref)
-        gv_ref[...] = jnp.zeros_like(gv_ref)
+        g_ref[...] = jnp.zeros_like(g_ref)
 
     @pl.when(_block_is_live(tbl_ref, pos_ref, b, j, bs, m))
     def _gather():
-        k, v = k_ref[0], v_ref[0]                     # (H_kv, bs, D)
+        blk = kv_ref[0]                               # (H_kv, bs, 2*D)
         if quantized:
-            k = _dequant(k, ks_ref[0], gk_ref.dtype)
-            v = _dequant(v, vs_ref[0], gv_ref.dtype)
-        rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
-        gk_ref[:, rows, :] = k
-        gv_ref[:, rows, :] = v
+            blk = _dequant(blk, ks_ref[0], vs_ref[0])
+        g_ref[:, pl.ds(pl.multiple_of(j * bs, bs), bs), :] = blk
 
     # ---- value path: the reference body on the VMEM-resident gather --
     # (same einsums batched over H, same mask constant, same
@@ -279,8 +294,9 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m, h,
     @pl.when(j == m - 1)
     def _attend():
         q = q_ref[0]                                      # (H, C, D)
-        gk = _repeat_heads(gk_ref[...], h // hp)
-        gv = _repeat_heads(gv_ref[...], h // hp)
+        g = g_ref[...]
+        gk = _repeat_heads(g[..., :d], h // hp)
+        gv = _repeat_heads(g[..., d:].astype(o_ref.dtype), h // hp)
         s = jnp.einsum("hcd,htd->hct", q.astype(gk.dtype), gk,
                        precision=_mxu_precision(gk.dtype),
                        preferred_element_type=jnp.float32) / np.sqrt(d)
@@ -293,18 +309,20 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m, h,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _paged_call(name, kernel, q, pools, scales, block_table,
+def _paged_call(name, kernel, q, kv_pool, scales, block_table,
                 q_positions, scratch, out_dtype, vmem_bytes, interpret):
     """The pallas_call both generations share: grid (lane, table
     column), table + positions scalar-prefetched, q/out one lane per
-    block, every pool one table-addressed block per step."""
+    block, the pool (and each scale pool) one table-addressed block per
+    step."""
     b, h, c, d = q.shape
-    _n, hp, bs, _d = pools[0].shape
+    _n, hp, bs, _d2 = kv_pool.shape
     m = block_table.shape[1]
     lane_spec = pl.BlockSpec((1, h, c, d),
                              lambda b_, j, tbl, pos: (b_, 0, 0, 0))
     in_specs = [lane_spec]
-    in_specs += [_page_spec(p.shape, c, bs, m) for p in pools + scales]
+    in_specs += [_page_spec(p.shape, c, bs, m)
+                 for p in [kv_pool] + scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_table, q_positions
         grid=(b, m),
@@ -321,20 +339,25 @@ def _paged_call(name, kernel, q, pools, scales, block_table,
         name=name,
         interpret=interpret,
     )(block_table.astype(jnp.int32), q_positions.astype(jnp.int32),
-      q, *pools, *scales)
+      q, kv_pool, *scales)
 
 
-def _v1_scratch_shapes(hp, bs, d, m, pool_dtype, out_dtype, quantized):
-    """v1's VMEM gather: K and V at full table width. Quantized pools
-    are dequantized as they land, so K sits in f32 and V in the output
-    dtype; dense pools sit in the pool dtype."""
-    if quantized:
-        return [((hp, m * bs, d), jnp.float32),
-                ((hp, m * bs, d), out_dtype)]
-    return [((hp, m * bs, d), pool_dtype)] * 2
+def _v1_scratch_shapes(hp, bs, d, m, pool_dtype):
+    """v1's VMEM gather: one lane's K and V side by side at full table
+    width, in the pool's dtype; int8 pools are dequantized as they land
+    and sit in f32."""
+    dt = jnp.float32 if pool_dtype == jnp.int8 else pool_dtype
+    return [((hp, m * bs, 2 * d), dt)]
 
 
-def ragged_paged_attention(q, k_pool, v_pool, block_table, q_positions,
+def v1_scratch_bytes(hp, bs, d, m, pool_dtype):
+    """VMEM bytes of that scratch as Mosaic tiles it — what the
+    dispatcher holds against its v1 ceiling."""
+    return sum(_padded_bytes(shp, dt) for shp, dt in
+               _v1_scratch_shapes(hp, bs, d, m, pool_dtype))
+
+
+def ragged_paged_attention(q, kv_pool, block_table, q_positions,
                            k_scale=None, v_scale=None, interpret=None):
     """Paged attention kernel v1: gather-then-compute table walk.
 
@@ -342,31 +365,31 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_positions,
     dispatcher that normally routes here):
 
         q:           (B, H, C, D) — C query tokens per request lane
-        k/v_pool:    (N, H_kv, bs, D), same dtype (f32, bf16 or int8);
-                     H_kv == H (MHA) or a divisor of H (GQA)
+        kv_pool:     (N, H_kv, bs, 2*D), K beside V (f32, bf16 or
+                     int8); H_kv == H (MHA) or a divisor of H (GQA)
         block_table: (B, M) int32 (NULL_BLOCK-padded)
         q_positions: (B, C) int32
         k/v_scale:   (N, H_kv, bs) f32 — required for int8 pools (the
                      per-row dequant scales; dequant is fused into the
                      kernel's gather), absent otherwise
-        returns      (B, H, C, D) in v_pool's dtype (int8 pools: in
+        returns      (B, H, C, D) in the pool's dtype (int8 pools: in
                      q's dtype)
 
     `interpret` defaults to "off-TPU" (flash.py policy)."""
     global TRACE_COUNT
     TRACE_COUNT += 1
     b, h, c, d, n, hp, bs, m, quantized = _validate_paged_args(
-        q, k_pool, v_pool, block_table, q_positions, k_scale, v_scale)
+        q, kv_pool, block_table, q_positions, k_scale, v_scale)
     if interpret is None:
         interpret = _interpret()
-    out_dtype = q.dtype if quantized else v_pool.dtype
-    scratch = _v1_scratch_shapes(hp, bs, d, m, k_pool.dtype, out_dtype,
-                                 quantized)
-    # the gather, its head-repeated f32 view, and the (H, C, T) scores
-    vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
-            * (h // hp) + 3 * _padded_bytes((h, c, m * bs), jnp.float32))
-    return _paged_call("paged_attention_v1", _paged_kernel, q,
-                       [k_pool, v_pool],
+    out_dtype = q.dtype if quantized else kv_pool.dtype
+    scratch = _v1_scratch_shapes(hp, bs, d, m, kv_pool.dtype)
+    # the gather, its two head-repeated slices (each padded back to the
+    # scratch's 128 lanes at head_dim 64), and the (H, C, T) scores
+    vmem = (v1_scratch_bytes(hp, bs, d, m, kv_pool.dtype)
+            + 2 * _padded_bytes((h, m * bs, d), scratch[0][1])
+            + 3 * _padded_bytes((h, c, m * bs), jnp.float32))
+    return _paged_call("paged_attention_v1", _paged_kernel, q, kv_pool,
                        [k_scale, v_scale] if quantized else [],
                        block_table, q_positions, scratch, out_dtype,
                        vmem, interpret)
@@ -379,19 +402,20 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_positions,
 def _v2_scratch_shapes(h, c, d):
     """The v2 VMEM scratch contract, exposed for the white-box test:
     the online-softmax carry (running max, exp-sum, PV partial) — NO
-    dimension depends on the table width M, and the K/V windows are the
-    pipeline's own two block-sized buffers. That independence IS the
+    dimension depends on the table width M, and the block windows are
+    the pipeline's own two block-sized buffers. That independence IS the
     unbounded-context claim. Returns [(shape, dtype), ...]."""
     return [((h, c, 1), jnp.float32), ((h, c, 1), jnp.float32),
             ((h, c, d), jnp.float32)]
 
 
-def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m,
-                     h, hp, d, quantized=False):
+def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h,
+                     hp, d, quantized=False):
     """Grid step (b, j): lane b, table column j, all heads. Block
     table[b, j] arrives through the pipeline (the NEXT block's copy is
-    already in flight while this one computes — the two-window overlap)
-    and folds into the online-softmax carry held in VMEM scratch
+    already in flight while this one computes — the two-window overlap),
+    is split into its K lanes [0, D) and V lanes [D, 2*D), and folds
+    into the online-softmax carry held in VMEM scratch
     (m: running row max, l: rescaled exp-sum, acc: rescaled PV partial,
     all f32). NULL blocks (padding, idle lanes) and columns past the
     lane's last live block are predicated off whole: nothing they hold
@@ -420,12 +444,12 @@ def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m,
 
     @pl.when(_block_is_live(tbl_ref, pos_ref, b, j, bs, m))
     def _fold():
-        kb, vb = k_ref[0], v_ref[0]                   # (H_kv, bs, D)
+        blk = kv_ref[0]                               # (H_kv, bs, 2*D)
         if quantized:
-            kb = _dequant(kb, ks_ref[0], jnp.float32)
-            vb = _dequant(vb, vs_ref[0], jnp.float32)
-        kb = _repeat_heads(kb.astype(jnp.float32), h // hp)
-        vb = _repeat_heads(vb.astype(jnp.float32), h // hp)
+            blk = _dequant(blk, ks_ref[0], vs_ref[0])
+        blk = blk.astype(jnp.float32)
+        kb = _repeat_heads(blk[..., :d], h // hp)
+        vb = _repeat_heads(blk[..., d:], h // hp)
         s = jnp.einsum("hcd,hbd->hcb", q_ref[0].astype(jnp.float32), kb,
                        preferred_element_type=jnp.float32) / np.sqrt(d)
         key_pos = j * bs + jax.lax.broadcasted_iota(
@@ -454,13 +478,13 @@ def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest, bs, m,
             o_ref.dtype)
 
 
-def ragged_paged_attention_v2(q, k_pool, v_pool, block_table,
-                              q_positions, k_scale=None, v_scale=None,
+def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
+                              k_scale=None, v_scale=None,
                               interpret=None):
     """Paged attention kernel v2: block streaming with a flash-style
     online softmax. Identical call contract to
     `ragged_paged_attention` (v1); the difference is the resource
-    shape — VMEM is two block windows per pool plus the carry
+    shape — VMEM is two block windows plus the carry
     (`_v2_scratch_shapes`) regardless of the table width, and
     scores/softmax/PV accumulate in f32 for EVERY pool dtype, with the
     output cast once at the end. v2 is mathematically exact vs the
@@ -471,16 +495,16 @@ def ragged_paged_attention_v2(q, k_pool, v_pool, block_table,
     TRACE_COUNT += 1
     V2_TRACE_COUNT += 1
     b, h, c, d, n, hp, bs, m, quantized = _validate_paged_args(
-        q, k_pool, v_pool, block_table, q_positions, k_scale, v_scale)
+        q, kv_pool, block_table, q_positions, k_scale, v_scale)
     if interpret is None:
         interpret = _interpret()
-    out_dtype = q.dtype if quantized else v_pool.dtype
+    out_dtype = q.dtype if quantized else kv_pool.dtype
     scratch = _v2_scratch_shapes(h, c, d)
     # carry + the f32 head-repeated views of one K and one V block
     vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
             + 4 * _padded_bytes((h, bs, d), jnp.float32))
     return _paged_call("paged_attention_v2", _paged_kernel_v2, q,
-                       [k_pool, v_pool],
+                       kv_pool,
                        [k_scale, v_scale] if quantized else [],
                        block_table, q_positions, scratch, out_dtype,
                        vmem, interpret)
@@ -500,9 +524,9 @@ def gather_pool_blocks(pool, blocks, interpret=None):
     int32 -> (G, H, bs, ...). One block a grid step, the id scalar-
     prefetched into the pool's index_map as the attention kernels do
     it, so the pool is read in the row-major layout those kernels read
-    it in. For an XLA gather of the same blocks the TPU's compiler
-    re-laid the whole pool out first, 52 MB to fetch 1.6 (PERF.md
-    section 6, PR 26). NULL may repeat among `blocks`; each repeat
+    it in. For an XLA gather of the same blocks of a 64-lane pool the
+    TPU's compiler re-laid the whole pool out first, 52 MB to fetch 1.6
+    (PERF.md section 6, PR 26). NULL may repeat among `blocks`; each repeat
     reads it again."""
     if interpret is None:
         interpret = _interpret()

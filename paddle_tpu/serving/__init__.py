@@ -79,8 +79,9 @@ has the block-table layout and tuning guide.
 """
 
 from .kv_cache import (NULL_BLOCK, PagedDecodeLayer, PagedKVCache,
-                       build_paged_decode_cache, gather_block_kv,
-                       paged_attention, paged_attention_reference)
+                       build_paged_decode_cache, fuse_kv,
+                       gather_block_kv, paged_attention,
+                       paged_attention_reference, split_kv)
 from .prefix_cache import PrefixCacheIndex, prompt_chain_keys
 from .scheduler import (ContinuousBatchingScheduler, DeadlineExceeded,
                         GenerationResult, RequestCancelled)
@@ -99,8 +100,8 @@ from .remote import WorkerProxy, make_subprocess_spawn, spawn_worker
 
 __all__ = [
     "PagedKVCache", "PagedDecodeLayer", "paged_attention",
-    "paged_attention_reference", "gather_block_kv",
-    "build_paged_decode_cache", "NULL_BLOCK",
+    "paged_attention_reference", "gather_block_kv", "fuse_kv",
+    "split_kv", "build_paged_decode_cache", "NULL_BLOCK",
     "PrefixCacheIndex", "prompt_chain_keys", "SpecDecodeConfig",
     "SamplingParams", "BeamParams", "BeamHypothesis", "GroupResult",
     "GroupFuture", "Constraint", "RegexConstraint", "ChoiceConstraint",

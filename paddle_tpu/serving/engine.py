@@ -44,7 +44,7 @@ from . import kv_cache as _kvc
 from .decode_strategies import (GroupFuture, RequestGroup,
                                 SamplingParams, gumbel_noise)
 from .kv_cache import (NEG_INF, NULL_BLOCK, PagedKVCache,
-                       paged_attention, write_block_kv,
+                       fuse_kv, paged_attention, write_block_kv,
                        write_block_kv_quant)
 from .scheduler import ContinuousBatchingScheduler, RequestCancelled, _Request
 
@@ -145,20 +145,19 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
     new_pools = []
     for i in range(cfg.num_layers):
         lp = params[f"l{i}"]
-        kp, vp = pools[i]["k"], pools[i]["v"]
+        kvp = pools[i]["kv"]
         ks, vs = pools[i].get("k_scale"), pools[i].get("v_scale")
         hn = _ln(x, lp["ln1_s"], lp["ln1_b"])
         q = (hn @ w(lp, "wq") + lp["bq"]).reshape(s, c, h_count, d)
         k = (hn @ w(lp, "wk") + lp["bk"]).reshape(s, c, kv_count, d)
         v = (hn @ w(lp, "wv") + lp["bv"]).reshape(s, c, kv_count, d)
         if ks is not None:
-            kp, ks = write_block_kv_quant(kp, ks, k, bidx, off)
-            vp, vs = write_block_kv_quant(vp, vs, v, bidx, off)
+            kvp, ks, vs = write_block_kv_quant(kvp, ks, vs, k, v, bidx,
+                                               off)
         else:
-            kp = write_block_kv(kp, k, bidx, off)
-            vp = write_block_kv(vp, v, bidx, off)
-        o = paged_attention(q.transpose(0, 2, 1, 3), kp, vp,
-                            tables, pos, k_scale=ks, v_scale=vs,
+            kvp = write_block_kv(kvp, fuse_kv(k, v), bidx, off)
+        o = paged_attention(q.transpose(0, 2, 1, 3), kvp, tables, pos,
+                            k_scale=ks, v_scale=vs,
                             in_shard_map=in_shard_map)
         o = o.transpose(0, 2, 1, 3).reshape(s, c, h_count * d)
         x = x + (reduce_fn(o @ w(lp, "wo")) + lp["bo"]).astype(x.dtype)
@@ -167,7 +166,7 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
                         approximate=False)
         x = x + (reduce_fn(f @ w(lp, "f1w")) + lp["f1b"]).astype(
             x.dtype)
-        layer = {"k": kp, "v": vp}
+        layer = {"kv": kvp}
         if ks is not None:
             layer["k_scale"], layer["v_scale"] = ks, vs
         new_pools.append(layer)
@@ -383,8 +382,7 @@ class GPTServingModel:
 
         param_specs = jax.tree_util.tree_map(
             lambda ns: ns.spec, shardings)
-        layer_spec = {"k": P(None, axis, None, None),
-                      "v": P(None, axis, None, None)}
+        layer_spec = {"kv": P(None, axis, None, None)}
         if kv_quantized:
             # the (N, H, bs) scale pools shard on the SAME head axis as
             # their code pools — a shard's rows carry their own scales
@@ -1247,7 +1245,7 @@ class GenerationServer:
         k0 = None
         try:
             with cache.pools_lock:
-                k0 = cache.pools[0]["k"]
+                k0 = cache.pools[0]["kv"]
                 out = fn(cache.pools, *args)
                 cache.pools = out[0]
         except Exception as e:
@@ -1420,7 +1418,8 @@ class GenerationServer:
 
     def _nan_block(self, layer, block):
         """Chaos primitive: make `block`'s keys read as NaN. Dense
-        pools take the NaN in the k rows; quantized pools take it in
+        pools take the NaN in the K lanes of their rows; quantized
+        pools take it in
         the k_scale rows instead — an int8 array cannot hold a NaN, but
         NaN * any code dequantizes to NaN, so the poison propagates
         through the SAME attention arithmetic on both layouts."""
@@ -1428,7 +1427,8 @@ class GenerationServer:
         if "k_scale" in pool:
             pool["k_scale"] = pool["k_scale"].at[block].set(jnp.nan)
         else:
-            pool["k"] = pool["k"].at[block].set(jnp.nan)
+            pool["kv"] = pool["kv"].at[
+                block, :, :, :self.cache.head_dim].set(jnp.nan)
 
     def _poison_kv(self, layer, lanes):
         """Chaos hook: NaN the first KV block of the oldest ACTIVE lane
@@ -1520,7 +1520,7 @@ class GenerationServer:
         traced, fell_back = self._kernel_counts
         self._kernel_engaged = traced > 0 and fell_back == 0
         p0 = self.cache.pools[0]
-        kp = p0["k"]
+        kvp = p0["kv"]
         # the probe q uses the COMPUTE dtype (what the fused step feeds
         # the dispatcher) — an int8 pool's queries are never int8
         # the probe q is shaped like the real step's queries ((1, H, 1,
@@ -1530,13 +1530,13 @@ class GenerationServer:
                     _kvc.paged_kernel_supported(
                         jnp.zeros((1, self.model.num_heads, 1,
                                    self.cache.head_dim),
-                                  self.cache.compute_dtype), kp, kp,
+                                  self.cache.compute_dtype), kvp,
                         p0.get("k_scale"), p0.get("v_scale")))
         if expected and not self._kernel_engaged:
             raise RuntimeError(
                 "paged attention kernel was expected "
                 f"(PADDLE_TPU_PAGED_KERNEL={self._kernel_mode}, "
-                f"pool dtype {kp.dtype}) but the fused step traced "
+                f"pool dtype {kvp.dtype}) but the fused step traced "
                 f"{traced} kernel / {fell_back} reference dispatches")
         if not expected and traced > 0:
             raise RuntimeError(
@@ -1701,6 +1701,12 @@ class GenerationServer:
             "version": self._kernel_version,
             "kernel_dispatches": traced,
             "fallback_dispatches": fell_back,
+            # what one table entry addresses, and one grid step of the
+            # walk copies: (H_kv, block_size, 2 * head_dim), K beside V
+            # (a fact for whoever reads a trace, not a switch)
+            "pool_block_shape": [self.cache.num_kv_heads,
+                                 self.cache.block_size,
+                                 2 * self.cache.head_dim],
         }
         # quantized-pool facts (None when dense): the TRUE int8+scales
         # footprint, the dense compute-dtype size the same blocks would

@@ -6,10 +6,23 @@ worst case, and a new batch shape means a new executable. The paged
 layout (PAPERS.md "Ragged Paged Attention") pools KV in fixed-size
 blocks instead:
 
-    per layer:  k_pool, v_pool : (num_blocks, H, block_size, D)
-    per request: block_table   : (max_blocks,) int32 — logical position
+    per layer:  kv_pool      : (num_blocks, H, block_size, 2*D) — the
+                 K row of a token in lanes [0, D), its V row in
+                 [D, 2*D) (`fuse_kv` / `split_kv`)
+    per request: block_table : (max_blocks,) int32 — logical position
                  p lives in pool block table[p // block_size] at row
                  p % block_size.
+
+K and V of a block lie side by side in ONE array, under the key "kv" of
+the layer's dict, because of how a TPU keeps arrays: a minor dim of 64
+(GPT-2's head_dim) would be padded to the 128 lanes of a tile, so the
+compiler kept a (N, H, bs, 64) pool with the BLOCK dim minor, and every
+step re-laid each pool out into the row-major form the Pallas kernels
+read and back again: 62 of a 104 ms step (PERF.md section 6, PR 26 and
+PR 29). With 2*D = 128 in the minor dim the device's own layout is the
+kernels', the bytes are the same, and a step writes a layer's K and V
+with one plan, one read of the touched blocks and one scatter. It is
+the only layout, whatever the head_dim.
 
 Requests of wildly different lengths then share ONE pool (and one
 compiled step): length is data (positions + tables), never shape. Block
@@ -31,13 +44,13 @@ mode picks v1 while its scratch fits the VMEM ceiling and v2 past it;
 everything above the op (scheduler, engine) is kernel-agnostic.
 
 Grouped-query attention (ISSUE 16): ``PagedKVCache(num_kv_heads=)``
-shrinks the pools to (num_blocks, H_kv, block_size, D) with
+shrinks the pools to (num_blocks, H_kv, block_size, 2*D) with
 H % H_kv == 0; query head j attends KV head j // (H/H_kv) (the
 contiguous-group convention). Every byte count — pool_bytes, shard
 bytes, ledger rows, handoff transfers — divides by the group factor,
 compounding with int8 quantization.
 
-`PagedDecodeLayer` adapts a layer's pool slice to the dense mapping
+`PagedDecodeLayer` adapts a layer's pool to the dense mapping
 interface `decoding.py` step_fns consume (`cache[i]["k"]`,
 `update_kv_cache`), so an existing step_fn decodes against either cache
 unchanged. Beam search runs paged too (ISSUE 20): the serving engine's
@@ -60,9 +73,9 @@ share block ids) so the writer's table can be repointed while readers
 keep the original.
 
 Quantized pools (ISSUE 14): ``PagedKVCache(kv_dtype="int8")`` stores
-the block pools as int8 with per-block-row, per-head f32 scales in a
-PARALLEL pool of shape (num_blocks, H, block_size) beside each
-(num_blocks, H, block_size, D) data pool. The write path quantizes
+the block pools as int8 with per-block-row, per-head f32 scales in two
+PARALLEL pools of shape (num_blocks, H, block_size), "k_scale" and
+"v_scale", beside the (num_blocks, H, block_size, 2*D) code pool. The write path quantizes
 (symmetric absmax over D, one scale per written token row per head —
 a full-block scale would force requantizing every resident row on
 every incremental write, which doubles write traffic and compounds
@@ -85,7 +98,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["PagedKVCache", "HostKVTier", "PagedDecodeLayer",
-           "paged_attention",
+           "paged_attention", "fuse_kv", "split_kv", "KV_LAYOUT",
            "paged_attention_reference", "gather_block_kv",
            "gather_block_kv_pair", "gather_block_scales",
            "build_paged_decode_cache", "quantize_kv_rows",
@@ -113,41 +126,50 @@ FALLBACK_REASONS = {}
 # across its first trace, mirroring serving.kernel.version
 KERNEL_VERSIONS = {}
 
-# v1 gathers a lane's whole table into VMEM: 2 pools x M blocks x
-# H_kv x bs x D x itemsize (+ f32 scale rows when quantized). Auto
-# mode streams through v2 once that estimate passes this ceiling —
-# env-overridable so tests (and unusual VMEM budgets) can move it.
+# v1 gathers a lane's whole table into VMEM: M blocks x H_kv x bs x
+# 2*D in the pool's dtype (f32 for int8 pools). Auto mode streams
+# through v2 once that passes this ceiling — env-overridable so tests
+# (and unusual VMEM budgets) can move it.
 V2_AUTO_VMEM_BYTES = 8 * 1024 * 1024
+
+# what a wire payload says of its blocks' layout (wire_geometry): a
+# peer whose pools are the older {"k", "v"} pair of (N, H, bs, D) arrays
+# sends no such word, and deserialize_block refuses it unread
+KV_LAYOUT = "kv_side_by_side"
 
 
 # ---------------------------------------------------------------------------
 # functional ops (jit-traceable; the Pallas kernel contract)
 # ---------------------------------------------------------------------------
 
-def gather_block_kv_pair(k_pool, v_pool, block_table):
-    """Gather BOTH pools dense in one indexed pass: the (B, M) table is
-    flattened into a single gather-index plan applied to k and v, so the
-    reference pays one index build instead of two per layer per step.
-    The two dense (B, H, M*bs, D) materializations themselves are the
-    reference's inherent O(M*bs) HBM cost per lane per step — every
-    decode iteration copies each request's FULL table width regardless
-    of its true length. That is the traffic the Pallas kernel
-    (ops/pallas/paged.py) is built to remove by walking the table
-    in-kernel with a per-lane early stop (not measured on the chip)."""
-    b, m = block_table.shape
-    n, h, bs, d = k_pool.shape
-    flat = block_table.reshape(-1)              # ONE index plan
+def fuse_kv(k, v):
+    """K rows (..., D) and V rows (..., D) side by side, (..., 2*D): the
+    layout of a pool's minor dim, and of what a step writes into it."""
+    return jnp.concatenate([k, v], axis=-1)
 
-    def _take(pool):
-        g = jnp.take(pool, flat, axis=0).reshape(b, m, h, bs, d)
-        return jnp.moveaxis(g, 2, 1).reshape(b, h, m * bs, d)
 
-    return _take(k_pool), _take(v_pool)
+def split_kv(kv):
+    """(..., 2*D) -> (K (..., D), V (..., D)): two slices of the minor
+    dim, the inverse of fuse_kv."""
+    d = kv.shape[-1] // 2
+    return kv[..., :d], kv[..., d:]
+
+
+def gather_block_kv_pair(kv_pool, block_table):
+    """Gather a fused pool dense, one indexed pass for K and V both,
+    and split it: -> (K, V), each (B, H, M*bs, D).
+    The dense materialization is the reference's inherent O(M*bs) HBM
+    cost per lane per step — every decode iteration copies each
+    request's FULL table width regardless of its true length. That is
+    the traffic the Pallas kernel (ops/pallas/paged.py) is built to
+    remove by walking the table in-kernel with a per-lane early stop
+    (not measured on the chip)."""
+    return split_kv(gather_block_kv(kv_pool, block_table))
 
 
 def gather_block_kv(pool, block_table):
-    """pool (N, H, bs, D) gathered by table (B, M) -> dense
-    (B, H, M*bs, D) view in logical-position order."""
+    """pool (N, H, bs, W) gathered by table (B, M) -> dense
+    (B, H, M*bs, W) view in logical-position order."""
     b, m = block_table.shape
     n, h, bs, d = pool.shape
     g = jnp.take(pool, block_table.reshape(-1), axis=0)
@@ -178,18 +200,18 @@ def quantize_kv_rows(vals):
     return q.astype(jnp.int8), scale
 
 
-def paged_attention_reference(q, k_pool, v_pool, block_table,
-                              q_positions, k_scale=None, v_scale=None):
+def paged_attention_reference(q, kv_pool, block_table, q_positions,
+                              k_scale=None, v_scale=None):
     """Pure-JAX paged attention: gather blocks by table, mask keys
     beyond each query's position, softmax in f32, weighted sum.
 
     q:           (B, H, C, D) — C query tokens per request lane
-    k/v_pool:    (N, H, bs, D)
+    kv_pool:     (N, H, bs, 2*D), K beside V (split_kv)
     block_table: (B, M) int32
     q_positions: (B, C) int32 — logical position of each query token
     k/v_scale:   (N, H, bs) f32 per-row scales — REQUIRED for int8
                  pools, absent otherwise
-    returns      (B, H, C, D) in v_pool's dtype (int8 pools: in q's
+    returns      (B, H, C, D) in the pool's dtype (int8 pools: in q's
                  dtype — the model's activation dtype)
 
     The numerics deliberately mirror the dense cache path in
@@ -212,28 +234,29 @@ def paged_attention_reference(q, k_pool, v_pool, block_table,
     physically stored each KV head H/H_kv times (the repeat-KV
     equivalence the GQA tests pin)."""
     d = q.shape[-1]
-    h, hp = q.shape[1], k_pool.shape[1]
-    if hp > h or h % hp:
+    h, hp = q.shape[1], kv_pool.shape[1]
+    if hp > h or h % hp or kv_pool.shape[3] != 2 * d:
         raise ValueError(
-            f"pool heads {hp} do not match q heads {h} (GQA needs q "
-            f"heads a multiple of pool heads)")
+            f"pool {kv_pool.shape} and q {q.shape} do not match (a fused "
+            f"pool is (N, H_kv, bs, 2 * head_dim); GQA needs q heads a "
+            f"multiple of pool heads)")
     rep = h // hp
-    if k_pool.dtype != jnp.int8 and (k_scale is not None
-                                     or v_scale is not None):
+    if kv_pool.dtype != jnp.int8 and (k_scale is not None
+                                      or v_scale is not None):
         # same guard as the kernel entry point, so the error does not
         # depend on WHICH path the dispatcher happened to take (a
         # PADDLE_TPU_PAGED_KERNEL=0 dev loop must not silently drop
         # scales a TPU run would reject)
         raise ValueError(
-            f"scale pools passed with non-int8 pools ({k_pool.dtype}) "
+            f"scale pools passed with a non-int8 pool ({kv_pool.dtype}) "
             f"— scales only mean something for quantized KV")
-    if k_pool.dtype == jnp.int8:
+    if kv_pool.dtype == jnp.int8:
         if k_scale is None or v_scale is None:
             raise ValueError(
                 "int8 pools need k_scale/v_scale (the per-row f32 "
                 "scale pools stored beside the blocks)")
         cdt = q.dtype
-        gkq, gvq = gather_block_kv_pair(k_pool, v_pool, block_table)
+        gkq, gvq = gather_block_kv_pair(kv_pool, block_table)
         gks = gather_block_scales(k_scale, block_table)
         gvs = gather_block_scales(v_scale, block_table)
         gk = gkq.astype(jnp.float32) * gks[..., None]
@@ -250,7 +273,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_table,
         s = jnp.where(mask, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
         return jnp.einsum("bhct,bhtd->bhcd", p, gv)
-    gk, gv = gather_block_kv_pair(k_pool, v_pool, block_table)
+    gk, gv = gather_block_kv_pair(kv_pool, block_table)
     if rep > 1:
         gk = jnp.repeat(gk, rep, axis=1)
         gv = jnp.repeat(gv, rep, axis=1)
@@ -288,14 +311,13 @@ def paged_kernel_mode():
         f"or v2")
 
 
-def _v1_scratch_bytes(k_pool, block_table):
-    """v1's VMEM scratch footprint for these operands: both gathered
-    pools at full table width, plus the f32 scale windows for int8."""
-    n, hp, bs, d = k_pool.shape
-    m = block_table.shape[1]
-    per = m * hp * bs * d * np.dtype(k_pool.dtype).itemsize
-    scales = (2 * m * hp * bs * 4) if k_pool.dtype == jnp.int8 else 0
-    return 2 * per + scales
+def _v1_scratch_bytes(kv_pool, block_table):
+    """v1's VMEM scratch footprint for these operands: the lane's K and
+    V side by side at full table width, as the kernel allocates it."""
+    from ..ops.pallas.paged import v1_scratch_bytes
+    n, hp, bs, d2 = kv_pool.shape
+    return v1_scratch_bytes(hp, bs, d2 // 2, block_table.shape[1],
+                            kv_pool.dtype)
 
 
 def _v2_auto_vmem_bytes():
@@ -303,7 +325,7 @@ def _v2_auto_vmem_bytes():
     return int(raw) if raw else V2_AUTO_VMEM_BYTES
 
 
-def _kernel_version_for(mode, k_pool, block_table):
+def _kernel_version_for(mode, kv_pool, block_table):
     """Which kernel generation a kernel-bound dispatch takes. Explicit
     'v1'/'v2' modes pin it; 'auto'/'force' keep the bitwise-stable v1
     while its table-wide gather fits the VMEM ceiling and stream via
@@ -311,33 +333,26 @@ def _kernel_version_for(mode, k_pool, block_table):
     VMEM problem)."""
     if mode in ("v1", "v2"):
         return mode
-    return ("v2" if _v1_scratch_bytes(k_pool, block_table)
+    return ("v2" if _v1_scratch_bytes(kv_pool, block_table)
             > _v2_auto_vmem_bytes() else "v1")
 
 
-def paged_kernel_supported(q, k_pool, v_pool, k_scale=None,
-                           v_scale=None):
-    """Shapes/dtypes the kernels handle: 4-D operands with matching
-    same-dtype f32 or bf16 pools — pool heads equal to q's heads (MHA)
-    or an exact divisor (GQA) — or int8 pools accompanied by their
-    (N, H_kv, bs) f32 scale pools (quantized serving — the kernels
-    fuse the dequant into the gather)."""
-    if q.ndim != 4 or k_pool.ndim != 4 or v_pool.ndim != 4:
+def paged_kernel_supported(q, kv_pool, k_scale=None, v_scale=None):
+    """Shapes/dtypes the kernels handle: 4-D operands with an f32 or
+    bf16 fused pool (N, H_kv, bs, 2 * q's head_dim) — pool heads equal
+    to q's heads (MHA) or an exact divisor (GQA) — or an int8 pool
+    accompanied by its two (N, H_kv, bs) f32 scale pools (quantized
+    serving — the kernels fuse the dequant into the gather)."""
+    if q.ndim != 4 or kv_pool.ndim != 4:
         return False
-    if k_pool.dtype != v_pool.dtype:
+    h, hp = q.shape[1], kv_pool.shape[1]
+    if hp > h or h % hp or kv_pool.shape[3] != 2 * q.shape[3]:
         return False
-    h, hp = q.shape[1], k_pool.shape[1]
-    if (hp > h or h % hp or q.shape[3] != k_pool.shape[3]
-            or k_pool.shape != v_pool.shape):
-        return False
-    if k_pool.dtype == jnp.int8:
-        return (k_scale is not None and v_scale is not None
-                and k_scale.ndim == 3 and v_scale.ndim == 3
-                and k_scale.shape == k_pool.shape[:3]
-                and v_scale.shape == v_pool.shape[:3]
-                and k_scale.dtype == jnp.float32
-                and v_scale.dtype == jnp.float32)
-    return k_pool.dtype in (jnp.float32, jnp.bfloat16)
+    if kv_pool.dtype == jnp.int8:
+        return all(s is not None and s.shape == kv_pool.shape[:3]
+                   and s.dtype == jnp.float32
+                   for s in (k_scale, v_scale))
+    return kv_pool.dtype in (jnp.float32, jnp.bfloat16)
 
 
 def _record_dispatch(kernel, reason=None, version=None):
@@ -391,7 +406,7 @@ def kernel_dispatch_stats():
             "mode": paged_kernel_mode()}
 
 
-def paged_attention(q, k_pool, v_pool, block_table, q_positions,
+def paged_attention(q, kv_pool, block_table, q_positions,
                     k_scale=None, v_scale=None, *, in_shard_map=False):
     """Paged attention dispatcher — the frozen serving contract.
 
@@ -403,8 +418,9 @@ def paged_attention(q, k_pool, v_pool, block_table, q_positions,
     qualify. `paged_attention_reference`, the documented pure-JAX spec,
     runs only when the operator pinned it (mode off) or the operands do
     not qualify — each with a labeled `serving.kernel.fallback` reason.
-    int8 pools ride the SAME auto mode: the scale pools travel as two
-    extra operands and the decision happens at TRACE time
+    The pool is the fused (N, H_kv, bs, 2*D) array, K beside V. int8
+    pools ride the SAME auto mode: the scale pools travel as two extra
+    operands and the decision happens at TRACE time
     (shapes/dtypes are static under jit), so a compiled fused step pays
     zero dispatch overhead.
 
@@ -418,27 +434,27 @@ def paged_attention(q, k_pool, v_pool, block_table, q_positions,
     mode = paged_kernel_mode()
     if mode == "off":
         _record_dispatch(kernel=False, reason="pinned_off")
-        return paged_attention_reference(q, k_pool, v_pool, block_table,
+        return paged_attention_reference(q, kv_pool, block_table,
                                          q_positions, k_scale, v_scale)
-    if not paged_kernel_supported(q, k_pool, v_pool, k_scale, v_scale):
+    if not paged_kernel_supported(q, kv_pool, k_scale, v_scale):
         if mode == "force" and not in_shard_map:
             raise ValueError(
                 "PADDLE_TPU_PAGED_KERNEL=1 but operands do not qualify "
-                f"(q {q.shape} {q.dtype}, pools {k_pool.shape} "
-                f"{k_pool.dtype}/{v_pool.dtype}, scales "
+                f"(q {q.shape} {q.dtype}, pool {kv_pool.shape} "
+                f"{kv_pool.dtype}, scales "
                 f"{'present' if k_scale is not None else 'absent'})")
         _record_dispatch(kernel=False,
                          reason="unsupported_under_shard_map"
                          if in_shard_map else "unsupported")
-        return paged_attention_reference(q, k_pool, v_pool, block_table,
+        return paged_attention_reference(q, kv_pool, block_table,
                                          q_positions, k_scale, v_scale)
     from ..ops.pallas.paged import (ragged_paged_attention,
                                     ragged_paged_attention_v2)
-    version = _kernel_version_for(mode, k_pool, block_table)
+    version = _kernel_version_for(mode, kv_pool, block_table)
     _record_dispatch(kernel=True, version=version)
     fn = (ragged_paged_attention_v2 if version == "v2"
           else ragged_paged_attention)
-    return fn(q, k_pool, v_pool, block_table, q_positions,
+    return fn(q, kv_pool, block_table, q_positions,
               k_scale=k_scale, v_scale=v_scale)
 
 
@@ -475,20 +491,25 @@ def _plan_block_writes(block_idx, offset, block_size):
 
 
 def write_block_kv(pool, vals, block_idx, offset):
-    """Write vals (B, C, H, D) into pool (N, H, bs, D) at
+    """Write vals (B, C, H, W) into pool (N, H, bs, W) at
     (block_idx (B, C), :, offset (B, C), :); a scale pool (N, H, bs)
-    takes vals (B, C, H) the same way. Masked tokens should be routed
-    to (NULL_BLOCK, 0) by the caller, and a lane's live columns hold
-    consecutive positions (_plan_block_writes). The pool dtype wins
-    (same contract as decoding.update_kv_cache).
+    takes vals (B, C, H) the same way. A step calls it once a layer,
+    on `fuse_kv(k, v)` over the fused pool (W = 2*D): one plan, one
+    read of the touched blocks, one scatter for K and V both. Masked
+    tokens should be routed to (NULL_BLOCK, 0) by the caller, and a
+    lane's live columns hold consecutive positions
+    (_plan_block_writes). The pool dtype wins (same contract as
+    decoding.update_kv_cache).
 
     The touched blocks are read, overlaid and written back whole, all
-    in the row-major layout the Pallas kernels read the pool in, so the
-    step re-lays a pool out once for all three; a scatter of single
-    rows, and an XLA gather of blocks too, each had XLA:TPU re-lay the
-    whole pool out once more (PERF.md section 6, PR 26). Lanes never
-    share a block they write (copy-on-write comes first), so only NULL
-    repeats among the indices, and NULL holds garbage by design."""
+    in the row-major layout the Pallas kernels read the pool in, which
+    is also how the device keeps a pool whose minor dim fills the 128
+    lanes: the step's module holds no copy of a pool (PERF.md section
+    6, PR 29; a scatter of single rows, and an XLA gather of blocks
+    too, each had XLA:TPU re-lay a 64-lane pool out, PR 26). Lanes
+    never share a block they write (copy-on-write comes first), so
+    only NULL repeats among the indices, and NULL holds garbage by
+    design."""
     from ..ops.pallas.paged import gather_pool_blocks
     blocks, src, hit = _plan_block_writes(block_idx, offset,
                                           pool.shape[2])
@@ -503,18 +524,22 @@ def write_block_kv(pool, vals, block_idx, offset):
     return pool.at[flat].set(new.reshape((-1,) + new.shape[2:]))
 
 
-def write_block_kv_quant(pool, scale_pool, vals, block_idx, offset):
-    """write_block_kv for int8 pools: quantize-at-write. vals
+def write_block_kv_quant(pool, k_scale, v_scale, k, v, block_idx,
+                         offset):
+    """write_block_kv for int8 pools: quantize-at-write. k and v
     (B, C, H, D) float are absmax-quantized per (lane, column, head)
-    row; the int8 codes land in pool (N, H, bs, D) and the f32 scales
-    in scale_pool (N, H, bs) at the same (block, row) address, so a
-    block id alone always names BOTH halves of its data. Returns
-    (pool, scale_pool). Masked tokens route to (NULL_BLOCK, 0) like the
-    dense write — the NULL block's codes/scales are garbage by design
-    and the kernel/reference never read them."""
-    q, s = quantize_kv_rows(vals)
-    return (write_block_kv(pool, q, block_idx, offset),
-            write_block_kv(scale_pool, s, block_idx, offset))
+    row, each with a scale of its own; the int8 codes land side by side
+    in pool (N, H, bs, 2*D) in ONE write and the f32 scales in
+    k_scale / v_scale (N, H, bs) at the same (block, row) address, so a
+    block id alone always names all of its data. Returns
+    (pool, k_scale, v_scale). Masked tokens route to (NULL_BLOCK, 0)
+    like the dense write — the NULL block's codes/scales are garbage by
+    design and the kernel/reference never read them."""
+    kq, ks = quantize_kv_rows(k)
+    vq, vs = quantize_kv_rows(v)
+    return (write_block_kv(pool, fuse_kv(kq, vq), block_idx, offset),
+            write_block_kv(k_scale, ks, block_idx, offset),
+            write_block_kv(v_scale, vs, block_idx, offset))
 
 
 # ---------------------------------------------------------------------------
@@ -524,9 +549,9 @@ def write_block_kv_quant(pool, scale_pool, vals, block_idx, offset):
 class HostKVTier:
     """Host-RAM block pool mirroring one PagedKVCache's geometry.
 
-    Same per-layer dict keys as the device pools ("k"/"v" plus
-    "k_scale"/"v_scale" for int8) with the same (N, H_kv, bs, D) block
-    shape, but numpy-backed: eviction under memory pressure becomes a
+    Same per-layer dict keys as the device pools ("kv" plus
+    "k_scale"/"v_scale" for int8) with the same (N, H_kv, bs, 2*D)
+    block shape, K beside V, but numpy-backed: eviction under memory pressure becomes a
     device->host copy (``PagedKVCache.spill_block``) that keeps the
     prefix-chain KV alive, and a later hit swaps the block back in
     (``swap_in_block``) instead of re-prefilling. Preempt-and-resume
@@ -549,7 +574,7 @@ class HostKVTier:
         self.num_blocks = int(num_blocks)
         self.block_size = cache.block_size
         shape = (self.num_blocks, cache.num_kv_heads, cache.block_size,
-                 cache.head_dim)
+                 2 * cache.head_dim)
         # np.dtype() resolves bf16 via the ml_dtypes registration jax
         # itself installs, so the host rows store the device bytes 1:1
         dt = np.dtype(cache.dtype)
@@ -559,7 +584,7 @@ class HostKVTier:
         self._scale_elems = int(np.prod(shape[:3]))
         self.pools = []
         for _ in range(cache.num_layers):
-            layer = {"k": np.zeros(shape, dt), "v": np.zeros(shape, dt)}
+            layer = {"kv": np.zeros(shape, dt)}
             if cache.quantized:
                 # scale 1.0 like the device pools: an unwritten row
                 # dequantizes to exact zeros without a 0*NaN hazard
@@ -603,8 +628,8 @@ class HostKVTier:
         of the ledger's device/host split."""
         n = len(self.pools)
         per = self._layer_elems * self._itemsize
-        scales = self._scale_elems * 4 if self._quantized else 0
-        return 2 * n * (per + scales)
+        scales = 2 * self._scale_elems * 4 if self._quantized else 0
+        return n * (per + scales)
 
 
 # ---------------------------------------------------------------------------
@@ -612,7 +637,8 @@ class HostKVTier:
 # ---------------------------------------------------------------------------
 
 class PagedKVCache:
-    """Device block pools (one k/v pair per layer) + a host free list.
+    """Device block pools (one fused K|V array per layer, `pools[i]["kv"]`
+    of (num_blocks, H_kv, block_size, 2*head_dim)) + a host free list.
 
     Allocation is host-side bookkeeping only (ints in a list); the
     device arrays are fixed-shape for the process lifetime, so every
@@ -621,7 +647,7 @@ class PagedKVCache:
 
     With `mesh=` the pools are laid out head-sharded over the mesh's
     `axis` via NamedSharding — each device holds an
-    (num_blocks, H/tp, block_size, D) shard, the Megatron serving
+    (num_blocks, H/tp, block_size, 2*D) shard, the Megatron serving
     layout the tp decoders already use for the dense cache. ONLY the
     device layout moves: the free list, the block tables, and every
     allocation decision stay replicated host state, so the scheduler
@@ -641,7 +667,7 @@ class PagedKVCache:
     - "bf16": dense bf16 pools, whatever `dtype` says (a convenience
       alias — identical to dtype=jnp.bfloat16);
     - "int8": int8 pools + per-block-row per-head f32 scale pools
-      ("k_scale"/"v_scale" beside "k"/"v" in every layer dict, shape
+      ("k_scale"/"v_scale" beside "kv" in every layer dict, shape
       (num_blocks, H, block_size), head-sharded the same way). Reads
       dequantize to `dtype`; `pool_bytes()` counts codes AND scales."""
 
@@ -702,8 +728,11 @@ class PagedKVCache:
                 f"num_kv_heads={self.num_kv_heads} (head-sharded "
                 f"pools shard the KV heads; with GQA that is H_kv, "
                 f"not the {self.num_heads} query heads)")
+        # K and V of a token side by side in the minor dim (fuse_kv):
+        # at head_dim 64 that is the 128 lanes, and the device keeps
+        # the pool row-major, as the kernels read it
         shape = (self.num_blocks, self.num_kv_heads, self.block_size,
-                 self.head_dim)
+                 2 * self.head_dim)
         sshape = shape[:3]          # the (N, H, bs) scale pools
         if mesh is None:
             def make(shp=shape, dt=dtype):
@@ -714,7 +743,7 @@ class PagedKVCache:
             ns3 = NamedSharding(mesh, P(None, axis, None))
 
             def make(shp=shape, dt=dtype):
-                # device= allocates each (N, H/tp, bs, D) shard in
+                # device= allocates each (N, H/tp, bs, 2*D) shard in
                 # place — a zeros-then-device_put would materialize the
                 # FULL pool on device 0 first, OOMing at exactly the
                 # near-ceiling pool sizes tp serving exists for
@@ -722,7 +751,7 @@ class PagedKVCache:
                                  device=ns if len(shp) == 4 else ns3)
 
         def make_layer():
-            layer = {"k": make(), "v": make()}
+            layer = {"kv": make()}
             if self.quantized:
                 # scale 1.0, not 0: an unwritten row dequantizes to
                 # exact zeros either way, but a zero scale would turn a
@@ -938,7 +967,7 @@ class PagedKVCache:
         COW-repointed block carries its dequantization state with it
         (mixed fleets work too: each holder copies ITS OWN keys, so a
         dense draft sibling beside a quantized target just copies
-        k/v). One jitted signature for the cache lifetime: the block
+        its "kv"). One jitted signature for the cache lifetime: the block
         ids ride as traced scalars, so distinct (src, dst) pairs hit
         the same executable — the fused-step signature budget is
         untouched."""
@@ -1030,13 +1059,16 @@ class PagedKVCache:
     def wire_geometry(self):
         """The block-shape contract a serialized block travels with:
         receivers validate it before touching their pools (the same
-        tuple adopt_block_from checks in-process)."""
+        tuple adopt_block_from checks in-process), and `layout` says
+        how a block's K and V lie, so that a peer whose pools are laid
+        out otherwise is refused, not misread."""
         return {"num_layers": self.num_layers,
                 "num_heads": self.num_heads,
                 "num_kv_heads": self.num_kv_heads,
                 "head_dim": self.head_dim,
                 "block_size": self.block_size,
-                "quantized": bool(self.quantized)}
+                "quantized": bool(self.quantized),
+                "layout": KV_LAYOUT}
 
     def serialize_block(self, block):
         """-> (meta, arrays) for block `block`: meta carries the
@@ -1063,6 +1095,13 @@ class PagedKVCache:
         jitted write signature per cache lifetime (block id rides as a
         traced scalar)."""
         g = meta.get("geometry", {})
+        if g.get("layout") != KV_LAYOUT:
+            raise ValueError(
+                f"deserialize_block: the payload's blocks are laid out "
+                f"as {g.get('layout', 'separate k and v pools')!r}, "
+                f"this cache's as {KV_LAYOUT!r} (one (H_kv, bs, "
+                f"2*head_dim) block, K beside V) — the sender runs "
+                f"another version of the cache")
         src_geo = (g.get("num_layers"), g.get("num_heads"),
                    g.get("num_kv_heads"), g.get("head_dim"),
                    g.get("block_size"))
@@ -1229,9 +1268,9 @@ class PagedKVCache:
 
 @jax.tree_util.register_pytree_node_class
 class PagedDecodeLayer:
-    """One layer's paged cache behind the dense {'k','v'} mapping
-    interface: `layer["k"]` gathers the table's blocks into a dense
-    (B, H, M*bs, D) view (positions past t are NULL-block rows, masked
+    """One layer's paged cache (its fused pool) behind the dense
+    {'k','v'} mapping interface: `layer["k"]` gathers the table's
+    blocks and slices the K lanes out into a dense (B, H, M*bs, D) view (positions past t are NULL-block rows, masked
     by the step_fn's own cache_attention_bias), and
     `decoding.update_kv_cache` routes to `paged_update`, which writes
     this step's K/V into the right (block, offset) slot. A pytree, so
@@ -1243,10 +1282,9 @@ class PagedDecodeLayer:
     write — the existing greedy/sample decode loops run against int8
     KV unchanged."""
 
-    def __init__(self, k_pool, v_pool, block_table, k_scale=None,
+    def __init__(self, kv_pool, block_table, k_scale=None,
                  v_scale=None, compute_dtype=None):
-        self.k_pool = k_pool
-        self.v_pool = v_pool
+        self.kv_pool = kv_pool                  # (N, H, bs, 2*D)
         self.block_table = block_table          # (B, M) int32
         self.k_scale = k_scale                  # (N, H, bs) f32 or None
         self.v_scale = v_scale
@@ -1255,7 +1293,7 @@ class PagedDecodeLayer:
 
     # pytree protocol -------------------------------------------------------
     def tree_flatten(self):
-        return ((self.k_pool, self.v_pool, self.block_table,
+        return ((self.kv_pool, self.block_table,
                  self.k_scale, self.v_scale), self.compute_dtype)
 
     @classmethod
@@ -1266,8 +1304,8 @@ class PagedDecodeLayer:
     def __getitem__(self, key):
         if key not in ("k", "v"):
             raise KeyError(key)
-        pool = self.k_pool if key == "k" else self.v_pool
-        g = gather_block_kv(pool, self.block_table)
+        g = gather_block_kv_pair(self.kv_pool,
+                                 self.block_table)[key == "v"]
         scale = self.k_scale if key == "k" else self.v_scale
         if scale is None:
             return g
@@ -1278,9 +1316,9 @@ class PagedDecodeLayer:
     def paged_update(self, k_t, v_t, t):
         """Write this step's K/V (B, H, 1, D) at logical position t
         (same t for every lane — the lax.scan decode contract). Returns
-        a new adapter over the updated pools; the pool dtype wins, same
+        a new adapter over the updated pool; the pool dtype wins, same
         as the dense path (int8 pools quantize-at-write)."""
-        bs = self.k_pool.shape[2]
+        bs = self.kv_pool.shape[2]
         block_idx = jnp.take_along_axis(
             self.block_table,
             jnp.broadcast_to(t // bs, (self.block_table.shape[0], 1)),
@@ -1291,19 +1329,15 @@ class PagedDecodeLayer:
             # quantized write expects, then index with (B, 1) rows
             bi = block_idx[:, None]
             offs = jnp.broadcast_to(off, bi.shape)
-            kp, ks = write_block_kv_quant(
-                self.k_pool, self.k_scale, k_t.transpose(0, 2, 1, 3),
+            pool, ks, vs = write_block_kv_quant(
+                self.kv_pool, self.k_scale, self.v_scale,
+                k_t.transpose(0, 2, 1, 3), v_t.transpose(0, 2, 1, 3),
                 bi, offs)
-            vp, vs = write_block_kv_quant(
-                self.v_pool, self.v_scale, v_t.transpose(0, 2, 1, 3),
-                bi, offs)
-            return PagedDecodeLayer(kp, vp, self.block_table, ks, vs,
+            return PagedDecodeLayer(pool, self.block_table, ks, vs,
                                     compute_dtype=self.compute_dtype)
-        kp = self.k_pool.at[block_idx, :, off, :].set(
-            k_t[:, :, 0, :].astype(self.k_pool.dtype))
-        vp = self.v_pool.at[block_idx, :, off, :].set(
-            v_t[:, :, 0, :].astype(self.v_pool.dtype))
-        return PagedDecodeLayer(kp, vp, self.block_table,
+        pool = self.kv_pool.at[block_idx, :, off, :].set(
+            fuse_kv(k_t, v_t)[:, :, 0, :].astype(self.kv_pool.dtype))
+        return PagedDecodeLayer(pool, self.block_table,
                                 compute_dtype=self.compute_dtype)
 
 
@@ -1325,7 +1359,7 @@ def build_paged_decode_cache(cache, batch, max_len):
         rows.append(cache.make_table(blocks, m))
         flat.extend(blocks)
     tables = jnp.asarray(np.stack(rows))
-    layers = [PagedDecodeLayer(p["k"], p["v"], tables,
+    layers = [PagedDecodeLayer(p["kv"], tables,
                                p.get("k_scale"), p.get("v_scale"),
                                compute_dtype=cache.compute_dtype)
               for p in cache.pools]

@@ -448,8 +448,9 @@ def test_ledger_kv_pool_bytes_and_retire_on_close(serving_params, dtype):
     per_pool = (srv.cache.num_blocks * cfg.num_heads * 8
                 * (cfg.hidden_size // cfg.num_heads) * itemsize)
     expected = cfg.num_layers * 2 * per_pool        # k AND v pools
-    assert sum(p["k"].size * p["k"].dtype.itemsize
-               + p["v"].size * p["v"].dtype.itemsize
+    # ... which lie side by side in one array a layer
+    assert all(set(p) == {"kv"} for p in srv.cache.pools)
+    assert sum(p["kv"].size * p["kv"].dtype.itemsize
                for p in srv.cache.pools) == expected
     mem = srv.get_stats()["memory"]
     assert mem["kv_cache"] == expected

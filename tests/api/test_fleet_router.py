@@ -44,6 +44,7 @@ from paddle_tpu.serving import (AdmissionPolicy, AdmissionRejected,
                                 GPTServingModel, PagedKVCache,
                                 PrefixCacheIndex, RouterPolicy,
                                 prompt_chain_keys)
+from paddle_tpu.serving.kv_cache import fuse_kv, split_kv
 
 pytestmark = pytest.mark.fleet
 
@@ -118,16 +119,17 @@ def test_adopt_block_from_copies_rows_across_caches():
     (sb,) = src.allocate(1)
     (db,) = dst.allocate(1)
     rng = np.random.default_rng(3)
+    want = []
     for i in range(2):
         rows = rng.standard_normal((2, 4, 4)).astype(np.float32)
-        src.pools[i]["k"] = src.pools[i]["k"].at[sb].set(rows)
-        src.pools[i]["v"] = src.pools[i]["v"].at[sb].set(rows + 1)
+        src.pools[i]["kv"] = src.pools[i]["kv"].at[sb].set(
+            fuse_kv(rows, rows + 1))
+        want.append(rows)
     dst.adopt_block_from(src, sb, db)
     for i in range(2):
-        np.testing.assert_array_equal(np.asarray(dst.pools[i]["k"][db]),
-                                      np.asarray(src.pools[i]["k"][sb]))
-        np.testing.assert_array_equal(np.asarray(dst.pools[i]["v"][db]),
-                                      np.asarray(src.pools[i]["v"][sb]))
+        k, v = split_kv(np.asarray(dst.pools[i]["kv"][db]))
+        np.testing.assert_array_equal(k, want[i])
+        np.testing.assert_array_equal(v, want[i] + 1)
     other = PagedKVCache(2, 4, 4, 6, block_size=4)  # wrong head count
     with pytest.raises(ValueError):
         other.adopt_block_from(src, sb, 1)

@@ -138,6 +138,9 @@ def test_gqa_stream_bitwise_matches_repeat_kv_dense(tiny_gpt,
         assert st["kernel"]["fallback_dispatches"] == 0
         assert st["cancelled"] == 1 and st["retired"] == 3
         assert st["blocks_free"] == st["blocks_total"]
+    # the pool's block carries H_kv heads, and says so
+    assert st_gqa["kernel"]["pool_block_shape"][0] == kv
+    assert st_rep["kernel"]["pool_block_shape"][0] == cfg.num_heads
 
 
 def test_gqa_engages_kernel_v2(tiny_gpt, monkeypatch):
@@ -171,7 +174,7 @@ def test_gqa_pool_bytes_divide_by_group_factor():
     mqa = kvc.PagedKVCache(4, 4, 32, 9, block_size=8, num_kv_heads=1)
     assert mha.pool_bytes() == 2 * gqa.pool_bytes()
     assert mha.pool_bytes() == 4 * mqa.pool_bytes()
-    assert gqa.pools[0]["k"].shape == (9, 2, 8, 32)
+    assert gqa.pools[0]["kv"].shape == (9, 2, 8, 2 * 32)
     # int8 composes: codes AND scales shrink with H_kv, and the dense
     # equivalent stays on the SAME H_kv geometry (the honest
     # denominator — the GQA saving is a separate factor)
@@ -252,4 +255,4 @@ def test_adopt_block_rejects_mismatched_kv_heads():
     src.pools = [{k: v.at[1].set(1.0) for k, v in p.items()}
                  for p in src.pools]
     dst2.adopt_block_from(src, 1, 3)
-    assert float(np.asarray(dst2.pools[0]["k"][3]).min()) == 1.0
+    assert float(np.asarray(dst2.pools[0]["kv"][3]).min()) == 1.0

@@ -54,7 +54,7 @@ from paddle_tpu.robustness import (ChaosInjector, CheckpointManager,
 from paddle_tpu.serving import (FleetRouter, GenerationServer,
                                 GPTServingModel, PagedKVCache,
                                 SpecDecodeConfig, prompt_chain_keys)
-from paddle_tpu.serving.kv_cache import HostKVTier
+from paddle_tpu.serving.kv_cache import HostKVTier, fuse_kv, split_kv
 from paddle_tpu.serving.prefix_cache import PrefixCacheIndex
 
 pytestmark = pytest.mark.serving
@@ -79,10 +79,10 @@ def test_host_tier_mirrors_device_geometry():
     assert host is c.host and isinstance(host, HostKVTier)
     assert len(host.pools) == c.num_layers
     for layer in host.pools:
-        assert set(layer) == {"k", "v"}
-        assert layer["k"].shape == (5, c.num_kv_heads, c.block_size,
-                                    c.head_dim)
-        assert layer["k"].dtype == np.dtype(c.dtype)
+        assert set(layer) == {"kv"}
+        assert layer["kv"].shape == (5, c.num_kv_heads, c.block_size,
+                                     2 * c.head_dim)
+        assert layer["kv"].dtype == np.dtype(c.dtype)
     # no NULL reservation: all 5 ids usable, id 0 included
     got = host.allocate(5)
     assert sorted(got) == [0, 1, 2, 3, 4]
@@ -95,16 +95,16 @@ def test_host_tier_int8_carries_scale_pools():
     c = _cache(kv_dtype="int8")
     host = c.enable_host_tier(3)
     layer = host.pools[0]
-    assert set(layer) == {"k", "v", "k_scale", "v_scale"}
-    assert layer["k"].dtype == np.int8
+    assert set(layer) == {"kv", "k_scale", "v_scale"}
+    assert layer["kv"].dtype == np.int8
     assert layer["k_scale"].dtype == np.float32
     assert layer["k_scale"].shape == (3, c.num_kv_heads, c.block_size)
     # unwritten rows carry scale 1.0 (the 0*NaN lesson from the
     # device pools)
     assert float(layer["k_scale"][0, 0, 0]) == 1.0
     # pool_bytes counts codes AND scales, both k and v, every layer
-    per_layer = layer["k"].nbytes + layer["k_scale"].nbytes
-    assert host.pool_bytes() == 2 * c.num_layers * per_layer
+    per_layer = layer["kv"].nbytes + 2 * layer["k_scale"].nbytes
+    assert host.pool_bytes() == c.num_layers * per_layer
 
 
 def test_host_tier_double_free_raises():
@@ -218,13 +218,42 @@ def test_sibling_pools_spill_and_swap_at_mirrored_ids():
     want_d = _fill_block(d, blk, seed=4)
     hb = c.spill_block(blk)
     np.testing.assert_array_equal(
-        np.asarray(d.host.pools[0]["k"][hb]), want_d[0]["k"])
+        split_kv(np.asarray(d.host.pools[0]["kv"][hb]))[0],
+        split_kv(want_d[0]["kv"])[0])
     nb = c.allocate(1)[0]
     c.swap_in_block(hb, nb)
     np.testing.assert_array_equal(
-        np.asarray(c.pools[1]["v"][nb]), want_c[1]["v"])
+        split_kv(np.asarray(c.pools[1]["kv"][nb]))[1],
+        split_kv(want_c[1]["kv"])[1])
     np.testing.assert_array_equal(
-        np.asarray(d.pools[0]["v"][nb]), want_d[0]["v"])
+        split_kv(np.asarray(d.pools[0]["kv"][nb]))[1],
+        split_kv(want_d[0]["kv"])[1])
+    c.host.free([hb])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_spill_and_swap_in_return_the_same_k_and_v_rows(kv_dtype):
+    """A block is one (H_kv, bs, 2 * head_dim) array, K beside V. Rows
+    written as a K half and a V half come back from the host tier as
+    the same two halves, in a block of another id."""
+    c = _cache(kv_dtype=kv_dtype)
+    c.enable_host_tier(2)
+    rng = np.random.default_rng(17)
+    shape = (c.num_kv_heads, c.block_size, c.head_dim)
+    blk, other = c.allocate(2)
+    want = []
+    for li in range(c.num_layers):
+        k, v = (rng.integers(-100, 100, shape).astype(np.dtype(c.dtype))
+                for _ in range(2))
+        c.pools[li]["kv"] = c.pools[li]["kv"].at[blk].set(fuse_kv(k, v))
+        want.append((k, v))
+    hb = c.spill_block(blk)
+    c.swap_in_block(hb, other)
+    for li, (k, v) in enumerate(want):
+        for rows in (c.host.pools[li]["kv"][hb], c.pools[li]["kv"][other]):
+            got_k, got_v = split_kv(np.asarray(rows))
+            np.testing.assert_array_equal(got_k, k)
+            np.testing.assert_array_equal(got_v, v)
     c.host.free([hb])
 
 

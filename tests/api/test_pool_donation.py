@@ -7,7 +7,7 @@ Tier-1 (`serving` marker, CPU, no sleeps). The contract under test:
   `sampling`, `per_column`, int8 KV with its scale pools, the
   `shard_map` body on a mesh;
 - a step consumes the pools it was handed (the array that was
-  `pools[0]["k"]` is deleted after it) and counts it:
+  `pools[0]["kv"]` is deleted after it) and counts it:
   `serving.kv.pool_donations` moves with `serving.iterations`;
 - so do the other rewriters: `cow_copy`, `swap_in_block`,
   `deserialize_block`, `adopt_block_from` (destination consumed, source
@@ -106,7 +106,7 @@ def test_fused_step_aliases_every_pool_leaf_and_consumes_them(
         tiny_gpt, variant):
     cfg, params = tiny_gpt
     srv = VARIANTS[variant](params, cfg)
-    n_leaves = (4 if variant == "int8_kv" else 2) * cfg.num_layers
+    n_leaves = (3 if variant == "int8_kv" else 1) * cfg.num_layers
     assert len(_leaves(srv.cache)) == n_leaves
     if variant == "plain":
         assert not srv._strategies
@@ -120,11 +120,11 @@ def test_fused_step_aliases_every_pool_leaf_and_consumes_them(
     srv._fused = recording
     don0, it0 = _counters()
     fut = srv.submit([5, 6, 7, 8, 9], max_new_tokens=4)
-    k0 = srv.cache.pools[0]["k"]
+    k0 = srv.cache.pools[0]["kv"]
     assert srv.step()
     # the step took the pools it was handed, and said so
     assert k0.is_deleted()
-    assert not srv.cache.pools[0]["k"].is_deleted()
+    assert not srv.cache.pools[0]["kv"].is_deleted()
     assert _counters() == (don0 + 1, it0 + 1)
     srv.run_until_idle()
     assert len(fut.result(timeout=5).token_ids) == 4
@@ -230,7 +230,7 @@ def _adopt(c):
 def test_pool_rewriters_consume_what_they_rewrite(rewrite, kv_dtype):
     c = _cache(kv_dtype=kv_dtype)
     before, after = rewrite(c)
-    assert len(before) == len(after) >= (4 if kv_dtype else 2) * 2
+    assert len(before) == len(after) >= (3 if kv_dtype else 1) * 2
     assert all(a.is_deleted() for a in before)
     assert not any(a.is_deleted() for a in after)
 

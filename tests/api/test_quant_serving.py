@@ -329,22 +329,26 @@ def test_adopt_block_carries_scales_between_quantized_pools():
     dst = PagedKVCache(2, 2, 8, 9, block_size=4, dtype=jnp.float32,
                        kv_dtype="int8")      # num_blocks may differ
     rng = np.random.default_rng(1)
-    vals = jnp.asarray(rng.standard_normal((1, 4, 2, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 4, 2, 8)), jnp.float32)
+            for _ in range(2))
     bidx = np.full((1, 4), 2, np.int32)
     off = np.arange(4, dtype=np.int32)[None, :]
     for li in range(2):
         p = src.pools[li]
-        kp, ks = kvc.write_block_kv_quant(p["k"], p["k_scale"], vals,
-                                          bidx, off)
-        src.pools[li] = dict(p, k=kp, k_scale=ks)
+        kvp, ks, vs = kvc.write_block_kv_quant(
+            p["kv"], p["k_scale"], p["v_scale"], k, v, bidx, off)
+        src.pools[li] = dict(kv=kvp, k_scale=ks, v_scale=vs)
+    want = [{n: np.asarray(a[2]) for n, a in p.items()}
+            for p in src.pools]
     dst.adopt_block_from(src, 2, 5)
     for li in range(2):
-        np.testing.assert_array_equal(
-            np.asarray(dst.pools[li]["k"][5]),
-            np.asarray(src.pools[li]["k"][2]))
-        np.testing.assert_array_equal(
-            np.asarray(dst.pools[li]["k_scale"][5]),
-            np.asarray(src.pools[li]["k_scale"][2]))
+        for name in ("kv", "k_scale", "v_scale"):
+            np.testing.assert_array_equal(
+                np.asarray(dst.pools[li][name][5]), want[li][name])
+        # K and V codes differ, and each half kept its own scale rows
+        kq, vq = kvc.split_kv(want[li]["kv"])
+        assert (kq != vq).any()
+        assert (want[li]["k_scale"] != want[li]["v_scale"]).any()
 
 
 def test_adopt_block_quantized_dense_mismatch_raises():

@@ -123,9 +123,9 @@ def test_paged_attention_matches_dense():
             tables[i, j] = blk
             pool_k[blk] = k[i, :, j * bs:(j + 1) * bs, :]
             pool_v[blk] = v[i, :, j * bs:(j + 1) * bs, :]
-    out = serving.paged_attention(jnp.asarray(q), jnp.asarray(pool_k),
-                                  jnp.asarray(pool_v),
-                                  jnp.asarray(tables), jnp.asarray(q_pos))
+    out = serving.paged_attention(
+        jnp.asarray(q), serving.fuse_kv(pool_k, pool_v),
+        jnp.asarray(tables), jnp.asarray(q_pos))
     # dense reference with the same masking + f32 softmax
     s = np.einsum("bhcd,bhtd->bhct", q, k) / np.sqrt(d)
     mask = np.arange(t_max)[None, None, None, :] <= q_pos[:, None, :, None]

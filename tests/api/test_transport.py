@@ -357,7 +357,8 @@ def test_serialize_block_round_trip_int8_gqa():
     (blk_a,) = a.allocate(1)
     meta, zeros = a.serialize_block(blk_a)
     assert meta["geometry"]["num_kv_heads"] == 2
-    assert meta["names"] == ["k", "k_scale", "v", "v_scale"]
+    assert meta["names"] == ["k_scale", "kv", "v_scale"]
+    assert meta["geometry"]["layout"] == "kv_side_by_side"
     # fill the block with random codes+scales of the wire shapes,
     # then round-trip: cache A -> bytes -> cache B -> bytes
     payload = []
@@ -375,6 +376,55 @@ def test_serialize_block_round_trip_int8_gqa():
     _, out_b = b.serialize_block(blk_b)
     for got, want in zip(out_b, payload):
         np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_serialized_block_returns_the_same_k_and_v_rows(kv_dtype):
+    """A block travels as one (H_kv, bs, 2 * head_dim) array a layer,
+    K beside V: rows written as a K half and a V half arrive in the
+    other cache as the same two halves."""
+    from paddle_tpu.serving.kv_cache import fuse_kv, split_kv
+    rng = np.random.default_rng(9)
+    kw = dict(num_layers=2, num_heads=4, head_dim=4, num_blocks=6,
+              block_size=8, kv_dtype=kv_dtype, num_kv_heads=2)
+    a, b = PagedKVCache(**kw), PagedKVCache(**kw)
+    (blk_a,), (blk_b, _) = a.allocate(1), b.allocate(2)
+    want = []
+    for li in range(2):
+        k, v = (rng.integers(-100, 100, (2, 8, 4)).astype(
+            np.dtype(a.dtype)) for _ in range(2))
+        a.pools[li]["kv"] = a.pools[li]["kv"].at[blk_a].set(fuse_kv(k, v))
+        want.append((k, v))
+    meta, arrays = a.serialize_block(blk_a)
+    assert arrays[meta["names"].index("kv")].shape == (2, 8, 2 * 4)
+    b.deserialize_block(blk_b, meta, arrays)
+    for li, (k, v) in enumerate(want):
+        got_k, got_v = split_kv(np.asarray(b.pools[li]["kv"][blk_b]))
+        np.testing.assert_array_equal(got_k, k)
+        np.testing.assert_array_equal(got_v, v)
+
+
+def test_deserialize_refuses_a_payload_of_the_paired_layout():
+    """A peer whose pools are the older pair, `k` and `v` of
+    (N, H_kv, bs, head_dim) each, names no layout in its geometry (and
+    two arrays a layer where this cache has one): it is refused by
+    that word, before any array is looked at, and nothing is written."""
+    dense = PagedKVCache(num_layers=2, num_heads=4, head_dim=4,
+                         num_blocks=6, block_size=8, num_kv_heads=2)
+    (dst,) = dense.allocate(1)
+    geo = {k: v for k, v in dense.wire_geometry().items()
+           if k != "layout"}
+    old_meta = {"geometry": geo, "names": ["k", "v"]}
+    old_arrays = [np.ones((2, 8, 4), np.float32)] * 4
+    before = dense.pools[0]["kv"]
+    with pytest.raises(ValueError, match="laid out"):
+        dense.deserialize_block(dst, old_meta, old_arrays)
+    assert dense.pools[0]["kv"] is before and not before.is_deleted()
+    # and a layout word this cache does not know is refused as well
+    with pytest.raises(ValueError, match="laid out"):
+        dense.deserialize_block(
+            dst, {"geometry": dict(geo, layout="v_then_k"),
+                  "names": ["kv"]}, old_arrays[:2])
 
 
 def test_deserialize_rejects_mismatched_pools():

@@ -66,12 +66,15 @@ def test_paged_update_kv_cache_dtype_wins_too():
     layers, _tables, blocks = serving.build_paged_decode_cache(
         pool, batch=2, max_len=8)
     k_t = jnp.full((2, 2, 1, 4), 1.0078125, jnp.float32)
-    out = dec.update_kv_cache(layers[0], k_t, k_t, 5)
+    out = dec.update_kv_cache(layers[0], k_t, 2 * k_t, 5)
     assert isinstance(out, serving.PagedDecodeLayer)
-    dense_view = out["k"]
-    assert dense_view.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(
-        np.asarray(dense_view[:, :, 5, :], np.float32), 1.0078125)
+    # the dense views are the K lanes and the V lanes of the one pool
+    for key, want in (("k", 1.0078125), ("v", 2.015625)):
+        dense_view = out[key]
+        assert dense_view.dtype == jnp.bfloat16
+        assert dense_view.shape == (2, 2, 8, 4)
+        np.testing.assert_array_equal(
+            np.asarray(dense_view[:, :, 5, :], np.float32), want)
     pool.free(blocks)
 
 

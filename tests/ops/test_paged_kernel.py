@@ -44,7 +44,8 @@ def make_case(dtype=jnp.float32, b=3, h=2, c=4, d=8, bs=8, m=6, seed=0,
     live-block count), tables are NULL-padded past the live blocks, and
     block assignment is shuffled so table order != pool order.
     idle_lane=True turns lane 0 into an engine-style masked lane: all
-    positions 0, table all NULL."""
+    positions 0, table all NULL. The pool is the fused one,
+    `kvc.fuse_kv(k_pool, v_pool)`: K beside V in the minor dim."""
     rng = np.random.default_rng(seed)
     n = 1 + b * m
     k_pool = rng.standard_normal((n, h, bs, d)).astype(dtype)
@@ -64,7 +65,8 @@ def make_case(dtype=jnp.float32, b=3, h=2, c=4, d=8, bs=8, m=6, seed=0,
         for j in range(-(-(length + c) // bs)):
             tables[i, j] = free.pop()
         q_pos[i] = np.arange(length, length + c)
-    return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+    return (jnp.asarray(q),
+            kvc.fuse_kv(jnp.asarray(k_pool), jnp.asarray(v_pool)),
             jnp.asarray(tables), jnp.asarray(q_pos))
 
 
@@ -121,7 +123,7 @@ def test_kernel_bf16_allclose(c):
                                rtol=2e-2, atol=2e-2)
 
 
-def test_kernel_output_dtype_follows_v_pool():
+def test_kernel_output_dtype_follows_the_pool():
     argsf = make_case()
     assert paged.ragged_paged_attention(*argsf).dtype == jnp.float32
     argsb = make_case(dtype=jnp.bfloat16)
@@ -145,6 +147,24 @@ def test_null_block_poison_stays_finite_op_level():
         out, np.asarray(paged.ragged_paged_attention(*clean)))
 
 
+@pytest.mark.parametrize("version", ["v1", "v2"])
+@pytest.mark.parametrize("half", ["k_lanes", "v_lanes"])
+def test_null_block_poison_in_one_half_stays_finite(half, version):
+    """K and V of the NULL block share one array now: NaN in its K
+    lanes alone, or in its V lanes alone, reaches no output either, and
+    changes no bit of it, under both kernels."""
+    fn = jax.jit(paged.ragged_paged_attention if version == "v1"
+                 else paged.ragged_paged_attention_v2)
+    q, kv_pool, tables, pos = make_case(seed=3, idle_lane=True)
+    d = q.shape[-1]
+    lanes = slice(0, d) if half == "k_lanes" else slice(d, 2 * d)
+    dirty = kv_pool.at[kvc.NULL_BLOCK, :, :, lanes].set(jnp.nan)
+    out = np.asarray(fn(q, dirty, tables, pos))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(
+        out, np.asarray(fn(q, kv_pool, tables, pos)))
+
+
 def test_consts_mirror_kv_cache():
     """The kernel module duplicates NULL_BLOCK/NEG_INF (it must not
     import the serving layer); drift would silently break the bitwise
@@ -158,8 +178,13 @@ def test_consts_mirror_kv_cache():
 # ---------------------------------------------------------------------------
 
 def test_gather_block_kv_pair_matches_single_gathers():
-    _q, k_pool, v_pool, tables, _pos = make_case(seed=5)
-    gk, gv = kvc.gather_block_kv_pair(k_pool, v_pool, tables)
+    """The fused pool gathered once and split is the K pool and the V
+    pool each gathered alone; fuse_kv and split_kv are inverses."""
+    _q, kv_pool, tables, _pos = make_case(seed=5)
+    k_pool, v_pool = kvc.split_kv(kv_pool)
+    np.testing.assert_array_equal(
+        np.asarray(kvc.fuse_kv(k_pool, v_pool)), np.asarray(kv_pool))
+    gk, gv = kvc.gather_block_kv_pair(kv_pool, tables)
     np.testing.assert_array_equal(
         np.asarray(gk), np.asarray(kvc.gather_block_kv(k_pool, tables)))
     np.testing.assert_array_equal(
@@ -202,18 +227,21 @@ def test_dispatch_env_zero_pins_reference(monkeypatch):
 
 def test_dispatch_force_raises_on_unsupported(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
-    q, k_pool, v_pool, tables, pos = make_case(seed=6)
+    q, kv_pool, tables, pos = make_case(seed=6)
     with pytest.raises(ValueError, match="do not qualify"):
-        kvc.paged_attention(q, k_pool,
-                            v_pool.astype(jnp.float16), tables, pos)
+        kvc.paged_attention(q, kv_pool.astype(jnp.float16), tables, pos)
+    # a pool that is not (N, H, bs, 2 * head_dim) is no fused pool of
+    # this q's, whatever its dtype: K alone, the pair's older shape
+    with pytest.raises(ValueError, match="do not qualify"):
+        kvc.paged_attention(q, kvc.split_kv(kv_pool)[0], tables, pos)
 
 
 def test_dispatch_auto_falls_back_on_unsupported(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
-    q, k_pool, v_pool, tables, pos = make_case(seed=6)
+    q, kv_pool, tables, pos = make_case(seed=6)
     f0 = kvc.FALLBACK_DISPATCHES
-    out = kvc.paged_attention(q, k_pool.astype(jnp.float16),
-                              v_pool.astype(jnp.float16), tables, pos)
+    out = kvc.paged_attention(q, kv_pool.astype(jnp.float16), tables,
+                              pos)
     assert kvc.FALLBACK_DISPATCHES == f0 + 1
     assert out.dtype == jnp.float16
 
@@ -234,23 +262,22 @@ def test_dispatch_takes_the_shard_map_fact_as_an_argument(monkeypatch):
     it takes the kernel like any other trace."""
     from paddle_tpu.observability.metrics import global_registry
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
-    q, k_pool, v_pool, tables, pos = make_case(b=2, c=1, m=3, seed=8)
-    k16, v16 = k_pool.astype(jnp.float16), v_pool.astype(jnp.float16)
+    q, kv_pool, tables, pos = make_case(b=2, c=1, m=3, seed=8)
+    kv16 = kv_pool.astype(jnp.float16)
     reason = global_registry().counter(
         "serving.kernel.fallback").labels(
         reason="unsupported_under_shard_map")
     r0 = reason.value()
     with pytest.raises(ValueError, match="do not qualify"):
-        kvc.paged_attention(q, k16, v16, tables, pos)
-    out = kvc.paged_attention(q, k16, v16, tables, pos,
-                              in_shard_map=True)
+        kvc.paged_attention(q, kv16, tables, pos)
+    out = kvc.paged_attention(q, kv16, tables, pos, in_shard_map=True)
     assert reason.value() == r0 + 1
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(kvc.paged_attention_reference(
-            q, k16, v16, tables, pos)))
+            q, kv16, tables, pos)))
     k0, f0 = kvc.KERNEL_DISPATCHES, kvc.FALLBACK_DISPATCHES
     jax.jit(jax.vmap(lambda a: kvc.paged_attention(
-        a, k_pool, v_pool, tables, pos)))(jnp.stack([q, q + 1]))
+        a, kv_pool, tables, pos)))(jnp.stack([q, q + 1]))
     assert (kvc.KERNEL_DISPATCHES, kvc.FALLBACK_DISPATCHES) == \
         (k0 + 1, f0)
 
@@ -293,26 +320,28 @@ def test_dispatch_fallback_reason_labels(monkeypatch):
     kvc.paged_attention(*args)
     assert off.value() == o0 + 1 and uns.value() == u0
     monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
-    q, k_pool, v_pool, tables, pos = args
-    kvc.paged_attention(q, k_pool.astype(jnp.float16),
-                        v_pool.astype(jnp.float16), tables, pos)
+    q, kv_pool, tables, pos = args
+    kvc.paged_attention(q, kv_pool.astype(jnp.float16), tables, pos)
     assert uns.value() == u0 + 1
     # a deliberate pin DOMINATES: off mode under a vmap trace still
     # records pinned_off, never vmap_trace — a dashboard alerting on
     # non-pinned_off fallback reasons must not page on the pin
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "0")
     o1 = off.value()
-    jax.vmap(lambda a: kvc.paged_attention(a, k_pool, v_pool, tables,
+    jax.vmap(lambda a: kvc.paged_attention(a, kv_pool, tables,
                                            pos))(jnp.stack([q, q]))
     assert off.value() == o1 + 1
 
 
 def test_kernel_validates_shapes():
-    q, k_pool, v_pool, tables, pos = make_case(seed=6)
+    q, kv_pool, tables, pos = make_case(seed=6)
     with pytest.raises(ValueError, match="do not match"):
-        paged.ragged_paged_attention(q, k_pool, v_pool, tables, pos[:1])
+        paged.ragged_paged_attention(q, kv_pool, tables, pos[:1])
     with pytest.raises(ValueError, match="do not match"):
-        paged.ragged_paged_attention(q[:, :1], k_pool, v_pool, tables,
+        paged.ragged_paged_attention(q[:, :1], kv_pool, tables, pos)
+    # the older pair's K pool alone: half a fused pool's minor dim
+    with pytest.raises(ValueError, match="do not match"):
+        paged.ragged_paged_attention(q, kvc.split_kv(kv_pool)[0], tables,
                                      pos)
 
 
@@ -361,6 +390,11 @@ def test_engine_reports_kernel_engagement(tiny_gpt, monkeypatch):
     assert st["kernel"]["engaged"] is True
     assert st["kernel"]["kernel_dispatches"] == cfg.num_layers
     assert st["kernel"]["fallback_dispatches"] == 0
+    # the layout, as a fact beside the kernel's: one block of a layer's
+    # pool is (H_kv, block_size, 2 * head_dim), K beside V
+    shape = [cfg.num_heads, 8, 2 * cfg.hidden_size // cfg.num_heads]
+    assert st["kernel"]["pool_block_shape"] == shape
+    assert list(srv.cache.pools[0]["kv"].shape[1:]) == shape
 
 
 def test_engine_reference_mode_not_engaged(tiny_gpt, monkeypatch):
@@ -419,11 +453,10 @@ def test_engine_null_block_poison_full_stream(tiny_gpt, monkeypatch):
         srv = _server(params, cfg)
         if poison:
             nanrow = jnp.full((cfg.num_heads, srv.block_size,
-                               cfg.hidden_size // cfg.num_heads),
+                               2 * cfg.hidden_size // cfg.num_heads),
                               jnp.nan, srv.cache.dtype)
             srv.cache.pools = [
-                {"k": p["k"].at[kvc.NULL_BLOCK].set(nanrow),
-                 "v": p["v"].at[kvc.NULL_BLOCK].set(nanrow)}
+                {"kv": p["kv"].at[kvc.NULL_BLOCK].set(nanrow)}
                 for p in srv.cache.pools]
         futs = [srv.submit(p, max_new_tokens=n)
                 for p, n in zip(prompts, lens)]

@@ -59,7 +59,8 @@ def make_case(qdt=jnp.float32, b=3, h=2, c=4, d=8, bs=8, m=6, seed=0,
         for j in range(-(-(length + c) // bs)):
             tables[i, j] = free.pop()
         q_pos[i] = np.arange(length, length + c)
-    args = (q, kq, vq, jnp.asarray(tables), jnp.asarray(q_pos), ks, vs)
+    args = (q, kvc.fuse_kv(kq, vq), jnp.asarray(tables),
+            jnp.asarray(q_pos), ks, vs)
     return args, (kf, vf)
 
 
@@ -104,10 +105,10 @@ def test_int8_attention_close_to_dense():
     exact-match-rate pin (per-row absmax keeps the worst-case rounding
     at scale/2 ~= absmax/254 per element)."""
     args, (kf, vf) = make_case(seed=5)
-    q, _kq, _vq, tables, q_pos, _ks, _vs = args
+    q, _kvq, tables, q_pos, _ks, _vs = args
     out = np.asarray(jax.jit(paged.ragged_paged_attention)(*args))
     dense = np.asarray(jax.jit(kvc.paged_attention_reference)(
-        q, jnp.asarray(kf), jnp.asarray(vf), tables, q_pos))
+        q, kvc.fuse_kv(jnp.asarray(kf), jnp.asarray(vf)), tables, q_pos))
     np.testing.assert_allclose(out, dense, rtol=0.05, atol=0.02)
 
 
@@ -128,24 +129,28 @@ def test_quantize_kv_rows_roundtrip_bound():
 
 
 def test_write_block_kv_quant_addresses_both_pools():
-    """A written row's codes and scale land at the SAME (block, row)
-    address, and reading them back dequantizes to the written values
-    within the int8 bound."""
+    """A written row's K and V codes land side by side, and their two
+    scales, at the SAME (block, row) address, and reading them back
+    dequantizes to the written values within the int8 bound."""
     cache = kvc.PagedKVCache(1, 2, 8, 6, block_size=4,
                              dtype=jnp.float32, kv_dtype="int8")
     rng = np.random.default_rng(7)
-    vals = jnp.asarray(rng.standard_normal((1, 4, 2, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 4, 2, 8)), jnp.float32)
+            for _ in range(2))
     bidx = np.full((1, 4), 3, np.int32)
     off = np.arange(4, dtype=np.int32)[None, :]
     p = cache.pools[0]
-    kp, ks = kvc.write_block_kv_quant(p["k"], p["k_scale"], vals, bidx,
-                                      off)
-    back = (np.asarray(kp[3], np.float32)
-            * np.asarray(ks[3])[..., None])        # (H, bs, D)
-    want = np.asarray(vals[0]).transpose(1, 0, 2)  # (H, C=bs, D)
-    np.testing.assert_allclose(back, want, atol=np.abs(want).max() / 64)
+    kvp, ks, vs = kvc.write_block_kv_quant(
+        p["kv"], p["k_scale"], p["v_scale"], k, v, bidx, off)
+    for codes, scales, vals in zip(kvc.split_kv(kvp[3]),
+                                   (ks[3], vs[3]), (k, v)):
+        back = (np.asarray(codes, np.float32)
+                * np.asarray(scales)[..., None])        # (H, bs, D)
+        want = np.asarray(vals[0]).transpose(1, 0, 2)   # (H, C=bs, D)
+        np.testing.assert_allclose(back, want,
+                                   atol=np.abs(want).max() / 64)
     # untouched blocks keep the benign init scale
-    assert np.asarray(ks[2]).min() == 1.0
+    assert np.asarray(ks[2]).min() == np.asarray(vs[2]).min() == 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -183,21 +188,20 @@ def test_int8_without_scales_is_unsupported(monkeypatch):
     pools (codes alone are meaningless), force mode raises the
     dispatcher's message, and the kernel itself validates too."""
     args, _ = make_case(seed=10)
-    q, kq, vq, tables, q_pos, ks, vs = args
-    assert kvc.paged_kernel_supported(q, kq, vq, ks, vs)
-    assert not kvc.paged_kernel_supported(q, kq, vq)
-    assert not kvc.paged_kernel_supported(q, kq, vq, ks, None)
+    q, kvq, tables, q_pos, ks, vs = args
+    assert kvc.paged_kernel_supported(q, kvq, ks, vs)
+    assert not kvc.paged_kernel_supported(q, kvq)
+    assert not kvc.paged_kernel_supported(q, kvq, ks, None)
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
     with pytest.raises(ValueError, match="do not qualify"):
-        kvc.paged_attention(q, kq, vq, tables, q_pos)
+        kvc.paged_attention(q, kvq, tables, q_pos)
     with pytest.raises(ValueError, match="scale"):
-        paged.ragged_paged_attention(q, kq, vq, tables, q_pos)
+        paged.ragged_paged_attention(q, kvq, tables, q_pos)
     # scales with FLOAT pools are a caller bug, not a silent no-op —
     # on EVERY path: the kernel entry point, the reference (so a
     # PADDLE_TPU_PAGED_KERNEL=0 dev loop cannot silently drop scales
     # a TPU run would reject), and the pinned-off dispatcher
-    argsf = (q.astype(jnp.float32),
-             kq.astype(jnp.float32), vq.astype(jnp.float32))
+    argsf = (q.astype(jnp.float32), kvq.astype(jnp.float32))
     with pytest.raises(ValueError, match="scale"):
         paged.ragged_paged_attention(*argsf, tables, q_pos, ks, vs)
     with pytest.raises(ValueError, match="scale"):
@@ -209,7 +213,7 @@ def test_int8_without_scales_is_unsupported(monkeypatch):
 
 def test_int8_scale_shape_validated():
     args, _ = make_case(seed=11)
-    q, kq, vq, tables, q_pos, ks, vs = args
+    q, kvq, tables, q_pos, ks, vs = args
     with pytest.raises(ValueError, match="scale pools"):
-        paged.ragged_paged_attention(q, kq, vq, tables, q_pos,
+        paged.ragged_paged_attention(q, kvq, tables, q_pos,
                                      ks[:, :, :-1], vs)

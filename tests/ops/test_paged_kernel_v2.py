@@ -63,7 +63,8 @@ def make_case(dtype=jnp.float32, b=3, h=4, hp=None, c=4, d=8, bs=8, m=6,
         for j in range(-(-(length + c) // bs)):
             tables[i, j] = free.pop()
         q_pos[i] = np.arange(length, length + c)
-    return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+    return (jnp.asarray(q),
+            kvc.fuse_kv(jnp.asarray(k_pool), jnp.asarray(v_pool)),
             jnp.asarray(tables), jnp.asarray(q_pos))
 
 
@@ -90,7 +91,8 @@ def make_case_int8(b=3, h=4, hp=None, c=4, d=8, bs=8, m=6, seed=0,
         for j in range(-(-(length + c) // bs)):
             tables[i, j] = free.pop()
         q_pos[i] = np.arange(length, length + c)
-    return (q, kq, vq, jnp.asarray(tables), jnp.asarray(q_pos), ks, vs)
+    return (q, kvc.fuse_kv(kq, vq), jnp.asarray(tables),
+            jnp.asarray(q_pos), ks, vs)
 
 
 def _assert_v2_close(args, rtol=1e-5, atol=1e-6):
@@ -131,7 +133,7 @@ def test_v2_idle_lane_is_exact_zero():
     assert not out[0].any()
 
 
-def test_v2_output_dtype_follows_v_pool():
+def test_v2_output_dtype_follows_the_pool():
     assert paged.ragged_paged_attention_v2(
         *make_case()).dtype == jnp.float32
     assert paged.ragged_paged_attention_v2(
@@ -204,30 +206,40 @@ def test_v2_scratch_is_m_independent():
     assert paged._v2_scratch_shapes(h, c, d) == [
         ((h, c, 1), jnp.float32), ((h, c, 1), jnp.float32),
         ((h, c, d), jnp.float32)]
-    narrow = paged._v1_scratch_shapes(2, 8, d, 6, jnp.bfloat16,
-                                      jnp.bfloat16, False)
-    wide = paged._v1_scratch_shapes(2, 8, d, 24, jnp.bfloat16,
-                                    jnp.bfloat16, False)
+    narrow = paged._v1_scratch_shapes(2, 8, d, 6, jnp.bfloat16)
+    wide = paged._v1_scratch_shapes(2, 8, d, 24, jnp.bfloat16)
+    # one scratch, K beside V, in the pool's dtype (f32 for int8 codes,
+    # which are dequantized as they land)
+    assert narrow == [((2, 6 * 8, 2 * d), jnp.bfloat16)]
+    assert paged._v1_scratch_shapes(2, 8, d, 6, jnp.int8) == [
+        ((2, 6 * 8, 2 * d), jnp.float32)]
     assert [s[0][1] for s in wide] == [4 * s[0][1] for s in narrow]
     # and the dispatcher's v1 estimate DOES scale with M — the gap auto
     # mode routes on
-    _q, k_pool, _v, tables, _p = make_case(m=6)
+    _q, kv_pool, tables, _p = make_case(m=6)
     wide = jnp.concatenate([tables] * 4, axis=1)
-    assert kvc._v1_scratch_bytes(k_pool, wide) == \
-        4 * kvc._v1_scratch_bytes(k_pool, tables)
+    assert kvc._v1_scratch_bytes(kv_pool, wide) == \
+        4 * kvc._v1_scratch_bytes(kv_pool, tables)
+    # at head_dim 64 the fused minor dim is the 128 lanes: the estimate
+    # is the bytes themselves, nothing padded (the GPT-2 XL cell's
+    # scratch: 25 heads x 1,024 tokens of bf16, 6.5 MB, under the
+    # ceiling that keeps it on v1)
+    cell = jnp.zeros((2, 25, 16, 128), jnp.bfloat16)
+    assert kvc._v1_scratch_bytes(cell, jnp.zeros((16, 64), jnp.int32)) \
+        == 25 * 1024 * 128 * 2 < kvc.V2_AUTO_VMEM_BYTES
 
 
 def test_v2_wide_table_same_answer():
     """Functionally M-independent: widening the table with NULL padding
     (the shape a long-context pool geometry produces) changes nothing
     — v2 streams the same live blocks through the same 2 slots."""
-    q, k_pool, v_pool, tables, pos = make_case(seed=4)
+    q, kv_pool, tables, pos = make_case(seed=4)
     pad = jnp.full((tables.shape[0], 26), kvc.NULL_BLOCK, jnp.int32)
     wide = jnp.concatenate([tables, pad], axis=1)
     out = np.asarray(jax.jit(paged.ragged_paged_attention_v2)(
-        q, k_pool, v_pool, tables, pos))
+        q, kv_pool, tables, pos))
     out_w = np.asarray(jax.jit(paged.ragged_paged_attention_v2)(
-        q, k_pool, v_pool, wide, pos))
+        q, kv_pool, wide, pos))
     np.testing.assert_array_equal(out, out_w)
 
 
@@ -238,12 +250,11 @@ def test_v2_wide_table_same_answer():
 def _repeat_pools(args, g):
     """The repeat-KV dense equivalent: pools (and scales) expanded to
     one KV head per query head — the bitwise reference for GQA."""
-    q, k_pool, v_pool, tables, pos = args[:5]
-    rep = (q, jnp.repeat(k_pool, g, axis=1),
-           jnp.repeat(v_pool, g, axis=1), tables, pos)
-    if len(args) > 5:
-        rep += (jnp.repeat(args[5], g, axis=1),
-                jnp.repeat(args[6], g, axis=1))
+    q, kv_pool, tables, pos = args[:4]
+    rep = (q, jnp.repeat(kv_pool, g, axis=1), tables, pos)
+    if len(args) > 4:
+        rep += (jnp.repeat(args[4], g, axis=1),
+                jnp.repeat(args[5], g, axis=1))
     return rep
 
 
@@ -308,18 +319,17 @@ def test_gqa_bad_head_geometry_raises():
     validator, the reference, and paged_kernel_supported (so the
     dispatcher degrades instead of tracing garbage)."""
     args = make_case(h=4, hp=2, seed=13)
-    q, k_pool, v_pool, tables, pos = args
+    q, kv_pool, tables, pos = args
     bad_q = q[:, :3]                       # h=3 not a multiple of hp=2
     for fn in (paged.ragged_paged_attention,
                paged.ragged_paged_attention_v2):
         with pytest.raises(ValueError, match="multiple of pool heads"):
-            fn(bad_q, k_pool, v_pool, tables, pos)
+            fn(bad_q, kv_pool, tables, pos)
     with pytest.raises(ValueError, match="multiple of pool heads"):
-        kvc.paged_attention_reference(bad_q, k_pool, v_pool, tables,
-                                      pos)
-    assert not kvc.paged_kernel_supported(bad_q, k_pool, v_pool)
+        kvc.paged_attention_reference(bad_q, kv_pool, tables, pos)
+    assert not kvc.paged_kernel_supported(bad_q, kv_pool)
     # more pool heads than query heads is just as dead
-    assert not kvc.paged_kernel_supported(q[:, :1], k_pool, v_pool)
+    assert not kvc.paged_kernel_supported(q[:, :1], kv_pool)
 
 
 # ---------------------------------------------------------------------------
@@ -371,11 +381,11 @@ def test_dispatch_auto_routes_on_vmem_ceiling(monkeypatch):
     PADDLE_TPU_PAGED_V2_AUTO_BYTES (default V2_AUTO_VMEM_BYTES)."""
     monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
     args = make_case(seed=6)
-    _q, k_pool, _v, tables, _p = args
-    assert kvc._kernel_version_for("auto", k_pool, tables) == "v1"
+    _q, kv_pool, tables, _p = args
+    assert kvc._kernel_version_for("auto", kv_pool, tables) == "v1"
     monkeypatch.setenv("PADDLE_TPU_PAGED_V2_AUTO_BYTES", "1")
     assert kvc._v2_auto_vmem_bytes() == 1
-    assert kvc._kernel_version_for("auto", k_pool, tables) == "v2"
+    assert kvc._kernel_version_for("auto", kv_pool, tables) == "v2"
     t0 = paged.V2_TRACE_COUNT
     jax.jit(lambda *a: kvc.paged_attention(*a))(*args)
     assert paged.V2_TRACE_COUNT == t0 + 1
@@ -393,10 +403,10 @@ def test_dispatch_generation_pin_degrades_on_unsupported(monkeypatch,
     non-qualifying operands — labeled fallback, never a raise (only
     force mode raises)."""
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", env)
-    q, k_pool, v_pool, tables, pos = make_case(seed=6)
+    q, kv_pool, tables, pos = make_case(seed=6)
     f0 = kvc.FALLBACK_DISPATCHES
-    out = kvc.paged_attention(q, k_pool.astype(jnp.float16),
-                              v_pool.astype(jnp.float16), tables, pos)
+    out = kvc.paged_attention(q, kv_pool.astype(jnp.float16), tables,
+                              pos)
     assert kvc.FALLBACK_DISPATCHES == f0 + 1
     assert out.dtype == jnp.float16
     assert kvc.kernel_dispatch_stats()["mode"] == env

@@ -197,9 +197,9 @@ def test_tp2_fused_step_compiles_collectives_and_sharded_pools(trained):
     text = srv._fused.lower(srv.cache.pools, *args).compile().as_text()
     assert "all-reduce" in text or "all_reduce" in text, \
         "tp fused step compiled without any all-reduce"
-    kp = srv.cache.pools[0]["k"]
-    n, h, bs, d = kp.shape
-    sharded_pool = f"f32[{n},{h // 2},{bs},{d}]"
+    kp = srv.cache.pools[0]["kv"]
+    n, h, bs, d2 = kp.shape
+    sharded_pool = f"f32[{n},{h // 2},{bs},{d2}]"
     assert sharded_pool in text, \
         f"no head-sharded pool tensor {sharded_pool} in compiled step"
     srv.close()
@@ -228,7 +228,8 @@ def _ragged_case(h=4, b=3, c=2, d=8, bs=4, m=5, seed=0):
         for j in range(-(-(length + c) // bs)):
             tables[i, j] = free.pop()
         q_pos[i] = np.arange(length, length + c)
-    return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+    return (jnp.asarray(q),
+            kvc.fuse_kv(jnp.asarray(k_pool), jnp.asarray(v_pool)),
             jnp.asarray(tables), jnp.asarray(q_pos))
 
 
@@ -243,20 +244,19 @@ def test_head_sharded_paged_attention_bitwise(monkeypatch, mode):
     from jax import shard_map
 
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", mode)
-    q, k_pool, v_pool, tables, q_pos = _ragged_case()
-    ref = jax.jit(kvc.paged_attention_reference)(q, k_pool, v_pool,
-                                                 tables, q_pos)
+    q, kv_pool, tables, q_pos = _ragged_case()
+    ref = jax.jit(kvc.paged_attention_reference)(q, kv_pool, tables,
+                                                 q_pos)
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     head_ns = NamedSharding(mesh, P(None, "tp", None, None))
     q_s = jax.device_put(q, NamedSharding(mesh, P(None, "tp")))
-    kp_s, vp_s = (jax.device_put(x, head_ns) for x in (k_pool, v_pool))
+    kvp_s = jax.device_put(kv_pool, head_ns)
     k0, f0 = kvc.KERNEL_DISPATCHES, kvc.FALLBACK_DISPATCHES
     fn = shard_map(kvc.paged_attention, mesh=mesh,
-                   in_specs=(P(None, "tp"), P(None, "tp"),
-                             P(None, "tp"), P(), P()),
+                   in_specs=(P(None, "tp"), P(None, "tp"), P(), P()),
                    out_specs=P(None, "tp"), check_vma=False)
-    out = jax.jit(fn)(q_s, kp_s, vp_s, tables, q_pos)
+    out = jax.jit(fn)(q_s, kvp_s, tables, q_pos)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     if mode == "1":     # the kernel really engaged inside shard_map
         assert kvc.KERNEL_DISPATCHES == k0 + 1
@@ -275,10 +275,9 @@ def test_force_mode_unsupported_under_shard_map_falls_back(monkeypatch):
     from jax import shard_map
 
     monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
-    q, k_pool, v_pool, tables, q_pos = _ragged_case(seed=3)
+    q, kv_pool, tables, q_pos = _ragged_case(seed=3)
     q16 = q.astype(jnp.float16)
-    k16 = k_pool.astype(jnp.float16)
-    v16 = v_pool.astype(jnp.float16)
+    kv16 = kv_pool.astype(jnp.float16)
     reason = global_registry().counter(
         "serving.kernel.fallback").labels(
         reason="unsupported_under_shard_map")
@@ -286,17 +285,16 @@ def test_force_mode_unsupported_under_shard_map_falls_back(monkeypatch):
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     fn = shard_map(functools.partial(kvc.paged_attention,
                                      in_shard_map=True), mesh=mesh,
-                   in_specs=(P(None, "tp"), P(None, "tp"),
-                             P(None, "tp"), P(), P()),
+                   in_specs=(P(None, "tp"), P(None, "tp"), P(), P()),
                    out_specs=P(None, "tp"), check_vma=False)
-    out = jax.jit(fn)(q16, k16, v16, tables, q_pos)   # must NOT raise
+    out = jax.jit(fn)(q16, kv16, tables, q_pos)   # must NOT raise
     assert reason.value() == r0 + 1
-    ref = jax.jit(kvc.paged_attention_reference)(q16, k16, v16,
-                                                 tables, q_pos)
+    ref = jax.jit(kvc.paged_attention_reference)(q16, kv16, tables,
+                                                 q_pos)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # plain (no-transform) force misuse still raises loudly
     with pytest.raises(ValueError, match="do not qualify"):
-        kvc.paged_attention(q16, k16, v16, tables, q_pos)
+        kvc.paged_attention(q16, kv16, tables, q_pos)
 
 
 # ---------------------------------------------------------------------------
@@ -400,9 +398,9 @@ from jax.sharding import Mesh
 from paddle_tpu.serving.kv_cache import PagedKVCache
 mesh = Mesh(np.array(jax.devices()), ("tp",))
 cache = PagedKVCache(2, 4, 8, 9, block_size=4, mesh=mesh)
-kp = cache.pools[0]["k"]
+kp = cache.pools[0]["kv"]
 shard = kp.sharding.shard_shape(tuple(kp.shape))
-assert shard == (9, 2, 4, 8), shard
+assert shard == (9, 2, 4, 2 * 8), shard
 assert cache.shard_pool_bytes() * 2 == cache.pool_bytes()
 print("TP_RECIPE_OK")
 """
@@ -484,10 +482,10 @@ def test_tp2_gqa_bitwise_vs_tp1_gqa(trained):
     assert st["blocks_free"] == st["blocks_total"]
     # the pool shards carry H_kv/tp heads — ONE KV head per device
     # here, while each device computes 2 query heads against it
-    kp = srv.cache.pools[0]["k"]
+    kp = srv.cache.pools[0]["kv"]
     shard = kp.sharding.shard_shape(tuple(kp.shape))
     assert shard == (srv.cache.num_blocks, kv // 2,
-                     srv.cache.block_size, cfg.hidden_size
+                     srv.cache.block_size, 2 * cfg.hidden_size
                      // cfg.num_heads)
     assert srv.cache.shard_pool_bytes() * 2 == srv.cache.pool_bytes()
     srv.close()

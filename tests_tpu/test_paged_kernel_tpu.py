@@ -48,14 +48,14 @@ def _case(h, hp, c, d, bs, m, dtype, quantized, seed, b=4):
     if quantized:
         kq, ks = kvc.quantize_kv_rows(jnp.asarray(k))
         vq, vs = kvc.quantize_kv_rows(jnp.asarray(v))
-        clean = [kq, vq] + tail + [ks, vs]
+        clean = [kvc.fuse_kv(kq, vq)] + tail + [ks, vs]
         # poison lives in the scales: int8 codes cannot hold a NaN
-        dirty = [kq, vq] + tail + [ks.at[NULL].set(jnp.nan),
-                                   vs.at[NULL].set(jnp.nan)]
+        dirty = clean[:3] + [ks.at[NULL].set(jnp.nan),
+                             vs.at[NULL].set(jnp.nan)]
     else:
-        kd, vd = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
-        clean = [kd, vd] + tail
-        dirty = [kd.at[NULL].set(jnp.nan), vd.at[NULL].set(jnp.nan)] + tail
+        kv = kvc.fuse_kv(jnp.asarray(k, dtype), jnp.asarray(v, dtype))
+        clean = [kv] + tail
+        dirty = [kv.at[NULL].set(jnp.nan)] + tail
     qd = jnp.asarray(q, dtype)
     return [qd] + dirty, [qd] + clean
 
@@ -176,26 +176,30 @@ def test_block_write_matches_row_scatter_tpu(geom, kv_dtype):
     seeded = {name: jnp.asarray(
         rng.integers(-100, 100, a.shape), a.dtype) for name, a in
         layer.items()}
-    vals = jnp.asarray(rng.standard_normal((b, c, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b, c, h, d)), jnp.float32)
+            for _ in range(2))
     bidx, off = _write_case(cache, b, c, seed=sum(geom) + 1)
 
-    def write(p, vals):
+    def write(p, k, v):
         if cache.quantized:
-            k, ks = kvc.write_block_kv_quant(p["k"], p["k_scale"], vals,
-                                             bidx, off)
-            return dict(p, k=k, k_scale=ks)
-        return dict(p, k=kvc.write_block_kv(p["k"], vals, bidx, off))
+            kv, ks, vs = kvc.write_block_kv_quant(
+                p["kv"], p["k_scale"], p["v_scale"], k, v, bidx, off)
+            return dict(kv=kv, k_scale=ks, v_scale=vs)
+        return dict(kv=kvc.write_block_kv(p["kv"], kvc.fuse_kv(k, v),
+                                          bidx, off))
 
-    def rows(p, vals):
+    def rows(p, k, v):
         if cache.quantized:
-            q, s = kvc.quantize_kv_rows(vals)
-            return dict(p, k=p["k"].at[bidx, :, off, :].set(q),
-                        k_scale=p["k_scale"].at[bidx, :, off].set(s))
-        return dict(p, k=p["k"].at[bidx, :, off, :].set(
-            vals.astype(p["k"].dtype)))
+            (kq, ks), (vq, vs) = (kvc.quantize_kv_rows(x) for x in (k, v))
+            return dict(
+                kv=p["kv"].at[bidx, :, off, :].set(kvc.fuse_kv(kq, vq)),
+                k_scale=p["k_scale"].at[bidx, :, off].set(ks),
+                v_scale=p["v_scale"].at[bidx, :, off].set(vs))
+        return dict(kv=p["kv"].at[bidx, :, off, :].set(
+            kvc.fuse_kv(k, v).astype(p["kv"].dtype)))
 
-    want = jax.jit(rows)(seeded, vals)
-    got = jax.jit(write, donate_argnums=(0,))(seeded, vals)
+    want = jax.jit(rows)(seeded, k, v)
+    got = jax.jit(write, donate_argnums=(0,))(seeded, k, v)
     assert all(a.is_deleted() for a in seeded.values())
     for name in layer:
         np.testing.assert_array_equal(np.asarray(got[name])[1:],
