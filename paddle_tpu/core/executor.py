@@ -68,7 +68,7 @@ def _canon_host(name, a):
 
 
 def _canon_feed(name, value):
-    """Single-value canonicalization (dp path, bench helpers)."""
+    """Single-value canonicalization (dp path)."""
     if isinstance(value, jax.Array):
         # already on device (e.g. the compiled path device_put the feed
         # with its mesh sharding) — converting via numpy would pull it
@@ -607,7 +607,7 @@ class Executor:
 
     def last_compiled_text(self):
         """Optimized HLO of the most recent step executable (post-XLA-opt;
-        what actually ran). Used by bench.py's self-audit and kernel tests."""
+        what actually ran). Used by the HLO audits and kernel tests."""
         return self._last_compiled().as_text()
 
     def last_lowered_text(self):
@@ -617,30 +617,6 @@ class Executor:
         legalization (bf16->f32 upcast) and CSE would erase them from the
         optimized text. Used by tests/perf/ HLO audits."""
         return self._lower_last().as_text()
-
-    def last_cost_analysis(self):
-        """XLA's own cost model for the most recent step executable:
-        {'flops': ..., 'bytes accessed': ..., ...} (keys as XLA names
-        them; flops is the compiler's count for ONE step). Used by
-        bench.py to cross-check the analytic FLOPs/step number — a big
-        mismatch means the MFU denominator is lying."""
-        costs = self._last_compiled().cost_analysis()
-        # older jax returns a one-element list of dicts
-        if isinstance(costs, (list, tuple)):
-            costs = costs[0] if costs else {}
-        return dict(costs or {})
-
-    def static_cost_analysis(self):
-        """Backend-independent cost model of the most recent step: a
-        walk of its traced jaxpr (compile_insight.analyze_jaxpr) —
-        {'flops', 'per_primitive', 'intermediate_bytes', ...}. The
-        cross-check column next to last_cost_analysis(): when XLA's
-        number and this one disagree >2x, one of the tools is lying
-        (tools/roofline.py reports both)."""
-        step_fn, args, mesh_ctx = self._last_step()
-        from ..observability.compile_insight import analyze_jaxpr
-        with mesh_ctx:
-            return analyze_jaxpr(jax.make_jaxpr(step_fn)(*args))
 
     def explain(self, program=None, feed=None, fetch_list=None,
                 scope=None, backend=None):

@@ -5,8 +5,8 @@ ships both).
 Each module exposes `build_*` functions that append ops to the current
 default program and return the key variables (prediction/loss/...), mirroring
 how the reference's book tests compose `fluid.layers`. Training loops live in
-the callers (tests, bench.py) — the framework compiles the whole step to one
-XLA executable either way.
+the callers (tests, examples/, benchmark/) — the framework compiles the whole
+step to one XLA executable either way.
 """
 
 from . import fit_a_line

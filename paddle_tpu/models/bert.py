@@ -10,7 +10,7 @@ TPU notes (why this looks different from the CUDA recipe):
   score tensor in HBM at seq 512;
 - masked-position gather uses a static max_predictions_per_seq so the MLM
   matmul (P, H) x (H, V) stays a fixed MXU shape;
-- matmul path runs bf16 under amp (bench.py wraps with amp bf16 mode),
+- matmul path runs bf16 under amp (the caller wraps with amp bf16 mode),
   params fp32;
 - the whole step (fwd+bwd+adam) is one donated XLA executable via Executor.
 """
@@ -200,8 +200,8 @@ def build_pretrain_net(cfg=None, seq_len=128):
 
 def make_pretrain_feed(cfg, seq_len, batch, seed=0, dtype=None):
     """Synthetic feed dict matching build_pretrain_net's contract — the one
-    place that knows the feed schema (used by bench.py, __graft_entry__ and
-    the model-zoo tests)."""
+    place that knows the feed schema (used by __graft_entry__, examples/
+    and the model-zoo tests)."""
     import numpy as np
     dtype = dtype or np.int64
     rs = np.random.RandomState(seed)

@@ -97,7 +97,7 @@ def apply_knowledge_mask(src_ids, spans_per_row, cfg, seed=0,
 def make_pretrain_feed(cfg, seq_len, batch, seed=0, dtype=None,
                        span_rate=0.2, max_span=4):
     """Synthetic ERNIE feed: random tokens + random phrase spans run through
-    the real knowledge-masking pipeline (bench/dryrun/test entry)."""
+    the real knowledge-masking pipeline (dryrun/test entry)."""
     dtype = dtype or np.int64
     rs = np.random.RandomState(seed)
     src = rs.randint(0, cfg.vocab_size, (batch, seq_len))

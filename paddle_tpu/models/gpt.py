@@ -235,7 +235,7 @@ def build_kv_step(params, cfg, max_len):
 
 def _cast_params(params, dtype):
     """Serving-dtype cast: f32 leaves -> dtype, everything else as-is
-    (the shared policy of every decoder factory and the bench)."""
+    (the shared policy of every decoder factory)."""
     if dtype is None:
         return params
     return jax.tree_util.tree_map(
@@ -249,7 +249,7 @@ def gqa_slice_kv_params(params, cfg, kv_heads):
     (and bk/bv rows), shrinking both projections to kv_heads * head_dim
     outputs. Pair with ``GPTConfig(kv_heads=...)`` to serve the result.
     This is the cheap-ablation GQA conversion (mean-pooling the group
-    is the published alternative) — tests and the bench use it because
+    is the published alternative) — tests use it because
     composing with `gqa_repeat_kv_params` is an EXACT round trip: the
     repeated tree projects bitwise-identical K/V to the sliced tree's
     shared heads, which is what makes a repeat-KV dense server the
@@ -481,8 +481,7 @@ def make_greedy_decoder(params, cfg, max_len, eos_id=None, dtype=None):
     (ids (B, max_len), scores (B,)). `dtype` casts f32 params AND the
     cache for serving (bf16 halves the bandwidth decode is bound by);
     scores/softmax stay f32 inside (build_kv_step). The single wiring
-    point for cache-init + greedy_decode — generate() and bench.py's
-    gpt_decode mode both ride it, so they cannot drift apart."""
+    point for cache-init + greedy_decode — generate() rides it."""
     import jax
     from ..inference import decoding as dec
     params = _cast_params(params, dtype)

@@ -606,7 +606,7 @@ class RecompileTracker:
 
     Hot-path cost: zero on cache hits (never called); one small
     signature comparison per miss — next to the XLA compile a miss
-    pays anyway, this is noise (perf/compile_sample.json pins it).
+    pays anyway, this is noise.
     """
 
     MAX_EVENTS = 64         # bounded postmortem ring per executor

@@ -6,7 +6,7 @@ runtime's unit of work is a whole jitted step, so what matters instead is
 *how the compile caches behave* (hit/miss/evict churn is the difference
 between 1ms and 30s steps). This module is the zero-dependency store for
 those numbers: thread-safe, label-aware, exportable as JSON (one line,
-machine-diffable — perf/ artifacts and tools/trace_report.py read it) and
+machine-diffable — tools/trace_report.py reads it) and
 as Prometheus text exposition (dots sanitized to underscores).
 
 Every metric name the runtime emits is declared in METRIC_SPECS; the
@@ -184,7 +184,7 @@ METRIC_SPECS = [
      "(off-TPU), 0 when compiled for a real TPU"),
     ("serving.prefix.hits", "counter",
      "prefix-cache chunk probes that matched an indexed block (token-"
-     "verified; the bench's hit-rate numerator)"),
+     "verified; the hit-rate numerator)"),
     ("serving.prefix.misses", "counter",
      "prefix-cache chunk probes that missed (absent key, or a hash "
      "collision rejected by the token verify — the chain walk stops "
@@ -722,8 +722,8 @@ class MetricsRegistry:
                             sorted(metrics, key=lambda m: m.name)]}
 
     def to_json(self, indent=None):
-        """One-line JSON by default: perf/ artifacts are parsed
-        line-wise (readers take the LAST line)."""
+        """One-line JSON by default, keys sorted, so two dumps diff
+        line against line."""
         return json.dumps(self.to_dict(), indent=indent, sort_keys=True)
 
     def to_prometheus(self):

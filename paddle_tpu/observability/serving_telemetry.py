@@ -35,8 +35,7 @@ scheduler call into:
 
 Everything here is host-side bookkeeping — dict appends and float
 arithmetic, no jax — and the engine can switch the whole layer off
-(``GenerationServer(telemetry=False)``); the telemetry-on overhead is
-benched in perf/bench_telemetry.json (acceptance < 5%).
+(``GenerationServer(telemetry=False)``).
 """
 
 import collections
@@ -432,9 +431,8 @@ class SLOTracker:
         count) over the same ~2-window view as window_digest, but
         with NO sketch copies or merges — rank() reads the sketches
         in place. This is the router's per-heartbeat burn-rate feed;
-        the copy-and-merge cost of window_digest measured out as a
-        multi-percent serving tax at CPU-tiny step times
-        (perf/bench_signals.json). Returns (None, 0) when the window
+        window_digest's copy-and-merge is too dear for a per-heartbeat
+        read. Returns (None, 0) when the window
         holds no samples. Cross-sketch combination is exact: rank is
         a fraction of mass, so the union's rank is the count-weighted
         mean of member ranks."""
@@ -787,8 +785,7 @@ class ServingTelemetry:
                               window_s=window_s, compression=compression)
         # the signal plane: per-iteration scalars + SLO window closes
         # land here as (t, value) points on the SAME injected clock;
-        # series_capacity=0 switches the store off (the bench's
-        # signals-off arm)
+        # series_capacity=0 switches the store off
         self.series = (SeriesStore(capacity=series_capacity,
                                    label=self.slo.labels.get("server"))
                        if series_capacity else None)

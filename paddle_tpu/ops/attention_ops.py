@@ -23,7 +23,7 @@ _ring_seg_warned = False
 
 def _use_pallas():
     # PADDLE_TPU_FORCE_FLASH=1 routes attention through the Pallas kernels
-    # (interpreter mode off-TPU) — used by tests and bench self-audit.
+    # (interpreter mode off-TPU) — used by tests.
     if os.environ.get("PADDLE_TPU_FORCE_FLASH") == "1":
         return True
     if os.environ.get("PADDLE_TPU_DISABLE_FLASH") == "1":

@@ -104,7 +104,7 @@ NEG_INF = -1e9          # mirrors serving.kv_cache.NEG_INF (the masked
                         # score value the bitwise pin depends on)
 
 # Incremented each time a kernel is TRACED — the serving engine and
-# bench assert the kernel path actually engaged instead of silently
+# its tests assert the kernel path actually engaged instead of silently
 # falling back to the dense gather (flash.py's TRACE_COUNT /
 # VERDICT r1 weak #7 lesson). V2_TRACE_COUNT counts the v2 subset.
 TRACE_COUNT = 0

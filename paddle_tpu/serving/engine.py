@@ -556,9 +556,9 @@ class GenerationServer:
                     f"straight into the target's verify step")
         # request-level telemetry (observability/serving_telemetry.py):
         # lifecycle span trees, SLO digests, and the fault flight
-        # recorder. telemetry=False runs the bare PR-6 engine (the
-        # bench's baseline); an explicit ServingTelemetry instance lets
-        # tests inject clocks/sampling without env vars.
+        # recorder. telemetry=False runs the bare PR-6 engine; an
+        # explicit ServingTelemetry instance lets tests inject
+        # clocks/sampling without env vars.
         if telemetry is True:
             from ..observability.serving_telemetry import ServingTelemetry
             telemetry = ServingTelemetry(
@@ -1059,8 +1059,8 @@ class GenerationServer:
     # -- serve loop --------------------------------------------------------
     def step(self):
         """Run one scheduler iteration + fused device step. Returns
-        True if any lane did work. Public so tests (and the bench) can
-        pump the engine deterministically without the worker thread."""
+        True if any lane did work. Public so tests can pump the engine
+        deterministically without the worker thread."""
         with self._step_lock:
             tel = self._tel
             rec = get_recorder()
@@ -1515,7 +1515,7 @@ class GenerationServer:
         """Runs once, right after the first fused-step trace: if the
         dispatch mode says the Pallas kernel should serve this pool
         dtype but the trace took the reference path (or vice versa when
-        it is pinned off), fail LOUDLY now — not after a bench round
+        it is pinned off), fail LOUDLY now — not after a benchmark run
         reports reference numbers as kernel numbers."""
         traced, fell_back = self._kernel_counts
         self._kernel_engaged = traced > 0 and fell_back == 0

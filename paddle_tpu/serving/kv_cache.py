@@ -113,7 +113,7 @@ KV_QMAX = 127.0         # symmetric int8 range; -128 is never produced,
 
 # Trace-time dispatch accounting (flash.py's TRACE_COUNT idiom): how
 # many paged_attention dispatches routed to the Pallas kernel vs the
-# pure-JAX reference. The engine and bench assert engagement off these
+# pure-JAX reference. The engine and its tests assert engagement off these
 # so a silent fallback can never masquerade as a kernel win.
 # FALLBACK_REASONS mirrors the `serving.kernel.fallback{reason=...}`
 # labeled series so tests and get_stats can tell a deliberate pin
@@ -398,7 +398,7 @@ def _record_dispatch(kernel, reason=None, version=None):
 
 
 def kernel_dispatch_stats():
-    """Module-level dispatch counters as a dict (engine/bench surface)."""
+    """Module-level dispatch counters as a dict (tests read it)."""
     return {"kernel_dispatches": KERNEL_DISPATCHES,
             "fallback_dispatches": FALLBACK_DISPATCHES,
             "fallback_reasons": dict(FALLBACK_REASONS),

@@ -476,8 +476,7 @@ class FleetRouter:
         # snapshots, same idiom as the fleet tracer), and the alert
         # manager evaluates its rules against the router's own series
         # — including the per-heartbeat windowed fleet burn rate fed
-        # by _sample_signals(). signals=False removes the whole plane
-        # (the bench off-arm).
+        # by _sample_signals(). signals=False removes the whole plane.
         self._tenants = TenantLedger()      # router-side costs only:
         #                                     sheds/failovers/handoff
         #                                     bytes (engines own the
@@ -489,10 +488,9 @@ class FleetRouter:
                                and chaos.drives_clock()
                                else time.monotonic)
         # registry-sampling decimation: the per-heartbeat registry
-        # walk + burn-rate digest merge + alert evaluation cost real
-        # microseconds, and at CPU-tiny step times paying them every
-        # iteration is a double-digit tax (perf/bench_signals.json
-        # measures the <5% bar). Keyed to the iteration counter, so
+        # walk + burn-rate digest merge + alert evaluation are host
+        # work on every heartbeat, so they run every signals_every-th
+        # iteration. Keyed to the iteration counter, so
         # decimated timelines replay bit-identically under injected
         # clocks; deterministic storm tests pin signals_every=1.
         self._signals_every = max(1, int(signals_every))

@@ -1050,7 +1050,7 @@ class ContinuousBatchingScheduler:
         arrays always describe live lanes only. A truly idle call
         (nothing queued, active, or to cancel) does NOT count an
         iteration — the background worker's poll loop must not inflate
-        the counter chaos plans and the bench's accounting key off."""
+        the counter chaos plans key off."""
         with self._lock:
             if not (self._queue or self._cancel_rids or self._preempted
                     or any(s is not None for s in self._slots)):

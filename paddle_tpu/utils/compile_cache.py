@@ -1,12 +1,12 @@
 """Where compiled executables persist between processes.
 
-One rule for every entry point that compiles (chip_smoke.py, bench.py,
-the serving worker, the tools): when ``JAX_COMPILATION_CACHE_DIR`` is
-set the directory belongs to whoever set it — JAX reads the variable
-itself and no code here names another. Otherwise the cache lives at a
-fixed path inside the checkout, ``<root>/.jax_cache`` (git-ignored).
-The path is part of how a cache is found again, so it is never a temp
-name, a pid or a timestamp.
+One rule for every entry point that compiles (chip_smoke.py,
+benchmark/run.py, the serving worker, the tools): when
+``JAX_COMPILATION_CACHE_DIR`` is set the directory belongs to whoever
+set it — JAX reads the variable itself and no code here names another.
+Otherwise the cache lives at a fixed path inside the checkout,
+``<root>/.jax_cache`` (git-ignored). The path is part of how a cache is
+found again, so it is never a temp name, a pid or a timestamp.
 """
 
 import os

@@ -518,20 +518,7 @@ def test_memory_endpoint_serves_ledger_snapshot():
 # tool surfaces
 # ---------------------------------------------------------------------------
 
-def test_roofline_crosscheck_flags_2x_disagreement():
-    import roofline
-    ok = roofline._flops_crosscheck(
-        {"analytic_train_flops": 3e9, "static_flops_per_step": 2e9})
-    assert ok.startswith("ok")
-    bad = roofline._flops_crosscheck(
-        {"analytic_train_flops": 9e9, "static_flops_per_step": 2e9})
-    assert "TOOL BUG" in bad
-    none = roofline._flops_crosscheck(
-        {"analytic_train_flops": 3e9, "static_flops_per_step": None})
-    assert "unavailable" in none
-
-
-def test_compile_report_renders_committed_artifact(tmp_path):
+def test_compile_report_renders_saved_report(tmp_path):
     import compile_report
     payload = {
         "explain": {"program": "program_1_v1", "flops": 7.05e8,
@@ -539,30 +526,15 @@ def test_compile_report_renders_committed_artifact(tmp_path):
                     "source": {"flops": "static"},
                     "compile_ms": {"count": 1, "avg": 700.0},
                     "recompiles": [{"summary": "tokens: 10 -> 12"}]},
-        "storm": {"events": 3, "storms": 1,
-                  "last_summary": "tokens: 10 -> 12"},
         "memory_ledger": {"total_bytes": 1000, "entries": [],
                           "by_component": {"exe0": {"params": 1000}}},
     }
     p = tmp_path / "sample.json"
-    p.write_text("garbage preamble\n" + json.dumps(payload) + "\n")
+    p.write_text(json.dumps(payload))
     out = io.StringIO()
-    # run_from prints the table to stdout by default; route via file
-    # param of the printers by monkeypatching is overkill — just check
-    # it parses and returns 0 (demo smoke covers the rendering)
     assert compile_report.run_from(str(p), file=out) == 0
+    text = out.getvalue()
+    assert "program_1_v1" in text and "tokens: 10 -> 12" in text
+    assert "exe0: params=1.00KB" in text
 
 
-def test_committed_compile_sample_is_parseable_and_passed():
-    """The committed artifact stays honest: acceptance bar met,
-    storm observed, explain report present."""
-    path = os.path.join(_REPO, "perf", "compile_sample.json")
-    with open(path) as f:
-        lines = [ln for ln in f if ln.strip().startswith("{")]
-    d = json.loads(lines[-1])
-    assert d["metric"] == "compile_detector_steady_state_overhead"
-    assert d["value"] is not None and d["value"] < 0.05
-    assert d["storm"]["events"] >= 3 and d["storm"]["storms"] >= 1
-    assert d["explain"]["flops"] > 0
-    assert d["explain"]["peak_hbm_bytes"] > 0
-    assert d["tracker_miss_cost_us"] < 5000

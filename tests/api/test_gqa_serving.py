@@ -188,6 +188,39 @@ def test_gqa_pool_bytes_divide_by_group_factor():
     assert q_gqa.pools[0]["k_scale"].shape == (9, 2, 8)
 
 
+def test_gqa_admits_twice_the_lanes_at_one_byte_budget(tiny_gpt,
+                                                       monkeypatch):
+    """The same arithmetic made observable: the bytes of a 26-block
+    MHA pool hold 52 blocks at H_kv = H/2, so a storm of 4-block
+    requests (16-token prompt + 15 new at block_size 8) admits 12
+    lanes (51 usable blocks) where MHA admits 6 (25 usable)."""
+    monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
+    cfg, params = tiny_gpt
+    gqa_params = gpt.gqa_slice_kv_params(params, cfg, 2)
+
+    def pool_bytes(nb, kv_heads):
+        return kvc.PagedKVCache(cfg.num_layers, cfg.num_heads, 32, nb,
+                                block_size=8,
+                                num_kv_heads=kv_heads).pool_bytes()
+
+    nb_gqa = pool_bytes(26, 4) // (pool_bytes(2, 2) // 2)
+    assert nb_gqa == 52
+
+    def admitted(model, nb):
+        srv = _server(model, num_slots=13, max_context=96, num_blocks=nb)
+        prompt = np.arange(3, 19, dtype=np.int32)
+        for _ in range(13):
+            srv.submit(prompt, max_new_tokens=15)
+        srv._sched.plan()       # admission only: no device step
+        got = srv._sched.active_count
+        srv.close(drain=False)
+        return got
+
+    assert admitted(GPTServingModel(params, cfg), 26) == 6
+    assert admitted(GPTServingModel(gqa_params, _gqa_cfg(cfg, 2)),
+                    nb_gqa) == 12
+
+
 def test_gqa_ledger_and_stats_report_kv_truth(tiny_gpt, monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
     cfg, params = tiny_gpt

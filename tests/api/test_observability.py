@@ -388,7 +388,7 @@ def test_trace_report_demo_smoke(tmp_path, capsys):
     assert rc == 0
     assert (tmp_path / "metrics_sample.json").exists()
     assert (tmp_path / "trace_sample.timeline.json").exists()
-    # sample dump is single-line JSON (perf/ artifacts parse line-wise)
+    # sample dump is single-line JSON
     text = (tmp_path / "metrics_sample.json").read_text()
     assert len(text.strip().splitlines()) == 1
     stats = json.loads(text)["executor_stats"]

@@ -6,8 +6,8 @@ speculative decoding, fleet handoff — running against quantized pools.
 The accuracy contract is pinned as EXACT-MATCH RATE against the dense
 engine on the PR-5 acceptance stream (staggered arrivals, mixed
 prompt/output lengths, one mid-stream cancel): greedy ids from int8
-pools must reproduce the dense ids at a floor asserted here and
-recorded in perf/bench_quant.json. The capacity contract is pinned in
+pools must reproduce the dense ids at a floor asserted here. The
+capacity contract is pinned in
 BYTES: an int8 pool (scales included) costs <= 0.56x the same block
 count dense in bf16, and the HBM ledger reports the true quantized
 size, never the dense equivalent.
@@ -109,6 +109,50 @@ def test_int8_pool_bytes_beat_056x_dense_bf16():
     assert q.dense_pool_bytes() == d.pool_bytes()   # same blocks, bf16
 
 
+def test_int8_admits_11_lanes_where_dense_admits_6_at_one_byte_budget():
+    """The capacity claim as block arithmetic made observable, at
+    head_dim 64: the bytes of a 26-block dense bf16 pool hold 48 int8
+    blocks (scales included), so a storm of 4-block requests admits 11
+    lanes (47 usable blocks) where dense admits 6 (25 usable). A
+    request is a 16-token prompt + 15 new = 31 positions at block_size
+    8."""
+    cfg = gpt.GPTConfig(vocab_size=256, hidden_size=128, num_layers=1,
+                        num_heads=2, inner_size=256, max_position=128,
+                        dropout=0.0)
+    main, startup = framework.Program(), framework.Program()
+    with framework.program_guard(main, startup):
+        gpt.build_lm_net(cfg, seq_len=8)
+    scope = Scope()
+    with scope_guard(scope):
+        fluid.Executor().run(startup)
+        params = gpt.load_params(scope, cfg)
+
+    def pool(nb, kv_dtype):
+        return PagedKVCache(cfg.num_layers, cfg.num_heads, 64, nb,
+                            block_size=8, dtype=jnp.bfloat16,
+                            kv_dtype=kv_dtype)
+
+    budget = pool(26, None).pool_bytes()
+    nb_int8 = budget // (pool(2, "int8").pool_bytes() // 2)
+    assert nb_int8 == 48
+
+    def admitted(kv_dtype, nb):
+        srv = GenerationServer(
+            GPTServingModel(params, cfg, dtype=jnp.bfloat16),
+            num_slots=12, block_size=8, max_context=96, chunk=4,
+            start=False, num_blocks=nb, kv_dtype=kv_dtype)
+        prompt = np.arange(3, 19, dtype=np.int32)
+        for _ in range(12):
+            srv.submit(prompt, max_new_tokens=15)
+        srv._sched.plan()       # admission only: no device step
+        got = srv._sched.active_count
+        srv.close(drain=False)
+        return got
+
+    assert admitted(None, 26) == 6
+    assert admitted("int8", nb_int8) == 11
+
+
 def test_ledger_reports_true_quantized_bytes(trained):
     """get_stats()["memory"] kv rows carry int8+scales bytes — the
     watermark/capacity math (shrink-by-tp from PR 9 included) keys off
@@ -189,9 +233,8 @@ def test_staggered_stream_int8_exact_match_floor(trained):
     """THE accuracy pin: int8 KV greedy ids vs dense on the staggered
     mixed-length stream with a mid-stream cancel. The floor is
     asserted here and the measured rate recorded in the failure
-    message (and independently in perf/bench_quant.json); the
-    invariants around it (one signature, kernel engaged, every block
-    reclaimed) must survive quantization untouched."""
+    message; the invariants around it (one signature, kernel engaged,
+    every block reclaimed) must survive quantization untouched."""
     cfg, _scope, params = trained
     dense = _server(params, cfg)
     dense_ids = _drive_staggered_stream(dense)

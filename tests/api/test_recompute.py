@@ -70,7 +70,7 @@ def test_recompute_rematerializes_forward():
     backward when a policy is set (rematted instructions in the optimized
     HLO), and must not when it isn't. Peak-memory benefit is a TPU
     runtime property (the CPU scheduler reuses buffers either way);
-    bench.py audits that on the real chip."""
+    no cell of the benchmark sets a recompute policy yet."""
     def remat_count(recompute):
         _, hlo = _train(recompute, steps=1)
         return hlo.count("rematted")

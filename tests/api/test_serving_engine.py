@@ -317,7 +317,7 @@ def test_chunked_prefill_counts_prompt_tokens(tiny_gpt):
 def test_idle_steps_do_not_count_iterations(tiny_gpt):
     """An idle plan() (nothing queued/active/cancelling) is not an
     iteration: the threaded worker's poll loop must not inflate the
-    counter that chaos plans and bench accounting key off."""
+    counter that chaos plans key off."""
     cfg, _scope, params = tiny_gpt
     srv = _server(params, cfg)
     assert srv.step() is False

@@ -254,9 +254,9 @@ def test_slo_windows_publish_quantile_gauges(tiny_gpt):
 
 
 def test_two_servers_do_not_alias_slo_stats(tiny_gpt):
-    """Two telemetry-enabled servers in one process (the serving bench
-    does exactly this) must keep distinct window gauges and per-server
-    traced counts — the regression is one server reporting the other's
+    """Two telemetry-enabled servers in one process (a fleet of
+    in-process replicas does exactly this) must keep distinct window
+    gauges and per-server traced counts — the regression is one server reporting the other's
     requests."""
     cfg, _scope, params = tiny_gpt
     servers, chaoses = [], []

@@ -1,8 +1,8 @@
 """Test config: force a virtual 8-device CPU mesh before jax initializes.
 
 Mirrors SURVEY.md §4 — parallel tests run on
-xla_force_host_platform_device_count=8 CPU devices; TPU perf is bench.py's
-job, correctness is this suite's job.
+xla_force_host_platform_device_count=8 CPU devices; TPU perf is
+benchmark/run.py's job, correctness is this suite's job.
 """
 
 import os
@@ -22,7 +22,8 @@ import pytest
 import jax
 
 # Numeric tests compare against fp64/numpy goldens; force fp32 matmuls
-# (production path uses bf16 on the MXU — precision is bench.py's concern).
+# (production path uses bf16 on the MXU — tests_tpu/ and the benchmark's
+# `correct` hold that path to its tolerance on the chip).
 jax.config.update("jax_default_matmul_precision", "highest")
 
 

@@ -57,7 +57,7 @@ def test_apply_knowledge_mask_contract():
 
 
 def test_ernie_pretrain_memorizes_fixed_batch():
-    """Real convergence gate (VERDICT r3 #6) on the bench headline
+    """Real convergence gate (VERDICT r3 #6) on the flagship
     model: tiny-ERNIE must OVERFIT a fixed pretrain batch to <5% of the
     initial loss. Calibrated: 80 steps @1e-3 reaches ~0.1% of initial."""
     np.random.seed(0)

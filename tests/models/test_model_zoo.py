@@ -1,7 +1,7 @@
 """Model-zoo smoke + convergence tests (SURVEY.md §4 'models' tier).
 
 Mirrors the reference's book tests: build each model's program, run a few
-steps, assert the loss moves (full convergence is bench/CI-scale; here we
+steps, assert the loss moves (full convergence is CI-scale; here we
 assert trainability on tiny shapes)."""
 
 import numpy as np

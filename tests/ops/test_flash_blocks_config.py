@@ -1,79 +1,28 @@
 """Block-size resolution for the Pallas flash kernels.
 
-Priority order (locked here so the hardware tuner's persisted winner
-actually reaches the end-of-round bench): explicit env var >
-perf/flash_tuned.json (written by tools/tune_flash.py on real TPU,
-applied only when running on TPU) > built-in 128. A malformed file or
-value must fall back cleanly, never crash kernel setup.
+An explicit PADDLE_TPU_FLASH_BLOCK_Q / _K overrides its own side; a
+side with no variable set is the built-in 128. A bad value fails at
+resolution, naming the variable, never inside kernel setup.
 """
 
-import json
-
-import jax
 import pytest
 
 from paddle_tpu.ops.pallas import flash
 
 
 @pytest.fixture(autouse=True)
-def _reset_cache(monkeypatch):
-    monkeypatch.setattr(flash, "_TUNED_CACHE", flash._TUNED_UNSET)
+def _no_block_env(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_FLASH_BLOCK_Q", raising=False)
     monkeypatch.delenv("PADDLE_TPU_FLASH_BLOCK_K", raising=False)
-    yield
-    monkeypatch.setattr(flash, "_TUNED_CACHE", flash._TUNED_UNSET)
 
 
-def _write_tuned(tmp_path, monkeypatch, payload, on_tpu=True):
-    p = tmp_path / "flash_tuned.json"
-    p.write_text(payload if isinstance(payload, str) else json.dumps(payload))
-    monkeypatch.setenv("PADDLE_TPU_FLASH_TUNED_FILE", str(p))
-    if on_tpu:
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
-def test_builtin_default_without_file(monkeypatch, tmp_path):
-    monkeypatch.setenv("PADDLE_TPU_FLASH_TUNED_FILE",
-                       str(tmp_path / "absent.json"))
+def test_builtin_default_without_file():
     assert flash.default_blocks() == (128, 128)
 
 
-def test_tuned_file_supplies_default(monkeypatch, tmp_path):
-    _write_tuned(tmp_path, monkeypatch,
-                 {"block_q": 256, "block_k": 512, "backend": "tpu"})
-    assert flash.default_blocks() == (256, 512)
-
-
-def test_tuned_file_ignored_off_tpu(monkeypatch, tmp_path):
-    # this suite runs on CPU: a committed v5e-tuned file must not
-    # change interpreter-mode test shapes
-    _write_tuned(tmp_path, monkeypatch,
-                 {"block_q": 256, "block_k": 512, "backend": "tpu"},
-                 on_tpu=False)
-    assert flash.default_blocks() == (128, 128)
-
-
-def test_env_overrides_tuned_file(monkeypatch, tmp_path):
-    _write_tuned(tmp_path, monkeypatch,
-                 {"block_q": 256, "block_k": 512, "backend": "tpu"})
+def test_env_overrides_tuned_file(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "64")
-    assert flash.default_blocks() == (64, 512)
-
-
-def test_malformed_file_falls_back(monkeypatch, tmp_path):
-    _write_tuned(tmp_path, monkeypatch, "{not json")
-    assert flash.default_blocks() == (128, 128)
-
-
-@pytest.mark.parametrize("payload", [
-    {"block_q": 0, "block_k": 512, "backend": "tpu"},
-    {"block_q": None, "block_k": 128, "backend": "tpu"},  # TypeError path
-    [128, 128],                                           # wrong shape
-    {"block_q": 128, "backend": "tpu"},                   # missing key
-])
-def test_bad_tuned_values_ignored(monkeypatch, tmp_path, payload):
-    _write_tuned(tmp_path, monkeypatch, payload)
-    assert flash.default_blocks() == (128, 128)
+    assert flash.default_blocks() == (64, 128)
 
 
 def test_bad_env_value_still_raises(monkeypatch):

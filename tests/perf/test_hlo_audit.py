@@ -265,7 +265,7 @@ def test_donated_state_is_aliased(mesh_kwargs, n_dev):
 
 
 def test_kv_decode_scan_stays_on_device():
-    """The KV-cache decode loop (bench gpt_decode / gpt.generate) must
+    """The KV-cache decode loop (gpt.generate) must
     compile to one on-device scan: a host transfer per generated token
     would turn serving latency into host round trips x max_len."""
     import jax.numpy as jnp

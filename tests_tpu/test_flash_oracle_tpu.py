@@ -73,10 +73,10 @@ def test_flash_bwd_tpu(bias_kind):
 
 def test_flash_bench_shape_bwd_runs_promptly():
     """Isolates the headline attention shape (BERT-base: h=12, t=512,
-    d=64, bf16, fwd+bwd) from the rest of the bench: if the Mosaic
-    kernel compiles and steps in seconds here, a future bench stall is
-    not the flash kernel's fault. The bound is a hang tripwire (minutes
-    of slack), not a perf assertion."""
+    d=64, bf16, fwd+bwd) from the rest of a training step: if the Mosaic
+    kernel compiles and steps in seconds here, a future stall of a
+    training cell is not the flash kernel's fault. The bound is a hang
+    tripwire (minutes of slack), not a perf assertion."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash

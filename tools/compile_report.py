@@ -4,12 +4,11 @@ compile ms / recompile causes.
 The data comes from ``Executor.explain(program, feed)``
 (docs/observability.md "Compile & memory"). Two modes:
 
-    python tools/compile_report.py --from perf/compile_sample.json
-    python tools/compile_report.py --demo [--out-dir perf]
+    python tools/compile_report.py --demo [--out-dir DIR]
+    python tools/compile_report.py --from DIR/compile_report_demo.json
 
-``--from`` renders a committed artifact (the BENCH_COMPILE_SAMPLE
-bench's JSON line, or any file whose last JSON line carries an
-"explain" report or a list of them). ``--demo`` builds a tiny GPT
+``--from`` renders a saved JSON file: what ``--demo --out-dir`` wrote,
+one ``explain()`` report, or a list of them. ``--demo`` builds a tiny GPT
 train program on the CPU backend, drives an unbucketed-shape stream
 past the recompile-storm threshold, calls explain(), and prints the
 table plus the storm summary — the 60-second smoke of the whole
@@ -69,8 +68,8 @@ def print_memory_summary(snapshot, file=None):
 
 
 def _extract_reports(payload):
-    """Accept an explain() report, a list of them, or a bench
-    compile_sample line ({"explain": {...}, ...})."""
+    """Accept an explain() report, a list of them, or what --demo
+    --out-dir wrote ({"explain": {...}, "memory_ledger": {...}})."""
     if isinstance(payload, list):
         return payload
     if "explain" in payload:
@@ -79,25 +78,11 @@ def _extract_reports(payload):
 
 
 def run_from(path, file=None):
-    last = None
     with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if line.startswith("{") or line.startswith("["):
-                last = line
-    if last is None:
-        print(f"compile_report: no JSON line in {path}", file=sys.stderr)
-        return 1
-    payload = json.loads(last)
-    reports = _extract_reports(payload)
-    print_report_table(reports, file=file)
+        payload = json.load(f)
+    print_report_table(_extract_reports(payload), file=file)
     if isinstance(payload, dict) and payload.get("memory_ledger"):
         print_memory_summary(payload["memory_ledger"], file=file)
-    if isinstance(payload, dict) and payload.get("storm"):
-        s = payload["storm"]
-        print(f"recompile storm sample: {s.get('events')} events, "
-              f"{s.get('storms')} warning(s); latest diff: "
-              f"{s.get('last_summary')}", file=file)
     return 0
 
 
@@ -162,8 +147,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description="compile-plane report table (Executor.explain)")
     ap.add_argument("--from", dest="src", default=None,
-                    help="render a committed artifact "
-                         "(perf/compile_sample.json)")
+                    help="render a saved explain() report "
+                         "(what --demo --out-dir wrote)")
     ap.add_argument("--demo", action="store_true",
                     help="build a tiny GPT, storm the jit cache, "
                          "explain, print the table (CPU backend)")

@@ -12,12 +12,11 @@ metrics dump (observability MetricsRegistry.to_json()) and prints:
 Usage:
     python tools/trace_report.py TRACE.json [--metrics METRICS.json]
         [--sorted-key total] [--limit 30]
-    python tools/trace_report.py --demo [--out-dir perf]
+    python tools/trace_report.py --demo [--out-dir DIR]
 
 --demo runs a tiny cached 3-step training loop on CPU, writes
 `trace_sample.timeline.json` + `metrics_sample.json` into --out-dir,
-then reports on them — the zero-to-trace smoke path; the committed
-sample under perf/ comes from it.
+then reports on them — the zero-to-trace smoke path.
 """
 
 import argparse
@@ -412,8 +411,7 @@ def run_demo(out_dir):
 
     # async + bucketed demo loop: a second tiny program driven through
     # run_pipelined with a FeedBucketer, so executor.async.* and
-    # executor.bucket.* series land in the committed sample dump and the
-    # BENCH_* trajectory shows the pipeline's metrics round over round
+    # executor.bucket.* series land in the sample dump
     from paddle_tpu.core import framework
     from paddle_tpu.core.bucketing import FeedBucketer
     amain, astart = framework.Program(), framework.Program()
@@ -457,8 +455,8 @@ def run_demo(out_dir):
                "y": rng.randn(8, 1).astype(np.float32)} for _ in range(6)]
     with tempfile.TemporaryDirectory() as ckdir:
         # fixed-name subdir: the CheckpointManager gauge label is
-        # basename(root), and a random tempdir name would commit a
-        # different label into perf/metrics_sample.json on every run
+        # basename(root), and a random tempdir name would put a
+        # different label into metrics_sample.json on every run
         trainer = GuardedTrainer(
             exe3, gmain, fetch_list=[gloss], scope=gscope,
             checkpoint_dir=os.path.join(ckdir, "demo_ckpts"),
@@ -652,7 +650,7 @@ def run_demo(out_dir):
     dump["fleet_stats"] = fleet_stats
     dump["signals_sample"] = signals_sample
     with open(metrics_path, "w") as f:
-        # single line: perf/ artifacts are parsed line-wise
+        # single line, keys sorted: two dumps diff line against line
         json.dump(dump, f, sort_keys=True)
         f.write("\n")
     return trace_base + ".timeline.json", metrics_path
