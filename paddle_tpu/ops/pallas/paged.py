@@ -5,25 +5,53 @@ materializes a dense (B, H, M*bs, D) gather of every request's FULL
 block table on every fused step — each decode iteration pays
 O(max_blocks) HBM traffic per lane regardless of how many tokens the
 lane actually holds. The kernels here (per the *Ragged Paged Attention*
-TPU paper, PAPERS.md) walk the block table INSIDE the kernel instead:
-the grid is (lane, table column) and each block arrives through a
-BlockSpec whose index_map reads the scalar-prefetched table, so the
-Pallas pipeline issues (and double-buffers) the HBM->VMEM copies.
+TPU paper, PAPERS.md) walk the block table INSIDE the kernel instead,
+and a call costs what its lanes hold, not what the table could hold.
+
+**The walk** (`_plan_walk`, `_paged_call`). The unit is a GROUP of
+table columns of one lane: 128 key positions (`walk_group`: 8 columns of
+16-token blocks, read from the shapes), one column for v2. The grid is
+ONE axis whose steps are the LIVE groups only, lane after lane; its
+bound, the sum of the lanes' live groups (an idle lane takes one step,
+to write its zeros), is a value the call computes from the positions,
+not a shape. `_plan_walk` is plain XLA on the table and the positions
+(every layer of a step shares them: a step computes the plan once): it
+names each step's lane and group and each column's block, and all of
+it rides scalar prefetch (SMEM), where the index maps read it. A step's
+blocks arrive through BlockSpecs — the one pool operand is handed to
+the call once a column of the group, each with its own index map — so
+the Pallas pipeline issues (and double-buffers) the HBM->VMEM copies,
+eight in flight where a step of one column had one, and the next
+lane's first group lands while this lane's last computes. A dead group
+is no step at all; a column past a lane's last live one repeats the
+block its window held last, and the pipeline skips a copy whose block
+index did not change. (On the chip, at `gpt2-xl.closed-16`'s shape and
+contexts: 1,024 steps of a column each took 415 us a call, 128 steps
+of a group, live or dead, 157, the 59 live ones 119: PERF.md section
+6, PR 31.)
 
 A layer's pool is ONE array (N, H_kv, bs, 2*D): the K row of a token in
 lanes [0, D), its V row in [D, 2*D) (`serving/kv_cache.fuse_kv`). At
 head_dim 64 the minor dim is the 128 lanes of a TPU tile, so the
 device's own layout of the pool is the row-major one these kernels
-read and no step re-lays a pool out (PERF.md section 6, PR 29); a grid
-step is one DMA, not two. Two generations share that walk:
+read and no step re-lays a pool out (PERF.md section 6, PR 29); a
+block is one DMA, not two. Two generations share that walk:
 
 * **v1** (`ragged_paged_attention`): every live block is copied into
-  an (H_kv, M*bs, 2*D) VMEM scratch as it arrives; the last grid step
-  takes K and V out of it with two lane slices and runs the reference's
-  exact op sequence on the VMEM-resident gather.
-  f32 and int8 pools are pinned BITWISE against the reference under jit
-  in interpret mode — the price is VMEM scratch proportional to the
-  table width M.
+  an (H_kv, M*bs, 2*D) VMEM scratch as it arrives; the lane's last
+  step takes K and V out of it with two lane slices and runs the
+  reference's exact op sequence on the VMEM-resident gather, over the
+  key positions of the lane's live groups and no further: one branch a
+  live-group count, each of static width (128, 256, ... M*bs; a table
+  of more than 8 groups is cut in `walk_rung` groups, so a kernel is
+  compiled at 8 widths at most).
+  The contract: f32 and int8 pools are BITWISE, under jit in interpret
+  mode, the reference evaluated on the lane's table CUT to those live
+  groups. Masked keys contribute exact zeros, so the cut is an identity
+  in real arithmetic; in floating point it names the width the
+  softmax's and the PV product's sums run over. A table of one group is
+  bitwise the reference as it is. The price is VMEM scratch
+  proportional to the table width M.
 * **v2** (`ragged_paged_attention_v2`): each arriving block folds
   straight into a flash-style online-softmax accumulator (running max,
   rescaled sum, rescaled PV partial, all f32 VMEM scratch), split into
@@ -38,18 +66,11 @@ step is one DMA, not two. Two generations share that walk:
 
 Both kernels share the serving contract:
 
-* the block table and query positions ride scalar prefetch (SMEM), so
-  block indices are available to the index_maps the way jax's own
-  paged-attention kernel does it;
-* per-lane early stop: past a lane's highest live block the index_map
-  repeats the last live block index, so that the pipeline can skip a
-  copy whose block index did not change, and the step's compute is
-  predicated off (the HBM bytes this saves are not measured: PERF.md
-  section 7);
 * the NULL block (block 0 — table padding, masked-lane writes) never
   enters the arithmetic: padding entries and idle lanes contribute
-  exactly nothing, even if block 0 holds garbage (pinned by NaN-poison
-  tests);
+  exactly nothing, even if block 0 holds garbage, and neither does a
+  stale entry past a lane's last live column (pinned by NaN-poison
+  tests); an idle lane is an exact zero, and does no arithmetic;
 * chunked prefill (C > 1) and decode (C = 1) are ONE kernel — the
   engine's single fused-step signature survives unchanged;
 * bf16 pools are welcome: scores and softmax accumulate in f32
@@ -68,11 +89,15 @@ Both kernels share the serving contract:
   across each group (a pure copy, so v1's bitwise pin extends to GQA);
   HBM traffic stays at H_kv heads.
 
-Why BlockSpecs and not hand-rolled `make_async_copy`: Mosaic refuses a
-DMA slice of an array whose minor dim is under one 128-lane tile
-("Slice shape along dimension 3 must be aligned to tiling (128), but is
-64"), and head_dim 64 is the GPT-2 geometry. The pipeline's own copies
-take any block whose trailing dims equal the array's.
+Why BlockSpecs and not hand-rolled `make_async_copy`: the pipeline's
+own copies take any block whose trailing dims equal the array's, every
+pool dtype and block size the same way (a DMA slice of an array whose
+minor dim is under one 128-lane tile Mosaic refuses, which ruled the
+other way out while a pool was 64 wide; since PR 29 it is 128 and that
+reason is gone), the interpreter runs them unchanged, and the dequant
+of an int8 block needs the block staged in VMEM anyway. What they cost
+is the pipeline's bookkeeping, about 0.1 us a window a step: the floor
+this walk stands on (PERF.md section 6, PR 31).
 
 VMEM budget: v1's scratch holds one lane's full K+V working set,
 H_kv * M*bs * 2*D elements in the pool's dtype (f32 for int8 pools,
@@ -81,10 +106,11 @@ of flash.py's default forward. At head_dim 64 the 2*D minor dim is
 exactly the 128 lanes, so nothing is padded and the dispatcher's
 estimate (`v1_scratch_bytes`) is what Mosaic allocates: 6.5 MB for 25
 heads x 1,024 tokens of bf16, where two 64-lane scratches padded to
-13. The K and V slices `_attend` takes are temporaries of the same
-size again. v2 holds two block windows whatever M is; the dispatcher
-(serving/kv_cache.paged_attention) routes tables past the v1 ceiling
-to v2 automatically.
+13. The K and V slices the value path takes are temporaries of the same
+size again at most. The windows of a group are 2 * `walk_group` blocks
+(1.6 MB at that geometry). v2 holds two block windows whatever M is;
+the dispatcher (serving/kv_cache.paged_attention) routes tables past
+the v1 ceiling to v2 automatically.
 
 Off-TPU the kernels run under the Pallas interpreter (same policy as
 flash.py) so the CPU suite exercises the real kernel code.
@@ -151,35 +177,78 @@ def _validate_paged_args(q, kv_pool, block_table, q_positions,
     return b, h, c, d, n, hp, bs, m, quantized
 
 
-def _n_live(pos_ref, b, c, bs, m):
-    """A lane's live-block count, from its highest query position
-    (scalar reads; C is static and small). Always >= 1."""
-    max_pos = pos_ref[b, 0]
-    for ci in range(1, c):
-        max_pos = jnp.maximum(max_pos, pos_ref[b, ci])
-    return jnp.minimum(max_pos // bs + 1, m)
+def walk_group(bs, m):
+    """Table columns a grid step of v1 takes: 128 key positions' worth
+    (one lane tile of scores), the whole table where it is narrower."""
+    return max(1, min(128 // bs, m))
 
 
-def _block_is_live(tbl_ref, pos_ref, b, j, bs, m):
-    """Does grid step (b, j) hold a block to attend? Not past the
-    lane's last live block, and not table padding / an idle lane's
-    NULL_BLOCK — whatever the pipeline delivered for those steps is
-    never touched."""
-    return ((j < _n_live(pos_ref, b, pos_ref.shape[1], bs, m))
-            & (tbl_ref[b, j] != NULL_BLOCK))
+def walk_rung(bs, m):
+    """Groups between two widths of v1's value path: one, until a table
+    holds more than 8 groups; then an eighth of them, so that a kernel
+    is compiled at no more than 8 widths however long its table is."""
+    return -(-(-(-m // walk_group(bs, m))) // 8)
 
 
-def _page_spec(block_shape, c, bs, m):
-    """BlockSpec for one pool: grid step (b, j) sees pool block
-    table[b, j]. Past the lane's last live block the index repeats, so
-    the pipeline issues no further copies for that lane (early stop)."""
+def _plan_walk(block_table, q_positions, bs, p):
+    """The walk, as small int32 arrays the kernels read from SMEM
+    (plain XLA on the table and the positions: every layer of a step
+    shares them, so a step computes them once). A grid step is one LIVE
+    group of p table columns of one lane; column j * p + i of a lane is
+    the i-th block of its group j. Returns (steps, plan):
+
+    steps (): how many grid steps the call takes, the sum of the lanes'
+        live groups (an idle lane takes one, to write its zeros): the
+        grid's bound, known only when the call runs;
+    lane, group (B * G,): the lane and the group of each step, lane by
+        lane, groups in order (G = ceil(M / p));
+    fetch (B, G * p): the pool block that column's window holds. Past
+        the lane's last live column, in its last live group, a window
+        repeats the block it held a step before (NULL_BLOCK in a first
+        group), and the pipeline skips a copy whose block index did not
+        change: the dead columns of a lane's last group move nothing.
+        (A dead group is no step: what `fetch` says there is not read);
+    live (B, G * p): 1 where the column holds a block to attend: not
+        past the lane's highest query position, and not NULL_BLOCK
+        (table padding, an idle lane). Whatever the pipeline delivered
+        for a column that is not live is never touched;
+    groups (B,): the lane's live groups, ceil(live columns / p); 0 for
+        a lane with no live block at all (idle)."""
+    tbl = block_table.astype(jnp.int32)
+    b, m = tbl.shape
+    g = -(-m // p)
+    tbl = jnp.pad(tbl, ((0, 0), (0, g * p - m)),
+                  constant_values=NULL_BLOCK)
+    n_live = jnp.minimum(
+        jnp.max(q_positions.astype(jnp.int32), axis=1) // bs + 1, m)
+    col = jnp.arange(g * p, dtype=jnp.int32)[None]
+    last = n_live[:, None] - 1
+    a_step_before = jnp.pad(tbl, ((0, 0), (p, 0)),
+                            constant_values=NULL_BLOCK)[:, :g * p]
+    fetch = jnp.where(col <= last, tbl, a_step_before)
+    live = (col <= last) & (tbl != NULL_BLOCK)
+    groups = jnp.where(live.any(axis=1), (n_live + p - 1) // p, 0)
+    steps = jnp.maximum(groups, 1)
+    step = jnp.arange(b * g, dtype=jnp.int32)
+    done = step[:, None] >= jnp.cumsum(steps)[None]     # (steps, lanes)
+    lane = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), b - 1)
+    group = step - jnp.sum(jnp.where(done, steps[None], 0), axis=1)
+    return jnp.sum(steps), (lane, group, fetch, live.astype(jnp.int32),
+                            groups)
+
+
+def _page_specs(block_shape, p):
+    """BlockSpecs for one pool, one per column of a group: at grid step
+    s the i-th window holds pool block fetch[lane[s], group[s] * p + i]."""
     zeros = (0,) * (len(block_shape) - 1)
 
-    def index_map(b, j, tbl, pos):
-        last = _n_live(pos, b, c, bs, m) - 1
-        return (tbl[b, jnp.minimum(j, last)],) + zeros
+    def index_map(i, s, lane, group, fetch, live, groups, pos):
+        del live, groups, pos
+        return (fetch[lane[s], group[s] * p + i],) + zeros
 
-    return pl.BlockSpec((1,) + tuple(block_shape[1:]), index_map)
+    return [pl.BlockSpec((1,) + tuple(block_shape[1:]),
+                         functools.partial(index_map, i))
+            for i in range(p)]
 
 
 def _pos_matrix(pos_ref, b, c, shape, axis):
@@ -235,66 +304,90 @@ def _padded_bytes(shape, dtype):
 
 
 def _compiler_params(vmem_bytes):
-    """Lanes are independent, table columns carry scratch state. The
-    VMEM limit is stated (Mosaic's default scope is smaller than v1's
-    gather at long tables) with headroom for the pipeline windows and
-    value temporaries."""
+    """One grid axis, lane after lane: a lane's steps carry scratch
+    state. The VMEM limit is stated (Mosaic's default scope is smaller
+    than v1's gather at long tables) with headroom for the pipeline
+    windows and value temporaries."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
+        dimension_semantics=("arbitrary",),
         vmem_limit_bytes=int(min(max(2 * vmem_bytes + (8 << 20),
                                      32 << 20), 100 << 20)))
 
 
-def _paged_kernel(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h, hp,
-                  d, quantized=False):
-    """Grid step (b, j): lane b, table column j, all heads — dense AND
-    int8 pools share this walk (selected at trace time by `quantized`,
-    so the early-stop arithmetic, the NULL guard, the zero-fill the
-    bitwise pin depends on, and the mask/softmax tail exist exactly
-    once).
+def _paged_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
+                  pos_ref, q_ref, *rest, bs, m, p, h, hp, d,
+                  quantized=False):
+    """Grid step s: lane b = lane[s], its j-th GROUP of p table columns
+    (j = group[s]), all heads — dense AND int8 pools share this walk
+    (selected at trace time by `quantized`, so the NULL guard, the
+    zero-fill the bitwise pin depends on, and the mask/softmax tail
+    exist exactly once).
 
-    tbl_ref (B, M) / pos_ref (B, C): scalar-prefetched SMEM.
-    q_ref (1, H, C, D); kv_ref (1, H_kv, bs, 2*D): pool block
-    table[b, j], delivered by the pipeline, K in lanes [0, D) and V in
+    lane_ref / group_ref (B*G,), fetch_ref / live_ref (B, G*p),
+    groups_ref (B,), pos_ref (B, C): scalar-prefetched SMEM
+    (`_plan_walk`). q_ref (1, H, C, D); then p windows
+    (1, H_kv, bs, 2*D): pool blocks fetch[b, j*p : (j+1)*p],
+    delivered by the pipeline together, K in lanes [0, D) and V in
     [D, 2*D). g scratch (H_kv, M*bs, 2*D) VMEM — the lane's gathered
     view, rows in logical-position order exactly like the reference's
     dense gather, so the value-path math below can mirror it op for op
-    once K and V are sliced out of it. Quantized adds the two
-    (1, H_kv, bs) f32 scale blocks; the dequant product happens as each
-    block lands (g scratch f32; V is cast to the output dtype where the
-    reference casts it, after the gather). GQA (hp < h) repeats the
-    gathered rows across each query-head group — a pure copy, identical
-    to the reference's repeat of its dense gather, so the bitwise pin
-    holds."""
+    once K and V are sliced out of it. Quantized adds p windows
+    (1, H_kv, bs) for each of the two f32 scale pools; the dequant
+    product happens as each block lands (g scratch f32; V is cast to
+    the output dtype where the reference casts it, after the gather).
+    GQA (hp < h) repeats the gathered rows across each query-head group
+    — a pure copy, identical to the reference's repeat of its dense
+    gather, so the bitwise pin holds.
+
+    Only a lane's live groups are steps at all, and the value path
+    runs once, at the lane's last step, over the key positions of its
+    live groups and no further (rounded up to `walk_rung` groups, and
+    those cleared, where a table holds more than 8)."""
+    del fetch_ref                           # read by the index maps
+    kv_refs, rest = rest[:p], rest[p:]
     if quantized:
-        ks_ref, vs_ref, o_ref, g_ref = rest
-    else:
-        o_ref, g_ref = rest
-    b, j = pl.program_id(0), pl.program_id(1)
+        ks_refs, vs_refs, rest = rest[:p], rest[p:2 * p], rest[2 * p:]
+    o_ref, g_ref = rest
+    step = pl.program_id(0)
+    b, j = lane_ref[step], group_ref[step]
     c = pos_ref.shape[1]
-    t = m * bs
+    n_groups, n_all = groups_ref[b], -(-m // p)
 
-    # the skipped tail must hold zeros, not stale VMEM: its (masked)
-    # probabilities are exactly 0 and 0 * 0 keeps the PV partial sums
-    # bitwise-identical to the reference's 0 * null-block terms
-    @pl.when(j == 0)
-    def _zero():
-        g_ref[...] = jnp.zeros_like(g_ref)
-
-    @pl.when(_block_is_live(tbl_ref, pos_ref, b, j, bs, m))
+    @pl.when(n_groups > 0)
     def _gather():
-        blk = kv_ref[0]                               # (H_kv, bs, 2*D)
-        if quantized:
-            blk = _dequant(blk, ks_ref[0], vs_ref[0])
-        g_ref[:, pl.ds(pl.multiple_of(j * bs, bs), bs), :] = blk
+        for i in range(p):
+            col = j * p + i
+            rows = pl.ds(pl.multiple_of(col * bs, bs), bs)
+            is_live = live_ref[b, col] != 0
+
+            @pl.when(is_live)
+            def _land():
+                blk = kv_refs[i][0]                   # (H_kv, bs, 2*D)
+                if quantized:
+                    blk = _dequant(blk, ks_refs[i][0], vs_refs[i][0])
+                g_ref[:, rows, :] = blk
+
+            # the rest of a live group must hold zeros, not stale VMEM:
+            # its (masked) probabilities are exactly 0 and 0 * 0 keeps
+            # the PV partial sums bitwise-identical to the reference's
+            # 0 * null-block terms. Columns the table's padding added
+            # past M have no rows.
+            dead = jnp.logical_not(is_live)
+            if (n_all - 1) * p + i >= m:
+                dead &= col < m
+
+            @pl.when(dead)
+            def _clear():
+                g_ref[:, rows, :] = jnp.zeros((hp, bs, 2 * d),
+                                              g_ref.dtype)
 
     # ---- value path: the reference body on the VMEM-resident gather --
     # (same einsums batched over H, same mask constant, same
-    # jax.nn.softmax — the bitwise pin lives here)
-    @pl.when(j == m - 1)
-    def _attend():
+    # jax.nn.softmax — the bitwise pin lives here), at the width of the
+    # lane's live groups: one branch a group count, each of static shape
+    def _attend(t):
         q = q_ref[0]                                      # (H, C, D)
-        g = g_ref[...]
+        g = g_ref[:, :t, :]
         gk = _repeat_heads(g[..., :d], h // hp)
         gv = _repeat_heads(g[..., d:].astype(o_ref.dtype), h // hp)
         s = jnp.einsum("hcd,htd->hct", q.astype(gk.dtype), gk,
@@ -303,43 +396,75 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h, hp,
         key_pos = jax.lax.broadcasted_iota(jnp.int32, (c, t), 1)
         mask = (key_pos <= _pos_matrix(pos_ref, b, c, (c, t), 0))[None]
         s = jnp.where(mask, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
+        prob = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
         o_ref[0] = jnp.einsum(
-            "hct,htd->hcd", p, gv, precision=_mxu_precision(gv.dtype),
+            "hct,htd->hcd", prob, gv, precision=_mxu_precision(gv.dtype),
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
+    rung = walk_rung(bs, m)
+    for lo in range(0, n_all, rung):
+        hi = min(lo + rung, n_all)
 
-def _paged_call(name, kernel, q, kv_pool, scales, block_table,
-                q_positions, scratch, out_dtype, vmem_bytes, interpret):
-    """The pallas_call both generations share: grid (lane, table
-    column), table + positions scalar-prefetched, q/out one lane per
-    block, the pool (and each scale pool) one table-addressed block per
-    step."""
+        @pl.when((j == n_groups - 1) & (n_groups > lo) & (n_groups <= hi))
+        def _(lo=lo, hi=hi):
+            for dead in range(lo + 1, hi):      # none while rung is 1
+                rows = slice(dead * p * bs, min((dead + 1) * p, m) * bs)
+
+                @pl.when(dead >= n_groups)
+                def _clear_group():
+                    g_ref[:, rows, :] = jnp.zeros(
+                        (hp, rows.stop - rows.start, 2 * d), g_ref.dtype)
+
+            _attend(min(hi * p, m) * bs)
+
+    @pl.when(n_groups == 0)
+    def _idle():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "name", "kernel", "group", "scratch", "out_dtype", "vmem_bytes",
+    "interpret"))
+def _paged_call(q, kv_pool, scales, block_table, q_positions, *, name,
+                kernel, group, scratch, out_dtype, vmem_bytes, interpret):
+    """The pallas_call both generations share: one grid step a live
+    group of `group` table columns of a lane (`_plan_walk`; the grid's
+    bound is a value, not a shape), the walk's plan and the positions
+    scalar-prefetched, q/out one lane per block, the pool (and each
+    scale pool) `group` table-addressed blocks per step: the one pool
+    operand is handed in once a window, each with its own index map.
+
+    Jitted, so that the layers of a step, which call it with the same
+    shapes, share ONE trace of the kernel and ONE lowering to Mosaic:
+    48 layers each tracing and lowering v1's eight branches put 14 s
+    into every start of a server, cache or no cache."""
     b, h, c, d = q.shape
     _n, hp, bs, _d2 = kv_pool.shape
     m = block_table.shape[1]
-    lane_spec = pl.BlockSpec((1, h, c, d),
-                             lambda b_, j, tbl, pos: (b_, 0, 0, 0))
+    lane_spec = pl.BlockSpec(
+        (1, h, c, d), lambda s, lane, *plan: (lane[s], 0, 0, 0))
+    pools = [kv_pool] + scales
     in_specs = [lane_spec]
-    in_specs += [_page_spec(p.shape, c, bs, m)
-                 for p in [kv_pool] + scales]
+    for pool in pools:
+        in_specs += _page_specs(pool.shape, group)
+    steps, plan = _plan_walk(block_table, q_positions, bs, group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_table, q_positions
-        grid=(b, m),
+        num_scalar_prefetch=6,          # the plan's five, positions
+        grid=(steps,),
         in_specs=in_specs,
         out_specs=lane_spec,
         scratch_shapes=[pltpu.VMEM(shp, dt) for shp, dt in scratch],
     )
     return pl.pallas_call(
-        functools.partial(kernel, bs=bs, m=m, h=h, hp=hp, d=d,
+        functools.partial(kernel, bs=bs, m=m, p=group, h=h, hp=hp, d=d,
                           quantized=bool(scales)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, c, d), out_dtype),
         compiler_params=_compiler_params(vmem_bytes),
         name=name,
         interpret=interpret,
-    )(block_table.astype(jnp.int32), q_positions.astype(jnp.int32),
-      q, kv_pool, *scales)
+    )(*plan, q_positions.astype(jnp.int32), q,
+      *[pool for pool in pools for _ in range(group)])
 
 
 def _v1_scratch_shapes(hp, bs, d, m, pool_dtype):
@@ -389,10 +514,13 @@ def ragged_paged_attention(q, kv_pool, block_table, q_positions,
     vmem = (v1_scratch_bytes(hp, bs, d, m, kv_pool.dtype)
             + 2 * _padded_bytes((h, m * bs, d), scratch[0][1])
             + 3 * _padded_bytes((h, c, m * bs), jnp.float32))
-    return _paged_call("paged_attention_v1", _paged_kernel, q, kv_pool,
+    return _paged_call(q, kv_pool,
                        [k_scale, v_scale] if quantized else [],
-                       block_table, q_positions, scratch, out_dtype,
-                       vmem, interpret)
+                       block_table, q_positions,
+                       name="paged_attention_v1", kernel=_paged_kernel,
+                       group=walk_group(bs, m), scratch=tuple(scratch),
+                       out_dtype=out_dtype, vmem_bytes=vmem,
+                       interpret=bool(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -409,9 +537,11 @@ def _v2_scratch_shapes(h, c, d):
             ((h, c, d), jnp.float32)]
 
 
-def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h,
-                     hp, d, quantized=False):
-    """Grid step (b, j): lane b, table column j, all heads. Block
+def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
+                     groups_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, p,
+                     h, hp, d, quantized=False):
+    """Grid step s: lane b = lane[s], its live table column
+    j = group[s] (a group of v2's walk is one column), all heads. Block
     table[b, j] arrives through the pipeline (the NEXT block's copy is
     already in flight while this one computes — the two-window overlap),
     is split into its K lanes [0, D) and V lanes [D, 2*D), and folds
@@ -433,7 +563,9 @@ def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h,
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    b, j = pl.program_id(0), pl.program_id(1)
+    del fetch_ref, m, p
+    step = pl.program_id(0)
+    b, j = lane_ref[step], group_ref[step]
     c = pos_ref.shape[1]
 
     @pl.when(j == 0)
@@ -442,7 +574,7 @@ def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_block_is_live(tbl_ref, pos_ref, b, j, bs, m))
+    @pl.when(live_ref[b, j] != 0)
     def _fold():
         blk = kv_ref[0]                               # (H_kv, bs, 2*D)
         if quantized:
@@ -469,7 +601,7 @@ def _paged_kernel_v2(tbl_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, h,
             "hcb,hbd->hcd", p, vb, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(j == m - 1)
+    @pl.when(j == jnp.maximum(groups_ref[b], 1) - 1)
     def _flush():
         # idle lanes (every key masked) land l == 0: divide by 1 and
         # output an exact 0 — never NaN
@@ -503,11 +635,13 @@ def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
     # carry + the f32 head-repeated views of one K and one V block
     vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
             + 4 * _padded_bytes((h, bs, d), jnp.float32))
-    return _paged_call("paged_attention_v2", _paged_kernel_v2, q,
-                       kv_pool,
+    return _paged_call(q, kv_pool,
                        [k_scale, v_scale] if quantized else [],
-                       block_table, q_positions, scratch, out_dtype,
-                       vmem, interpret)
+                       block_table, q_positions,
+                       name="paged_attention_v2", kernel=_paged_kernel_v2,
+                       group=1, scratch=tuple(scratch),
+                       out_dtype=out_dtype, vmem_bytes=vmem,
+                       interpret=bool(interpret))
 
 
 # ---------------------------------------------------------------------------

@@ -1199,15 +1199,27 @@ class GenerationServer:
         """The `serving.iteration` span's args, built only while a
         capture is live: what the fused step was handed. `lanes_qc` is
         each lane's (queries, context) — columns fed this iteration, and
-        the tokens its attention reads once they are written."""
+        the tokens its attention reads once they are written.
+        `walk_groups_live` over `walk_groups` is the share of the block
+        table the paged kernel touches: it walks a lane's table in
+        groups of `walk_group` columns and stops after the last one
+        that holds a token."""
+        from ..ops.pallas.paged import walk_group
         cols = plan.valid.sum(axis=1)
+        lanes_qc = [[int(cols[sid]),
+                     int(plan.positions[sid, cols[sid] - 1]) + 1]
+                    for sid in plan.slot_ids]
+        width = plan.tables.shape[1]
+        group = walk_group(self.block_size, width)
+        keys = group * self.block_size          # 128 key positions
         return {"iteration": it, "lanes": len(plan.slot_ids),
                 "prefill_tokens": plan.prefill_tokens,
                 "valid_columns": plan.valid_columns,
                 "padded_columns": plan.padded_columns,
-                "lanes_qc": [[int(cols[sid]),
-                              int(plan.positions[sid, cols[sid] - 1]) + 1]
-                             for sid in plan.slot_ids]}
+                "lanes_qc": lanes_qc,
+                "walk_groups_live": sum(-(-ctx // keys)
+                                        for _q, ctx in lanes_qc),
+                "walk_groups": len(lanes_qc) * -(-width // group)}
 
     def _apply_step_chaos(self, it, lanes):
         """Injected KV poison, applied before the step is fed."""
@@ -1701,8 +1713,8 @@ class GenerationServer:
             "version": self._kernel_version,
             "kernel_dispatches": traced,
             "fallback_dispatches": fell_back,
-            # what one table entry addresses, and one grid step of the
-            # walk copies: (H_kv, block_size, 2 * head_dim), K beside V
+            # what one table entry addresses, and the walk copies a
+            # group of: (H_kv, block_size, 2 * head_dim), K beside V
             # (a fact for whoever reads a trace, not a switch)
             "pool_block_shape": [self.cache.num_kv_heads,
                                  self.cache.block_size,

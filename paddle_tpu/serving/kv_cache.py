@@ -31,11 +31,12 @@ land there, and the attention mask guarantees it is never read.
 
 `paged_attention` is the op's dispatcher: by default it routes to a
 Pallas ragged paged attention kernel (`ops/pallas/paged.py` — the table
-walk fused into the kernel, early stop at each lane's true length,
+walk fused into the kernel, a grid step a live group of 128 key
+positions of a lane and no step past a lane's true length,
 bf16 KV with f32 accumulation), falling back to
 `paged_attention_reference`, the pure-JAX semantic spec (gather blocks
 by table -> masked attention) that kernel v1 is pinned bitwise against
-in interpret mode. Two kernel generations exist: v1 (gather the live
+in interpret mode (on the lane's table cut to its live groups). Two kernel generations exist: v1 (gather the live
 blocks to VMEM, then the reference math — bitwise-stable, VMEM scales
 with the table width) and v2 (double-buffered block STREAMING with an
 online softmax — O(2 blocks) of VMEM whatever the table width). Auto
@@ -162,8 +163,8 @@ def gather_block_kv_pair(kv_pool, block_table):
     cost per lane per step — every decode iteration copies each
     request's FULL table width regardless of its true length. That is
     the traffic the Pallas kernel (ops/pallas/paged.py) is built to
-    remove by walking the table in-kernel with a per-lane early stop
-    (not measured on the chip)."""
+    remove by walking only each lane's live groups in-kernel (PERF.md
+    section 6, PR 31)."""
     return split_kv(gather_block_kv(kv_pool, block_table))
 
 

@@ -490,6 +490,9 @@ def test_leaf_spans_tile_every_iteration(tiny_gpt):
         assert sum(q for q, _ctx in args["lanes_qc"]) \
             == args["valid_columns"]
         assert all(1 <= q <= c and ctx >= q for q, ctx in args["lanes_qc"])
+        # a 64-token table is one group of the paged walk: all of it live
+        assert args["walk_groups_live"] == args["walk_groups"] \
+            == args["lanes"]
         valid += args["valid_columns"]
         padded += args["padded_columns"]
     assert delta["serving.valid_columns"] == valid
@@ -501,6 +504,54 @@ def test_leaf_spans_tile_every_iteration(tiny_gpt):
     # the idle poll that ends run_until_idle plans and does nothing else
     idle = [e for e in events if e["args"].get("idle")]
     assert [e["name"] for e in idle] == ["serving.plan"]
+
+
+def test_iteration_record_counts_the_walks_live_groups(monkeypatch):
+    """A table of three groups of 128 key positions (320 tokens of
+    16-token blocks), a lane of 5 tokens beside one past 128: the
+    record says how many groups the paged walk has (`walk_groups`) and
+    how many hold a token (`walk_groups_live`), and the stream over the
+    grouped walk is the reference path's."""
+    from paddle_tpu.ops.pallas import paged
+    cfg = gpt.GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
+                        num_heads=2, inner_size=64, max_position=320,
+                        dropout=0.0)
+    main, startup = framework.Program(), framework.Program()
+    main.random_seed = startup.random_seed = 5
+    with framework.program_guard(main, startup):
+        gpt.build_lm_net(cfg, seq_len=8)
+    scope = Scope()
+    with scope_guard(scope):
+        fluid.Executor().run(startup)
+    params = gpt.load_params(scope, cfg)
+    rng = np.random.default_rng(0)
+    prompts = [(list(rng.integers(1, 64, 126)), 6), ([3, 4, 5], 14)]
+    kw = dict(num_slots=2, block_size=16, max_context=320, chunk=16)
+    assert paged.walk_group(16, 320 // 16) == 8
+
+    monkeypatch.delenv("PADDLE_TPU_PAGED_KERNEL", raising=False)
+    srv, events, _delta = _traced_run(params, cfg, prompts, **kw)
+    assert srv.get_stats()["kernel"]["engaged"] is True
+    records = [e["args"] for e in events
+               if e["name"] == "serving.iteration"]
+    for args in records:
+        assert args["walk_groups"] == 3 * args["lanes"]
+        assert args["walk_groups_live"] == sum(
+            -(-ctx // 128) for _q, ctx in args["lanes_qc"])
+        assert args["lanes"] <= args["walk_groups_live"] \
+            <= args["walk_groups"]
+    # the long lane crosses into its second group while it decodes
+    assert {a["walk_groups_live"] for a in records} >= {1, 2, 3}
+
+    def ids(server_kw):
+        srv = _server(params, cfg, **kw, **server_kw)
+        futs = [srv.submit(p, max_new_tokens=n) for p, n in prompts]
+        srv.run_until_idle()
+        return [list(f.result(timeout=30).token_ids) for f in futs]
+
+    kernel_ids = ids({})
+    monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "0")
+    assert kernel_ids == ids({})
 
 
 def test_draft_step_is_a_child_of_feed(tiny_gpt):

@@ -78,6 +78,39 @@ def test_paged_kernels_compile_at_smoke_geometry(v5e, version, pool):
     assert len(calls) == 1 and f"paged_attention_{version}" in calls[0]
 
 
+def test_paged_v1_compiles_at_the_cell_geometry_with_a_grouped_grid(v5e):
+    """GPT-2 XL as `gpt2-xl.closed-16` serves it: 16 lanes x 16-token
+    chunks, 25 heads x 64, bf16 blocks of 16 tokens, a 64-column table.
+    One `paged_attention_v1` call whose grid is one axis of a length
+    only the call knows, a step a live group of 8 columns = 128 key
+    positions (at most 128 steps, where a column a step took 1,024:
+    PERF.md section 6, PR 31), with the eight branches of the value
+    path, one a live-group count, all typed by Mosaic."""
+    s, h, c, d, bs, m = 16, 25, 16, 64, 16, 64
+    shapes = [((s, h, c, d), jnp.bfloat16),
+              ((1 + s * m, h, bs, 2 * d), jnp.bfloat16),
+              ((s, m), jnp.int32), ((s, c), jnp.int32)]
+
+    def fn(*a):
+        return paged.ragged_paged_attention(*a, interpret=False)
+
+    jaxpr = jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(shp, dt) for shp, dt in shapes])
+    # (the call sits in a jit of its own, which the layers share)
+    inner, = [e.params["jaxpr"] for e in jaxpr.eqns
+              if e.primitive.name in ("pjit", "jit")]
+    calls = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    mapping = calls[0].params["grid_mapping"]
+    assert len(mapping.grid) == mapping.num_dynamic_grid_bounds == 1
+    # after the bound, the plan: a lane and a group for each of the
+    # steps there can be
+    lane, group = (v.aval.shape for v in calls[0].invars[1:3])
+    assert lane == group == (s * (m * bs // 128),)
+    calls = _compile(v5e, fn, *shapes)
+    assert len(calls) == 1 and "paged_attention_v1" in calls[0]
+
+
 @pytest.mark.parametrize("nested", [False, True],
                          ids=["gspmd", "in_callers_shard_map"])
 def test_flash_compiles_under_an_executor_mesh(v5e_2x2, nested,

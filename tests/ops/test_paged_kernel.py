@@ -9,7 +9,10 @@ kernel code path, not a shadow implementation). The contract:
   chunked prefill (C>1), decode (C=1), ragged mixed-length batches,
   and NULL-padded tables — the kernel mirrors the reference's op
   sequence on its in-kernel gather, so partial sums are identical, not
-  just close;
+  just close. A table of several groups of 128 key positions is walked
+  a live group a step, and a lane is bitwise the reference on its
+  table CUT to its live groups (`cut_reference`); a table of one group
+  is the reference as it is;
 - bf16 pools: allclose within bf16 tolerance — the kernel accumulates
   scores/softmax in f32 where the reference rounds through bf16 (on
   the CPU backend XLA upcasts bf16 matmuls, so the observed diff here
@@ -106,6 +109,156 @@ def test_kernel_eager_allclose_f32():
     out = np.asarray(paged.ragged_paged_attention(*args))
     ref = np.asarray(kvc.paged_attention_reference(*args))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the grouped walk: tables of several groups (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+def make_walk_case(kind="f32", c=4, bs=16, m=24, seed=0, poison=False):
+    """A table of several groups of 128 key positions, one lane a live
+    group count: lane 0 idle, lane 1 filling the table, the others
+    holding 5, 130 and 300 tokens and one just short of full. kind:
+    "f32" (MHA), "gqa" (2 KV heads under 4 query heads) or "int8"
+    (codes + scale pools). poison=True fills the NULL block AND every
+    table column past a lane's live blocks (stale entries, whole dead
+    groups among them) with NaN blocks: nothing of them may be read."""
+    rng = np.random.default_rng(seed)
+    t = m * bs
+    lengths = [None, t - c, 5, 130, 300, t - c - bs]
+    b, h, d = len(lengths), 4, 8
+    hp = 2 if kind == "gqa" else h
+    n = 2 + b * m
+    bad = n - 1                             # a real block, all NaN
+    k = rng.standard_normal((n, hp, bs, d)).astype(np.float32)
+    v = rng.standard_normal((n, hp, bs, d)).astype(np.float32)
+    k[kvc.NULL_BLOCK] = v[kvc.NULL_BLOCK] = 0.0
+    q = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
+    tables = np.full((b, m), bad if poison else kvc.NULL_BLOCK, np.int32)
+    q_pos = np.zeros((b, c), np.int32)
+    free = list(range(1, bad))
+    rng.shuffle(free)
+    for i, length in enumerate(lengths):
+        if length is None:
+            tables[i, 0] = kvc.NULL_BLOCK   # idle: nothing to attend
+            continue
+        for j in range(-(-(length + c) // bs)):
+            tables[i, j] = free.pop()
+        q_pos[i] = np.arange(length, length + c)
+    tail = (jnp.asarray(tables), jnp.asarray(q_pos))
+    if kind == "int8":
+        kq, ks = kvc.quantize_kv_rows(jnp.asarray(k))
+        vq, vs = kvc.quantize_kv_rows(jnp.asarray(v))
+        if poison:      # int8 codes cannot hold a NaN: the scales do
+            ks, vs = (x.at[kvc.NULL_BLOCK].set(jnp.nan).at[bad].set(
+                jnp.nan) for x in (ks, vs))
+        return (q, kvc.fuse_kv(kq, vq)) + tail + (ks, vs)
+    if poison:
+        k[kvc.NULL_BLOCK] = v[kvc.NULL_BLOCK] = k[bad] = v[bad] = np.nan
+    return (q, kvc.fuse_kv(jnp.asarray(k), jnp.asarray(v))) + tail
+
+
+def cut_reference(args):
+    """v1's contract on a table of several groups: the reference, lane
+    by lane, on the lane's table CUT to its live groups (masked keys
+    contribute exact zeros, so the cut is an identity in real
+    arithmetic; in floating point it fixes the width the sums run
+    over). A table of more than 8 groups is cut in steps of
+    `walk_rung` groups. An idle lane keeps one step."""
+    q, kv_pool, tables, q_pos = args[:4]
+    bs, m = kv_pool.shape[2], tables.shape[1]
+    p = paged.walk_group(bs, m) * paged.walk_rung(bs, m)
+    ref = jax.jit(kvc.paged_attention_reference)
+    out = []
+    for i in range(q.shape[0]):
+        n_live = min(int(np.max(np.asarray(q_pos[i]))) // bs + 1, m)
+        width = min(-(-n_live // p) * p, m)
+        out.append(np.asarray(ref(q[i:i + 1], kv_pool,
+                                  tables[i:i + 1, :width],
+                                  q_pos[i:i + 1], *args[4:])))
+    return np.concatenate(out)
+
+
+WALK_GEOMETRIES = [dict(bs=16, m=24),       # 384 keys: 3 groups of 8
+                   dict(bs=16, m=64),       # 1,024: the cell's table
+                   dict(bs=8, m=50),        # 400: 3 groups of 16 + 2
+                   dict(bs=16, m=21),       # 336: 2 groups of 8 + 5
+                   dict(bs=16, m=84)]       # 1,344: 11 groups, 2 a rung
+WALK_IDS = ["t384", "t1024", "t400_ragged_group", "t336_ragged_group",
+            "t1344_rungs_of_two"]
+
+
+def testwalk_group_is_128_key_positions():
+    assert paged.walk_group(16, 64) == 8
+    assert paged.walk_group(8, 50) == 16
+    assert paged.walk_group(32, 64) == 4
+    assert paged.walk_group(256, 4) == 1       # a block past a group
+    # a table under one group is one step, at its own width: as before
+    assert paged.walk_group(8, 6) == 6
+    assert paged.walk_group(4, 9) == 9
+    # the value path is compiled at 8 widths at most
+    assert [paged.walk_rung(16, m) for m in (3, 64, 65, 84, 512)] \
+        == [1, 1, 2, 2, 8]
+
+
+@pytest.mark.parametrize("c", [1, 4], ids=["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["f32", "int8", "gqa"])
+@pytest.mark.parametrize("geom", WALK_GEOMETRIES, ids=WALK_IDS)
+def test_grouped_walk_bitwise_matches_cut_reference(geom, kind, c):
+    """Lanes of 0, 1, 2, ... and all live groups in one call: each is
+    bitwise the reference at the width of its own live groups, and the
+    idle lane an exact zero."""
+    args = make_walk_case(kind=kind, c=c, seed=c + geom["m"], **geom)
+    out = np.asarray(jax.jit(paged.ragged_paged_attention)(*args))
+    np.testing.assert_array_equal(out, cut_reference(args))
+    assert not out[0].any()
+    assert out[1:].any()
+
+
+@pytest.mark.parametrize("kind", ["f32", "int8", "gqa"])
+@pytest.mark.parametrize("geom", WALK_GEOMETRIES[::2], ids=WALK_IDS[::2])
+def test_grouped_walk_never_reads_a_dead_block(geom, kind):
+    """NaN in the NULL block, in the dead columns of a live group and
+    in every block of a dead group reaches no output and changes no bit
+    of it; the idle lane stays an exact zero."""
+    fn = jax.jit(paged.ragged_paged_attention)
+    dirty = np.asarray(fn(*make_walk_case(kind=kind, seed=5, poison=True,
+                                          **geom)))
+    clean = np.asarray(fn(*make_walk_case(kind=kind, seed=5, **geom)))
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+    assert not dirty[0].any()
+
+
+def test_plan_walk_steps_only_through_live_groups():
+    """A step is one live group of one lane (an idle lane takes one, to
+    write its zeros) and their sum is the grid's bound; past a lane's
+    last live column every window of its last group repeats the block
+    it held a step before, so the pipeline issues no copy for it, and
+    in a lane's first group it names the NULL block."""
+    tables = jnp.asarray([[7, 8, 9, 10, 11, 0, 0, 0, 0, 0],
+                          [3, 0, 0, 0, 0, 0, 0, 0, 0, 0],
+                          [0, 0, 0, 0, 0, 0, 0, 0, 0, 0],
+                          [4, 5, 6, 12, 13, 14, 15, 16, 17, 18]],
+                         jnp.int32)
+    pos = jnp.asarray([[4 * 5 - 1], [2], [0], [39]], jnp.int32)  # bs 4
+    steps, plan = paged._plan_walk(tables, pos, 4, 4)
+    lane, group, fetch, live, groups = (np.asarray(x) for x in plan)
+    assert fetch.shape == live.shape == (4, 12)     # padded to 3 groups
+    # (what `fetch` says of a dead group is never read)
+    np.testing.assert_array_equal(fetch[0, :8], [7, 8, 9, 10, 11, 8, 9, 10])
+    np.testing.assert_array_equal(fetch[1, :4], [3, 0, 0, 0])
+    np.testing.assert_array_equal(
+        fetch[3], [4, 5, 6, 12, 13, 14, 15, 16, 17, 18, 15, 16])
+    np.testing.assert_array_equal(live[0], [1] * 5 + [0] * 7)
+    np.testing.assert_array_equal(live[1], [1] + [0] * 11)
+    np.testing.assert_array_equal(live[3], [1] * 10 + [0] * 2)
+    assert not live[2].any() and not fetch[2].any()
+    np.testing.assert_array_equal(groups, [2, 1, 0, 3])
+    assert int(steps) == 2 + 1 + 1 + 3
+    assert lane.shape == group.shape == (4 * 3,)
+    np.testing.assert_array_equal(lane[:7], [0, 0, 1, 2, 3, 3, 3])
+    np.testing.assert_array_equal(group[:7], [0, 1, 0, 0, 0, 1, 2])
 
 
 # ---------------------------------------------------------------------------

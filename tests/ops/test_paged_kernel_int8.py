@@ -88,6 +88,23 @@ def test_int8_kernel_bitwise_matches_reference(case):
     np.testing.assert_array_equal(out, ref)
 
 
+@pytest.mark.parametrize("c", [1, 4], ids=["decode", "prefill"])
+def test_int8_grouped_walk_bf16_activations_bitwise(c):
+    """A table of several groups (384 keys, lanes of 0 to 3 live
+    groups), int8 pools under bf16 activations: the codes are
+    dequantised as each block of a live group lands, and each lane is
+    bitwise the reference on its table cut to its live groups."""
+    from test_paged_kernel import cut_reference, make_walk_case
+    args = make_walk_case(kind="int8", c=c, bs=16, m=24, seed=21 + c)
+    args = (args[0].astype(jnp.bfloat16),) + args[1:]
+    out = jax.jit(paged.ragged_paged_attention)(*args)
+    assert out.dtype == jnp.bfloat16
+    out = np.asarray(out, np.float32)
+    np.testing.assert_array_equal(
+        out, np.asarray(cut_reference(args), np.float32))
+    assert not out[0].any() and out[1:].any()
+
+
 def test_int8_output_dtype_follows_query():
     for qdt in (jnp.float32, jnp.bfloat16):
         args, _ = make_case(qdt=qdt, seed=4)

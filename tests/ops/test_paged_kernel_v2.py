@@ -243,6 +243,27 @@ def test_v2_wide_table_same_answer():
     np.testing.assert_array_equal(out, out_w)
 
 
+@pytest.mark.parametrize("kind", ["f32", "int8", "gqa"])
+def test_v2_steps_only_through_live_blocks(kind):
+    """v2 shares v1's plan of the walk with a group of one column: a
+    step is a live block of a lane, so lanes of 0 (idle), 1, 9, 19 and
+    all 24 blocks of a 384-key table each fold exactly their own, and
+    NaN in the NULL block and in every stale column reaches nothing."""
+    from test_paged_kernel import make_walk_case
+    args = make_walk_case(kind=kind, bs=16, m=24, seed=23)
+    steps, plan = paged._plan_walk(args[2], args[3], 16, 1)
+    groups = np.asarray(plan[4])
+    np.testing.assert_array_equal(groups, [0, 24, 1, 9, 19, 23])
+    assert int(steps) == groups.sum() + 1       # the idle lane's one
+    out, _ref = _assert_v2_close(args, rtol=1e-4 if kind == "int8" else 1e-5,
+                                 atol=1e-5 if kind == "int8" else 1e-6)
+    assert not out[0].any()
+    dirty = make_walk_case(kind=kind, bs=16, m=24, seed=23, poison=True)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(paged.ragged_paged_attention_v2)(*dirty),
+                   np.float32), out)
+
+
 # ---------------------------------------------------------------------------
 # grouped-query attention, op level
 # ---------------------------------------------------------------------------

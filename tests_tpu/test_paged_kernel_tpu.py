@@ -40,7 +40,9 @@ def _case(h, hp, c, d, bs, m, dtype, quantized, seed, b=4):
     lengths = [0, m * bs - c, int(rng.integers(1, bs)),
                int(rng.integers(bs, m * bs - c))]
     for i in range(1, b):
-        length = lengths[i % len(lengths)]
+        # past the four, lanes of every live-group count of the walk
+        length = (lengths[i] if i < len(lengths)
+                  else int(rng.integers(bs, m * bs - c)))
         for j in range(-(-(length + c) // bs)):
             tables[i, j] = free.pop()
         pos[i] = np.arange(length, length + c)
@@ -61,8 +63,9 @@ def _case(h, hp, c, d, bs, m, dtype, quantized, seed, b=4):
 
 
 GEOMETRIES = [
-    # h, hp,  c,   d, bs,   m
+    # h, hp,  c,   d, bs,   m[,  b]
     (12, 12, 4, 64, 16, 64),        # the smoke's: GPT 12x64, defaults
+    (25, 25, 16, 64, 16, 64, 16),   # gpt2-xl.closed-16's: 16 lanes
     (12, 12, 1, 64, 16, 64),        # ... decoding
     (8, 2, 4, 128, 32, 64),         # GQA, head_dim 128, wide blocks
     (8, 2, 1, 64, 16, 512),         # GQA, 8k-token table
@@ -86,10 +89,10 @@ def test_paged_kernel_matches_reference_tpu(version, geom, pool):
     from paddle_tpu.ops.pallas import paged
     from paddle_tpu.serving import kv_cache as kvc
 
-    h, hp, c, d, bs, m = geom
+    h, hp, c, d, bs, m = geom[:6]
     dtype_name, quantized = pool
     args, clean = _case(h, hp, c, d, bs, m, jnp.dtype(dtype_name),
-                        quantized, seed=sum(geom))
+                        quantized, seed=sum(geom), b=(geom[6:] or (4,))[0])
     fn = (paged.ragged_paged_attention if version == "v1"
           else paged.ragged_paged_attention_v2)
     assert not paged._interpret()
