@@ -64,6 +64,47 @@ def least_time_s(flops, nbytes, peaks):
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
 
 
+def lane_calls(requests, chunk, t0, t1):
+    """(queries, context) of every attention call a lane made in
+    [t0, t1], from a runner's request log (`prompt`, `t_submit`,
+    `stamps`): a token streamed at context L read the K and V of L
+    tokens; a prefill chunk read the prompt so far. The runner does not
+    see prefill chunks, so a request's ceil(prompt / chunk) chunks are
+    spread evenly between its submit and its first token, which is exact
+    in a closed loop with a free lane and off only for the few requests
+    that straddle the window's edge."""
+    calls = []
+    for r in requests:
+        p = len(r.prompt)
+        if r.stamps:
+            n_chunks = -(-p // chunk)
+            first = r.stamps[0]
+            for k in range(n_chunks):
+                t = r.t_submit + (first - r.t_submit) * (k + 1) / n_chunks
+                if t0 <= t <= t1:
+                    end = min((k + 1) * chunk, p)
+                    calls.append((end - k * chunk, end))
+        # token j (0-based) is fed back at context p + j to yield j + 1
+        for j, t in enumerate(r.stamps[1:]):
+            if t0 <= t <= t1:
+                calls.append((1, p + j + 1))
+    return calls
+
+
+def decoder_step_flops(calls, sampled, body_per_token, head_per_token,
+                       layers, heads, head_dim):
+    """Forward operations a decoder NEEDS for the attention calls of
+    `lane_calls` and `sampled` sampled tokens: every fed token through
+    the layers' matrix products (`body_per_token`), every sampled token
+    through the head (`head_per_token`), and each call's score and value
+    products at its live context. Padded columns, idle lanes and a head
+    computed for columns that sample nothing are no work."""
+    fed = sum(c for c, _ctx in calls)
+    attention, _bytes = paged_attention_work(calls, heads, head_dim, 0)
+    return (fed * body_per_token + sampled * head_per_token
+            + layers * attention)
+
+
 def paged_attention_work(lane_calls, heads, head_dim, kv_itemsize):
     """One layer's paged attention over a list of (queries, context)
     lane calls: a decode token at context L is (1, L); a prefill chunk

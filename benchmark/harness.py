@@ -67,15 +67,25 @@ class CompileCounter:
     """Counts what XLA compiles (or loads from the persistent cache) in
     this process, with the host time of each, through JAX's own
     monitoring events. `inside(t0, t1)` is the guard: it must be 0 for
-    the measured window."""
+    the measured window. `cache_misses` counts the programs the
+    persistent cache did not hold: above 0, this run compiled (its
+    cache was cold)."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
+    MISS = "/jax/compilation_cache/cache_misses"
 
     def __init__(self):
         import jax.monitoring
         self.stamps = []
+        self.cache_misses = 0
         self._lock = threading.Lock()
         jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event, **_kw):
+        if event == self.MISS:
+            with self._lock:
+                self.cache_misses += 1
 
     def _on(self, event, duration_secs, **_kw):
         if event == self.EVENT:
@@ -167,9 +177,20 @@ class Run:
         self.samples = {}               # name -> [values] the runner took
         self.requests = []              # serving: the runner's request log
         self.facts = {}                 # shapes and counts of the run
+        self.compared = {}              # name -> {"value", "limit"}
         self.memory_peak_bytes = 0
 
     def check(self, ok, msg):
         """Log one condition of `correct`; all must hold."""
         log(("ok   " if ok else "FAIL ") + msg)
         return bool(ok)
+
+    def within(self, name, value, limit, what):
+        """One NUMBER of `correct` against its limit (holds where value
+        <= limit); `run.py` prints every such pair last, on standard
+        error and in the result line, so that a run that is not correct
+        says by how much."""
+        self.compared[name] = {"value": float(value),
+                               "limit": float(limit)}
+        return self.check(value <= limit,
+                          f"{what}: {value:.6g} (limit {limit})")

@@ -13,6 +13,16 @@ def program_config(c):
         max_position=int(c["n_positions"]), dropout=0.0)
 
 
+def serving_flops(c):
+    """Forward matrix-product operations (x2) one token needs: through
+    the layers (q, k, v, o and the two feed-forward products) and
+    through the head tied to the token embedding."""
+    h, inner = int(c["n_embd"]), int(c["n_inner"])
+    return {"body_matmul_flops_per_token":
+            int(c["n_layer"]) * 2 * (4 * h * h + 2 * h * inner),
+            "head_matmul_flops_per_token": 2 * h * int(c["vocab_size"])}
+
+
 def serving_model(c, seed):
     """Parameters made on the device by the startup program (one
     compiled call, seeded), then cast to the serving type. The float32

@@ -137,7 +137,14 @@ def main(argv=None):
     result["facts"]["wall_s"] = time.perf_counter() - T_START
     if rehearsal:
         result["rehearsal"] = True
+    # every number `correct` compared, beside its limit: last in the
+    # line, and the last lines on standard error
+    result["compared"] = run.compared
     print(json.dumps(result), flush=True)
+    for name, pair in run.compared.items():
+        print(f"compared {name} {pair['value']:.6g} limit "
+              f"{pair['limit']:.6g}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

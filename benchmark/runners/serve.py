@@ -16,6 +16,7 @@ before it at the token's stamp, a time to first token at the first
 token's stamp, a request at its resolution.
 """
 
+import gc
 import time
 
 import numpy as np
@@ -42,6 +43,40 @@ def _window_metrics(run, log, t0, t1):
                      ttft_p90_ms=(stats.percentile(ttft, 90) if ttft
                                   else None),
                      itl_p50_ms=stats.median(gaps) if gaps else None)
+
+
+def _window_facts(run, log, t0, t1):
+    """Why a window read as it did, from the request log alone (no clock
+    of its own): where deliveries stopped, and how the work lay along
+    the window. A stall shows as one long no-token interval, a slow
+    stretch as low tenths with no long interval, another phase of the
+    closed loop as prompt tokens bunched in some tenths."""
+    gaps = stats.no_token_gaps([r.stamps for r in log], t0, t1)
+    longest = max(gaps, key=lambda g: g[1] - g[0])
+    stalls = stats.stalls(gaps)
+    tokens = stats.by_tenth([(t, 1) for r in log for t in r.stamps],
+                            t0, t1)
+    prompts = stats.by_tenth([(r.t_submit, len(r.prompt)) for r in log],
+                             t0, t1)
+    run.facts.update(
+        no_token_gap_p50_ms=stats.weighted_median_s(gaps) * 1e3,
+        longest_no_token_gap_ms=(longest[1] - longest[0]) * 1e3,
+        stall_share=stats.stall_share(gaps, t0, t1),
+        stalls=len(stalls),
+        # "seconds into the window:ms", the first dozen
+        stalls_at_s_ms=" ".join(f"{a - t0:.2f}:{(b - a) * 1e3:.0f}"
+                                for a, b in stalls[:12]),
+        tokens_by_tenth=" ".join(str(n) for n in tokens),
+        prompt_tokens_by_tenth=" ".join(str(n) for n in prompts))
+
+
+def _host_reading():
+    """What the interpreter counts for this process, read before and
+    after the window and never inside it. (The chip machine's kernel
+    keeps no `getrusage` context switches or page faults, no
+    `/proc/stat` and no load average: all read 0 there, PR 33.)"""
+    return {"cpu_s": time.process_time(),
+            "gc_gen2_collections": gc.get_stats()[2]["collections"]}
 
 
 def _count_requests(run, log, t0, t1):
@@ -111,14 +146,15 @@ def _reference_check(run, ref, model, cfg, c, log, finished):
                      token_regret_nats=worst["regret"])
     for kind, key, what in (
             ("token", "token_logp_tol_nats",
-             "log-prob of a one-token request vs reference"),
+             "log-prob of a one-token request vs reference, worst, nats"),
             ("mean", "mean_logp_tol_nats_per_token",
-             "score per token of a longer request vs reference"),
+             "score per token of a longer request vs reference, worst, "
+             "nats"),
             ("regret", "token_regret_tol_nats",
-             "reference's best token over the engine's token")):
-        ok &= run.check(worst[kind] <= float(tol[key]),
-                        f"{what}: worst {worst[kind]:.5f} nats "
-                        f"(tolerance {tol[key]})")
+             "reference's best token over the engine's token, worst, "
+             "nats")):
+        ok &= run.within(key.replace("_tol", "_gap"), worst[kind],
+                         float(tol[key]), what)
     return ok
 
 
@@ -152,6 +188,7 @@ def run(ctx):
         if not load.warm.wait(timeout=ctx.warm_timeout_s):
             raise RuntimeError(
                 f"the load was not warm after {ctx.warm_timeout_s}s")
+        host_before = _host_reading()
         t0 = out.t0 = _clock()
         out.e2e["setup_s"] = t0 - ctx.t_start
         t1 = t0 + ctx.seconds
@@ -170,6 +207,7 @@ def run(ctx):
         if trace_until is not None:
             traced.stop()
         out.t1 = t1
+        host_after = _host_reading()
         load.stop()
         st = srv.get_stats()
         interp = reg.gauge("serving.kernel.interpret").value()
@@ -178,6 +216,9 @@ def run(ctx):
     # -- the window, from the client's side ------------------------------
     log = list(load.log)
     _window_metrics(out, log, t0, t1)
+    _window_facts(out, log, t0, t1)
+    out.facts.update({"window_" + k: host_after[k] - host_before[k]
+                      for k in host_before})
     finished = _count_requests(out, log, t0, t1)
     out.failed += load.submit_errors
     out.attempted += load.submit_errors
@@ -194,7 +235,12 @@ def run(ctx):
         num_layers=cfg.num_layers, num_heads=cfg.num_heads,
         head_dim=cfg.hidden_size // cfg.num_heads,
         kv_itemsize=np.dtype(srv.cache.dtype).itemsize,
-        chunk=st["chunk"], requests_submitted=len(log))
+        chunk=st["chunk"], requests_submitted=len(log),
+        requests_resolved=out.attempted,
+        compiles_in_run=compiles.total(),
+        compile_cache_misses=compiles.cache_misses,
+        **(family.serving_flops(c) if hasattr(family, "serving_flops")
+           else {}))
     out.memory_peak_bytes = harness.memory_peak_bytes(ctx.devices)
     harness.log(f"serve: window {ctx.seconds}s: {out.attempted} requests "
                 f"resolved, {out.failed} failed, "
@@ -205,8 +251,9 @@ def run(ctx):
                 f"{out.facts['itl_p50_ms']}")
     # -- correct ---------------------------------------------------------
     kern = st["kernel"]
-    ok = out.check(out.failed == 0 and out.attempted > 0,
-                   f"{out.failed} of {out.attempted} requests failed")
+    ok = out.within("requests_failed", out.failed, 0,
+                    f"requests failed of {out.attempted} resolved")
+    ok &= out.check(out.attempted > 0, "a request resolved in the window")
     ok &= out.check(kern["version"] in ("v1", "v2")
                     and kern["fallback_dispatches"] == 0
                     and kern["kernel_dispatches"] == cfg.num_layers,
@@ -215,9 +262,9 @@ def run(ctx):
                     f"serving.kernel.interpret gauge is {interp}")
     ok &= out.check(st["fused_step_signatures"] == 1,
                     f"{st['fused_step_signatures']} fused-step signature(s)")
-    ok &= out.check(compiles.inside(t0, t1) == 0,
-                    f"{compiles.inside(t0, t1)} compilation(s) inside the "
-                    f"window ({compiles.total()} in the whole run)")
+    ok &= out.within("compilations_in_window", compiles.inside(t0, t1), 0,
+                     f"compilations inside the window ({compiles.total()} "
+                     f"in the whole run)")
     ok &= _reference_check(out, ref, model, cfg, c, log, finished)
     out.correct = bool(ok)
     return out

@@ -154,11 +154,10 @@ def run(ctx):
         half >= 1 and np.mean(losses[half:]) < np.mean(losses[:half]),
         f"loss falls: later half {np.mean(losses[half:]):.4f} below "
         f"earlier half {np.mean(losses[:max(half, 1)]):.4f}")
-    ok &= out.check(
-        abs(got - want) <= rtol * abs(want),
+    ok &= out.within(
+        "loss_relative_gap", abs(got - want) / abs(want), rtol,
         f"test-mode loss of batch 0 at the trained parameters: program "
-        f"{got:.6f}, reference {want:.6f}, relative "
-        f"{abs(got - want) / abs(want):.2e} (tolerance {rtol})")
+        f"{got:.6f}, reference {want:.6f}, relative gap")
     ok &= out.check(flash_traced >= shape["num_layers"],
                     f"flash attention traced {flash_traced} times")
     ok &= out.check(flash._interpret() == ctx.rehearsal,
@@ -172,8 +171,8 @@ def run(ctx):
             k = traced.device.kernel_calls((kernel,))
             ok &= out.check(k >= 1, f"the trace holds {k:.0f} executions "
                                     f"of {kernel}")
-    ok &= out.check(compiles.inside(t0, t1) == 0,
-                    f"{compiles.inside(t0, t1)} compilation(s) inside the "
-                    f"window ({compiles.total()} in the whole run)")
+    ok &= out.within("compilations_in_window", compiles.inside(t0, t1), 0,
+                     f"compilations inside the window ({compiles.total()} "
+                     f"in the whole run)")
     out.correct = bool(ok)
     return out

@@ -41,15 +41,38 @@ def test_flash_work_per_kernel():
         == flops.flash_work("flash_fwd", 1, 1, 8, 8, 4)[0] // 2
 
 
-def test_lane_calls_from_a_request_log():
-    roof = _reader("paged_attention_roofline")
-    req = types.SimpleNamespace(
+def _request():
+    return types.SimpleNamespace(
         prompt=list(range(40)), t_submit=0.0,
         stamps=[3.0, 4.0, 5.0])       # 3 chunks of 16, then 2 decodes
-    calls = roof.lane_calls([req], 16, 0.0, 10.0)
+
+
+def test_lane_calls_from_a_request_log():
+    calls = flops.lane_calls([_request()], 16, 0.0, 10.0)
     assert calls == [(16, 16), (16, 32), (8, 40), (1, 41), (1, 42)]
     # only what falls in the window: the last chunk and the first decode
-    assert roof.lane_calls([req], 16, 2.5, 4.5) == [(8, 40), (1, 41)]
+    assert flops.lane_calls([_request()], 16, 2.5, 4.5) == [(8, 40),
+                                                            (1, 41)]
+
+
+def test_decoder_step_flops_and_the_serving_mfu_reader():
+    calls = flops.lane_calls([_request()], 16, 0.0, 10.0)
+    ops = flops.decoder_step_flops(calls, 3, 1000, 50, layers=2, heads=4,
+                                   head_dim=8)
+    # 42 fed tokens, 3 sampled, attention 4 x c x ctx x 32 a layer
+    attention = 4 * 32 * (16 * 16 + 16 * 32 + 8 * 40 + 41 + 42)
+    assert ops == 42 * 1000 + 3 * 50 + 2 * attention
+    mfu = _reader("fused_step.mfu")
+    run = types.SimpleNamespace(
+        requests=[_request()], t0=0.0, t1=10.0,
+        facts={"chunk": 16, "window_tokens": 3, "num_layers": 2,
+               "num_heads": 4, "head_dim": 8,
+               "body_matmul_flops_per_token": 1000,
+               "head_matmul_flops_per_token": 50},
+        ctx=types.SimpleNamespace(chips=1, peaks={"flops_per_s": 1e6}))
+    assert mfu.read(run) == pytest.approx(100.0 * ops / (10.0 * 1e6))
+    del run.facts["body_matmul_flops_per_token"]
+    assert mfu.read(run) is None
 
 
 def test_mfu_reader_arithmetic():
