@@ -236,6 +236,16 @@ METRIC_SPECS = [
      "(the array that was pools[0]['kv'] before the call is deleted "
      "after it); equals serving.iterations when donation works, and "
      "stays behind it when a step copied the pools instead"),
+    ("serving.moe.assignments", "counter",
+     "token-to-expert assignments the routers of an expert model's "
+     "fused steps made, over ALL of a layer's experts and summed over "
+     "the expert layers (valid columns x experts per token x expert "
+     "layers)"),
+    ("serving.moe.assignments_held", "counter",
+     "those of serving.moe.assignments that went to an expert THIS "
+     "chip holds (serving/moe.py expert_share): the ones it computed; "
+     "the rest belong to the other chips of the deployment and are "
+     "left out"),
     ("serving.kv.quant.pool_bytes", "gauge",
      "TRUE footprint of a quantized KV block pool: int8 codes plus the "
      "f32 per-row scale pools, across k+v and every layer (label: "

@@ -423,28 +423,35 @@ def _paged_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "name", "kernel", "group", "scratch", "out_dtype", "vmem_bytes",
-    "interpret"))
+    "name", "kernel", "statics", "group", "scratch", "out_shape",
+    "out_dtype", "vmem_bytes", "interpret"))
 def _paged_call(q, kv_pool, scales, block_table, q_positions, *, name,
-                kernel, group, scratch, out_dtype, vmem_bytes, interpret):
-    """The pallas_call both generations share: one grid step a live
-    group of `group` table columns of a lane (`_plan_walk`; the grid's
-    bound is a value, not a shape), the walk's plan and the positions
-    scalar-prefetched, q/out one lane per block, the pool (and each
-    scale pool) `group` table-addressed blocks per step: the one pool
-    operand is handed in once a window, each with its own index map.
+                kernel, statics, group, scratch, out_shape, out_dtype,
+                vmem_bytes, interpret):
+    """The pallas_call every walk shares: one grid step a live group of
+    `group` table columns of a lane (`_plan_walk`; the grid's bound is
+    a value, not a shape), the walk's plan and the positions
+    scalar-prefetched, q/out one lane per block (whatever lies behind
+    the lane axis), the pool (and each scale pool) `group`
+    table-addressed blocks per step: the one pool operand is handed in
+    once a window, each with its own index map. `statics` are the
+    kernel's own keyword facts (a tuple of pairs), beside the block
+    size, the table width and the group every walk is told.
 
     Jitted, so that the layers of a step, which call it with the same
     shapes, share ONE trace of the kernel and ONE lowering to Mosaic:
     48 layers each tracing and lowering v1's eight branches put 14 s
     into every start of a server, cache or no cache."""
-    b, h, c, d = q.shape
-    _n, hp, bs, _d2 = kv_pool.shape
+    bs = kv_pool.shape[2]
     m = block_table.shape[1]
-    lane_spec = pl.BlockSpec(
-        (1, h, c, d), lambda s, lane, *plan: (lane[s], 0, 0, 0))
+
+    def lane_spec(shape):
+        zeros = (0,) * (len(shape) - 1)
+        return pl.BlockSpec((1,) + tuple(shape[1:]),
+                            lambda s, lane, *plan: (lane[s],) + zeros)
+
     pools = [kv_pool] + scales
-    in_specs = [lane_spec]
+    in_specs = [lane_spec(q.shape)]
     for pool in pools:
         in_specs += _page_specs(pool.shape, group)
     steps, plan = _plan_walk(block_table, q_positions, bs, group)
@@ -452,19 +459,23 @@ def _paged_call(q, kv_pool, scales, block_table, q_positions, *, name,
         num_scalar_prefetch=6,          # the plan's five, positions
         grid=(steps,),
         in_specs=in_specs,
-        out_specs=lane_spec,
+        out_specs=lane_spec(out_shape),
         scratch_shapes=[pltpu.VMEM(shp, dt) for shp, dt in scratch],
     )
     return pl.pallas_call(
-        functools.partial(kernel, bs=bs, m=m, p=group, h=h, hp=hp, d=d,
-                          quantized=bool(scales)),
+        functools.partial(kernel, bs=bs, m=m, p=group, **dict(statics)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, c, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         compiler_params=_compiler_params(vmem_bytes),
         name=name,
         interpret=interpret,
     )(*plan, q_positions.astype(jnp.int32), q,
       *[pool for pool in pools for _ in range(group)])
+
+
+def _kv_statics(h, hp, d, quantized):
+    """What the two K-beside-V kernels are told beside the walk."""
+    return (("h", h), ("hp", hp), ("d", d), ("quantized", quantized))
 
 
 def _v1_scratch_shapes(hp, bs, d, m, pool_dtype):
@@ -518,8 +529,10 @@ def ragged_paged_attention(q, kv_pool, block_table, q_positions,
                        [k_scale, v_scale] if quantized else [],
                        block_table, q_positions,
                        name="paged_attention_v1", kernel=_paged_kernel,
+                       statics=_kv_statics(h, hp, d, quantized),
                        group=walk_group(bs, m), scratch=tuple(scratch),
-                       out_dtype=out_dtype, vmem_bytes=vmem,
+                       out_shape=q.shape, out_dtype=out_dtype,
+                       vmem_bytes=vmem,
                        interpret=bool(interpret))
 
 
@@ -639,9 +652,194 @@ def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
                        [k_scale, v_scale] if quantized else [],
                        block_table, q_positions,
                        name="paged_attention_v2", kernel=_paged_kernel_v2,
+                       statics=_kv_statics(h, hp, d, quantized),
                        group=1, scratch=tuple(scratch),
-                       out_dtype=out_dtype, vmem_bytes=vmem,
+                       out_shape=q.shape, out_dtype=out_dtype,
+                       vmem_bytes=vmem,
                        interpret=bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# the latent walk: one row a token, read by every head
+# ---------------------------------------------------------------------------
+
+LATENT_TRACE_COUNT = 0
+
+
+def latent_row_width(kv_lora_rank, rope_dim):
+    """Minor dim of a latent pool: the token's compressed KV and its
+    one rotated key, `[c_kv | k_rope]`, padded with zeros to whole
+    128-lane tiles (576 -> 640). The device pads a minor dim to the
+    tile anyway, so the padding is stated, and the score product runs
+    over whole tiles."""
+    return -(-(int(kv_lora_rank) + int(rope_dim)) // 128) * 128
+
+
+def _latent_scratch_shapes(rows, width, value_width, p, bs, pool_dtype):
+    """The latent walk's VMEM scratch: the group's keys in position
+    order, and the online-softmax carry (running max, exp-sum, value
+    partial) of all heads' rows. Nothing depends on the table width."""
+    return [((p * bs, width), pool_dtype),
+            ((rows, 1), jnp.float32), ((rows, 1), jnp.float32),
+            ((rows, value_width), jnp.float32)]
+
+
+def _latent_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
+                   pos_ref, q_ref, *rest, bs, m, p, heads, value_width,
+                   scale):
+    """Grid step s: lane b = lane[s], its j-th live GROUP of p table
+    columns (j = group[s]), every head of every column at once.
+
+    q_ref (1, C * H, W): the lane's absorbed queries, column-major
+    (row ci * H + h is head h of column ci), `[q_nope W_uk^T | q_rope]`
+    padded like the pool's rows. Then p windows (1, 1, bs, W): pool
+    blocks fetch[b, j*p : (j+1)*p], `[c_kv | k_rope | 0]` a token, ONE
+    row whoever the head. The group's rows land in `k_ref` in position
+    order (a dead column's rows as zeros); one (C*H, W) x (W, p*bs)
+    product scores them for all heads, and the VALUES are the first
+    `value_width` lanes of the same VMEM rows: the pool is read once.
+    The rows fold into a flash-style carry (m, l, acc: f32), as v2's
+    do, with v2's two traps dodged the same way (probabilities come
+    from where(mask, exp, 0); an idle lane divides by 1). A lane that
+    feeds one token computes its first column's rows only."""
+    del fetch_ref, m                        # read by the index maps
+    kv_refs, rest = rest[:p], rest[p:]
+    o_ref, k_ref, m_ref, l_ref, acc_ref = rest
+    step = pl.program_id(0)
+    b, j = lane_ref[step], group_ref[step]
+    c = pos_ref.shape[1]
+    rows, t = c * heads, p * bs
+    n_groups = groups_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_groups > 0)
+    def _land_group():
+        for i in range(p):
+            span = pl.ds(i * bs, bs)
+            is_live = live_ref[b, j * p + i] != 0
+
+            @pl.when(is_live)
+            def _land():
+                k_ref[span, :] = kv_refs[i][0, 0]
+
+            @pl.when(jnp.logical_not(is_live))
+            def _clear():
+                k_ref[span, :] = jnp.zeros((bs, k_ref.shape[1]),
+                                           k_ref.dtype)
+
+    def fold(n_cols):
+        """Fold the group into the carry of the lane's first `n_cols`
+        columns (static): their n_cols * heads rows."""
+        n = n_cols * heads
+        keys = k_ref[...]                                   # (t, W)
+        q = q_ref[0, :n, :].astype(keys.dtype)              # (n, W)
+        s = jax.lax.dot_general(
+            q, keys, (((1,), (1,)), ((), ())),
+            precision=_mxu_precision(keys.dtype),
+            preferred_element_type=jnp.float32) * scale     # (n, t)
+        # row r belongs to column r // heads: its query position
+        row = jax.lax.broadcasted_iota(jnp.int32, (n, t), 0)
+        q_pos = jnp.full((n, t), pos_ref[b, 0], jnp.int32)
+        for ci in range(1, n_cols):
+            q_pos = jnp.where(row >= ci * heads, pos_ref[b, ci], q_pos)
+        key = jax.lax.broadcasted_iota(jnp.int32, (n, t), 1)
+        dead = jnp.full((n, t), 1 - live_ref[b, j * p], jnp.int32)
+        for i in range(1, p):
+            dead = jnp.where(key >= i * bs,
+                             1 - live_ref[b, j * p + i], dead)
+        mask = (j * t + key <= q_pos) & (dead == 0)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[:n, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        prob = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[:n, :] = l_ref[:n, :] * corr + jnp.sum(prob, axis=-1,
+                                                     keepdims=True)
+        acc_ref[:n, :] = acc_ref[:n, :] * corr + jnp.dot(
+            prob.astype(keys.dtype), keys[:, :value_width],
+            precision=_mxu_precision(keys.dtype),
+            preferred_element_type=jnp.float32)
+        m_ref[:n, :] = m_new
+
+    # A lane's live columns hold consecutive positions and the rest
+    # hold 0 (the scheduler's contract, `_plan_block_writes`), so a
+    # lane whose second column does not continue its first feeds ONE
+    # token: a decode lane. Its other columns' rows are padding: they
+    # are not computed, and stay the zeros the carry starts from.
+    if c == 1:
+        pl.when(n_groups > 0)(lambda: fold(1))
+    else:
+        one = pos_ref[b, 1] <= pos_ref[b, 0]
+        pl.when((n_groups > 0) & one)(lambda: fold(1))
+        pl.when((n_groups > 0) & jnp.logical_not(one))(lambda: fold(c))
+
+    @pl.when(j == jnp.maximum(n_groups, 1) - 1)
+    def _flush():
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def paged_latent_attention(q, kv_pool, block_table, q_positions, *,
+                           value_width, scale, interpret=None):
+    """Paged attention over a LATENT pool (multi-head latent attention
+    in its absorbed form): the walk of `_plan_walk` / `_paged_call`,
+    over one row a token that every head reads.
+
+        q:           (B, C, H, W) absorbed queries, `[q_nope W_uk^T |
+                     q_rope]` zero-padded to the pool's row width
+        kv_pool:     (N, 1, bs, W), `[c_kv | k_rope | 0]` a token (f32
+                     or bf16; `latent_row_width`)
+        block_table: (B, M) int32 (NULL_BLOCK-padded)
+        q_positions: (B, C) int32
+        value_width: the leading lanes of a row that are its value
+                     (kv_lora_rank); `scale` multiplies the scores
+        returns      (B, C, H, value_width) in the pool's dtype: each
+                     head's probabilities over c_kv, which the caller
+                     expands through W_uv
+
+    Scores and softmax state are f32 whatever the pool holds. The same
+    serving contract as v1/v2: NULL and stale blocks never enter the
+    arithmetic, an idle lane is an exact zero, prefill chunks and
+    decode tokens are one kernel."""
+    global TRACE_COUNT, LATENT_TRACE_COUNT
+    TRACE_COUNT += 1
+    LATENT_TRACE_COUNT += 1
+    b, c, h, w = q.shape
+    n, one, bs, wp = kv_pool.shape
+    if one != 1 or wp != w or not 0 < value_width <= w:
+        raise ValueError(
+            f"latent pool {kv_pool.shape} and q {q.shape} do not match "
+            f"(a latent pool is (N, 1, bs, W), one row a token; q is "
+            f"(B, C, H, W) at the same W; value_width {value_width})")
+    m = block_table.shape[1]
+    if block_table.shape[0] != b or q_positions.shape != (b, c):
+        raise ValueError(
+            f"table {block_table.shape} / positions {q_positions.shape} "
+            f"do not match q {q.shape}")
+    if interpret is None:
+        interpret = _interpret()
+    group = walk_group(bs, m)
+    scratch = _latent_scratch_shapes(c * h, w, value_width, group, bs,
+                                     kv_pool.dtype)
+    vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
+            + 2 * _padded_bytes((c * h, w), q.dtype)
+            + 4 * _padded_bytes((c * h, group * bs), jnp.float32))
+    out = _paged_call(q.reshape(b, c * h, w), kv_pool, [], block_table,
+                      q_positions, name="paged_latent_attention",
+                      kernel=_latent_kernel,
+                      statics=(("heads", h), ("value_width", value_width),
+                               ("scale", float(scale))),
+                      group=group, scratch=tuple(scratch),
+                      out_shape=(b, c * h, value_width),
+                      out_dtype=kv_pool.dtype, vmem_bytes=vmem,
+                      interpret=bool(interpret))
+    return out.reshape(b, c, h, value_width)
 
 
 # ---------------------------------------------------------------------------

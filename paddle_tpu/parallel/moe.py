@@ -3,7 +3,12 @@
 Parity target: scale story (reference's pserver sharded embeddings are its
 biggest-model mechanism; the TPU equivalent for conditional compute is MoE
 over the 'ep' axis with all_to_all dispatch — EP in SURVEY.md §2.6).
-Top-k gating with capacity, all_to_all to experts and back.
+What is here is the TRAINING-side sketch: Switch-style TOP-1 softmax gating
+with a capacity (a token past its expert's capacity is dropped), an
+all_to_all to the experts and back. Nothing under `serving/` imports it.
+The served expert layer is `serving/moe.py`: sigmoid scores, top-k with a
+selection-only bias, a chip that holds its share of the experts, no
+capacity and no dropped token (kernel `ops/pallas/moe.moe_experts`).
 """
 
 import jax

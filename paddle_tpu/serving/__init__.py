@@ -90,6 +90,7 @@ from .decode_strategies import (BeamHypothesis, BeamParams, GroupFuture,
 from .guided import (ChoiceConstraint, Constraint, JsonConstraint,
                      RegexConstraint)
 from .engine import GenerationFuture, GenerationServer, GPTServingModel
+from .latent_moe import LatentMoEServingModel
 from .spec_decode import SpecDecodeConfig
 from .replica import Replica
 from .router import (AdmissionPolicy, AdmissionRejected, FleetFuture,
@@ -109,6 +110,7 @@ __all__ = [
     "ContinuousBatchingScheduler", "GenerationResult",
     "DeadlineExceeded", "RequestCancelled",
     "GenerationServer", "GenerationFuture", "GPTServingModel",
+    "LatentMoEServingModel",
     "Replica", "FleetRouter", "FleetFuture", "RouterPolicy",
     "AdmissionPolicy", "AdmissionRejected",
     "WorkerProxy", "make_subprocess_spawn", "spawn_worker",

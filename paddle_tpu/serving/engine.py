@@ -36,16 +36,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import _cast_params, _ln, load_params
+from ..models.gpt import _cast_params, load_params
 from ..observability import _help
 from ..observability.metrics import global_registry
 from ..observability.tracing import get_recorder
 from . import kv_cache as _kvc
+from .blocks import (ATTENTIONS, MLPS, NORMS, LayerSpec, StepContext,
+                     StepSpec, fold_counts, rotary_angles)
 from .decode_strategies import (GroupFuture, RequestGroup,
                                 SamplingParams, gumbel_noise)
-from .kv_cache import (NEG_INF, NULL_BLOCK, PagedKVCache,
-                       fuse_kv, paged_attention, write_block_kv,
-                       write_block_kv_quant)
+from .kv_cache import NEG_INF, NULL_BLOCK, PagedKVCache
 from .scheduler import ContinuousBatchingScheduler, RequestCancelled, _Request
 
 __all__ = ["GenerationServer", "GenerationFuture", "GPTServingModel"]
@@ -83,20 +83,30 @@ def _sample_rows(base, rng, temperature, do_top_k, top_p):
     return samp.astype(jnp.int32), samp_lp
 
 
-def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
-                     reduce_fn, pools, tokens, positions, valid, tables,
-                     per_column=False, sampling=False, mask=None,
-                     rng=None, temperature=None, do_sample=None,
-                     top_k=None, top_p=None, in_shard_map=False):
-    """The ONE fused prefill/decode step body (build_kv_step's math over
-    (S, C) ragged lanes with paged KV), shared by the single-device and
-    tensor-parallel fused steps exactly like gpt._prefill_forward:
-    `h_count` is the QUERY head count THIS caller sees (H, or H/tp
-    inside shard_map over head-sharded params and pools), `kv_count`
-    the KV head count (equal for MHA; H_kv or H_kv/tp for
-    grouped-query attention, where wk/wv project to kv_count * d
-    columns and the paged_attention dispatcher groups the query heads
-    onto the shared KV heads), and `reduce_fn`
+def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
+                     positions, valid, tables, per_column=False,
+                     sampling=False, mask=None, rng=None,
+                     temperature=None, do_sample=None, top_k=None,
+                     top_p=None, in_shard_map=False):
+    """The ONE fused prefill/decode step body, over (S, C) ragged lanes
+    with paged KV, for every model family: `spec` (`blocks.StepSpec`)
+    says, layer by layer, which norm, which attention over which cache
+    geometry and which MLP a block is made of, how positions enter and
+    whether the head is tied; `blocks.py` holds each kind's
+    arithmetic. The skeleton around the layers is the same for all:
+    embedding, write targets (masked lanes to the NULL block), the
+    layer loop with its two residual adds, the final norm, the
+    last-column gather, log-softmax in float32, and the greedy and
+    sampled tails.
+
+    It is shared by the single-device and tensor-parallel fused steps
+    exactly like gpt._prefill_forward: `spec.heads` is the QUERY head
+    count THIS caller sees (H, or H/tp inside shard_map over
+    head-sharded params and pools), `spec.kv_heads` the KV head count
+    (equal for MHA; H_kv or H_kv/tp for grouped-query attention, where
+    wk/wv project to kv_heads * head_dim columns and the
+    paged_attention dispatcher groups the query heads onto the shared
+    KV heads), and `reduce_fn`
     finishes the row-parallel o-proj / ffn-down contractions (identity
     single-device; one psum per sub-block under tp — the partial sums
     those matmuls leave are the ONLY cross-shard state the step has);
@@ -114,6 +124,12 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
     outputs are bitwise the last-column gather's (the spec parity tests
     pin this); plain servers keep the narrow gemm — C x fewer lm-head
     FLOPs on the decode hot path.
+
+    A spec with layers that count (an MLP kind that returns counts
+    beside its addend: the expert layer its routing) returns ONE more
+    output, last: the step's counts, an int32 vector folded over those
+    layers (`blocks.fold_counts`), which the model names
+    (`step_counters`).
 
     Quantized serving (ISSUE 14) rides the same body: a layer dict
     carrying "k_scale"/"v_scale" pools takes the quantize-at-write path
@@ -137,45 +153,53 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
                 * container[name + "@scale"]).astype(wdt)
 
     pos = jnp.where(valid, positions, 0)
-    x = params["word_emb"][tokens] + params["pos_emb"][pos]
+    x = params["word_emb"][tokens]
+    angles = None
+    if spec.positions == "learned":
+        x = x + params["pos_emb"][pos]
+    else:
+        angles = rotary_angles(pos, spec.dims["qk_rope"],
+                               spec.dims["rope_theta"])
     # write targets: masked lanes route to the NULL block
     bidx = jnp.take_along_axis(tables, pos // block_size, axis=1)
     bidx = jnp.where(valid, bidx, NULL_BLOCK)
     off = jnp.where(valid, pos % block_size, 0)
-    new_pools = []
-    for i in range(cfg.num_layers):
+    ctx = StepContext(spec, s, c, x.dtype, pos, bidx, off, valid, tables,
+                      reduce_fn, in_shard_map, w, angles)
+    new_pools, counts = [], []
+    for i, layer in enumerate(spec.layers):
         lp = params[f"l{i}"]
-        kvp = pools[i]["kv"]
-        ks, vs = pools[i].get("k_scale"), pools[i].get("v_scale")
-        hn = _ln(x, lp["ln1_s"], lp["ln1_b"])
-        q = (hn @ w(lp, "wq") + lp["bq"]).reshape(s, c, h_count, d)
-        k = (hn @ w(lp, "wk") + lp["bk"]).reshape(s, c, kv_count, d)
-        v = (hn @ w(lp, "wv") + lp["bv"]).reshape(s, c, kv_count, d)
-        if ks is not None:
-            kvp, ks, vs = write_block_kv_quant(kvp, ks, vs, k, v, bidx,
-                                               off)
-        else:
-            kvp = write_block_kv(kvp, fuse_kv(k, v), bidx, off)
-        o = paged_attention(q.transpose(0, 2, 1, 3), kvp, tables, pos,
-                            k_scale=ks, v_scale=vs,
-                            in_shard_map=in_shard_map)
-        o = o.transpose(0, 2, 1, 3).reshape(s, c, h_count * d)
-        x = x + (reduce_fn(o @ w(lp, "wo")) + lp["bo"]).astype(x.dtype)
-        hn = _ln(x, lp["ln2_s"], lp["ln2_b"])
-        f = jax.nn.gelu(hn @ w(lp, "f0w") + lp["f0b"],
-                        approximate=False)
-        x = x + (reduce_fn(f @ w(lp, "f1w")) + lp["f1b"]).astype(
-            x.dtype)
-        layer = {"kv": kvp}
-        if ks is not None:
-            layer["k_scale"], layer["v_scale"] = ks, vs
-        new_pools.append(layer)
-    x = _ln(x, params["lnf_s"], params["lnf_b"])
+        norm = NORMS[layer.norm]
+        a, layer_pools = ATTENTIONS[layer.attention](
+            ctx, norm(spec, x, lp, "ln1"), lp, pools[i])
+        x = x + a
+        f, stats = MLPS[layer.mlp](ctx, norm(spec, x, lp, "ln2"), lp)
+        x = x + f
+        new_pools.append(layer_pools)
+        if stats is not None:
+            counts.append(stats)
+    x = NORMS[spec.layers[-1].norm](spec, x, params, "lnf")
+    head = params["word_emb"].T if spec.tied_head else params["head"]
+    out = _step_tail(x, head, tokens, valid, new_pools, per_column,
+                     sampling, mask, rng, temperature, do_sample, top_k,
+                     top_p)
+    if counts:
+        out += (fold_counts(counts),)
+    return out
+
+
+def _step_tail(x, head, tokens, valid, new_pools, per_column, sampling,
+               mask, rng, temperature, do_sample, top_k, top_p):
+    """From the final-normed residual to the step's outputs: the head
+    over each lane's last valid column (or every column), log-softmax
+    in float32, the greedy choice and, where compiled in, the sampled
+    one."""
+    s, c = tokens.shape
     if not per_column:
         # next token comes from each lane's LAST valid column only
         last = jnp.clip(valid.sum(1) - 1, 0, c - 1)
         xl = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        logits = xl @ params["word_emb"].T
+        logits = xl @ head
         logitsf = logits.astype(jnp.float32)
         if sampling:
             # guided-decoding constraint mask (S, V): additive 0 /
@@ -195,9 +219,8 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
         # beam re-ranking read these (the host transfer is paid only
         # when the plan says a group needs them)
         return new_pools, nxt, chosen, logp
-    vocab = params["word_emb"].shape[0]
-    logits = (x.reshape(s * c, -1) @ params["word_emb"].T).reshape(
-        s, c, vocab)
+    logits = (x.reshape(s * c, -1) @ head).reshape(
+        s, c, head.shape[1])
     logitsf = logits.astype(jnp.float32)
     if sampling:
         logitsf = logitsf + mask        # (S, C, V) per-column masks
@@ -219,6 +242,25 @@ def _fused_step_body(params, cfg, block_size, h_count, kv_count, d,
     chosen = chosen.at[:, 0].set(
         jnp.where(do_sample, samp_lp, chosen[:, 0]))
     return new_pools, nxt.astype(jnp.int32), chosen, fed, logp
+
+
+def single_device_step(params, spec, block_size, per_column=False,
+                       sampling=False):
+    """The fused step of one device over `params` and `spec`, as
+    `GenerationServer` jits it: (pools, tokens, positions, valid,
+    tables[, mask, rng, temperature, do_sample, top_k, top_p]) ->
+    `_fused_step_body`'s outputs. What every family's
+    `build_fused_step` returns without a mesh."""
+    controls = ("mask", "rng", "temperature", "do_sample", "top_k",
+                "top_p") if sampling else ()
+
+    def fused(pools, tokens, positions, valid, tables, *ctl):
+        return _fused_step_body(
+            params, spec, block_size, lambda z: z, pools, tokens,
+            positions, valid, tables, per_column=per_column,
+            sampling=sampling, **dict(zip(controls, ctl, strict=True)))
+
+    return fused
 
 
 class GPTServingModel:
@@ -300,11 +342,25 @@ class GPTServingModel:
         """Quantized weight-tensor count (0 = dense weights)."""
         return self._int8_weights
 
+    def step_spec(self, heads=None, kv_heads=None):
+        """This family's block as the fused step reads it
+        (`blocks.StepSpec`): LayerNorm with bias, learned positions,
+        multi-head attention with biased projections over a K-beside-V
+        pool, erf-GELU MLP, head tied to the embedding. `heads` /
+        `kv_heads` override the counts for a caller inside a
+        shard_map."""
+        return StepSpec(
+            layers=(LayerSpec("layer_norm", "mha", "gelu"),
+                    ) * self.num_layers,
+            positions="learned", tied_head=True,
+            heads=heads or self.num_heads,
+            kv_heads=kv_heads or self.num_kv_heads,
+            head_dim=self.head_dim, norm_eps=None, dims=None)
+
     def build_fused_step(self, block_size, mesh=None, axis="tp",
                          per_column=False, kv_quantized=False,
                          sampling=False):
         params, cfg = self.params, self.cfg
-        h_, kv_, d = self.num_heads, self.num_kv_heads, self.head_dim
 
         if mesh is not None and self._int8_weights:
             raise NotImplementedError(
@@ -319,26 +375,8 @@ class GPTServingModel:
                 "(replicating the mask/rng feeds through shard_map is "
                 "follow-up work, docs/serving.md)")
         if mesh is None:
-            if sampling:
-                def fused(pools, tokens, positions, valid, tables,
-                          mask, rng, temperature, do_sample,
-                          top_k, top_p):
-                    return _fused_step_body(
-                        params, cfg, block_size, h_, kv_, d,
-                        lambda z: z, pools, tokens, positions, valid,
-                        tables, per_column=per_column, sampling=True,
-                        mask=mask, rng=rng, temperature=temperature,
-                        do_sample=do_sample, top_k=top_k, top_p=top_p)
-
-                return fused
-
-            def fused(pools, tokens, positions, valid, tables):
-                return _fused_step_body(
-                    params, cfg, block_size, h_, kv_, d, lambda z: z,
-                    pools, tokens, positions, valid, tables,
-                    per_column=per_column)
-
-            return fused
+            return single_device_step(params, self.step_spec(),
+                                      block_size, per_column, sampling)
         if per_column:
             raise NotImplementedError(
                 "per-column outputs (speculative verify) are not "
@@ -373,9 +411,11 @@ class GPTServingModel:
         self.params = sharded
         del params
 
+        local_spec = self.step_spec(heads=h_loc, kv_heads=kv_loc)
+
         def local(lp_all, pools, tokens, positions, valid, tables):
             return _fused_step_body(
-                lp_all, cfg, block_size, h_loc, kv_loc, d,
+                lp_all, local_spec, block_size,
                 lambda z: jax.lax.psum(z, axis),
                 pools, tokens, positions, valid, tables,
                 in_shard_map=True)
@@ -519,7 +559,9 @@ class GenerationServer:
                                   block_size=self.block_size,
                                   dtype=model.kv_dtype, mesh=mesh,
                                   axis=mesh_axis, kv_dtype=kv_dtype,
-                                  num_kv_heads=kv_heads)
+                                  num_kv_heads=kv_heads,
+                                  geometry=getattr(model, "kv_geometry",
+                                                   None))
         if chaos is not None and clock is None and \
                 getattr(chaos, "drives_clock", lambda: False)():
             clock = chaos.serving_clock
@@ -817,6 +859,7 @@ class GenerationServer:
         self._kernel_mode = None        # mode the step traced under
         self._kernel_counts = (0, 0)    # this server's trace dispatches
         self._kernel_version = None     # v1/v2 the trace dispatched to
+        self._kernel_name = None        # and the kernel's own name
         self._next_rid = 0
         self._rid_lock = threading.Lock()
         self._closed = False
@@ -844,6 +887,16 @@ class GenerationServer:
                 "serving.kv.pool_donations",
                 _help("serving.kv.pool_donations")),
         }
+        # a model whose step counts something returns the counts as
+        # one more output (`_fused_step_body`) and names them:
+        # `step_counters`, a (name in the iteration record, registry
+        # counter it feeds or None) a count
+        self._step_counters = tuple(getattr(model, "step_counters", ()))
+        self._step_counts = None
+        self._counters_fed = [
+            (i, reg.counter(metric, _help(metric)))
+            for i, (_arg, metric) in enumerate(self._step_counters)
+            if metric]
         self._worker = None
         if start:
             self._worker = threading.Thread(target=self._serve,
@@ -1203,7 +1256,9 @@ class GenerationServer:
         `walk_groups_live` over `walk_groups` is the share of the block
         table the paged kernel touches: it walks a lane's table in
         groups of `walk_group` columns and stops after the last one
-        that holds a token."""
+        that holds a token (the latent walk the same). A model whose
+        step counts (`step_counters`) adds the counts under its
+        names."""
         from ..ops.pallas.paged import walk_group
         cols = plan.valid.sum(axis=1)
         lanes_qc = [[int(cols[sid]),
@@ -1212,14 +1267,17 @@ class GenerationServer:
         width = plan.tables.shape[1]
         group = walk_group(self.block_size, width)
         keys = group * self.block_size          # 128 key positions
-        return {"iteration": it, "lanes": len(plan.slot_ids),
-                "prefill_tokens": plan.prefill_tokens,
-                "valid_columns": plan.valid_columns,
-                "padded_columns": plan.padded_columns,
-                "lanes_qc": lanes_qc,
-                "walk_groups_live": sum(-(-ctx // keys)
-                                        for _q, ctx in lanes_qc),
-                "walk_groups": len(lanes_qc) * -(-width // group)}
+        record = {"iteration": it, "lanes": len(plan.slot_ids),
+                  "prefill_tokens": plan.prefill_tokens,
+                  "valid_columns": plan.valid_columns,
+                  "padded_columns": plan.padded_columns,
+                  "lanes_qc": lanes_qc,
+                  "walk_groups_live": sum(-(-ctx // keys)
+                                          for _q, ctx in lanes_qc),
+                  "walk_groups": len(lanes_qc) * -(-width // group)}
+        record.update(zip((arg for arg, _metric in self._step_counters),
+                          self._step_counts or ()))
+        return record
 
     def _apply_step_chaos(self, it, lanes):
         """Injected KV poison, applied before the step is fed."""
@@ -1289,6 +1347,7 @@ class GenerationServer:
             self._kernel_mode = _kvc.paged_kernel_mode()
             k0, f0 = (_kvc.KERNEL_DISPATCHES, _kvc.FALLBACK_DISPATCHES)
             v0 = dict(_kvc.KERNEL_VERSIONS)
+            n0 = dict(_kvc.KERNEL_NAMES)
             out = self._call_fused(args)
             self._kernel_counts = (_kvc.KERNEL_DISPATCHES - k0,
                                    _kvc.FALLBACK_DISPATCHES - f0)
@@ -1298,6 +1357,10 @@ class GenerationServer:
                   if _kvc.KERNEL_VERSIONS.get(v, 0) > v0.get(v, 0)]
             self._kernel_version = (dv[0] if len(dv) == 1 else
                                     ("mixed" if dv else None))
+            # and which kernel(s), by name in a device trace
+            self._kernel_name = "+".join(sorted(
+                n for n, k in _kvc.KERNEL_NAMES.items()
+                if k > n0.get(n, 0))) or None
         self._check_kernel_engagement()
         return out
 
@@ -1307,7 +1370,16 @@ class GenerationServer:
         # plain mode: (pools, ids (S,), logps (S,)) from the last-column
         # step; spec mode adds fed_logps and every output is per-column
         # (S, C)
+        if self._step_counters:
+            # the step's counts, its last output: their copy starts
+            # now and rides under the two waits below, so that reading
+            # them costs no round trip of its own
+            out[-1].copy_to_host_async()
         nxt, logps = np.asarray(out[1]), np.asarray(out[2])
+        if self._step_counters:
+            self._step_counts = np.asarray(out[-1]).tolist()
+            for i, counter in self._counters_fed:
+                counter.inc(self._step_counts[i])
         if nxt.ndim == 1:
             # commit() reads per-column arrays; a broadcast VIEW puts
             # the last-valid-column value at every column (a prefill
@@ -1439,8 +1511,10 @@ class GenerationServer:
         if "k_scale" in pool:
             pool["k_scale"] = pool["k_scale"].at[block].set(jnp.nan)
         else:
+            # the K lanes of a K-beside-V row; of a latent row, lanes
+            # of the c_kv every head's score reads
             pool["kv"] = pool["kv"].at[
-                block, :, :, :self.cache.head_dim].set(jnp.nan)
+                block, :, :, :pool["kv"].shape[-1] // 2].set(jnp.nan)
 
     def _poison_kv(self, layer, lanes):
         """Chaos hook: NaN the first KV block of the oldest ACTIVE lane
@@ -1538,12 +1612,16 @@ class GenerationServer:
         # the probe q is shaped like the real step's queries ((1, H, 1,
         # D) — the GQA-relaxed supported() check needs the true head
         # relation, a (1, 1, 1, 1) probe would fail it for any H_kv > 1)
+        # (a latent pool's queries are as wide as its rows)
+        latent = self.cache.latent
         expected = (self._kernel_mode != "off" and
                     _kvc.paged_kernel_supported(
                         jnp.zeros((1, self.model.num_heads, 1,
-                                   self.cache.head_dim),
+                                   kvp.shape[3] if latent
+                                   else self.cache.head_dim),
                                   self.cache.compute_dtype), kvp,
-                        p0.get("k_scale"), p0.get("v_scale")))
+                        p0.get("k_scale"), p0.get("v_scale"),
+                        latent=latent))
         if expected and not self._kernel_engaged:
             raise RuntimeError(
                 "paged attention kernel was expected "
@@ -1711,14 +1789,16 @@ class GenerationServer:
             # "v2"; None when nothing engaged) — mirrors the
             # serving.kernel.version gauge
             "version": self._kernel_version,
+            # the kernel's name as a device trace has it: the latent
+            # walk is "paged_latent_attention", of generation "v2"
+            "name": self._kernel_name,
             "kernel_dispatches": traced,
             "fallback_dispatches": fell_back,
             # what one table entry addresses, and the walk copies a
-            # group of: (H_kv, block_size, 2 * head_dim), K beside V
+            # group of: (H_kv, block_size, 2 * head_dim), K beside V,
+            # or a latent layer's (1, block_size, W), one row a token
             # (a fact for whoever reads a trace, not a switch)
-            "pool_block_shape": [self.cache.num_kv_heads,
-                                 self.cache.block_size,
-                                 2 * self.cache.head_dim],
+            "pool_block_shape": list(self.cache.layer_shapes[0][1:]),
         }
         # quantized-pool facts (None when dense): the TRUE int8+scales
         # footprint, the dense compute-dtype size the same blocks would

@@ -51,6 +51,16 @@ contiguous-group convention). Every byte count — pool_bytes, shard
 bytes, ledger rows, handoff transfers — divides by the group factor,
 compounding with int8 quantization.
 
+Latent pools (ISSUE 34): a layer of multi-head LATENT attention caches
+one row a token, `[c_kv | k_rope]` padded to whole lane tiles
+(`ops/pallas/paged.latent_row_width`), that every head reads, so its
+pool is (num_blocks, 1, block_size, W): the head axis is kept at
+length one so that every block-addressed rewriter here (the write, COW,
+the wire, the host tier) is the same code. `PagedKVCache(geometry=)`
+takes the (rows, width) of each layer's pool; `paged_latent_attention`
+is that layer's dispatcher, as `paged_attention` is a K-beside-V
+layer's, and counts into the same dispatch accounting.
+
 `PagedDecodeLayer` adapts a layer's pool to the dense mapping
 interface `decoding.py` step_fns consume (`cache[i]["k"]`,
 `update_kv_cache`), so an existing step_fn decodes against either cache
@@ -101,6 +111,7 @@ import jax.numpy as jnp
 __all__ = ["PagedKVCache", "HostKVTier", "PagedDecodeLayer",
            "paged_attention", "fuse_kv", "split_kv", "KV_LAYOUT",
            "paged_attention_reference", "gather_block_kv",
+           "paged_latent_attention", "paged_latent_attention_reference",
            "gather_block_kv_pair", "gather_block_scales",
            "build_paged_decode_cache", "quantize_kv_rows",
            "write_block_kv_quant",
@@ -126,6 +137,10 @@ FALLBACK_REASONS = {}
 # — the engine's get_stats()["kernel"]["version"] reads the delta
 # across its first trace, mirroring serving.kernel.version
 KERNEL_VERSIONS = {}
+# and which kernel, by its name in a device trace ("paged_attention_v1",
+# "paged_attention_v2", "paged_latent_attention"): a generation says how
+# a walk holds its state, and the latent walk shares v2's
+KERNEL_NAMES = {}
 
 # v1 gathers a lane's whole table into VMEM: M blocks x H_kv x bs x
 # 2*D in the pool's dtype (f32 for int8 pools). Auto mode streams
@@ -338,14 +353,23 @@ def _kernel_version_for(mode, kv_pool, block_table):
             > _v2_auto_vmem_bytes() else "v1")
 
 
-def paged_kernel_supported(q, kv_pool, k_scale=None, v_scale=None):
+def paged_kernel_supported(q, kv_pool, k_scale=None, v_scale=None,
+                           latent=False):
     """Shapes/dtypes the kernels handle: 4-D operands with an f32 or
     bf16 fused pool (N, H_kv, bs, 2 * q's head_dim) — pool heads equal
     to q's heads (MHA) or an exact divisor (GQA) — or an int8 pool
     accompanied by its two (N, H_kv, bs) f32 scale pools (quantized
-    serving — the kernels fuse the dequant into the gather)."""
+    serving — the kernels fuse the dequant into the gather). With
+    `latent`, the latent walk's geometry: an f32 or bf16 pool
+    (N, 1, bs, W), one row a token, against absorbed queries
+    (B, C, H, W) of the same width, and no scale pools (a latent row
+    has no K half and V half to scale apart)."""
     if q.ndim != 4 or kv_pool.ndim != 4:
         return False
+    if latent:
+        return (kv_pool.shape[1] == 1 and q.shape[3] == kv_pool.shape[3]
+                and k_scale is None and v_scale is None
+                and kv_pool.dtype in (jnp.float32, jnp.bfloat16))
     h, hp = q.shape[1], kv_pool.shape[1]
     if hp > h or h % hp or kv_pool.shape[3] != 2 * q.shape[3]:
         return False
@@ -356,7 +380,7 @@ def paged_kernel_supported(q, kv_pool, k_scale=None, v_scale=None):
     return kv_pool.dtype in (jnp.float32, jnp.bfloat16)
 
 
-def _record_dispatch(kernel, reason=None, version=None):
+def _record_dispatch(kernel, reason=None, version=None, name=None):
     """Trace-time metrics: dispatch counters + the interpret-mode gauge
     land in the global registry so GenerationServer.get_stats() and the
     trace_report serving summary can prove the kernel engaged.
@@ -377,6 +401,8 @@ def _record_dispatch(kernel, reason=None, version=None):
         KERNEL_DISPATCHES += 1
         version = version or "v1"
         KERNEL_VERSIONS[version] = KERNEL_VERSIONS.get(version, 0) + 1
+        name = name or "paged_attention_" + version
+        KERNEL_NAMES[name] = KERNEL_NAMES.get(name, 0) + 1
         c = reg.counter("serving.kernel.traced",
                         _help("serving.kernel.traced"))
         c.inc()                             # unlabeled aggregate
@@ -404,7 +430,31 @@ def kernel_dispatch_stats():
             "fallback_dispatches": FALLBACK_DISPATCHES,
             "fallback_reasons": dict(FALLBACK_REASONS),
             "kernel_versions": dict(KERNEL_VERSIONS),
+            "kernel_names": dict(KERNEL_NAMES),
             "mode": paged_kernel_mode()}
+
+
+def _dispatch(supported, operands, reference, kernel,
+              in_shard_map=False):
+    """The ladder every paged dispatcher walks, at trace time: the
+    reference where the operator pinned it (mode off) or the operands
+    do not qualify (force mode raises instead, except under a
+    shard_map), each a labeled fallback; else the kernel, counted with
+    its generation and name. `kernel(mode)` gives (generation, name or
+    None for "paged_attention_<generation>", the call); `operands` is
+    what the refusal says of them."""
+    mode = paged_kernel_mode()
+    if mode != "off" and supported:
+        version, name, call = kernel(mode)
+        _record_dispatch(kernel=True, version=version, name=name)
+        return call()
+    if mode == "force" and not in_shard_map:
+        raise ValueError("PADDLE_TPU_PAGED_KERNEL=1 but operands do not "
+                         "qualify " + operands)
+    _record_dispatch(kernel=False, reason=(
+        "pinned_off" if mode == "off" else
+        "unsupported_under_shard_map" if in_shard_map else "unsupported"))
+    return reference()
 
 
 def paged_attention(q, kv_pool, block_table, q_positions,
@@ -432,31 +482,71 @@ def paged_attention(q, kv_pool, block_table, q_positions,
     raising — a ValueError mid-shard_map-trace surfaces as transform
     internals, not as this dispatcher's message. Plain force-mode
     misuse still raises loudly."""
-    mode = paged_kernel_mode()
-    if mode == "off":
-        _record_dispatch(kernel=False, reason="pinned_off")
+    def reference():
         return paged_attention_reference(q, kv_pool, block_table,
                                          q_positions, k_scale, v_scale)
-    if not paged_kernel_supported(q, kv_pool, k_scale, v_scale):
-        if mode == "force" and not in_shard_map:
-            raise ValueError(
-                "PADDLE_TPU_PAGED_KERNEL=1 but operands do not qualify "
-                f"(q {q.shape} {q.dtype}, pool {kv_pool.shape} "
-                f"{kv_pool.dtype}, scales "
-                f"{'present' if k_scale is not None else 'absent'})")
-        _record_dispatch(kernel=False,
-                         reason="unsupported_under_shard_map"
-                         if in_shard_map else "unsupported")
-        return paged_attention_reference(q, kv_pool, block_table,
-                                         q_positions, k_scale, v_scale)
-    from ..ops.pallas.paged import (ragged_paged_attention,
-                                    ragged_paged_attention_v2)
-    version = _kernel_version_for(mode, kv_pool, block_table)
-    _record_dispatch(kernel=True, version=version)
-    fn = (ragged_paged_attention_v2 if version == "v2"
-          else ragged_paged_attention)
-    return fn(q, kv_pool, block_table, q_positions,
-              k_scale=k_scale, v_scale=v_scale)
+
+    def kernel(mode):
+        from ..ops.pallas.paged import (ragged_paged_attention,
+                                        ragged_paged_attention_v2)
+        version = _kernel_version_for(mode, kv_pool, block_table)
+        fn = (ragged_paged_attention_v2 if version == "v2"
+              else ragged_paged_attention)
+        return version, None, lambda: fn(
+            q, kv_pool, block_table, q_positions, k_scale=k_scale,
+            v_scale=v_scale)
+
+    return _dispatch(
+        paged_kernel_supported(q, kv_pool, k_scale, v_scale),
+        f"(q {q.shape} {q.dtype}, pool {kv_pool.shape} {kv_pool.dtype}, "
+        f"scales {'present' if k_scale is not None else 'absent'})",
+        reference, kernel, in_shard_map)
+
+
+def paged_latent_attention_reference(q, kv_pool, block_table,
+                                     q_positions, *, value_width, scale):
+    """Pure-JAX latent paged attention, the semantic spec of
+    `ops/pallas/paged.paged_latent_attention`: gather the lanes' rows
+    by table, score every head's absorbed query against the whole row,
+    mask keys past each query's position, softmax in f32, and sum the
+    rows' first `value_width` lanes.
+
+    q (B, C, H, W); kv_pool (N, 1, bs, W); block_table (B, M);
+    q_positions (B, C) -> (B, C, H, value_width) in the pool's dtype."""
+    g = gather_block_kv(kv_pool, block_table)[:, 0]         # (B, T, W)
+    s = jnp.einsum("bchw,btw->bcht", q.astype(jnp.float32),
+                   g.astype(jnp.float32)) * scale
+    key_pos = jnp.arange(g.shape[1])
+    mask = key_pos[None, None, None, :] <= q_positions[:, :, None, None]
+    p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
+    return jnp.einsum("bcht,btv->bchv", p.astype(g.dtype),
+                      g[..., :value_width])
+
+
+def paged_latent_attention(q, kv_pool, block_table, q_positions, *,
+                           value_width, scale):
+    """`paged_attention`'s sibling for a latent pool (N, 1, bs, W): the
+    same modes, the same labeled fallbacks, the same dispatch counters.
+    The latent walk streams its groups through an online softmax, so it
+    counts as kernel generation "v2" (VMEM independent of the table
+    width, allclose and not bitwise its reference) under a name of its
+    own, "paged_latent_attention" (`KERNEL_NAMES`,
+    `get_stats()["kernel"]["name"]`)."""
+    def reference():
+        return paged_latent_attention_reference(
+            q, kv_pool, block_table, q_positions,
+            value_width=value_width, scale=scale)
+
+    def kernel(mode):
+        from ..ops.pallas.paged import paged_latent_attention as walk
+        return "v2", "paged_latent_attention", lambda: walk(
+            q, kv_pool, block_table, q_positions,
+            value_width=value_width, scale=scale)
+
+    return _dispatch(
+        paged_kernel_supported(q, kv_pool, latent=True),
+        f"for the latent walk (q {q.shape} {q.dtype}, pool "
+        f"{kv_pool.shape} {kv_pool.dtype})", reference, kernel)
 
 
 def _plan_block_writes(block_idx, offset, block_size):
@@ -574,17 +664,18 @@ class HostKVTier:
             raise ValueError("host tier needs >= 1 block")
         self.num_blocks = int(num_blocks)
         self.block_size = cache.block_size
-        shape = (self.num_blocks, cache.num_kv_heads, cache.block_size,
-                 2 * cache.head_dim)
+        # the device pools' own block shapes, layer by layer
+        shapes = [(self.num_blocks,) + tuple(shp[1:])
+                  for shp in cache.layer_shapes]
         # np.dtype() resolves bf16 via the ml_dtypes registration jax
         # itself installs, so the host rows store the device bytes 1:1
         dt = np.dtype(cache.dtype)
         self._itemsize = dt.itemsize
         self._quantized = cache.quantized
-        self._layer_elems = int(np.prod(shape))
-        self._scale_elems = int(np.prod(shape[:3]))
+        self._elems = sum(int(np.prod(shp)) for shp in shapes)
+        self._scale_elems = sum(int(np.prod(shp[:3])) for shp in shapes)
         self.pools = []
-        for _ in range(cache.num_layers):
+        for shape in shapes:
             layer = {"kv": np.zeros(shape, dt)}
             if cache.quantized:
                 # scale 1.0 like the device pools: an unwritten row
@@ -627,10 +718,8 @@ class HostKVTier:
         """Host-RAM bytes of every block pool (k+v across layers,
         including the f32 scale pools when quantized) — the host half
         of the ledger's device/host split."""
-        n = len(self.pools)
-        per = self._layer_elems * self._itemsize
         scales = 2 * self._scale_elems * 4 if self._quantized else 0
-        return n * (per + scales)
+        return self._elems * self._itemsize + scales
 
 
 # ---------------------------------------------------------------------------
@@ -670,11 +759,18 @@ class PagedKVCache:
     - "int8": int8 pools + per-block-row per-head f32 scale pools
       ("k_scale"/"v_scale" beside "kv" in every layer dict, shape
       (num_blocks, H, block_size), head-sharded the same way). Reads
-      dequantize to `dtype`; `pool_bytes()` counts codes AND scales."""
+      dequantize to `dtype`; `pool_bytes()` counts codes AND scales.
+
+    `geometry` (ISSUE 34) gives each layer's block as (rows, width) of
+    (rows, block_size, width) where it is not (H_kv, 2 * head_dim): a
+    latent layer's (1, W), one row a token. The allocator, the tables,
+    the refcounts, COW, the wire and the host tier address whole blocks
+    by id and never look inside one, so they are the same code for
+    every geometry; every byte count sums the layers' own shapes."""
 
     def __init__(self, num_layers, num_heads, head_dim, num_blocks,
                  block_size=16, dtype=jnp.float32, mesh=None, axis="tp",
-                 kv_dtype=None, num_kv_heads=None):
+                 kv_dtype=None, num_kv_heads=None, geometry=None):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is reserved NULL)")
         if kv_dtype not in (None, "bf16", "int8"):
@@ -731,19 +827,37 @@ class PagedKVCache:
                 f"not the {self.num_heads} query heads)")
         # K and V of a token side by side in the minor dim (fuse_kv):
         # at head_dim 64 that is the 128 lanes, and the device keeps
-        # the pool row-major, as the kernels read it
-        shape = (self.num_blocks, self.num_kv_heads, self.block_size,
-                 2 * self.head_dim)
-        sshape = shape[:3]          # the (N, H, bs) scale pools
+        # the pool row-major, as the kernels read it. `geometry` says
+        # otherwise, layer by layer: (rows, width) of a block's
+        # (rows, block_size, width), (1, W) for a latent layer
+        if geometry is None:
+            geometry = [(self.num_kv_heads, 2 * self.head_dim)
+                        ] * self.num_layers
+        self.geometry = [(int(r), int(w)) for r, w in geometry]
+        if len(self.geometry) != self.num_layers:
+            raise ValueError(
+                f"geometry names {len(self.geometry)} layers, the cache "
+                f"has {self.num_layers}")
+        self.latent = any(g != (self.num_kv_heads, 2 * self.head_dim)
+                          for g in self.geometry)
+        if self.latent and (self.quantized or mesh is not None):
+            raise NotImplementedError(
+                "a pool whose block is not (H_kv, bs, 2 * head_dim) "
+                "(a latent layer's one row a token) is served dense "
+                "and on one device: int8 scales are per K row and per "
+                "V row, and the mesh shards the head axis such a pool "
+                "does not have (ROADMAP Reach, R4)")
+        self.layer_shapes = [(self.num_blocks, r, self.block_size, w)
+                             for r, w in self.geometry]
         if mesh is None:
-            def make(shp=shape, dt=dtype):
+            def make(shp, dt=dtype):
                 return jnp.zeros(shp, dt)
         else:
             from jax.sharding import NamedSharding, PartitionSpec as P
             ns = NamedSharding(mesh, P(None, axis, None, None))
             ns3 = NamedSharding(mesh, P(None, axis, None))
 
-            def make(shp=shape, dt=dtype):
+            def make(shp, dt=dtype):
                 # device= allocates each (N, H/tp, bs, 2*D) shard in
                 # place — a zeros-then-device_put would materialize the
                 # FULL pool on device 0 first, OOMing at exactly the
@@ -751,8 +865,9 @@ class PagedKVCache:
                 return jnp.zeros(shp, dt,
                                  device=ns if len(shp) == 4 else ns3)
 
-        def make_layer():
-            layer = {"kv": make()}
+        def make_layer(shape):
+            layer = {"kv": make(shape)}
+            sshape = shape[:3]      # the (N, H, bs) scale pools
             if self.quantized:
                 # scale 1.0, not 0: an unwritten row dequantizes to
                 # exact zeros either way, but a zero scale would turn a
@@ -762,7 +877,7 @@ class PagedKVCache:
                 layer["v_scale"] = make(sshape, jnp.float32) + 1.0
             return layer
 
-        self.pools = [make_layer() for _ in range(self.num_layers)]
+        self.pools = [make_layer(shp) for shp in self.layer_shapes]
         # every jitted rewriter of the pools DONATES them (the engine's
         # fused and draft steps, cow_copy, adopt_block_from,
         # deserialize_block, swap_in_block): the old arrays are dead the
@@ -808,17 +923,19 @@ class PagedKVCache:
         number, so quantized pools must report their true int8+scales
         size, never the dense equivalent — and GQA pools their true
         H_kv row count, never the H-head overcount."""
-        per = (self.num_blocks * self.num_kv_heads * self.block_size
-               * self.head_dim * np.dtype(self.dtype).itemsize)
-        return 2 * self.num_layers * per + self.scale_bytes()
+        return (self._pool_elems() * np.dtype(self.dtype).itemsize
+                + self.scale_bytes())
+
+    def _pool_elems(self):
+        return sum(int(np.prod(shp)) for shp in self.layer_shapes)
 
     def scale_bytes(self):
         """Bytes of the (N, H_kv, bs) f32 scale pools across k+v and
         every layer; 0 for dense pools."""
         if not self.quantized:
             return 0
-        return (2 * self.num_layers * self.num_blocks
-                * self.num_kv_heads * self.block_size * 4)
+        return 2 * 4 * sum(int(np.prod(shp[:3]))
+                           for shp in self.layer_shapes)
 
     def dense_pool_bytes(self, dtype=None):
         """What the SAME block count would cost unquantized in `dtype`
@@ -828,9 +945,7 @@ class PagedKVCache:
         factor: multiply by num_heads/num_kv_heads for the MHA-dense
         equivalent."""
         dt = dtype if dtype is not None else self.compute_dtype
-        per = (self.num_blocks * self.num_kv_heads * self.block_size
-               * self.head_dim * np.dtype(dt).itemsize)
-        return 2 * self.num_layers * per
+        return self._pool_elems() * np.dtype(dt).itemsize
 
     def shard_pool_bytes(self):
         """Bytes ONE device commits to the pools: pool_bytes()/tp under
@@ -1014,9 +1129,10 @@ class PagedKVCache:
         codes in the same jitted transfer."""
         src_kv = getattr(src_cache, "num_kv_heads", src_cache.num_heads)
         if (src_cache.num_layers, src_cache.num_heads, src_kv,
-                src_cache.head_dim, src_cache.block_size) != \
+                src_cache.head_dim, src_cache.block_size,
+                getattr(src_cache, "geometry", self.geometry)) != \
                 (self.num_layers, self.num_heads, self.num_kv_heads,
-                 self.head_dim, self.block_size):
+                 self.head_dim, self.block_size, self.geometry):
             raise ValueError(
                 f"adopt_block_from needs matching pool geometry; got "
                 f"src (L={src_cache.num_layers}, H={src_cache.num_heads},"
@@ -1063,13 +1179,18 @@ class PagedKVCache:
         tuple adopt_block_from checks in-process), and `layout` says
         how a block's K and V lie, so that a peer whose pools are laid
         out otherwise is refused, not misread."""
-        return {"num_layers": self.num_layers,
-                "num_heads": self.num_heads,
-                "num_kv_heads": self.num_kv_heads,
-                "head_dim": self.head_dim,
-                "block_size": self.block_size,
-                "quantized": bool(self.quantized),
-                "layout": KV_LAYOUT}
+        geo = {"num_layers": self.num_layers,
+               "num_heads": self.num_heads,
+               "num_kv_heads": self.num_kv_heads,
+               "head_dim": self.head_dim,
+               "block_size": self.block_size,
+               "quantized": bool(self.quantized),
+               "layout": KV_LAYOUT}
+        if self.latent:
+            # a block that is not (H_kv, bs, 2 * head_dim) says what it
+            # is, layer by layer: (rows, width)
+            geo["block_shapes"] = [list(g) for g in self.geometry]
+        return geo
 
     def serialize_block(self, block):
         """-> (meta, arrays) for block `block`: meta carries the
@@ -1105,10 +1226,11 @@ class PagedKVCache:
                 f"another version of the cache")
         src_geo = (g.get("num_layers"), g.get("num_heads"),
                    g.get("num_kv_heads"), g.get("head_dim"),
-                   g.get("block_size"))
+                   g.get("block_size"), g.get("block_shapes"))
         if src_geo != (self.num_layers, self.num_heads,
                        self.num_kv_heads, self.head_dim,
-                       self.block_size):
+                       self.block_size,
+                       self.wire_geometry().get("block_shapes")):
             raise ValueError(
                 f"deserialize_block needs matching pool geometry; got "
                 f"src (L={g.get('num_layers')}, H={g.get('num_heads')}, "

@@ -61,7 +61,7 @@ __all__ = ["SpecDecodeConfig", "build_draft_step"]
 class SpecDecodeConfig:
     """Engine-facing spec-decode settings: the draft model (any object
     with the GPTServingModel interface — params/cfg/num_layers/
-    num_heads/head_dim/kv_dtype), k proposals per iteration, the
+    num_heads/head_dim/kv_dtype/step_spec()), k proposals per iteration, the
     acceptance mode, and the rejection-mode RNG seed."""
 
     def __init__(self, draft_model, k=3, mode="greedy", seed=0):
@@ -81,9 +81,7 @@ def build_draft_step(model, block_size, k):
     the plan feed + k-1 rollout micro-steps, all inside one jit so the
     server lifetime holds exactly one draft signature."""
     from .engine import _fused_step_body
-    params, cfg = model.params, model.cfg
-    h_, d = model.num_heads, model.head_dim
-    kv_ = getattr(model, "num_kv_heads", model.num_heads)
+    params, spec = model.params, model.step_spec()
 
     def _ident(z):
         return z
@@ -95,8 +93,8 @@ def build_draft_step(model, block_size, k):
         # needs — no per-column projection here) is each decode lane's
         # first proposal d_1
         pools, cur, cur_lp = _fused_step_body(
-            params, cfg, block_size, h_, kv_, d, _ident,
-            pools, tokens, positions, valid, tables)
+            params, spec, block_size, _ident,
+            pools, tokens, positions, valid, tables)[:3]
         s, c = tokens.shape
         last = jnp.clip(valid.sum(1) - 1, 0, c - 1)
         base = jnp.take_along_axis(positions, last[:, None], 1)[:, 0] + 1
@@ -108,9 +106,9 @@ def build_draft_step(model, block_size, k):
             pos_i = base + i - 1
             v_i = (spec_go & (pos_i < limits))[:, None]
             pools, cur, cur_lp = _fused_step_body(
-                params, cfg, block_size, h_, kv_, d, _ident,
+                params, spec, block_size, _ident,
                 pools, cur[:, None], pos_i[:, None].astype(jnp.int32),
-                v_i, tables)
+                v_i, tables)[:3]
             props.append(cur)
             plps.append(cur_lp)
         return pools, jnp.stack(props, 1), jnp.stack(plps, 1)

@@ -155,11 +155,13 @@ def test_gqa_engages_kernel_v2(tiny_gpt, monkeypatch):
     srv1 = _server(GPTServingModel(gqa_params, _gqa_cfg(cfg, kv)))
     ids_v1, st_v1 = _staggered_stream(srv1)
     assert st_v1["kernel"]["version"] == "v1"
+    assert st_v1["kernel"]["name"] == "paged_attention_v1"
     monkeypatch.setenv("PADDLE_TPU_PAGED_V2_AUTO_BYTES", "1")
     srv2 = _server(GPTServingModel(gqa_params, _gqa_cfg(cfg, kv)))
     ids_v2, st_v2 = _staggered_stream(srv2)
     assert st_v2["kernel"]["engaged"] is True
     assert st_v2["kernel"]["version"] == "v2"
+    assert st_v2["kernel"]["name"] == "paged_attention_v2"
     assert st_v2["kernel"]["fallback_dispatches"] == 0
     assert ids_v2 == ids_v1
 

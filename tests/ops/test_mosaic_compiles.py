@@ -242,3 +242,32 @@ def test_kv_write_and_walk_leave_a_fused_pool_where_it_lies(
     # it (an entry layout the device chose, not one this code asked for)
     assert re.search(
         rf"\[{n},{h // tp},{bs},{2 * d}\]\{{3,2,1,0", header), header
+
+
+def test_latent_walk_compiles_at_the_cells_geometry(v5e):
+    """joyai-llm-flash-ep16: 16 lanes x 16-token chunks, 32 heads over
+    one 640-wide row a token (512 + 64, padded), 16-token blocks, a
+    256-block table: a (512, 640) query tile against 128 keys a step."""
+    s, c, h, w, bs, m = 16, 16, 32, 640, 16, 256
+    n = 1 + s * m
+    calls = _compile(
+        v5e, lambda q, pool, tbl, pos: paged.paged_latent_attention(
+            q, pool, tbl, pos, value_width=512, scale=192 ** -0.5,
+            interpret=False),
+        ((s, c, h, w), jnp.bfloat16), ((n, 1, bs, w), jnp.bfloat16),
+        ((s, m), jnp.int32), ((s, c), jnp.int32))
+    assert len(calls) == 1 and "paged_latent_attention" in calls[0]
+
+
+def test_held_experts_kernel_compiles_at_the_cells_geometry(v5e):
+    """16 held experts of 2048 x 768 (gate|up side by side), the 256
+    columns of a 16 x 16 step."""
+    from paddle_tpu.ops.pallas import moe
+    t, hid, inner, e = 256, 2048, 768, 16
+    calls = _compile(
+        v5e, lambda x, sel, comb, gu, down: moe.moe_experts(
+            x, sel, comb, gu, down, interpret=False),
+        ((t, hid), jnp.bfloat16), ((t, e), jnp.bool_),
+        ((t, e), jnp.float32), ((e, hid, 2 * inner), jnp.bfloat16),
+        ((e, inner, hid), jnp.bfloat16))
+    assert len(calls) == 1 and "moe_experts" in calls[0]
