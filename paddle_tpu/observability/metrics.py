@@ -142,6 +142,10 @@ METRIC_SPECS = [
      "requests cancelled because their deadline passed"),
     ("serving.iterations", "counter",
      "scheduler iterations (one fused prefill/decode step each)"),
+    ("serving.sampled_iterations", "counter",
+     "iterations in which at least one lane drew its token: the fused "
+     "step took its sampled branch (over serving.iterations, the share "
+     "of steps that paid for sampling; an all-greedy step skips it)"),
     ("serving.step_ms", "histogram",
      "wall ms of one serving iteration from the step's feed to the end "
      "of commit (plan() is not in it)"),

@@ -83,6 +83,39 @@ def _sample_rows(base, rng, temperature, do_top_k, top_p):
     return samp.astype(jnp.int32), samp_lp
 
 
+def _sampled_where_asked(logp, nxt, chosen, rng, temperature, do_sample,
+                         top_k, top_p):
+    """The step's (ids, their logp) from the greedy `(nxt, chosen)`: a
+    lane whose `do_sample` is set takes `_sample_rows`' draw over its
+    `logp` row instead. The draw (two sorts of the whole vocabulary, a
+    nucleus sum, the noise) runs under ONE device-side branch on
+    `do_sample.any()`, so a step whose lanes are all greedy pays for
+    none of it; the predicate is data the step already takes, so there
+    is still one executable. Over per-column `(S, C, ...)` inputs the
+    draw is column 0's. Everything a branch reads is an operand."""
+    per_column = logp.ndim == 3
+
+    def sampled(logp, nxt, chosen, rng, temperature, do_sample, top_k,
+                top_p):
+        samp, samp_lp = _sample_rows(logp[:, 0] if per_column else logp,
+                                     rng, temperature, top_k, top_p)
+        if per_column:
+            nxt = nxt.at[:, 0].set(jnp.where(do_sample, samp, nxt[:, 0]))
+            chosen = chosen.at[:, 0].set(
+                jnp.where(do_sample, samp_lp, chosen[:, 0]))
+        else:
+            nxt = jnp.where(do_sample, samp, nxt)
+            chosen = jnp.where(do_sample, samp_lp, chosen)
+        return nxt, chosen
+
+    def greedy(logp, nxt, chosen, *controls):
+        return nxt, chosen
+
+    return jax.lax.cond(jnp.any(do_sample), sampled, greedy, logp,
+                        nxt.astype(jnp.int32), chosen, rng, temperature,
+                        do_sample, top_k, top_p)
+
+
 def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
                      positions, valid, tables, per_column=False,
                      sampling=False, mask=None, rng=None,
@@ -211,10 +244,8 @@ def _step_tail(x, head, tokens, valid, new_pools, per_column, sampling,
         chosen = jnp.take_along_axis(logp, nxt[:, None], -1)[:, 0]
         if not sampling:
             return new_pools, nxt.astype(jnp.int32), chosen
-        samp, samp_lp = _sample_rows(logp, rng, temperature,
-                                     top_k, top_p)
-        nxt = jnp.where(do_sample, samp, nxt).astype(jnp.int32)
-        chosen = jnp.where(do_sample, samp_lp, chosen)
+        nxt, chosen = _sampled_where_asked(
+            logp, nxt, chosen, rng, temperature, do_sample, top_k, top_p)
         # 4th output: the full logp rows — fork-time host sampling and
         # beam re-ranking read these (the host transfer is paid only
         # when the plan says a group needs them)
@@ -236,12 +267,9 @@ def _step_tail(x, head, tokens, valid, new_pools, per_column, sampling,
         return new_pools, nxt.astype(jnp.int32), chosen, fed
     # sampled lanes run 1-column (the scheduler plans no drafts for
     # them), so the stochastic draw applies to column 0 only
-    samp, samp_lp = _sample_rows(logp[:, 0], rng, temperature,
-                                 top_k, top_p)
-    nxt = nxt.at[:, 0].set(jnp.where(do_sample, samp, nxt[:, 0]))
-    chosen = chosen.at[:, 0].set(
-        jnp.where(do_sample, samp_lp, chosen[:, 0]))
-    return new_pools, nxt.astype(jnp.int32), chosen, fed, logp
+    nxt, chosen = _sampled_where_asked(
+        logp, nxt, chosen, rng, temperature, do_sample, top_k, top_p)
+    return new_pools, nxt, chosen, fed, logp
 
 
 def single_device_step(params, spec, block_size, per_column=False,
@@ -871,6 +899,9 @@ class GenerationServer:
                                     _help("serving.requests")),
             "iterations": reg.counter("serving.iterations",
                                       _help("serving.iterations")),
+            "sampled_iterations": reg.counter(
+                "serving.sampled_iterations",
+                _help("serving.sampled_iterations")),
             "step_ms": reg.histogram("serving.step_ms",
                                      _help("serving.step_ms")),
             "valid_columns": reg.counter(
@@ -1220,6 +1251,10 @@ class GenerationServer:
                                              rows=rows)
             with rec.span("serving.account", cat="serving", args=leaf):
                 self._m["iterations"].inc()
+                if plan.sample_ctl[0].any():
+                    # the step's own predicate: it took its sampled
+                    # branch (`_sampled_where_asked`)
+                    self._m["sampled_iterations"].inc()
                 self._m["valid_columns"].inc(plan.valid_columns)
                 self._m["padded_columns"].inc(plan.padded_columns)
                 step_ms = (time.perf_counter() - t0) * 1e3
@@ -1253,7 +1288,9 @@ class GenerationServer:
         capture is live: what the fused step was handed. `lanes_qc` is
         each lane's (queries, context) — columns fed this iteration, and
         the tokens its attention reads once they are written.
-        `walk_groups_live` over `walk_groups` is the share of the block
+        `sampled_lanes` counts the lanes that draw their token: with 0
+        the step skips its sampled tail. `walk_groups_live` over
+        `walk_groups` is the share of the block
         table the paged kernel touches: it walks a lane's table in
         groups of `walk_group` columns and stops after the last one
         that holds a token (the latent walk the same). A model whose
@@ -1271,6 +1308,7 @@ class GenerationServer:
                   "prefill_tokens": plan.prefill_tokens,
                   "valid_columns": plan.valid_columns,
                   "padded_columns": plan.padded_columns,
+                  "sampled_lanes": int(plan.sample_ctl[0].sum()),
                   "lanes_qc": lanes_qc,
                   "walk_groups_live": sum(-(-ctx // keys)
                                           for _q, ctx in lanes_qc),

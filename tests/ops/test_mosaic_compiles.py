@@ -271,3 +271,88 @@ def test_held_experts_kernel_compiles_at_the_cells_geometry(v5e):
         ((t, e), jnp.float32), ((e, hid, 2 * inner), jnp.bfloat16),
         ((e, inner, hid), jnp.bfloat16))
     assert len(calls) == 1 and "moe_experts" in calls[0]
+
+
+_BRANCH_ATTRS = ("branch_computations", "true_computation",
+                 "false_computation")
+
+
+def _hlo_computations(text):
+    """name -> (its instruction lines, {callee: the attribute it is
+    called through}) for every computation of an HLO module's text."""
+    import re
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", ln)
+        if head and not ln.startswith(" "):
+            name = "ENTRY" if ln.startswith("ENTRY") else head.group(1)
+            comps[name] = ([], {})
+        elif name is not None and ln.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name][0].append(ln)
+            for attr, group in re.findall(
+                    r"(\w+)=(\{[^}]*\}|%?[\w.\-]+)", ln):
+                if attr in ("calls", "to_apply", "body", "condition",
+                            *_BRANCH_ATTRS):
+                    for callee in re.findall(r"%?([\w.\-]+)", group):
+                        comps[name][1][callee] = attr
+    return comps
+
+
+@pytest.mark.parametrize("per_column", [False, True],
+                         ids=["last_column", "per_column"])
+def test_the_sampling_tails_sorts_sit_in_a_conditional(v5e, per_column):
+    """The fused step's tail at the GPT-2 XL cell's geometry (16 lanes
+    x 16 columns, 1600 wide, 50,257 ids; the speculative servers' tail
+    at 4 columns), compiled for a v5e: both sorts of the whole
+    vocabulary are inside a `conditional`'s branch, none is reachable
+    from the entry computation without passing through it, so a step
+    whose lanes are all greedy does not run them (PERF.md section 6,
+    PR 35). A later edit that hoists the draw out of the branch, or a
+    compiler pass that does, fails here."""
+    from paddle_tpu.serving import engine
+    s, c, hid, v = 16, (4 if per_column else 16), 1600, 50257
+
+    def tail(x, head, tokens, valid, *ctl):
+        return engine._step_tail(x, head, tokens, valid, [], per_column,
+                                 True, *ctl)[1:]
+
+    shapes = (((s, c, hid), jnp.bfloat16), ((hid, v), jnp.bfloat16),
+              ((s, c), jnp.int32), ((s, c), jnp.bool_),
+              ((s, c, v) if per_column else (s, v), jnp.float32),
+              ((s, 2), jnp.uint32), ((s,), jnp.float32), ((s,), jnp.bool_),
+              ((s,), jnp.int32), ((s,), jnp.float32))
+    args = [jax.ShapeDtypeStruct(sh, d, sharding=SingleDeviceSharding(v5e))
+            for sh, d in shapes]
+    text = jax.jit(tail).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    comps = _hlo_computations(text)
+    assert "ENTRY" in comps
+
+    def reach(roots, through_branches):
+        seen, todo = set(), list(roots)
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in comps:
+                continue
+            seen.add(name)
+            todo += [callee for callee, attr in comps[name][1].items()
+                     if through_branches or attr not in _BRANCH_ATTRS]
+        return seen
+
+    def sorts(names):
+        return [ln for n in names for ln in comps[n][0]
+                if " sort(" in ln]
+
+    every_step = reach(["ENTRY"], through_branches=False)
+    branches = [callee for n in every_step
+                for callee, attr in comps[n][1].items()
+                if attr in _BRANCH_ATTRS]
+    assert len(branches) == 2, branches     # one conditional, two ways
+    assert sorts(every_step) == []
+    guarded = [sorts(reach([b], through_branches=True)) for b in branches]
+    # the sampled branch holds both sorts of f32[16,50257]; the greedy
+    # one none
+    assert sorted(len(g) for g in guarded) == [0, 2], guarded
+    assert all(f"[{s},{v}]" in ln for g in guarded for ln in g)
