@@ -250,6 +250,19 @@ METRIC_SPECS = [
      "chip holds (serving/moe.py expert_share): the ones it computed; "
      "the rest belong to the other chips of the deployment and are "
      "left out"),
+    ("serving.state.resets", "counter",
+     "lanes that began a request in a fused step of a model with state "
+     "layers (position 0 in the lane's first valid column): each "
+     "starts from a zero state and zero convolution rows inside the "
+     "step. Over a run it equals the requests admitted"),
+    ("serving.state.columns", "counter",
+     "valid columns x state layers fed through the chunked delta rule "
+     "(ops/pallas/linear.kda_chunk): each moved one lane's state by a "
+     "token"),
+    ("serving.state.bytes", "gauge",
+     "bytes the state layers hold over all lanes (float32 states and "
+     "the short convolutions' carried rows), labeled by server; part "
+     "of the kv_pool row of the HBM ledger"),
     ("serving.kv.quant.pool_bytes", "gauge",
      "TRUE footprint of a quantized KV block pool: int8 codes plus the "
      "f32 per-row scale pools, across k+v and every layer (label: "

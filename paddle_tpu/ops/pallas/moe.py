@@ -16,7 +16,11 @@ a tile of `tile` of an expert's tokens a grid step:
 * a step's expert arrives through BlockSpecs (gate|up and down, 9.4 MB
   at 2048 x 768 in bf16), so the pipeline fetches the next expert's
   weights while this one computes, and a second tile of the same
-  expert moves nothing;
+  expert moves nothing. An expert whose two buffers would pass
+  `WHOLE_EXPERT_VMEM_BYTES` (31.5 MB at 4096 x 1280: 63 MB of the
+  chip's 128) arrives a slice of its INNER width at a time instead, on
+  a second grid axis (`_inner_blocks`): the gate and up columns and
+  the down rows of the slice, whose partial products add up;
 * the tile's tokens are gathered from the VMEM-resident activations by
   a one-hot product (rank == row), which is exact, and their weighted
   results are scattered back into a float32 (T, hidden) accumulator the
@@ -39,6 +43,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .paged import _interpret, _mxu_precision, _padded_bytes
 
 TRACE_COUNT = 0
+# what an expert's double-buffered weights may take of VMEM before the
+# kernel takes its inner width in slices
+WHOLE_EXPERT_VMEM_BYTES = 32 << 20
 
 
 def _plan_groups(sel, tile):
@@ -63,6 +70,45 @@ def _plan_groups(sel, tile):
             tile_of.astype(jnp.int32), rank.T[:, None, :])
 
 
+def _inner_blocks(hidden, inner, dtype):
+    """How many slices an expert's inner width is taken in: 1 (the
+    whole expert a grid step) while its two buffers fit
+    `WHOLE_EXPERT_VMEM_BYTES`, else the fewest whole-lane-tile slices
+    that do."""
+    def buffers(n):
+        return 2 * (_padded_bytes((hidden, 2 * inner // n), dtype)
+                    + _padded_bytes((inner // n, hidden), dtype))
+    for n in range(1, inner // 128 + 1):
+        if inner % (n * 128) == 0 and \
+                buffers(n) <= WHOLE_EXPERT_VMEM_BYTES:
+            return n
+    return 1
+
+
+def _tile_rows(tile_ref, x_ref, rank_ref, rank_c_ref, comb_ref, tile):
+    """What a grid step computes of its tile whatever the weights'
+    blocking: (the tile's tokens gathered (tile, H), each row's combine
+    weight (tile, 1) f32, the (T, tile) one-hot that scatters the rows
+    back)."""
+    s = pl.program_id(0)
+    x = x_ref[...]
+    t = x.shape[0]
+    base = tile_ref[s] * tile
+    # (tile, T): row r takes the token whose rank is base + r
+    want = base + jax.lax.broadcasted_iota(jnp.int32, (tile, t), 0)
+    pick = rank_ref[0] == want
+    gather = jnp.where(pick, 1.0, 0.0).astype(x.dtype)
+    xg = jnp.dot(gather, x, precision=_mxu_precision(x.dtype),
+                 preferred_element_type=jnp.float32).astype(x.dtype)
+    # each row's combine weight, in f32: the one nonzero of its row
+    w_row = jnp.sum(jnp.where(pick, comb_ref[0], 0.0), axis=1,
+                    keepdims=True)
+    # (T, tile): token t receives row rank[t] - base
+    col = base + jax.lax.broadcasted_iota(jnp.int32, (t, tile), 1)
+    scatter = jnp.where(rank_c_ref[0] == col, 1.0, 0.0).astype(x.dtype)
+    return xg, w_row, scatter
+
+
 def _moe_kernel(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
                 comb_ref, gu_ref, down_ref, o_ref, *, tile, inner):
     """Grid step s: expert e = expert[s], the tile_of[s]-th tile of its
@@ -71,34 +117,48 @@ def _moe_kernel(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
     along lanes and along sublanes, comb_ref (1, 1, T) its combine
     weights; gu_ref (1, H, 2 * inner), down_ref (1, inner, H)."""
     del expert_ref                          # read by the index maps
-    s = pl.program_id(0)
-    x = x_ref[...]
-    t = x.shape[0]
-    prec = _mxu_precision(x.dtype)
+    dt = x_ref.dtype
+    prec = _mxu_precision(dt)
 
-    @pl.when(s == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    base = tile_ref[s] * tile
-    # (tile, T): row r takes the token whose rank is base + r
-    want = base + jax.lax.broadcasted_iota(jnp.int32, (tile, t), 0)
-    pick = rank_ref[0] == want
-    gather = jnp.where(pick, 1.0, 0.0).astype(x.dtype)
-    xg = jnp.dot(gather, x, precision=prec,
-                 preferred_element_type=jnp.float32).astype(x.dtype)
+    xg, w_row, scatter = _tile_rows(tile_ref, x_ref, rank_ref,
+                                    rank_c_ref, comb_ref, tile)
     gu = jnp.dot(xg, gu_ref[0], precision=prec,
                  preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gu[:, :inner]) * gu[:, inner:]).astype(x.dtype)
+    act = (jax.nn.silu(gu[:, :inner]) * gu[:, inner:]).astype(dt)
     y = jnp.dot(act, down_ref[0], precision=prec,
                 preferred_element_type=jnp.float32)        # (tile, H)
-    # each row's combine weight, in f32: the one nonzero of its row
-    w_row = jnp.sum(jnp.where(pick, comb_ref[0], 0.0), axis=1,
-                    keepdims=True)
-    # (T, tile): token t receives row rank[t] - base
-    col = base + jax.lax.broadcasted_iota(jnp.int32, (t, tile), 1)
-    scatter = jnp.where(rank_c_ref[0] == col, 1.0, 0.0).astype(x.dtype)
-    o_ref[...] += jnp.dot(scatter, (y * w_row).astype(x.dtype),
+    o_ref[...] += jnp.dot(scatter, (y * w_row).astype(dt),
+                          precision=prec,
+                          preferred_element_type=jnp.float32)
+
+
+def _moe_kernel_sliced(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
+                       comb_ref, gate_ref, up_ref, down_ref, o_ref, *,
+                       tile):
+    """Grid step (s, j): as `_moe_kernel`, over slice j of the expert's
+    inner width: gate_ref and up_ref (1, H, inner / n), down_ref
+    (1, inner / n, H). The slices' products add up in o_ref."""
+    del expert_ref
+    dt = x_ref.dtype
+    prec = _mxu_precision(dt)
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    xg, w_row, scatter = _tile_rows(tile_ref, x_ref, rank_ref,
+                                    rank_c_ref, comb_ref, tile)
+    gate = jnp.dot(xg, gate_ref[0], precision=prec,
+                   preferred_element_type=jnp.float32)
+    up = jnp.dot(xg, up_ref[0], precision=prec,
+                 preferred_element_type=jnp.float32)
+    y = jnp.dot((jax.nn.silu(gate) * up).astype(dt), down_ref[0],
+                precision=prec, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(scatter, (y * w_row).astype(dt),
                           precision=prec,
                           preferred_element_type=jnp.float32)
 
@@ -111,38 +171,56 @@ def _moe_call(x, sel, comb, w_gu, w_down, *, tile, interpret):
     rank_c = jnp.swapaxes(rank, 1, 2)                       # (E, T, 1)
     comb_t = comb.astype(jnp.float32).T[:, None, :]         # (E, 1, T)
 
+    inner = inner2 // 2
+    n = _inner_blocks(h, inner, w_gu.dtype)
+    width = inner // n
+
     def whole(shape):
-        return pl.BlockSpec(shape, lambda s, ex, tl: (0,) * len(shape))
+        return pl.BlockSpec(shape, lambda s, *_: (0,) * len(shape))
 
     def of_expert(shape):
         return pl.BlockSpec((1,) + shape[1:],
-                            lambda s, ex, tl: (ex[s],) + (0,) * (
+                            lambda s, *a: (a[-2][s],) + (0,) * (
                                 len(shape) - 1))
 
+    rows = [whole((t, h)), of_expert(rank.shape), of_expert(rank_c.shape),
+            of_expert(comb_t.shape)]
+    if n == 1:
+        grid, kernel = (steps,), functools.partial(
+            _moe_kernel, tile=tile, inner=inner)
+        weights = [of_expert(w_gu.shape), of_expert(w_down.shape)]
+        operands = (w_gu, w_down)
+    else:
+        # slice j of the inner width: gate columns [j], up columns
+        # [n + j] of the side-by-side array, down rows [j]
+        grid, kernel = (steps, n), functools.partial(
+            _moe_kernel_sliced, tile=tile)
+        weights = [
+            pl.BlockSpec((1, h, width), lambda s, j, ex, tl: (ex[s], 0, j)),
+            pl.BlockSpec((1, h, width),
+                         lambda s, j, ex, tl: (ex[s], 0, n + j)),
+            pl.BlockSpec((1, width, h), lambda s, j, ex, tl: (ex[s], j, 0))]
+        operands = (w_gu, w_gu, w_down)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # expert, tile_of
-        grid=(steps,),
-        in_specs=[whole((t, h)), of_expert(rank.shape),
-                  of_expert(rank_c.shape), of_expert(comb_t.shape),
-                  of_expert(w_gu.shape), of_expert(w_down.shape)],
-        out_specs=whole((t, h)),
+        grid=grid, in_specs=rows + weights, out_specs=whole((t, h)),
     )
-    vmem = (2 * (_padded_bytes(w_gu.shape[1:], w_gu.dtype)
-                 + _padded_bytes(w_down.shape[1:], w_down.dtype))
+    vmem = (2 * (_padded_bytes((h, 2 * width), w_gu.dtype)
+                 + _padded_bytes((width, h), w_down.dtype))
             + 2 * _padded_bytes((t, h), x.dtype)
             + 2 * _padded_bytes((t, h), jnp.float32)
-            + 4 * _padded_bytes((tile, inner2), jnp.float32)
+            + 4 * _padded_bytes((tile, 2 * width), jnp.float32)
             + 4 * _padded_bytes((t, 1), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_moe_kernel, tile=tile, inner=inner2 // 2),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=int(min(vmem + (16 << 20), 100 << 20))),
         name="moe_experts",
         interpret=interpret,
-    )(expert, tile_of, x, rank, rank_c, comb_t, w_gu, w_down)
+    )(expert, tile_of, x, rank, rank_c, comb_t, *operands)
 
 
 def moe_experts(x, sel, comb, w_gu, w_down, tile=None, interpret=None):

@@ -91,6 +91,7 @@ from .guided import (ChoiceConstraint, Constraint, JsonConstraint,
                      RegexConstraint)
 from .engine import GenerationFuture, GenerationServer, GPTServingModel
 from .latent_moe import LatentMoEServingModel
+from .linear_moe import LinearMoEServingModel
 from .spec_decode import SpecDecodeConfig
 from .replica import Replica
 from .router import (AdmissionPolicy, AdmissionRejected, FleetFuture,
@@ -110,7 +111,7 @@ __all__ = [
     "ContinuousBatchingScheduler", "GenerationResult",
     "DeadlineExceeded", "RequestCancelled",
     "GenerationServer", "GenerationFuture", "GPTServingModel",
-    "LatentMoEServingModel",
+    "LatentMoEServingModel", "LinearMoEServingModel",
     "Replica", "FleetRouter", "FleetFuture", "RouterPolicy",
     "AdmissionPolicy", "AdmissionRejected",
     "WorkerProxy", "make_subprocess_spawn", "spawn_worker",

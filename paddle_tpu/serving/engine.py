@@ -41,8 +41,9 @@ from ..observability import _help
 from ..observability.metrics import global_registry
 from ..observability.tracing import get_recorder
 from . import kv_cache as _kvc
-from .blocks import (ATTENTIONS, MLPS, NORMS, LayerSpec, StepContext,
-                     StepSpec, fold_counts, rotary_angles)
+from .blocks import (ATTENTIONS, MLPS, NORMS, STATE_ATTENTIONS, LayerSpec,
+                     StepContext, StepSpec, fold_counts, rotary_angles,
+                     state_counts)
 from .decode_strategies import (GroupFuture, RequestGroup,
                                 SamplingParams, gumbel_noise)
 from .kv_cache import NEG_INF, NULL_BLOCK, PagedKVCache
@@ -162,7 +163,8 @@ def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
     beside its addend: the expert layer its routing) returns ONE more
     output, last: the step's counts, an int32 vector folded over those
     layers (`blocks.fold_counts`), which the model names
-    (`step_counters`).
+    (`step_counters`). A spec with state layers (`STATE_ATTENTIONS`)
+    appends theirs (`blocks.state_counts`) to that vector.
 
     Quantized serving (ISSUE 14) rides the same body: a layer dict
     carrying "k_scale"/"v_scale" pools takes the quantize-at-write path
@@ -190,15 +192,20 @@ def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
     angles = None
     if spec.positions == "learned":
         x = x + params["pos_emb"][pos]
-    else:
+    elif spec.positions == "rotary":
         angles = rotary_angles(pos, spec.dims["qk_rope"],
                                spec.dims["rope_theta"])
     # write targets: masked lanes route to the NULL block
     bidx = jnp.take_along_axis(tables, pos // block_size, axis=1)
     bidx = jnp.where(valid, bidx, NULL_BLOCK)
     off = jnp.where(valid, pos % block_size, 0)
+    # what a state layer reads: a lane's valid columns are a prefix, and
+    # a lane whose first one holds position 0 starts a request
+    lane_cols = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    starts = valid[:, 0] & (positions[:, 0] == 0)
     ctx = StepContext(spec, s, c, x.dtype, pos, bidx, off, valid, tables,
-                      reduce_fn, in_shard_map, w, angles)
+                      reduce_fn, in_shard_map, w, angles, lane_cols,
+                      starts)
     new_pools, counts = [], []
     for i, layer in enumerate(spec.layers):
         lp = params[f"l{i}"]
@@ -216,8 +223,12 @@ def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
     out = _step_tail(x, head, tokens, valid, new_pools, per_column,
                      sampling, mask, rng, temperature, do_sample, top_k,
                      top_p)
-    if counts:
-        out += (fold_counts(counts),)
+    state_layers = sum(layer.attention in STATE_ATTENTIONS
+                       for layer in spec.layers)
+    vectors = ([fold_counts(counts)] if counts else []) + (
+        [state_counts(ctx, state_layers)] if state_layers else [])
+    if vectors:
+        out += (jnp.concatenate(vectors),)
     return out
 
 
@@ -570,6 +581,32 @@ class GenerationServer:
                 f"pools shard on the KV head axis (with GQA that is "
                 f"H_kv={kv_heads}, not the {model.num_heads} query "
                 f"heads)")
+        # a model with a state layer (a recurrent state a lane beside
+        # the paged cache): everything that copies, shares or moves
+        # cache by block cannot carry the state yet
+        geometry = getattr(model, "kv_geometry", None)
+        self._state_model = any(isinstance(g, dict)
+                                for g in geometry or ())
+        if self._state_model:
+            for asked, what in (
+                    (prefix_cache, "prefix_cache (reuse of a prefix's "
+                     "blocks needs a snapshot of the state at a block "
+                     "boundary)"),
+                    (host_kv_blocks, "the host tier and preemption "
+                     "(host_kv_blocks: a parked request's state is not "
+                     "spilled)"),
+                    (spec is not None, "speculative decoding "
+                     "(SpecDecodeConfig: a rejected draft cannot be "
+                     "rolled back out of a state)"),
+                    (mesh is not None, "a mesh (mesh=: the state and "
+                     "the expert exchange are not sharded)"),
+                    (kv_dtype == "int8", "int8 pools (kv_dtype='int8': "
+                     "the state is float32 by design)")):
+                if asked:
+                    raise NotImplementedError(
+                        f"{what} is not supported for a model with a "
+                        f"state layer ({type(model).__name__}): "
+                        f"ROADMAP R5")
         max_context = int(max_context or model.max_position)
         if max_context > model.max_position:
             raise ValueError(
@@ -588,8 +625,8 @@ class GenerationServer:
                                   dtype=model.kv_dtype, mesh=mesh,
                                   axis=mesh_axis, kv_dtype=kv_dtype,
                                   num_kv_heads=kv_heads,
-                                  geometry=getattr(model, "kv_geometry",
-                                                   None))
+                                  geometry=geometry,
+                                  num_slots=num_slots)
         if chaos is not None and clock is None and \
                 getattr(chaos, "drives_clock", lambda: False)():
             clock = chaos.serving_clock
@@ -854,6 +891,15 @@ class GenerationServer:
             for name, val in self._quant_gauges.items():
                 reg0.gauge(name, _help(name)).labels(
                     server=self._ledger_id).set(val)
+        # what the state layers hold over all lanes (0 series without
+        # one): retired on close like the gauges above
+        self._state_gauge = None
+        if self._state_model:
+            self._state_gauge = global_registry().gauge(
+                "serving.state.bytes",
+                _help("serving.state.bytes")).labels(
+                    server=self._ledger_id)
+            self._state_gauge.set(self.cache.state_bytes())
         # host-tier gauges (serving.kv.tier.*): the tier's capacity
         # plus its cumulative traffic (spills/swap-ins/preempts/
         # resumes/re-prefills avoided), server-labeled and re-published
@@ -1025,6 +1071,12 @@ class GenerationServer:
             raise ValueError(
                 "guided decoding requires eos_id (constraint "
                 "completion is signalled by unmasking eos)")
+        if k > 1 and self._state_model:
+            raise NotImplementedError(
+                "fork groups (n > 1, beam) are not supported for a model "
+                "with a state layer: the lanes of a group share the "
+                "prompt's blocks, and a lane's state is not copied to "
+                "its siblings (ROADMAP R5)")
         if k > 1:
             if k > self._sched.num_slots:
                 raise ValueError(
@@ -1353,7 +1405,7 @@ class GenerationServer:
         k0 = None
         try:
             with cache.pools_lock:
-                k0 = cache.pools[0]["kv"]
+                k0 = next(iter(cache.pools[0].values()))
                 out = fn(cache.pools, *args)
                 cache.pools = out[0]
         except Exception as e:
@@ -1643,7 +1695,11 @@ class GenerationServer:
         reports reference numbers as kernel numbers."""
         traced, fell_back = self._kernel_counts
         self._kernel_engaged = traced > 0 and fell_back == 0
-        p0 = self.cache.pools[0]
+        # the first layer whose pool is blocks (a state layer's kernel
+        # qualifies on its dtypes alone, and its state is float32)
+        p0 = next((p for p in self.cache.pools if "kv" in p), None)
+        if p0 is None:
+            return
         kvp = p0["kv"]
         # the probe q uses the COMPUTE dtype (what the fused step feeds
         # the dispatcher) — an int8 pool's queries are never int8
@@ -1787,6 +1843,10 @@ class GenerationServer:
         for name in (self._tier_gauges or ()):
             reg.gauge(name).remove(server=self._ledger_id)
         self._tier_gauges = None
+        if self._state_gauge is not None:
+            reg.gauge("serving.state.bytes").remove(
+                server=self._ledger_id)
+            self._state_gauge = None
 
     def get_stats(self):
         """Scheduler + engine stats; `fused_step_signatures` is the jit
@@ -1836,7 +1896,11 @@ class GenerationServer:
             # group of: (H_kv, block_size, 2 * head_dim), K beside V,
             # or a latent layer's (1, block_size, W), one row a token
             # (a fact for whoever reads a trace, not a switch)
-            "pool_block_shape": list(self.cache.layer_shapes[0][1:]),
+            # of the first layer that has blocks (a state layer has
+            # none)
+            "pool_block_shape": next(
+                (list(shp[1:]) for shp in self.cache.layer_shapes
+                 if shp is not None), None),
         }
         # quantized-pool facts (None when dense): the TRUE int8+scales
         # footprint, the dense compute-dtype size the same blocks would

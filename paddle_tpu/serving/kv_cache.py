@@ -61,6 +61,18 @@ takes the (rows, width) of each layer's pool; `paged_latent_attention`
 is that layer's dispatcher, as `paged_attention` is a K-beside-V
 layer's, and counts into the same dispatch accounting.
 
+State layers (ISSUE 36): a layer of linear attention (the gated delta
+rule, `ops/pallas/linear.py`) has no row a token. It keeps, for each
+LANE, one float32 state a head and the last rows of its short
+convolution's input, and `geometry` says so with a dict of the arrays
+a lane holds in place of a block's (rows, width). Such a layer's entry
+of `pools` is `{"state", "conv"}` with a leading lane axis of
+`num_slots`: the same donated list, but nothing the allocator, the
+tables, the refcounts, COW, the wire or the host tier address, so every
+block rewriter here REFUSES a cache that has one (ROADMAP R5 says what
+each would need), and the byte counts add the lanes' arrays.
+`kda_chunk` is that layer's dispatcher, counted like the paged ones.
+
 `PagedDecodeLayer` adapts a layer's pool to the dense mapping
 interface `decoding.py` step_fns consume (`cache[i]["k"]`,
 `update_kv_cache`), so an existing step_fn decodes against either cache
@@ -112,6 +124,7 @@ __all__ = ["PagedKVCache", "HostKVTier", "PagedDecodeLayer",
            "paged_attention", "fuse_kv", "split_kv", "KV_LAYOUT",
            "paged_attention_reference", "gather_block_kv",
            "paged_latent_attention", "paged_latent_attention_reference",
+           "kda_chunk",
            "gather_block_kv_pair", "gather_block_scales",
            "build_paged_decode_cache", "quantize_kv_rows",
            "write_block_kv_quant",
@@ -399,15 +412,19 @@ def _record_dispatch(kernel, reason=None, version=None, name=None):
                        _help("serving.kernel.version"))
     if kernel:
         KERNEL_DISPATCHES += 1
-        version = version or "v1"
-        KERNEL_VERSIONS[version] = KERNEL_VERSIONS.get(version, 0) + 1
-        name = name or "paged_attention_" + version
-        KERNEL_NAMES[name] = KERNEL_NAMES.get(name, 0) + 1
         c = reg.counter("serving.kernel.traced",
                         _help("serving.kernel.traced"))
         c.inc()                             # unlabeled aggregate
-        c.labels(version=version).inc()     # per-generation series
-        vgauge.set(2 if version == "v2" else 1)
+        if version or not name:
+            # a walk over a block table has a generation; a kernel that
+            # walks none (`kda_chunk`) is counted by its name alone
+            version = version or "v1"
+            KERNEL_VERSIONS[version] = KERNEL_VERSIONS.get(version,
+                                                           0) + 1
+            c.labels(version=version).inc()     # per-generation series
+            vgauge.set(2 if version == "v2" else 1)
+        name = name or "paged_attention_" + version
+        KERNEL_NAMES[name] = KERNEL_NAMES.get(name, 0) + 1
         from ..ops.pallas import paged as _paged
         reg.gauge("serving.kernel.interpret",
                   _help("serving.kernel.interpret")).set(
@@ -440,9 +457,10 @@ def _dispatch(supported, operands, reference, kernel,
     reference where the operator pinned it (mode off) or the operands
     do not qualify (force mode raises instead, except under a
     shard_map), each a labeled fallback; else the kernel, counted with
-    its generation and name. `kernel(mode)` gives (generation, name or
-    None for "paged_attention_<generation>", the call); `operands` is
-    what the refusal says of them."""
+    its generation and name. `kernel(mode)` gives (generation or None
+    for a kernel that walks no table, name or None for
+    "paged_attention_<generation>", the call); `operands` is what the
+    refusal says of them."""
     mode = paged_kernel_mode()
     if mode != "off" and supported:
         version, name, call = kernel(mode)
@@ -547,6 +565,32 @@ def paged_latent_attention(q, kv_pool, block_table, q_positions, *,
         paged_kernel_supported(q, kv_pool, latent=True),
         f"for the latent walk (q {q.shape} {q.dtype}, pool "
         f"{kv_pool.shape} {kv_pool.dtype})", reference, kernel)
+
+
+def kda_chunk(q, k, v, g, beta, state, counts, reset):
+    """A state layer's dispatcher: one chunk of every lane's columns
+    against the lane's carried state (`ops/pallas/linear.kda_chunk`,
+    whose docstring has the shapes), through the same modes, labeled
+    fallbacks and dispatch counters as the paged walks. The kernel
+    walks no block table, so it has no generation: it counts under its
+    name, "kda_chunk", alone. Its reference is the same chunk in plain
+    `jax.numpy`."""
+    from ..ops.pallas import linear
+
+    def reference():
+        return linear.kda_chunk_reference(q, k, v, g, beta, state,
+                                          counts, reset)
+
+    def kernel(mode):
+        return None, "kda_chunk", lambda: linear.kda_chunk(
+            q, k, v, g, beta, state, counts, reset)
+
+    return _dispatch(
+        q.ndim == 4 and state.ndim == 4
+        and state.dtype == jnp.float32
+        and q.dtype in (jnp.float32, jnp.bfloat16),
+        f"for the chunked delta rule (q {q.shape} {q.dtype}, state "
+        f"{state.shape} {state.dtype})", reference, kernel)
 
 
 def _plan_block_writes(block_idx, offset, block_size):
@@ -766,11 +810,20 @@ class PagedKVCache:
     latent layer's (1, W), one row a token. The allocator, the tables,
     the refcounts, COW, the wire and the host tier address whole blocks
     by id and never look inside one, so they are the same code for
-    every geometry; every byte count sums the layers' own shapes."""
+    every geometry; every byte count sums the layers' own shapes.
+
+    A STATE layer (ISSUE 36) gives a dict in place of (rows, width):
+    `{name: (shape a lane, dtype or None for the serving type)}`, the
+    arrays each of the `num_slots` lanes holds for it (a delta-rule
+    layer's `{"state": ((H, dv, dk), float32), "conv": ((3, channels),
+    None)}`). Its entry of `pools` is those arrays with a leading lane
+    axis; `layer_shapes[i]` is None; the block rewriters refuse the
+    cache (`_refuse_state`)."""
 
     def __init__(self, num_layers, num_heads, head_dim, num_blocks,
                  block_size=16, dtype=jnp.float32, mesh=None, axis="tp",
-                 kv_dtype=None, num_kv_heads=None, geometry=None):
+                 kv_dtype=None, num_kv_heads=None, geometry=None,
+                 num_slots=None):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is reserved NULL)")
         if kv_dtype not in (None, "bf16", "int8"):
@@ -833,13 +886,30 @@ class PagedKVCache:
         if geometry is None:
             geometry = [(self.num_kv_heads, 2 * self.head_dim)
                         ] * self.num_layers
-        self.geometry = [(int(r), int(w)) for r, w in geometry]
+        self.geometry = [
+            {name: (tuple(int(d) for d in shp), dt)
+             for name, (shp, dt) in g.items()} if isinstance(g, dict)
+            else (int(g[0]), int(g[1])) for g in geometry]
         if len(self.geometry) != self.num_layers:
             raise ValueError(
                 f"geometry names {len(self.geometry)} layers, the cache "
                 f"has {self.num_layers}")
+        # the layers whose cache is a state a lane, not rows a token
+        self.state_layers = [i for i, g in enumerate(self.geometry)
+                             if isinstance(g, dict)]
+        self.num_slots = int(num_slots) if num_slots else None
+        if self.state_layers and not self.num_slots:
+            raise ValueError(
+                "a state layer's pool has a lane axis: the cache must be "
+                "told the lane count (num_slots=), which the engine owns")
+        if self.state_layers and (self.quantized or mesh is not None):
+            raise NotImplementedError(
+                f"{'int8 pools' if self.quantized else 'a mesh'} with a "
+                f"state layer (a recurrent state a lane beside the paged "
+                f"cache): the state is float32 by design and is not "
+                f"sharded yet (ROADMAP R5)")
         self.latent = any(g != (self.num_kv_heads, 2 * self.head_dim)
-                          for g in self.geometry)
+                          for g in self.geometry if not isinstance(g, dict))
         if self.latent and (self.quantized or mesh is not None):
             raise NotImplementedError(
                 "a pool whose block is not (H_kv, bs, 2 * head_dim) "
@@ -847,8 +917,10 @@ class PagedKVCache:
                 "and on one device: int8 scales are per K row and per "
                 "V row, and the mesh shards the head axis such a pool "
                 "does not have (ROADMAP Reach, R4)")
-        self.layer_shapes = [(self.num_blocks, r, self.block_size, w)
-                             for r, w in self.geometry]
+        self.layer_shapes = [
+            None if isinstance(g, dict)
+            else (self.num_blocks, g[0], self.block_size, g[1])
+            for g in self.geometry]
         if mesh is None:
             def make(shp, dt=dtype):
                 return jnp.zeros(shp, dt)
@@ -877,7 +949,12 @@ class PagedKVCache:
                 layer["v_scale"] = make(sshape, jnp.float32) + 1.0
             return layer
 
-        self.pools = [make_layer(shp) for shp in self.layer_shapes]
+        def make_state(arrays):
+            return {name: jnp.zeros((self.num_slots,) + shp, dt or dtype)
+                    for name, (shp, dt) in arrays.items()}
+
+        self.pools = [make_state(g) if shp is None else make_layer(shp)
+                      for g, shp in zip(self.geometry, self.layer_shapes)]
         # every jitted rewriter of the pools DONATES them (the engine's
         # fused and draft steps, cow_copy, adopt_block_from,
         # deserialize_block, swap_in_block): the old arrays are dead the
@@ -924,10 +1001,30 @@ class PagedKVCache:
         size, never the dense equivalent — and GQA pools their true
         H_kv row count, never the H-head overcount."""
         return (self._pool_elems() * np.dtype(self.dtype).itemsize
-                + self.scale_bytes())
+                + self.scale_bytes() + self.state_bytes())
 
     def _pool_elems(self):
-        return sum(int(np.prod(shp)) for shp in self.layer_shapes)
+        return sum(int(np.prod(shp)) for shp in self.layer_shapes
+                   if shp is not None)
+
+    def state_bytes(self):
+        """Bytes of the state layers' arrays over all lanes; 0 without
+        one."""
+        return sum(
+            self.num_slots * int(np.prod(shp))
+            * np.dtype(dt or self.dtype).itemsize
+            for i in self.state_layers
+            for shp, dt in self.geometry[i].values())
+
+    def _refuse_state(self, what):
+        """Everything that copies, shares or moves cache BY BLOCK
+        cannot carry a lane's state yet."""
+        if self.state_layers:
+            raise NotImplementedError(
+                f"{what} with a state layer: a block id names rows of "
+                f"keys and values, and layers "
+                f"{self.state_layers} keep a recurrent state a lane "
+                f"that no block addresses (ROADMAP R5)")
 
     def scale_bytes(self):
         """Bytes of the (N, H_kv, bs) f32 scale pools across k+v and
@@ -935,7 +1032,8 @@ class PagedKVCache:
         if not self.quantized:
             return 0
         return 2 * 4 * sum(int(np.prod(shp[:3]))
-                           for shp in self.layer_shapes)
+                           for shp in self.layer_shapes
+                           if shp is not None)
 
     def dense_pool_bytes(self, dtype=None):
         """What the SAME block count would cost unquantized in `dtype`
@@ -945,7 +1043,8 @@ class PagedKVCache:
         factor: multiply by num_heads/num_kv_heads for the MHA-dense
         equivalent."""
         dt = dtype if dtype is not None else self.compute_dtype
-        return self._pool_elems() * np.dtype(dt).itemsize
+        return (self._pool_elems() * np.dtype(dt).itemsize
+                + self.state_bytes())
 
     def shard_pool_bytes(self):
         """Bytes ONE device commits to the pools: pool_bytes()/tp under
@@ -1087,6 +1186,7 @@ class PagedKVCache:
         ids ride as traced scalars, so distinct (src, dst) pairs hit
         the same executable — the fused-step signature budget is
         untouched."""
+        self._refuse_state("cow_copy (a forked or shared block)")
         if self._cow_fn is None:
             def _copy(pool_sets, s, d):
                 return [
@@ -1127,6 +1227,7 @@ class PagedKVCache:
         bf16 prefill tier feeding an f32 decode tier is legitimate);
         quantized<->quantized carries the scale rows alongside the
         codes in the same jitted transfer."""
+        self._refuse_state("adopt_block_from (the fleet's KV handoff)")
         src_kv = getattr(src_cache, "num_kv_heads", src_cache.num_heads)
         if (src_cache.num_layers, src_cache.num_heads, src_kv,
                 src_cache.head_dim, src_cache.block_size,
@@ -1199,6 +1300,7 @@ class PagedKVCache:
         rows when quantized. This is the byte payload of a
         cross-process ``adopt_block_from``; deserialize_block is the
         receiving half."""
+        self._refuse_state("serialize_block (the wire)")
         with self.pools_lock:
             names = sorted(self.pools[0].keys())
             # each slice is a device array of its own, queued before
@@ -1216,6 +1318,7 @@ class PagedKVCache:
         error contract rather than silently writing garbage KV. One
         jitted write signature per cache lifetime (block id rides as a
         traced scalar)."""
+        self._refuse_state("deserialize_block (the wire)")
         g = meta.get("geometry", {})
         if g.get("layout") != KV_LAYOUT:
             raise ValueError(
@@ -1287,6 +1390,7 @@ class PagedKVCache:
         tiers are pool storage at mirrored ids, their free lists
         unused. Idempotent resize is NOT supported: one tier per cache
         lifetime, like the pools themselves."""
+        self._refuse_state("enable_host_tier (spill, preempt and resume)")
         if self.host is not None:
             raise ValueError(
                 "host tier already enabled — it is sized once for the "
@@ -1306,6 +1410,7 @@ class PagedKVCache:
         request parks (preempt). ONE jitted extract signature for the
         cache lifetime — the block id rides as a traced scalar — and
         one device_get for the whole transfer."""
+        self._refuse_state("spill_block (the host tier)")
         if self.host is None:
             raise ValueError("spill_block without enable_host_tier")
         hb = self.host.allocate(1)
@@ -1339,6 +1444,7 @@ class PagedKVCache:
         upload IS the H2D copy and there is ONE swap-in signature for
         the cache lifetime. Does NOT free the host block: the owner
         (prefix entry or preempt record) releases it."""
+        self._refuse_state("swap_in_block (the host tier)")
         if self.host is None:
             raise ValueError("swap_in_block without enable_host_tier")
         host_block = int(host_block)

@@ -68,6 +68,7 @@ def export_chain(server, prompt, keys):
     Returns (chunks, arrays): chunks[i] = {key, parent, tokens, meta},
     arrays = the per-(layer, pool-entry) blobs, concatenated in chunk
     order."""
+    server.cache._refuse_state("export_chain (the fleet's chain handoff)")
     bs = server.block_size
     prompt = np.asarray(prompt, np.int32)
     pinned = []                 # (key, block, tokens)
@@ -111,6 +112,7 @@ def import_chain(server, chunks, arrays):
     holds are skipped; pool exhaustion ends the walk — the rest
     re-prefills, same partial-transfer-is-safe contract as the
     in-process path. Returns blocks moved."""
+    server.cache._refuse_state("import_chain (the fleet's chain handoff)")
     if server._prefix is None or not chunks:
         return 0
     names = list(chunks[0]["meta"].get("names", ()))

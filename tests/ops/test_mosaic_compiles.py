@@ -273,6 +273,40 @@ def test_held_experts_kernel_compiles_at_the_cells_geometry(v5e):
     assert len(calls) == 1 and "moe_experts" in calls[0]
 
 
+def test_held_experts_kernel_takes_a_wide_expert_in_slices(v5e):
+    """solar-open2-250b-ep16: 20 held experts of 4096 x 1280, 31.5 MB
+    each: two buffers of a whole expert are 63 MB of VMEM, so the
+    kernel takes the inner width in two slices of 640 on a second grid
+    axis; at JoyAI's 2048 x 768 (the test above) the expert arrives
+    whole, on the one-axis grid it always had."""
+    from paddle_tpu.ops.pallas import moe
+    assert moe._inner_blocks(2048, 768, jnp.bfloat16) == 1
+    assert moe._inner_blocks(4096, 1280, jnp.bfloat16) == 2
+    t, hid, inner, e = 256, 4096, 1280, 20
+    calls = _compile(
+        v5e, lambda x, sel, comb, gu, down: moe.moe_experts(
+            x, sel, comb, gu, down, interpret=False),
+        ((t, hid), jnp.bfloat16), ((t, e), jnp.bool_),
+        ((t, e), jnp.float32), ((e, hid, 2 * inner), jnp.bfloat16),
+        ((e, inner, hid), jnp.bfloat16))
+    assert len(calls) == 1 and "moe_experts" in calls[0]
+
+
+def test_chunked_delta_rule_compiles_at_the_cells_geometry(v5e):
+    """solar-open2-250b-ep16: 16 lanes x 16-token chunks, 64 heads of
+    128 x 128, a float32 state a lane rewritten where it lies."""
+    from paddle_tpu.ops.pallas import linear
+    s, c, h, d = 16, 16, 64, 128
+    bf = jnp.bfloat16
+    calls = _compile(
+        v5e, lambda q, k, v, g, b, st, cnt, rst: linear.kda_chunk(
+            q, k, v, g, b, st, cnt, rst, interpret=False),
+        ((s, c, h, d), bf), ((s, c, h, d), bf), ((s, c, h, d), bf),
+        ((s, c, h, d), jnp.float32), ((s, c, h), jnp.float32),
+        ((s, h, d, d), jnp.float32), ((s,), jnp.int32), ((s,), jnp.bool_))
+    assert len(calls) == 1 and "kda_chunk" in calls[0]
+
+
 _BRANCH_ATTRS = ("branch_computations", "true_computation",
                  "false_computation")
 
