@@ -10,7 +10,7 @@ and a call costs what its lanes hold, not what the table could hold.
 
 **The walk** (`_plan_walk`, `_paged_call`). The unit is a GROUP of
 table columns of one lane: 128 key positions (`walk_group`: 8 columns of
-16-token blocks, read from the shapes), one column for v2. The grid is
+16-token blocks, read from the shapes), for every kernel here. The grid is
 ONE axis whose steps are the LIVE groups only, lane after lane; its
 bound, the sum of the lanes' live groups (an idle lane takes one step,
 to write its zeros), is a value the call computes from the positions,
@@ -52,12 +52,18 @@ block is one DMA, not two. Two generations share that walk:
   softmax's and the PV product's sums run over. A table of one group is
   bitwise the reference as it is. The price is VMEM scratch
   proportional to the table width M.
-* **v2** (`ragged_paged_attention_v2`): each arriving block folds
-  straight into a flash-style online-softmax accumulator (running max,
-  rescaled sum, rescaled PV partial, all f32 VMEM scratch), split into
-  its K and V lanes as it lands. VMEM holds the pipeline's two block
-  windows plus the carry — independent of M,
-  so context length is unbounded at fixed VMEM. Online softmax is
+* **v2** (`ragged_paged_attention_v2`): a step's group lands in an
+  (H_kv, 128, 2*D) VMEM scratch (a dead column as zeros) and folds
+  straight into a flash-style online-softmax carry (running max,
+  rescaled sum, rescaled PV partial, all f32 VMEM scratch), each KV
+  head's H/H_kv query heads x C columns scored as ONE tile of rows
+  against the group's 128 keys (one product batched over the KV
+  heads for the scores, one for the values). VMEM holds the group and
+  the carry — independent of M, so context length is unbounded at
+  fixed VMEM. (In `solar-open2-250b-ep16.long8k-closed-16`, 64 query
+  heads on 8 KV heads of 128 at 1k-8k contexts: 4.26 ms a call while a
+  step was one column of repeated heads, 0.92 with a step a group:
+  PERF.md section 6, PR 37.) Online softmax is
   mathematically EXACT (every rescale is an identity in real
   arithmetic) but reorders the floating-point reductions the reference
   performs in one pass, so v2 is pinned allclose-at-f32-tightness plus
@@ -85,8 +91,10 @@ Both kernels share the serving contract:
 * grouped-query attention (ISSUE 16): pools may carry H_kv < H heads
   (H % H_kv == 0). Query head j attends KV head j // (H/H_kv) — the
   contiguous-group convention, so Megatron column-sharded projections
-  stay head-aligned. Both kernels repeat the VMEM-resident KV rows
-  across each group (a pure copy, so v1's bitwise pin extends to GQA);
+  stay head-aligned. v1 repeats the VMEM-resident KV rows across each
+  group (a pure copy, so its bitwise pin extends to GQA); v2 repeats
+  nothing: a KV head's query heads are contiguous, so (H, C, D) is a
+  free reshape to (H_kv, H/H_kv * C, D), one tile of rows a KV head.
   HBM traffic stays at H_kv heads.
 
 Why BlockSpecs and not hand-rolled `make_async_copy`: the pipeline's
@@ -108,8 +116,9 @@ estimate (`v1_scratch_bytes`) is what Mosaic allocates: 6.5 MB for 25
 heads x 1,024 tokens of bf16, where two 64-lane scratches padded to
 13. The K and V slices the value path takes are temporaries of the same
 size again at most. The windows of a group are 2 * `walk_group` blocks
-(1.6 MB at that geometry). v2 holds two block windows whatever M is;
-the dispatcher (serving/kv_cache.paged_attention) routes tables past
+(1.6 MB at that geometry). v2 holds one group and the carry whatever
+M is (2 MB at the Solar cell's geometry: `_v2_scratch_shapes`); the
+dispatcher (serving/kv_cache.paged_attention) routes tables past
 the v1 ceiling to v2 automatically.
 
 Off-TPU the kernels run under the Pallas interpreter (same policy as
@@ -537,32 +546,49 @@ def ragged_paged_attention(q, kv_pool, block_table, q_positions,
 
 
 # ---------------------------------------------------------------------------
-# kernel v2: block streaming + online softmax
+# kernel v2: a group walk + online softmax, each KV head's query heads one tile
 # ---------------------------------------------------------------------------
 
-def _v2_scratch_shapes(h, c, d):
-    """The v2 VMEM scratch contract, exposed for the white-box test:
-    the online-softmax carry (running max, exp-sum, PV partial) — NO
-    dimension depends on the table width M, and the block windows are
-    the pipeline's own two block-sized buffers. That independence IS the
+def _v2_scratch_shapes(h, hp, c, d, t, pool_dtype):
+    """The v2 VMEM scratch contract, exposed for the white-box test: a
+    group's t key positions (t = `walk_group` x bs: 128, or one block
+    where a block is wider), K beside V at H_kv heads in the pool's
+    dtype (f32 for int8 pools, which are dequantized as they land),
+    and the online-softmax carry (running max, exp-sum, PV partial, all
+    f32) of each KV head's (H / H_kv) x C query rows. NO dimension
+    depends on the table width M — that independence IS the
     unbounded-context claim. Returns [(shape, dtype), ...]."""
-    return [((h, c, 1), jnp.float32), ((h, c, 1), jnp.float32),
-            ((h, c, d), jnp.float32)]
+    dt = jnp.float32 if pool_dtype == jnp.int8 else pool_dtype
+    rows = (h // hp) * c
+    return [((hp, t, 2 * d), dt), ((hp, rows, 1), jnp.float32),
+            ((hp, rows, 1), jnp.float32), ((hp, rows, d), jnp.float32)]
 
 
 def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
-                     groups_ref, pos_ref, q_ref, kv_ref, *rest, bs, m, p,
-                     h, hp, d, quantized=False):
-    """Grid step s: lane b = lane[s], its live table column
-    j = group[s] (a group of v2's walk is one column), all heads. Block
-    table[b, j] arrives through the pipeline (the NEXT block's copy is
-    already in flight while this one computes — the two-window overlap),
-    is split into its K lanes [0, D) and V lanes [D, 2*D), and folds
-    into the online-softmax carry held in VMEM scratch
-    (m: running row max, l: rescaled exp-sum, acc: rescaled PV partial,
-    all f32). NULL blocks (padding, idle lanes) and columns past the
-    lane's last live block are predicated off whole: nothing they hold
-    — garbage, NaN poison — is ever multiplied.
+                     groups_ref, pos_ref, q_ref, *rest, bs, m, p, h, hp,
+                     d, quantized=False):
+    """Grid step s: lane b = lane[s], its j-th live GROUP of p table
+    columns (j = group[s]), every KV head. q_ref (1, H_kv, g*C, D): the
+    lane's queries with the g = H / H_kv query heads of a KV head as
+    one tile of rows (row gi*C + ci is head hk*g + gi, column ci: the
+    contiguous-group convention makes that a free reshape of (H, C,
+    D)). Then p windows (1, H_kv, bs, 2*D): pool blocks
+    fetch[b, j*p : (j+1)*p], K in lanes [0, D) and V in [D, 2*D)
+    (quantized adds p windows (1, H_kv, bs) for each of the two f32
+    scale pools, and a block is dequantized as it lands). The group's
+    blocks land in `g_ref` (H_kv, p*bs, 2*D) in position order, a dead
+    column's rows as zeros; ONE product batched over the KV heads
+    scores each head's g*C rows against the group's keys, and a second
+    against the same rows' V lanes folds them into the flash-style
+    carry (m: running row max, l: rescaled exp-sum, acc: rescaled PV
+    partial, all f32). No head is repeated.
+
+    Arithmetic: scores take bf16 operands where q and the pool are
+    both bf16 (every product exact in the f32 accumulator), f32
+    otherwise; probabilities enter the value product in f32, against
+    the V lanes in f32. NULL blocks (padding, idle lanes) and columns
+    past the lane's last live block are masked out and land as zeros:
+    nothing they hold — garbage, NaN poison — is ever multiplied.
 
     Two traps the masking dodges, pinned by tests:
     * NEG_INF is finite (-1e9), so on an all-masked prefix
@@ -572,14 +598,16 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
     * an idle lane finishes with l == 0; dividing by
       `where(l > 0, l, 1)` lands an exact 0 output instead of NaN (the
       engine's non-finite-logits guard sums every lane's logps)."""
+    del fetch_ref, m                        # read by the index maps
+    kv_refs, rest = rest[:p], rest[p:]
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    del fetch_ref, m, p
+        ks_refs, vs_refs, rest = rest[:p], rest[p:2 * p], rest[2 * p:]
+    o_ref, g_ref, m_ref, l_ref, acc_ref = rest
     step = pl.program_id(0)
     b, j = lane_ref[step], group_ref[step]
     c = pos_ref.shape[1]
+    rows, t = (h // hp) * c, p * bs
+    n_groups = groups_ref[b]
 
     @pl.when(j == 0)
     def _init():
@@ -587,19 +615,46 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(live_ref[b, j] != 0)
+    @pl.when(n_groups > 0)
     def _fold():
-        blk = kv_ref[0]                               # (H_kv, bs, 2*D)
-        if quantized:
-            blk = _dequant(blk, ks_ref[0], vs_ref[0])
-        blk = blk.astype(jnp.float32)
-        kb = _repeat_heads(blk[..., :d], h // hp)
-        vb = _repeat_heads(blk[..., d:], h // hp)
-        s = jnp.einsum("hcd,hbd->hcb", q_ref[0].astype(jnp.float32), kb,
+        for i in range(p):
+            span = pl.ds(i * bs, bs)
+            is_live = live_ref[b, j * p + i] != 0
+
+            @pl.when(is_live)
+            def _land():
+                blk = kv_refs[i][0]                   # (H_kv, bs, 2*D)
+                if quantized:
+                    blk = _dequant(blk, ks_refs[i][0], vs_refs[i][0])
+                g_ref[:, span, :] = blk
+
+            @pl.when(jnp.logical_not(is_live))
+            def _clear():
+                g_ref[:, span, :] = jnp.zeros((hp, bs, 2 * d),
+                                              g_ref.dtype)
+
+        kv = g_ref[...]                               # (H_kv, t, 2*D)
+        q = q_ref[0]                                  # (H_kv, g*C, D)
+        sdt = jnp.promote_types(q.dtype, kv.dtype)
+        s = jnp.einsum("hrd,htd->hrt", q.astype(sdt),
+                       kv[..., :d].astype(sdt),
+                       precision=_mxu_precision(sdt),
                        preferred_element_type=jnp.float32) / np.sqrt(d)
-        key_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (c, bs), 1)
-        mask = (key_pos <= _pos_matrix(pos_ref, b, c, (c, bs), 0))[None]
+        # the mask is one (g*C, t) tile for every KV head: row r asks
+        # at its column's position, pos[b, r % C]; key k of the group
+        # is position j*t + k, and attends only where its column lives
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 0)
+        key = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
+        q_pos = jnp.full((rows, t), pos_ref[b, 0], jnp.int32)
+        if c > 1:
+            col = jax.lax.rem(row, c)
+            for ci in range(1, c):
+                q_pos = jnp.where(col == ci, pos_ref[b, ci], q_pos)
+        dead = jnp.full((rows, t), 1 - live_ref[b, j * p], jnp.int32)
+        for i in range(1, p):
+            dead = jnp.where(key >= i * bs, 1 - live_ref[b, j * p + i],
+                             dead)
+        mask = ((j * t + key <= q_pos) & (dead == 0))[None]
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -607,14 +662,15 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
         # so m_prev - m_new == 0 and corr == 1 exactly — the carry stays
         # untouched instead of decaying through exp(-1e9)
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1,
+        prob = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(prob, axis=-1,
                                                  keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
-            "hcb,hbd->hcd", p, vb, preferred_element_type=jnp.float32)
+            "hrt,htd->hrd", prob, kv[..., d:].astype(jnp.float32),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(j == jnp.maximum(groups_ref[b], 1) - 1)
+    @pl.when(j == jnp.maximum(n_groups, 1) - 1)
     def _flush():
         # idle lanes (every key masked) land l == 0: divide by 1 and
         # output an exact 0 — never NaN
@@ -626,14 +682,14 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
 def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
                               k_scale=None, v_scale=None,
                               interpret=None):
-    """Paged attention kernel v2: block streaming with a flash-style
+    """Paged attention kernel v2: a group walk with a flash-style
     online softmax. Identical call contract to
     `ragged_paged_attention` (v1); the difference is the resource
-    shape — VMEM is two block windows plus the carry
+    shape — VMEM is one group of 128 key positions plus the carry
     (`_v2_scratch_shapes`) regardless of the table width, and
     scores/softmax/PV accumulate in f32 for EVERY pool dtype, with the
     output cast once at the end. v2 is mathematically exact vs the
-    reference but reorders its fp reductions (per-block partial sums +
+    reference but reorders its fp reductions (per-group partial sums +
     rescales), so the tier-1 pin is tight-allclose + argmax-identical
     rather than v1's bitwise."""
     global TRACE_COUNT, V2_TRACE_COUNT
@@ -644,19 +700,24 @@ def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
     if interpret is None:
         interpret = _interpret()
     out_dtype = q.dtype if quantized else kv_pool.dtype
-    scratch = _v2_scratch_shapes(h, c, d)
-    # carry + the f32 head-repeated views of one K and one V block
+    group = walk_group(bs, m)
+    rows, t = (h // hp) * c, group * bs
+    scratch = _v2_scratch_shapes(h, hp, c, d, t, kv_pool.dtype)
+    # the group and the carry, the (H_kv, g*C, t) scores and their
+    # temporaries, the V lanes in f32
     vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
-            + 4 * _padded_bytes((h, bs, d), jnp.float32))
-    return _paged_call(q, kv_pool,
-                       [k_scale, v_scale] if quantized else [],
-                       block_table, q_positions,
-                       name="paged_attention_v2", kernel=_paged_kernel_v2,
-                       statics=_kv_statics(h, hp, d, quantized),
-                       group=1, scratch=tuple(scratch),
-                       out_shape=q.shape, out_dtype=out_dtype,
-                       vmem_bytes=vmem,
-                       interpret=bool(interpret))
+            + 4 * _padded_bytes((hp, rows, t), jnp.float32)
+            + 2 * _padded_bytes((hp, t, d), jnp.float32))
+    out = _paged_call(q.reshape(b, hp, rows, d), kv_pool,
+                      [k_scale, v_scale] if quantized else [],
+                      block_table, q_positions,
+                      name="paged_attention_v2", kernel=_paged_kernel_v2,
+                      statics=_kv_statics(h, hp, d, quantized),
+                      group=group, scratch=tuple(scratch),
+                      out_shape=(b, hp, rows, d), out_dtype=out_dtype,
+                      vmem_bytes=vmem,
+                      interpret=bool(interpret))
+    return out.reshape(b, h, c, d)
 
 
 # ---------------------------------------------------------------------------

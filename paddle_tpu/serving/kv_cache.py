@@ -38,8 +38,8 @@ bf16 KV with f32 accumulation), falling back to
 by table -> masked attention) that kernel v1 is pinned bitwise against
 in interpret mode (on the lane's table cut to its live groups). Two kernel generations exist: v1 (gather the live
 blocks to VMEM, then the reference math — bitwise-stable, VMEM scales
-with the table width) and v2 (double-buffered block STREAMING with an
-online softmax — O(2 blocks) of VMEM whatever the table width). Auto
+with the table width) and v2 (a walk of groups of 128 keys with an
+online softmax — O(one group) of VMEM whatever the table width). Auto
 mode picks v1 while its scratch fits the VMEM ceiling and v2 past it;
 `PADDLE_TPU_PAGED_KERNEL` (0/1/auto/v1/v2) overrides the routing;
 everything above the op (scheduler, engine) is kernel-agnostic.

@@ -111,6 +111,28 @@ def test_paged_v1_compiles_at_the_cell_geometry_with_a_grouped_grid(v5e):
     assert len(calls) == 1 and "paged_attention_v1" in calls[0]
 
 
+@pytest.mark.parametrize("pool,c", [("bf16", 16), ("bf16", 1),
+                                    ("int8", 16)],
+                         ids=["bf16", "bf16_decode", "int8"])
+def test_paged_v2_compiles_at_the_gqa_cell_geometry(v5e, pool, c):
+    """Solar-Open2 as `solar-open2-250b-ep16.long8k-closed-16` serves
+    it: 16 lanes, 64 query heads on 8 KV heads of 128, bf16 blocks of
+    16 tokens, a 512-column table. One `paged_attention_v2` call, a
+    step a live group of 8 columns, each KV head's 8 x C query rows one
+    tile: the free reshape, the row-to-column mask and the group's
+    scratch all typed by Mosaic (and int8 pools past the v1 ceiling)."""
+    s, h, hp, d, bs, m = 16, 64, 8, 128, 16, 512
+    n = 1 + s * m
+    pdt = jnp.int8 if pool == "int8" else jnp.bfloat16
+    shapes = [((s, h, c, d), jnp.bfloat16), ((n, hp, bs, 2 * d), pdt),
+              ((s, m), jnp.int32), ((s, c), jnp.int32)]
+    if pool == "int8":
+        shapes += [((n, hp, bs), jnp.float32)] * 2
+    calls = _compile(v5e, lambda *a: paged.ragged_paged_attention_v2(
+        *a, interpret=False), *shapes)
+    assert len(calls) == 1 and "paged_attention_v2" in calls[0]
+
+
 @pytest.mark.parametrize("nested", [False, True],
                          ids=["gspmd", "in_callers_shard_map"])
 def test_flash_compiles_under_an_executor_mesh(v5e_2x2, nested,

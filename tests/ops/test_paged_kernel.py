@@ -115,25 +115,29 @@ def test_kernel_eager_allclose_f32():
 # the grouped walk: tables of several groups (ISSUE 31)
 # ---------------------------------------------------------------------------
 
-def make_walk_case(kind="f32", c=4, bs=16, m=24, seed=0, poison=False):
+def make_walk_case(kind="f32", c=4, bs=16, m=24, seed=0, poison=False,
+                   h=4, hp=None, d=8, lengths=None):
     """A table of several groups of 128 key positions, one lane a live
-    group count: lane 0 idle, lane 1 filling the table, the others
-    holding 5, 130 and 300 tokens and one just short of full. kind:
-    "f32" (MHA), "gqa" (2 KV heads under 4 query heads) or "int8"
-    (codes + scale pools). poison=True fills the NULL block AND every
-    table column past a lane's live blocks (stale entries, whole dead
-    groups among them) with NaN blocks: nothing of them may be read."""
+    group count: by default lane 0 idle, lane 1 filling the table, the
+    others holding 5, 130 and 300 tokens and one just short of full
+    (`lengths`, None for an idle lane). kind: "f32" (MHA), "gqa" (2 KV
+    heads under 4 query heads), "bf16" (q and pool) or "int8" (codes +
+    scale pools, f32 queries); `hp` sets the KV heads of any kind.
+    poison=True fills the NULL block AND every table column past a
+    lane's live blocks (stale entries, whole dead groups among them)
+    with NaN blocks: nothing of them may be read."""
     rng = np.random.default_rng(seed)
     t = m * bs
-    lengths = [None, t - c, 5, 130, 300, t - c - bs]
-    b, h, d = len(lengths), 4, 8
-    hp = 2 if kind == "gqa" else h
+    lengths = lengths or [None, t - c, 5, 130, 300, t - c - bs]
+    b = len(lengths)
+    hp = hp or (2 if kind == "gqa" else h)
     n = 2 + b * m
     bad = n - 1                             # a real block, all NaN
     k = rng.standard_normal((n, hp, bs, d)).astype(np.float32)
     v = rng.standard_normal((n, hp, bs, d)).astype(np.float32)
     k[kvc.NULL_BLOCK] = v[kvc.NULL_BLOCK] = 0.0
-    q = jnp.asarray(rng.standard_normal((b, h, c, d)), jnp.float32)
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    q = jnp.asarray(rng.standard_normal((b, h, c, d)), dtype)
     tables = np.full((b, m), bad if poison else kvc.NULL_BLOCK, np.int32)
     q_pos = np.zeros((b, c), np.int32)
     free = list(range(1, bad))
@@ -155,7 +159,8 @@ def make_walk_case(kind="f32", c=4, bs=16, m=24, seed=0, poison=False):
         return (q, kvc.fuse_kv(kq, vq)) + tail + (ks, vs)
     if poison:
         k[kvc.NULL_BLOCK] = v[kvc.NULL_BLOCK] = k[bad] = v[bad] = np.nan
-    return (q, kvc.fuse_kv(jnp.asarray(k), jnp.asarray(v))) + tail
+    return (q, kvc.fuse_kv(jnp.asarray(k, dtype),
+                           jnp.asarray(v, dtype))) + tail
 
 
 def cut_reference(args):

@@ -10,14 +10,15 @@ Contract (extends tests/ops/test_paged_kernel.py):
   its pins do not move;
 - scores/softmax/PV accumulate in f32 for every pool dtype (bf16 and
   int8 included), output cast once at the end;
-- the white-box VMEM contract: `_v2_scratch_shapes` buffers all lead
-  with dim 2 (the double-buffer slots) and NO dimension depends on the
-  table width M — that independence IS the unbounded-context claim;
+- the white-box VMEM contract: `_v2_scratch_shapes` is one group of
+  key positions (K beside V at H_kv heads) plus the carry of each KV
+  head's query rows, and NO dimension depends on the table width M —
+  that independence IS the unbounded-context claim;
 - GQA (H_kv < H): the reference on (N, H_kv, bs, D) pools is BITWISE
   the reference on repeat-KV dense (N, H, bs, D) pools under jit (the
   repeat is a pure copy), v1 inherits its bitwise pin through the same
-  repeat, v2 stays in its allclose envelope without ever materializing
-  the repeat;
+  repeat, v2 stays in its allclose envelope scoring each KV head's
+  query heads as one tile, without ever materializing the repeat;
 - the NULL block is never read by v2 either: NaN-poison changes
   nothing, bitwise (the zero-filled slots make a skipped DMA's
   0-probability product an exact 0, not NaN);
@@ -198,14 +199,21 @@ def test_v2_null_block_poison_stays_finite():
 
 def test_v2_scratch_is_m_independent():
     """The streaming claim, pinned structurally: v2's VMEM scratch is
-    the online-softmax carry only — no dimension involves the table
-    width M (the function cannot even be passed one); the K/V windows
-    are the pipeline's two block-sized buffers. v1's scratch by
-    contrast scales linearly with M."""
-    h, c, d = 4, 2, 16
-    assert paged._v2_scratch_shapes(h, c, d) == [
-        ((h, c, 1), jnp.float32), ((h, c, 1), jnp.float32),
-        ((h, c, d), jnp.float32)]
+    one group of key positions and the online-softmax carry — no
+    dimension involves the table width M (the function cannot even be
+    passed one). v1's scratch by contrast scales linearly with M."""
+    h, hp, c, d, t = 16, 2, 4, 16, 128
+    rows = (h // hp) * c                # a KV head's query rows
+    carry = [((hp, rows, 1), jnp.float32), ((hp, rows, 1), jnp.float32),
+             ((hp, rows, d), jnp.float32)]
+    assert paged._v2_scratch_shapes(h, hp, c, d, t, jnp.bfloat16) == [
+        ((hp, t, 2 * d), jnp.bfloat16)] + carry
+    # int8 blocks are dequantized as they land: the group sits in f32
+    assert paged._v2_scratch_shapes(h, hp, c, d, t, jnp.int8) == [
+        ((hp, t, 2 * d), jnp.float32)] + carry
+    # the group is 128 key positions however wide the table is
+    assert paged.walk_group(16, 24) * 16 == paged.walk_group(
+        16, 512) * 16 == t
     narrow = paged._v1_scratch_shapes(2, 8, d, 6, jnp.bfloat16)
     wide = paged._v1_scratch_shapes(2, 8, d, 24, jnp.bfloat16)
     # one scratch, K beside V, in the pool's dtype (f32 for int8 codes,
@@ -230,10 +238,13 @@ def test_v2_scratch_is_m_independent():
 
 
 def test_v2_wide_table_same_answer():
-    """Functionally M-independent: widening the table with NULL padding
-    (the shape a long-context pool geometry produces) changes nothing
-    — v2 streams the same live blocks through the same 2 slots."""
-    q, kv_pool, tables, pos = make_case(seed=4)
+    """Functionally M-independent: widening a table that holds a whole
+    group (128 key positions) with NULL padding (the shape a
+    long-context pool geometry produces) changes nothing — v2 walks the
+    same live groups of the same width; the dead ones are no step. (A
+    table narrower than a group is walked in one group of its own
+    width, whose sums run over fewer keys.)"""
+    q, kv_pool, tables, pos = make_case(seed=4, bs=16, m=8)
     pad = jnp.full((tables.shape[0], 26), kvc.NULL_BLOCK, jnp.int32)
     wide = jnp.concatenate([tables, pad], axis=1)
     out = np.asarray(jax.jit(paged.ragged_paged_attention_v2)(
@@ -245,15 +256,17 @@ def test_v2_wide_table_same_answer():
 
 @pytest.mark.parametrize("kind", ["f32", "int8", "gqa"])
 def test_v2_steps_only_through_live_blocks(kind):
-    """v2 shares v1's plan of the walk with a group of one column: a
-    step is a live block of a lane, so lanes of 0 (idle), 1, 9, 19 and
-    all 24 blocks of a 384-key table each fold exactly their own, and
-    NaN in the NULL block and in every stale column reaches nothing."""
+    """v2 shares v1's plan of the walk and its group of 128 key
+    positions: a step is a live group of a lane, so lanes of 0 (idle),
+    1, 9, 19, 23 and all 24 blocks of a 384-key table take 0 (one step,
+    to write its zeros), 1, 2, 3, 3 and 3 steps, and NaN in the NULL
+    block and in every stale column reaches nothing."""
     from test_paged_kernel import make_walk_case
     args = make_walk_case(kind=kind, bs=16, m=24, seed=23)
-    steps, plan = paged._plan_walk(args[2], args[3], 16, 1)
+    steps, plan = paged._plan_walk(args[2], args[3], 16,
+                                   paged.walk_group(16, 24))
     groups = np.asarray(plan[4])
-    np.testing.assert_array_equal(groups, [0, 24, 1, 9, 19, 23])
+    np.testing.assert_array_equal(groups, [0, 3, 1, 2, 3, 3])
     assert int(steps) == groups.sum() + 1       # the idle lane's one
     out, _ref = _assert_v2_close(args, rtol=1e-4 if kind == "int8" else 1e-5,
                                  atol=1e-5 if kind == "int8" else 1e-6)
@@ -267,6 +280,41 @@ def test_v2_steps_only_through_live_blocks(kind):
 # ---------------------------------------------------------------------------
 # grouped-query attention, op level
 # ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("c", [4, 1], ids=["prefill", "decode"])
+def test_gqa_v2_group_walk_at_the_cells_head_ratio(c, kind):
+    """The serving cell's head ratio (8 query heads a KV head, scored as
+    one tile against a group's 128 keys) on 16-token blocks: lanes that
+    end in the first group, mid-group, on a group's last key and on the
+    next group's first stay in v2's envelope (f32: allclose +
+    argmax-identical; bf16 q and pool: the cell's dtypes), the idle
+    lane is an exact zero, and NaN in the NULL block and the dead
+    columns of a partly live last group changes nothing, bitwise."""
+    from test_paged_kernel import make_walk_case
+    case = dict(kind=kind, c=c, h=16, hp=2, d=16, seed=31 + c,
+                lengths=[None, 5, 200 - c, 256 - c, 257 - c])
+    args = make_walk_case(**case)
+    steps, plan = paged._plan_walk(args[2], args[3], 16,
+                                   paged.walk_group(16, 24))
+    np.testing.assert_array_equal(np.asarray(plan[4]), [0, 1, 2, 2, 3])
+    assert int(steps) == 9
+    if kind == "f32":
+        out, _ref = _assert_v2_close(args)
+    else:
+        tol = dict(rtol=2e-2, atol=2e-2) if kind == "bf16" else \
+            dict(rtol=1e-4, atol=1e-5)
+        ref = np.asarray(jax.jit(kvc.paged_attention_reference)(*args),
+                         np.float32)
+        out = np.asarray(jax.jit(paged.ragged_paged_attention_v2)(*args),
+                         np.float32)
+        np.testing.assert_allclose(out, ref, **tol)
+    assert not out[0].any()
+    dirty = make_walk_case(poison=True, **case)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(paged.ragged_paged_attention_v2)(*dirty),
+                   np.float32), out)
+
 
 def _repeat_pools(args, g):
     """The repeat-KV dense equivalent: pools (and scales) expanded to

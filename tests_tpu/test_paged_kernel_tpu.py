@@ -71,6 +71,9 @@ GEOMETRIES = [
     (8, 2, 1, 64, 16, 512),         # GQA, 8k-token table
     (4, 4, 4, 128, 16, 512),        # MHA, head_dim 128, 8k-token table
     (8, 8, 4, 64, 32, 64),
+    # solar-open2-250b-ep16.long8k-closed-16's: 64 query heads on 8 KV
+    # heads of 128, 16 lanes x 16 columns, an 8k-token table (v2 only)
+    (64, 8, 16, 128, 16, 512, 16),
 ]
 POOLS = [("float32", False), ("bfloat16", False), ("bfloat16", True)]
 # f32: exact-f32 matmuls on both sides (conftest precision pin);
@@ -90,16 +93,25 @@ def test_paged_kernel_matches_reference_tpu(version, geom, pool):
     from paddle_tpu.serving import kv_cache as kvc
 
     h, hp, c, d, bs, m = geom[:6]
+    if version == "v1" and 2 * h * m * bs * d * 4 > 100 << 20:
+        pytest.skip("v1's head-repeated K and V of a full table exceed "
+                    "VMEM; the dispatcher routes such tables to v2")
     dtype_name, quantized = pool
+    b = (geom[6:] or (4,))[0]
     args, clean = _case(h, hp, c, d, bs, m, jnp.dtype(dtype_name),
-                        quantized, seed=sum(geom), b=(geom[6:] or (4,))[0])
+                        quantized, seed=sum(geom), b=b)
     fn = (paged.ragged_paged_attention if version == "v1"
           else paged.ragged_paged_attention_v2)
     assert not paged._interpret()
     got = jax.jit(fn)(*args)
-    want = jax.jit(kvc.paged_attention_reference)(*clean)
-    assert got.dtype == want.dtype
-    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    # the reference a lane at a time: its dense head-repeated gather of
+    # a whole batch of 8k-token tables would not fit the chip
+    ref = jax.jit(kvc.paged_attention_reference)
+    want = [ref(clean[0][i:i + 1], clean[1], clean[2][i:i + 1],
+                clean[3][i:i + 1], *clean[4:]) for i in range(b)]
+    assert got.dtype == want[0].dtype
+    got = np.asarray(got, np.float32)
+    want = np.concatenate([np.asarray(x, np.float32) for x in want])
     assert np.isfinite(got).all(), "NULL-block poison reached the output"
     assert not got[0].any(), "idle lane is not an exact zero"
     err = float(np.max(np.abs(got - want)))
