@@ -146,6 +146,10 @@ METRIC_SPECS = [
      "iterations in which at least one lane drew its token: the fused "
      "step took its sampled branch (over serving.iterations, the share "
      "of steps that paid for sampling; an all-greedy step skips it)"),
+    ("serving.feed.mask_uploads", "counter",
+     "iterations that uploaded a guided-decoding mask: those with a "
+     "guided lane (every other step reads the zero mask kept on the "
+     "device)"),
     ("serving.step_ms", "histogram",
      "wall ms of one serving iteration from the step's feed to the end "
      "of commit (plan() is not in it)"),

@@ -18,7 +18,8 @@ position. Requests of any length mix freely in one executable; after
 warmup the jit cache holds exactly one fused signature (asserted via
 get_stats()), plus at most one draft-step signature when speculative
 decoding is on (spec_decode.py) — the whole server lifetime compiles
-at most two step functions.
+at most two step functions. The small feeds reach that step as one
+packed int32 buffer, unpacked inside the jitted call (feed.py).
 
 The model side is pluggable; GPTServingModel adapts models/gpt.py
 params (same math as gpt.build_kv_step, vectorized over the chunk
@@ -46,6 +47,7 @@ from .blocks import (ATTENTIONS, MLPS, NORMS, STATE_ATTENTIONS, LayerSpec,
                      state_counts)
 from .decode_strategies import (GroupFuture, RequestGroup,
                                 SamplingParams, gumbel_noise)
+from .feed import FeedLayout
 from .kv_cache import NEG_INF, NULL_BLOCK, PagedKVCache
 from .scheduler import ContinuousBatchingScheduler, RequestCancelled, _Request
 
@@ -757,11 +759,37 @@ class GenerationServer:
             if self._strategies:
                 step_kw["sampling"] = True
             fused = model.build_fused_step(self.block_size, **step_kw)
+        # the feed (feed.py): tokens, positions, valid, tables and the
+        # sampling controls cross as ONE packed int32 buffer, unpacked
+        # inside the step in the model step's argument order. The
+        # strategies' mask is an argument of its own: a zero mask kept
+        # on the device, replaced by an uploaded one only on an
+        # iteration with guided lanes
+        s, c = self._sched.num_slots, self._sched.chunk
+        fields = [((s, c), np.int32), ((s, c), np.int32),
+                  ((s, c), np.bool_), ((s, self._sched.max_blocks),
+                                       np.int32)]
+        self._zero_mask = None
+        if self._strategies:
+            # keys, temperature, do_sample, top_k, top_p
+            fields += [((s, 2), np.uint32), ((s,), np.float32),
+                       ((s,), np.bool_), ((s,), np.int32),
+                       ((s,), np.float32)]
+            self._zero_mask = jnp.zeros(
+                (s, c, self._vocab) if spec is not None
+                else (s, self._vocab), jnp.float32)
+        self._feed = layout = FeedLayout(fields)
+
+        def packed_step(pools, packed, *mask):
+            feeds = layout.unpack(packed)
+            return fused(pools, *feeds[:4], *mask, *feeds[4:])
+
         # argument 0 is the pools: the step rewrites them in place (XLA
         # aliases each pool's output to its input), so the call consumes
         # them and _dispatch_fused stores the new set before anyone can
-        # look (docs/serving.md "Who owns the pools")
-        self._fused = jax.jit(fused, donate_argnums=(0,))
+        # look (docs/serving.md "Who owns the pools"). Only the pools:
+        # the resident zero mask is passed again every step
+        self._fused = jax.jit(packed_step, donate_argnums=(0,))
         self._signatures = set()
         self._n_arguments = None        # _arguments(): read once
         # HBM ledger (observability/compile_insight.py): the serving
@@ -958,6 +986,9 @@ class GenerationServer:
             "sampled_iterations": reg.counter(
                 "serving.sampled_iterations",
                 _help("serving.sampled_iterations")),
+            "mask_uploads": reg.counter(
+                "serving.feed.mask_uploads",
+                _help("serving.feed.mask_uploads")),
             "step_ms": reg.histogram("serving.step_ms",
                                      _help("serving.step_ms")),
             "valid_columns": reg.counter(
@@ -1279,17 +1310,27 @@ class GenerationServer:
                             draft_logps = self._run_draft(plan)
                     with rec.span("serving.feed.build", cat="serving",
                                   args=leaf):
-                        host = (plan.tokens, plan.positions, plan.valid,
-                                plan.tables)
+                        feeds = (plan.tokens, plan.positions, plan.valid,
+                                 plan.tables)
                         if self._strategies:
-                            # mask/rng/temperature/do_sample/top_k/top_p
-                            # are DATA with constant shapes — the
-                            # signature set below still collapses to one
-                            # entry
-                            host = host + self._strategies_args(plan, it)
+                            # the controls and the mask are DATA with
+                            # constant shapes — the signature set below
+                            # still collapses to one entry
+                            do_sample, temperature, top_k, top_p, keys = \
+                                plan.sample_ctl
+                            feeds += (keys, temperature, do_sample, top_k,
+                                      top_p)
+                        mask = (self._guided_mask(plan, it)
+                                if self._strategies else None)
+                        # a mask of None stands for the resident zeros
+                        host = (self._feed.pack(feeds),) + (
+                            (mask,) if self._strategies else ())
                     with rec.span("serving.feed.upload", cat="serving",
                                   args=lambda: _moved(leaf, host)):
-                        args = tuple(jnp.asarray(a) for a in host)
+                        args = tuple(self._zero_mask if a is None
+                                     else jnp.asarray(a) for a in host)
+                    if mask is not None:
+                        self._m["mask_uploads"].inc()
                     self._signatures.add(
                         tuple((a.shape, str(a.dtype)) for a in args))
                 with rec.span("serving.dispatch", cat="serving",
@@ -1565,22 +1606,24 @@ class GenerationServer:
                 plan.tokens[sid, 1:q] = props[sid, :q - 1]
         return np.asarray(dlps)
 
-    def _strategies_args(self, plan, iteration):
-        """The strategies step's extra feeds for one iteration, as host
-        arrays (the caller uploads them): the
-        guided-decoding mask ((S, V) plain, (S, C, V) per-column —
-        all-zero rows for unconstrained lanes) plus the sampling
-        control arrays the scheduler planned. Per-column guided lanes
+    def _guided_mask(self, plan, iteration):
+        """The guided-decoding mask of one iteration, as a host array
+        the caller uploads ((S, V) plain, (S, C, V) per-column —
+        all-zero rows for unconstrained lanes), or None where no lane
+        is guided: the step then reads the zero mask kept on the
+        device. Per-column guided lanes
         advance a SCRATCH automaton state through the fed draft tokens
         so each verify column is masked under the context it would
         commit under (the real state only advances in commit). Chaos
         mask-starve narrows every guided row to its single lowest
         allowed token — conformance holds, the loop must survive."""
+        if not plan.guided_lanes:
+            return None
         s, c = plan.tokens.shape
         per_col = self._spec is not None
         mask = np.zeros((s, c, self._vocab) if per_col
                         else (s, self._vocab), np.float32)
-        starve = (bool(plan.guided_lanes) and self._chaos is not None
+        starve = (self._chaos is not None
                   and self._chaos.mask_starves_at(iteration))
         starved_any = False
 
@@ -1591,7 +1634,7 @@ class GenerationServer:
                 out[allowed[0]] = 0.0
             return out
 
-        for sid, req in plan.guided_lanes or ():
+        for sid, req in plan.guided_lanes:
             state = req.guided_state
             if state is None:
                 continue        # dead automaton (chaos): unconstrained
@@ -1620,8 +1663,7 @@ class GenerationServer:
                 mask[sid, j] = row      # dead: repeat the last mask
         if starved_any:
             self._chaos.mask_starve_applied()
-        do_sample, temperature, top_k, top_p, keys = plan.sample_ctl
-        return mask, keys, temperature, do_sample, top_k, top_p
+        return mask
 
     def _kernel_info(self):
         # constant after the first step: built once, reused by every

@@ -432,7 +432,7 @@ def _traced_run(params, cfg, prompts, **kw):
     from paddle_tpu.observability.tracing import get_recorder
     reg = global_registry()
     names = ("serving.valid_columns", "serving.padded_columns",
-             "serving.iterations")
+             "serving.iterations", "serving.feed.mask_uploads")
     before = {n: reg.counter(n).value() for n in names}
     wait0 = reg.histogram("serving.queue_wait_ms").summary()
     rec = get_recorder()
@@ -513,18 +513,19 @@ CHILDREN = {"serving.feed": ("serving.feed.build", "serving.feed.upload"),
 def test_feed_and_fetch_split_into_child_spans(tiny_gpt):
     """feed is build then upload, fetch is wait then copy: each child
     once an iteration, inside its parent, on the parent's thread, in
-    order; the upload's bytes are the fed host arrays', the copy's the
-    two outputs', and dispatch names the arrays its call passes."""
+    order; the upload is the one packed buffer (no guided lane, so the
+    mask stays on the device), the copy's bytes are the two outputs',
+    and dispatch names the arrays its call passes."""
     cfg, _scope, params = tiny_gpt
     prompts = [([5, 6, 7, 8, 9, 10], 4), ([3, 4], 6), ([1], 2)]
-    srv, events, _delta = _traced_run(params, cfg, prompts)
+    srv, events, delta = _traced_run(params, cfg, prompts)
     s, c = srv._sched.num_slots, srv._sched.chunk
     m = srv._sched.max_blocks
-    # tokens, positions, tables int32 and valid bool (S, C) / (S, M);
-    # the (S, V) float32 mask; do_sample bool, temperature, top_k,
-    # top_p 4 bytes a lane, the rng keys (S, 2) uint32
-    fed = 4 * s * c * 2 + s * c + 4 * s * m + 4 * s * cfg.vocab_size \
-        + s + 3 * 4 * s + 8 * s
+    # one int32 buffer: tokens, positions, valid (S, C) and tables
+    # (S, M); the rng keys (S, 2); temperature, do_sample, top_k, top_p
+    # one element a lane each
+    fed = 4 * (3 * s * c + s * m + 2 * s + 4 * s)
+    assert delta["serving.feed.mask_uploads"] == 0
     import jax
     pools = jax.tree_util.tree_leaves(srv.cache.pools)
     by_iter = {}
@@ -545,12 +546,12 @@ def test_feed_and_fetch_split_into_child_spans(tiny_gpt):
             assert second["ts"] + second["dur"] \
                 <= outer["ts"] + outer["dur"] + 0.002
         upload = spans["serving.feed.upload"][0]["args"]
-        assert upload == {"iteration": it, "arrays": 10, "bytes": fed}
+        assert upload == {"iteration": it, "arrays": 1, "bytes": fed}
         copy = spans["serving.fetch.copy"][0]["args"]
         assert copy == {"iteration": it, "arrays": 2, "bytes": 2 * 4 * s}
         (dispatch,) = spans["serving.dispatch"]
         assert dispatch["args"] == {"iteration": it,
-                                    "arguments": len(pools) + 10}
+                                    "arguments": len(pools) + 2}
         assert spans["serving.fetch.wait"][0]["args"] == {"iteration": it}
 
 

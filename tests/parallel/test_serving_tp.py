@@ -190,11 +190,9 @@ def test_tp2_fused_step_compiles_collectives_and_sharded_pools(trained):
     cfg, params = trained
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     srv = _server(params, cfg, mesh=mesh)
-    s, c = srv._sched.num_slots, srv._sched.chunk
-    m = srv._sched.max_blocks
-    args = (jnp.zeros((s, c), jnp.int32), jnp.zeros((s, c), jnp.int32),
-            jnp.zeros((s, c), bool), jnp.zeros((s, m), jnp.int32))
-    text = srv._fused.lower(srv.cache.pools, *args).compile().as_text()
+    # the feed: tokens, positions, valid and tables in one int32 buffer
+    packed = jnp.zeros((srv._feed.size,), jnp.int32)
+    text = srv._fused.lower(srv.cache.pools, packed).compile().as_text()
     assert "all-reduce" in text or "all_reduce" in text, \
         "tp fused step compiled without any all-reduce"
     kp = srv.cache.pools[0]["kv"]
