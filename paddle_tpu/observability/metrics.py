@@ -267,6 +267,11 @@ METRIC_SPECS = [
      "bytes the state layers hold over all lanes (float32 states and "
      "the short convolutions' carried rows), labeled by server; part "
      "of the kv_pool row of the HBM ledger"),
+    ("serving.kv.window_ring_bytes", "gauge",
+     "bytes the window layers' rings hold over all lanes (a ring of "
+     "blocks a lane, sized by the window and the chunk: "
+     "serving/kv_cache.ring_blocks_for), labeled by server; part of the "
+     "kv_pool row of the HBM ledger"),
     ("serving.kv.quant.pool_bytes", "gauge",
      "TRUE footprint of a quantized KV block pool: int8 codes plus the "
      "f32 per-row scale pools, across k+v and every layer (label: "

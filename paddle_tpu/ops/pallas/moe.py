@@ -43,6 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .paged import _interpret, _mxu_precision, _padded_bytes
 
 TRACE_COUNT = 0
+# an expert's gate activation, by the name `moe_experts` is given
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 # what an expert's double-buffered weights may take of VMEM before the
 # kernel takes its inner width in slices
 WHOLE_EXPERT_VMEM_BYTES = 32 << 20
@@ -110,7 +112,8 @@ def _tile_rows(tile_ref, x_ref, rank_ref, rank_c_ref, comb_ref, tile):
 
 
 def _moe_kernel(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
-                comb_ref, gu_ref, down_ref, o_ref, *, tile, inner):
+                comb_ref, gu_ref, down_ref, o_ref, *, tile, inner,
+                activation):
     """Grid step s: expert e = expert[s], the tile_of[s]-th tile of its
     tokens. x_ref (T, H) and o_ref (T, H) f32 stay in VMEM for the
     whole call; rank_ref (1, 1, T) / rank_c_ref (1, T, 1) are e's ranks
@@ -128,7 +131,8 @@ def _moe_kernel(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
                                     rank_c_ref, comb_ref, tile)
     gu = jnp.dot(xg, gu_ref[0], precision=prec,
                  preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gu[:, :inner]) * gu[:, inner:]).astype(dt)
+    act = (ACTIVATIONS[activation](gu[:, :inner])
+           * gu[:, inner:]).astype(dt)
     y = jnp.dot(act, down_ref[0], precision=prec,
                 preferred_element_type=jnp.float32)        # (tile, H)
     o_ref[...] += jnp.dot(scatter, (y * w_row).astype(dt),
@@ -138,7 +142,7 @@ def _moe_kernel(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
 
 def _moe_kernel_sliced(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
                        comb_ref, gate_ref, up_ref, down_ref, o_ref, *,
-                       tile):
+                       tile, activation):
     """Grid step (s, j): as `_moe_kernel`, over slice j of the expert's
     inner width: gate_ref and up_ref (1, H, inner / n), down_ref
     (1, inner / n, H). The slices' products add up in o_ref."""
@@ -156,15 +160,18 @@ def _moe_kernel_sliced(expert_ref, tile_ref, x_ref, rank_ref, rank_c_ref,
                    preferred_element_type=jnp.float32)
     up = jnp.dot(xg, up_ref[0], precision=prec,
                  preferred_element_type=jnp.float32)
-    y = jnp.dot((jax.nn.silu(gate) * up).astype(dt), down_ref[0],
+    y = jnp.dot((ACTIVATIONS[activation](gate) * up).astype(dt),
+                down_ref[0],
                 precision=prec, preferred_element_type=jnp.float32)
     o_ref[...] += jnp.dot(scatter, (y * w_row).astype(dt),
                           precision=prec,
                           preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _moe_call(x, sel, comb, w_gu, w_down, *, tile, interpret):
+@functools.partial(jax.jit, static_argnames=("tile", "activation",
+                                             "interpret"))
+def _moe_call(x, sel, comb, w_gu, w_down, *, tile, activation,
+              interpret):
     t, h = x.shape
     e, _h, inner2 = w_gu.shape
     steps, expert, tile_of, rank = _plan_groups(sel, tile)
@@ -187,14 +194,14 @@ def _moe_call(x, sel, comb, w_gu, w_down, *, tile, interpret):
             of_expert(comb_t.shape)]
     if n == 1:
         grid, kernel = (steps,), functools.partial(
-            _moe_kernel, tile=tile, inner=inner)
+            _moe_kernel, tile=tile, inner=inner, activation=activation)
         weights = [of_expert(w_gu.shape), of_expert(w_down.shape)]
         operands = (w_gu, w_down)
     else:
         # slice j of the inner width: gate columns [j], up columns
         # [n + j] of the side-by-side array, down rows [j]
         grid, kernel = (steps, n), functools.partial(
-            _moe_kernel_sliced, tile=tile)
+            _moe_kernel_sliced, tile=tile, activation=activation)
         weights = [
             pl.BlockSpec((1, h, width), lambda s, j, ex, tl: (ex[s], 0, j)),
             pl.BlockSpec((1, h, width),
@@ -223,7 +230,8 @@ def _moe_call(x, sel, comb, w_gu, w_down, *, tile, interpret):
     )(expert, tile_of, x, rank, rank_c, comb_t, *operands)
 
 
-def moe_experts(x, sel, comb, w_gu, w_down, tile=None, interpret=None):
+def moe_experts(x, sel, comb, w_gu, w_down, tile=None, interpret=None,
+                activation="silu"):
     """sum over held experts e of comb[t, e] * E_e(x[t]), for the
     tokens `sel` sends to e:
 
@@ -232,7 +240,8 @@ def moe_experts(x, sel, comb, w_gu, w_down, tile=None, interpret=None):
         comb:   (T, E) f32 combine weights (read where sel)
         w_gu:   (E, H, 2 * I): an expert's gate and up side by side
         w_down: (E, I, H)
-        returns (T, H) float32; E_e(h) = (silu(h gate) * (h up)) down
+        returns (T, H) float32; E_e(h) = (act(h gate) * (h up)) down,
+                act `activation`: "silu" (SwiGLU) or "relu" (ReGLU)
 
     An expert's tokens are computed in the activations' type with
     float32 accumulation, weighted in float32, and summed over the
@@ -245,5 +254,8 @@ def moe_experts(x, sel, comb, w_gu, w_down, tile=None, interpret=None):
         tile = min(32, -(-t // 8) * 8)
     if interpret is None:
         interpret = _interpret()
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r}: expected one of "
+                         f"{sorted(ACTIVATIONS)}")
     return _moe_call(x, sel, comb, w_gu, w_down, tile=int(tile),
-                     interpret=bool(interpret))
+                     activation=activation, interpret=bool(interpret))

@@ -69,6 +69,13 @@ block is one DMA, not two. Two generations share that walk:
   performs in one pass, so v2 is pinned allclose-at-f32-tightness plus
   argmax-identical — v1 remains the bitwise-stable kernel and the
   dispatcher's default for tables under the VMEM ceiling.
+* the **windowed walk** (v2 given a `window`, traced under the name
+  `paged_window_attention`) is v2 bounded by a sliding window W: a
+  lane's walk starts at the group that holds its first query's
+  position less W - 1, and a key
+  past a query's window is masked beside the causal mask. Over a window
+  layer's ring (`serving/kv_cache.ring_table`) a lane at any context
+  reads about W keys.
 
 Both kernels share the serving contract:
 
@@ -199,12 +206,16 @@ def walk_rung(bs, m):
     return -(-(-(-m // walk_group(bs, m))) // 8)
 
 
-def _plan_walk(block_table, q_positions, bs, p):
+def _plan_walk(block_table, q_positions, bs, p, window=None):
     """The walk, as small int32 arrays the kernels read from SMEM
     (plain XLA on the table and the positions: every layer of a step
     shares them, so a step computes them once). A grid step is one LIVE
     group of p table columns of one lane; column j * p + i of a lane is
-    the i-th block of its group j. Returns (steps, plan):
+    the i-th block of its group j. With a `window` W, a column below
+    the one that holds `q_positions[b, 0] - W + 1` (the lane's first
+    query's oldest visible key: the scheduler puts a lane's lowest
+    position in its column 0) is dead too, and a lane's walk starts at
+    the group that holds that column. Returns (steps, plan):
 
     steps (): how many grid steps the call takes, the sum of the lanes'
         live groups (an idle lane takes one, to write its zeros): the
@@ -236,12 +247,21 @@ def _plan_walk(block_table, q_positions, bs, p):
                             constant_values=NULL_BLOCK)[:, :g * p]
     fetch = jnp.where(col <= last, tbl, a_step_before)
     live = (col <= last) & (tbl != NULL_BLOCK)
+    if window is not None:
+        below = jnp.maximum(
+            q_positions[:, 0].astype(jnp.int32) - window + 1, 0) // bs
+        live &= col >= below[:, None]
     groups = jnp.where(live.any(axis=1), (n_live + p - 1) // p, 0)
     steps = jnp.maximum(groups, 1)
+    if window is not None:
+        first = jnp.minimum(below // p, jnp.maximum(groups - 1, 0))
+        steps = jnp.maximum(groups - first, 1)
     step = jnp.arange(b * g, dtype=jnp.int32)
     done = step[:, None] >= jnp.cumsum(steps)[None]     # (steps, lanes)
     lane = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), b - 1)
     group = step - jnp.sum(jnp.where(done, steps[None], 0), axis=1)
+    if window is not None:
+        group = group + first[lane]
     return jnp.sum(steps), (lane, group, fetch, live.astype(jnp.int32),
                             groups)
 
@@ -463,7 +483,8 @@ def _paged_call(q, kv_pool, scales, block_table, q_positions, *, name,
     in_specs = [lane_spec(q.shape)]
     for pool in pools:
         in_specs += _page_specs(pool.shape, group)
-    steps, plan = _plan_walk(block_table, q_positions, bs, group)
+    steps, plan = _plan_walk(block_table, q_positions, bs, group,
+                             window=dict(statics).get("window"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,          # the plan's five, positions
         grid=(steps,),
@@ -566,7 +587,7 @@ def _v2_scratch_shapes(h, hp, c, d, t, pool_dtype):
 
 def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
                      groups_ref, pos_ref, q_ref, *rest, bs, m, p, h, hp,
-                     d, quantized=False):
+                     d, quantized=False, window=None):
     """Grid step s: lane b = lane[s], its j-th live GROUP of p table
     columns (j = group[s]), every KV head. q_ref (1, H_kv, g*C, D): the
     lane's queries with the g = H / H_kv query heads of a KV head as
@@ -597,7 +618,12 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
       `where(mask, exp(s - m_new), 0)`, never from the bare exp;
     * an idle lane finishes with l == 0; dividing by
       `where(l > 0, l, 1)` lands an exact 0 output instead of NaN (the
-      engine's non-finite-logits guard sums every lane's logps)."""
+      engine's non-finite-logits guard sums every lane's logps).
+
+    With a `window` W a key is visible to a query at position i where
+    0 <= i - key < W, and a lane's walk
+    starts at the group `_plan_walk` names, not at group 0: the carry
+    starts at the lane's first step."""
     del fetch_ref, m                        # read by the index maps
     kv_refs, rest = rest[:p], rest[p:]
     if quantized:
@@ -608,8 +634,12 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
     c = pos_ref.shape[1]
     rows, t = (h // hp) * c, p * bs
     n_groups = groups_ref[b]
+    if window is None:
+        starts = j == 0
+    else:
+        starts = (step == 0) | (lane_ref[jnp.maximum(step - 1, 0)] != b)
 
-    @pl.when(j == 0)
+    @pl.when(starts)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -654,7 +684,10 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
         for i in range(1, p):
             dead = jnp.where(key >= i * bs, 1 - live_ref[b, j * p + i],
                              dead)
-        mask = ((j * t + key <= q_pos) & (dead == 0))[None]
+        mask = (j * t + key <= q_pos) & (dead == 0)
+        if window is not None:
+            mask &= q_pos - (j * t + key) < window
+        mask = mask[None]
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -681,7 +714,7 @@ def _paged_kernel_v2(lane_ref, group_ref, fetch_ref, live_ref,
 
 def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
                               k_scale=None, v_scale=None,
-                              interpret=None):
+                              interpret=None, window=None):
     """Paged attention kernel v2: a group walk with a flash-style
     online softmax. Identical call contract to
     `ragged_paged_attention` (v1); the difference is the resource
@@ -691,7 +724,19 @@ def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
     output cast once at the end. v2 is mathematically exact vs the
     reference but reorders its fp reductions (per-group partial sums +
     rescales), so the tier-1 pin is tight-allclose + argmax-identical
-    rather than v1's bitwise."""
+    rather than v1's bitwise.
+
+    With a `window` W (sliding-window attention) a key is visible to a
+    query at position i only where 0 <= i - key < W, and the call
+    traces under a name of its own, `paged_window_attention`. The walk
+    starts at the group holding the lane's first query position less
+    W - 1 (`_plan_walk`), so a lane at any context reads about W keys a
+    layer; the blocks of the table below that group are never read,
+    which is what lets a window layer's ring of R blocks
+    (`serving/kv_cache.ring_table`: column j of lane s names ring block
+    j mod R of the lane) with R * bs >= W + C - 1 (plus the block a
+    window's start may fall inside) stand for the whole table: its
+    older blocks have been overwritten by then."""
     global TRACE_COUNT, V2_TRACE_COUNT
     TRACE_COUNT += 1
     V2_TRACE_COUNT += 1
@@ -708,11 +753,16 @@ def ragged_paged_attention_v2(q, kv_pool, block_table, q_positions,
     vmem = (sum(_padded_bytes(shp, dt) for shp, dt in scratch)
             + 4 * _padded_bytes((hp, rows, t), jnp.float32)
             + 2 * _padded_bytes((hp, t, d), jnp.float32))
+    statics = _kv_statics(h, hp, d, quantized)
+    name = "paged_attention_v2"
+    if window is not None:
+        statics += (("window", int(window)),)
+        name = "paged_window_attention"
     out = _paged_call(q.reshape(b, hp, rows, d), kv_pool,
                       [k_scale, v_scale] if quantized else [],
                       block_table, q_positions,
-                      name="paged_attention_v2", kernel=_paged_kernel_v2,
-                      statics=_kv_statics(h, hp, d, quantized),
+                      name=name, kernel=_paged_kernel_v2,
+                      statics=statics,
                       group=group, scratch=tuple(scratch),
                       out_shape=(b, hp, rows, d), out_dtype=out_dtype,
                       vmem_bytes=vmem,

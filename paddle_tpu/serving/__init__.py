@@ -92,6 +92,7 @@ from .guided import (ChoiceConstraint, Constraint, JsonConstraint,
 from .engine import GenerationFuture, GenerationServer, GPTServingModel
 from .latent_moe import LatentMoEServingModel
 from .linear_moe import LinearMoEServingModel
+from .smallthinker import SmallThinkerServingModel
 from .spec_decode import SpecDecodeConfig
 from .replica import Replica
 from .router import (AdmissionPolicy, AdmissionRejected, FleetFuture,
@@ -112,6 +113,7 @@ __all__ = [
     "DeadlineExceeded", "RequestCancelled",
     "GenerationServer", "GenerationFuture", "GPTServingModel",
     "LatentMoEServingModel", "LinearMoEServingModel",
+    "SmallThinkerServingModel",
     "Replica", "FleetRouter", "FleetFuture", "RouterPolicy",
     "AdmissionPolicy", "AdmissionRejected",
     "WorkerProxy", "make_subprocess_spawn", "spawn_worker",

@@ -11,17 +11,24 @@ of each head, latent attention over a one-row-a-token pool, gated or
 expert MLP, untied head); the linear-attention / mixture-of-experts
 family (`serving/linear_moe.py`) a third (no positions at all, a
 pattern of gated grouped-query layers over a K-beside-V pool and
-delta-rule layers over a state a lane, expert MLPs, untied head). A
-new family adds entries here and a spec; the step, the scheduler and
-the cache manager do not change.
+delta-rule layers over a state a lane, expert MLPs, untied head); the
+window / mixture-of-experts family (`serving/smallthinker.py`) a
+fourth (grouped-query layers that are either windowed with half-split
+rotary positions over a ring a lane, or full with none, softmax-routed
+ReLU-gated experts whose router reads the attention's input, untied
+head). A new family adds entries here and a spec; the step, the
+scheduler and the cache manager do not change.
 
 Every attention kind takes (ctx, hn, lp, layer_pools) and returns (the
 residual's addend, the layer's rewritten pools); every MLP kind takes
-(ctx, hn, lp) and returns (the addend, its counts or None). `ctx` is
-what the step computed once for all layers (`StepContext`).
+(ctx, hn, lp, attn_in), attn_in being what the layer's attention read
+(a router placed before the attention reads it), and returns (the
+addend, its counts or None). `ctx` is what the step computed once for
+all layers (`StepContext`).
 """
 
 import collections
+import functools
 
 import numpy as np
 
@@ -35,9 +42,10 @@ from .kv_cache import (fuse_kv, kda_chunk, paged_attention,
 from .moe import expert_share, step_stats
 
 __all__ = ["LayerSpec", "StepSpec", "StepContext", "NORMS", "ATTENTIONS",
-           "MLPS", "STATE_ATTENTIONS", "STATE_STATS", "fold_counts",
-           "state_counts", "rms_norm", "rotary_angles",
-           "rotate_interleaved", "short_conv"]
+           "MLPS", "STATE_ATTENTIONS", "STATE_STATS", "RING_ATTENTIONS",
+           "WINDOW_STATS", "fold_counts", "state_counts", "window_counts",
+           "rms_norm", "rotary_angles", "rotate_interleaved",
+           "rotate_half", "short_conv"]
 
 # one layer: the names of its norm, attention and MLP kinds
 LayerSpec = collections.namedtuple("LayerSpec", "norm attention mlp")
@@ -60,12 +68,15 @@ StepSpec = collections.namedtuple(
 # a shard_map is around, the int8-weight accessor, the rotary angles,
 # and what a state layer reads: each lane's count of valid columns (a
 # prefix of its C) and whether the lane STARTS a request in this step
-# (position 0 in its first valid column: the layer zeroes its state)
-# (None for a caller that builds a context for a kind that keeps none)
+# (position 0 in its first valid column: the layer zeroes its state),
+# and what a window layer reads: its ring's table and write targets
+# (`kv_cache.ring_table`) (None for a caller that builds a context for
+# a kind that keeps none)
 StepContext = collections.namedtuple(
     "StepContext", "spec s c xdt pos bidx off valid tables reduce_fn "
-                   "in_shard_map w angles counts starts",
-    defaults=(None, None))
+                   "in_shard_map w angles counts starts ring_tables "
+                   "ring_bidx",
+    defaults=(None, None, None, None))
 
 
 def rms_norm(x, scale, eps):
@@ -97,6 +108,17 @@ def rotate_interleaved(x, cos, sin):
     out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
                     axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotate_half(x, cos, sin):
+    """Rotary embedding in the HALF-SPLIT convention: the pair (x[i],
+    x[i + dim / 2]) is turned by the i-th angle. Shapes and types as
+    `rotate_interleaved`."""
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    lo, hi = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           axis=-1).astype(x.dtype)
 
 
 # -- norms: (spec, x, params, name) -> normed x -------------------------
@@ -182,24 +204,41 @@ def _attention_latent(ctx, hn, lp, pools):
     return (o @ lp["wo"]).astype(ctx.xdt), {"kv": pool}
 
 
-def _attention_gqa_gated(ctx, hn, lp, pools):
-    """Grouped-query attention with NO positional encoding and no
-    bias, over a K-beside-V pool (N, H_kv, bs, 2 * head_dim): the
-    causal order is all the position there is. The heads' outputs are
-    multiplied by `sigmoid(h w_gate)`, one gate a value channel, before
-    the output projection."""
+def _attention_gqa(ctx, hn, lp, pools, gated=False, windowed=False):
+    """Grouped-query attention with no bias, `q = h wq`, `[k | v] = h
+    wkv`, over a K-beside-V pool (N, H_kv, bs, 2 * head_dim):
+
+    * full (`gqa`, `gqa_gated`): no positional encoding, the causal
+      order is all the position there is; the pool is the allocator's
+      blocks, walked by `paged_attention`;
+    * windowed (`gqa_window`): q and k turned by half-split rotary
+      angles (`rotate_half`) over the whole head, a key visible only
+      within `dims["window"]` positions of the query; the pool is the
+      lane's ring (`ctx.ring_tables`), walked by
+      `paged_attention(window=)`.
+
+    `gated` (`gqa_gated`) multiplies the heads' outputs by `sigmoid(h
+    w_gate)`, one gate a value channel, before the output
+    projection."""
     s, c = ctx.s, ctx.c
     heads, kv_heads, d = (ctx.spec.heads, ctx.spec.kv_heads,
                           ctx.spec.head_dim)
     q = (hn @ lp["wq"]).reshape(s, c, heads, d)
     kv = (hn @ lp["wkv"]).reshape(s, c, 2, kv_heads, d)
-    pool = write_block_kv(pools["kv"], fuse_kv(kv[:, :, 0], kv[:, :, 1]),
-                          ctx.bidx, ctx.off)
-    o = paged_attention(q.transpose(0, 2, 1, 3), pool, ctx.tables,
-                        ctx.pos, in_shard_map=ctx.in_shard_map)
+    k, v = kv[:, :, 0], kv[:, :, 1]
+    tables, bidx, window = ctx.tables, ctx.bidx, None
+    if windowed:
+        cos, sin = (a[:, :, None] for a in ctx.angles)
+        q, k = rotate_half(q, cos, sin), rotate_half(k, cos, sin)
+        tables, bidx = ctx.ring_tables, ctx.ring_bidx
+        window = ctx.spec.dims["window"]
+    pool = write_block_kv(pools["kv"], fuse_kv(k, v), bidx, ctx.off)
+    o = paged_attention(q.transpose(0, 2, 1, 3), pool, tables, ctx.pos,
+                        in_shard_map=ctx.in_shard_map, window=window)
     o = o.transpose(0, 2, 1, 3).reshape(s, c, heads * d)
-    gate = jax.nn.sigmoid((hn @ lp["w_gate"]).astype(jnp.float32))
-    o = (o.astype(jnp.float32) * gate).astype(hn.dtype)
+    if gated:
+        gate = jax.nn.sigmoid((hn @ lp["w_gate"]).astype(jnp.float32))
+        o = (o.astype(jnp.float32) * gate).astype(hn.dtype)
     return (o @ lp["wo"]).astype(ctx.xdt), {"kv": pool}
 
 
@@ -264,13 +303,21 @@ def _attention_kda(ctx, hn, lp, pools):
     return (o @ lp["wo"]).astype(ctx.xdt), {"state": state, "conv": conv}
 
 
-ATTENTIONS = {"mha": _attention_mha, "latent": _attention_latent,
-              "gqa_gated": _attention_gqa_gated, "kda": _attention_kda}
+ATTENTIONS = {
+    "mha": _attention_mha, "latent": _attention_latent,
+    "gqa_gated": functools.partial(_attention_gqa, gated=True),
+    "gqa": _attention_gqa,
+    "gqa_window": functools.partial(_attention_gqa, windowed=True),
+    "kda": _attention_kda}
 
 # the kinds whose cache is a state a lane, and what a step counts of
 # them (`state_counts`)
 STATE_ATTENTIONS = ("kda",)
 STATE_STATS = ("lane_calls", "columns", "resets")
+# the kinds whose cache is a ring a lane (`kv_cache.ring_table`), and
+# what a step counts of them (`window_counts`)
+RING_ATTENTIONS = ("gqa_window",)
+WINDOW_STATS = ("lane_calls", "key_positions")
 
 
 def state_counts(ctx, layers):
@@ -284,28 +331,52 @@ def state_counts(ctx, layers):
         jnp.sum(ctx.starts, dtype=jnp.int32)])
 
 
+def window_counts(ctx, layers):
+    """A step's counts over its `layers` window layers (`WINDOW_STATS`,
+    int32): (lane, layer) pairs with a valid column, each a walk of the
+    lane's ring; and the key positions those walks needed, summed over
+    the layers: from the lane's first query position less the window
+    (never below 0) to its last."""
+    window = ctx.spec.dims["window"]
+    first = ctx.pos[:, 0]
+    keys = jnp.where(
+        ctx.counts > 0,
+        first + ctx.counts - jnp.maximum(first - window + 1, 0), 0)
+    return jnp.stack([
+        layers * jnp.sum(ctx.counts > 0, dtype=jnp.int32),
+        layers * jnp.sum(keys, dtype=jnp.int32)])
+
+
 # -- MLPs ---------------------------------------------------------------
 
-def _mlp_gelu(ctx, hn, lp):
+def _mlp_gelu(ctx, hn, lp, attn_in):
     w = ctx.w
     f = jax.nn.gelu(hn @ w(lp, "f0w") + lp["f0b"], approximate=False)
     return (ctx.reduce_fn(f @ w(lp, "f1w")) + lp["f1b"]).astype(
         ctx.xdt), None
 
 
-def _mlp_gated(ctx, hn, lp):
+def _mlp_gated(ctx, hn, lp, attn_in):
     f = jax.nn.silu(hn @ lp["w_gate"]) * (hn @ lp["w_up"])
     return (f @ lp["w_down"]).astype(ctx.xdt), None
 
 
-def _mlp_experts(ctx, hn, lp):
+def _mlp_experts(ctx, hn, lp, attn_in):
     """This chip's share of a routed expert layer (`moe.expert_share`):
-    padded columns are routed nowhere."""
+    padded columns are routed nowhere. `dims` may say how the router
+    scores (`scoring`, sigmoid by default), the experts' gate
+    activation (`expert_act`, silu by default), and that the router
+    reads the attention's input (`router_input`: "attention")."""
     dm = ctx.spec.dims
+    router_h = None
+    if dm.get("router_input") == "attention":
+        router_h = attn_in.reshape(ctx.s * ctx.c, -1)
     out, stats = expert_share(
         hn.reshape(ctx.s * ctx.c, -1), lp, ctx.valid.reshape(-1),
         k=dm["experts_per_tok"], scaling=dm["routed_scaling"],
-        normalize=dm["norm_topk_prob"], offset=dm["expert_offset"])
+        normalize=dm["norm_topk_prob"], offset=dm["expert_offset"],
+        scoring=dm.get("scoring", "sigmoid"),
+        activation=dm.get("expert_act", "silu"), router_h=router_h)
     return out.reshape(hn.shape).astype(ctx.xdt), stats
 
 

@@ -42,9 +42,10 @@ from ..observability import _help
 from ..observability.metrics import global_registry
 from ..observability.tracing import get_recorder
 from . import kv_cache as _kvc
-from .blocks import (ATTENTIONS, MLPS, NORMS, STATE_ATTENTIONS, LayerSpec,
-                     StepContext, StepSpec, fold_counts, rotary_angles,
-                     state_counts)
+from .blocks import (ATTENTIONS, MLPS, NORMS, RING_ATTENTIONS,
+                     STATE_ATTENTIONS, LayerSpec, StepContext, StepSpec,
+                     fold_counts, rotary_angles, state_counts,
+                     window_counts)
 from .decode_strategies import (GroupFuture, RequestGroup,
                                 SamplingParams, gumbel_noise)
 from .feed import FeedLayout
@@ -175,7 +176,14 @@ def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
     output, last: the step's counts, an int32 vector folded over those
     layers (`blocks.fold_counts`), which the model names
     (`step_counters`). A spec with state layers (`STATE_ATTENTIONS`)
-    appends theirs (`blocks.state_counts`) to that vector.
+    appends theirs (`blocks.state_counts`) to that vector, and one with
+    window layers (`RING_ATTENTIONS`) theirs (`blocks.window_counts`).
+
+    A window layer's pool is the lanes' rings: its table comes from
+    lane and position (`kv_cache.ring_table`, R read off the first such
+    layer's pool), computed once for all such layers. Each MLP kind is
+    handed what its layer's attention read, for a router placed before
+    the attention.
 
     Quantized serving (ISSUE 14) rides the same body: a layer dict
     carrying "k_scale"/"v_scale" pools takes the quantize-at-write path
@@ -214,17 +222,27 @@ def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
     # a lane whose first one holds position 0 starts a request
     lane_cols = jnp.sum(valid, axis=1, dtype=jnp.int32)
     starts = valid[:, 0] & (positions[:, 0] == 0)
+    ring = ring_bidx = None
+    ring_layers = [i for i, layer in enumerate(spec.layers)
+                   if layer.attention in RING_ATTENTIONS]
+    if ring_layers:
+        ring = _kvc.ring_table(
+            tables, (pools[ring_layers[0]]["kv"].shape[0] - 1) // s)
+        ring_bidx = jnp.where(
+            valid, jnp.take_along_axis(ring, pos // block_size, axis=1),
+            NULL_BLOCK)
     ctx = StepContext(spec, s, c, x.dtype, pos, bidx, off, valid, tables,
                       reduce_fn, in_shard_map, w, angles, lane_cols,
-                      starts)
+                      starts, ring, ring_bidx)
     new_pools, counts = [], []
     for i, layer in enumerate(spec.layers):
         lp = params[f"l{i}"]
         norm = NORMS[layer.norm]
-        a, layer_pools = ATTENTIONS[layer.attention](
-            ctx, norm(spec, x, lp, "ln1"), lp, pools[i])
+        hn = norm(spec, x, lp, "ln1")
+        a, layer_pools = ATTENTIONS[layer.attention](ctx, hn, lp,
+                                                     pools[i])
         x = x + a
-        f, stats = MLPS[layer.mlp](ctx, norm(spec, x, lp, "ln2"), lp)
+        f, stats = MLPS[layer.mlp](ctx, norm(spec, x, lp, "ln2"), lp, hn)
         x = x + f
         new_pools.append(layer_pools)
         if stats is not None:
@@ -237,7 +255,8 @@ def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
     state_layers = sum(layer.attention in STATE_ATTENTIONS
                        for layer in spec.layers)
     vectors = ([fold_counts(counts)] if counts else []) + (
-        [state_counts(ctx, state_layers)] if state_layers else [])
+        [state_counts(ctx, state_layers)] if state_layers else []) + (
+        [window_counts(ctx, len(ring_layers))] if ring_layers else [])
     if vectors:
         out += (jnp.concatenate(vectors),)
     return out
@@ -592,13 +611,21 @@ class GenerationServer:
                 f"pools shard on the KV head axis (with GQA that is "
                 f"H_kv={kv_heads}, not the {model.num_heads} query "
                 f"heads)")
-        # a model with a state layer (a recurrent state a lane beside
-        # the paged cache): everything that copies, shares or moves
-        # cache by block cannot carry the state yet
+        # a model with a layer whose cache a lane owns (a recurrent
+        # state, or a window layer's ring of blocks, beside the paged
+        # cache): everything that copies, shares or moves cache by
+        # block cannot carry it yet. A window layer's ring is sized
+        # here, where the chunk is known (`kv_cache.ring_blocks_for`)
         geometry = getattr(model, "kv_geometry", None)
-        self._state_model = any(isinstance(g, dict)
-                                for g in geometry or ())
-        if self._state_model:
+        if geometry is not None:
+            geometry = [
+                dict(g, ring_blocks=_kvc.ring_blocks_for(
+                    g["window"], chunk, self.block_size))
+                if isinstance(g, dict) and "window" in g else g
+                for g in geometry]
+        # (what a lane owns, where ROADMAP says what is left) or None
+        self._lane_owned = _kvc.lane_owned(geometry)
+        if self._lane_owned:
             for asked, what in (
                     (prefix_cache, "prefix_cache (reuse of a prefix's "
                      "blocks needs a snapshot of the state at a block "
@@ -616,8 +643,8 @@ class GenerationServer:
                 if asked:
                     raise NotImplementedError(
                         f"{what} is not supported for a model with a "
-                        f"state layer ({type(model).__name__}): "
-                        f"ROADMAP R5")
+                        f"{self._lane_owned[0]} ({type(model).__name__}):"
+                        f" {self._lane_owned[1]}")
         max_context = int(max_context or model.max_position)
         if max_context > model.max_position:
             raise ValueError(
@@ -931,13 +958,21 @@ class GenerationServer:
                     server=self._ledger_id).set(val)
         # what the state layers hold over all lanes (0 series without
         # one): retired on close like the gauges above
+        # (and a window ring's, `serving.kv.window_ring_bytes`)
         self._state_gauge = None
-        if self._state_model:
+        if self.cache.state_layers:
             self._state_gauge = global_registry().gauge(
                 "serving.state.bytes",
                 _help("serving.state.bytes")).labels(
                     server=self._ledger_id)
             self._state_gauge.set(self.cache.state_bytes())
+        self._ring_gauge = None
+        if self.cache.ring_layers:
+            self._ring_gauge = global_registry().gauge(
+                "serving.kv.window_ring_bytes",
+                _help("serving.kv.window_ring_bytes")).labels(
+                    server=self._ledger_id)
+            self._ring_gauge.set(self.cache.ring_bytes())
         # host-tier gauges (serving.kv.tier.*): the tier's capacity
         # plus its cumulative traffic (spills/swap-ins/preempts/
         # resumes/re-prefills avoided), server-labeled and re-published
@@ -1112,12 +1147,12 @@ class GenerationServer:
             raise ValueError(
                 "guided decoding requires eos_id (constraint "
                 "completion is signalled by unmasking eos)")
-        if k > 1 and self._state_model:
+        if k > 1 and self._lane_owned:
             raise NotImplementedError(
-                "fork groups (n > 1, beam) are not supported for a model "
-                "with a state layer: the lanes of a group share the "
-                "prompt's blocks, and a lane's state is not copied to "
-                "its siblings (ROADMAP R5)")
+                f"fork groups (n > 1, beam) are not supported for a "
+                f"model with a {self._lane_owned[0]}: the lanes of a "
+                f"group share the prompt's blocks, and what a lane owns "
+                f"is not copied to its siblings ({self._lane_owned[1]})")
         if k > 1:
             if k > self._sched.num_slots:
                 raise ValueError(
@@ -1932,6 +1967,10 @@ class GenerationServer:
             reg.gauge("serving.state.bytes").remove(
                 server=self._ledger_id)
             self._state_gauge = None
+        if self._ring_gauge is not None:
+            reg.gauge("serving.kv.window_ring_bytes").remove(
+                server=self._ledger_id)
+            self._ring_gauge = None
 
     def get_stats(self):
         """Scheduler + engine stats; `fused_step_signatures` is the jit

@@ -73,6 +73,17 @@ block rewriter here REFUSES a cache that has one (ROADMAP R5 says what
 each would need), and the byte counts add the lanes' arrays.
 `kda_chunk` is that layer's dispatcher, counted like the paged ones.
 
+Window rings: a layer of sliding-window attention reads the last W
+positions of a lane and no others, so its keys and values live in a
+ring of R blocks the lane owns, R * bs >= W + C + bs - 2
+(`ring_blocks_for`), and not in blocks from the allocator. A step
+derives the ring's table from lane and position (`ring_table`), writes
+through it as any layer writes through its table, and walks it with
+`paged_attention(window=)`, which starts at the window's first block. A
+new request on a lane reads only the positions it has written: the
+walk never reaches past the lane's own positions, whatever the ring
+held before. Like a state, a ring is refused by every block rewriter.
+
 `PagedDecodeLayer` adapts a layer's pool to the dense mapping
 interface `decoding.py` step_fns consume (`cache[i]["k"]`,
 `update_kv_cache`), so an existing step_fn decodes against either cache
@@ -124,7 +135,8 @@ __all__ = ["PagedKVCache", "HostKVTier", "PagedDecodeLayer",
            "paged_attention", "fuse_kv", "split_kv", "KV_LAYOUT",
            "paged_attention_reference", "gather_block_kv",
            "paged_latent_attention", "paged_latent_attention_reference",
-           "kda_chunk",
+           "ring_table", "ring_blocks_for",
+           "lane_owned", "kda_chunk",
            "gather_block_kv_pair", "gather_block_scales",
            "build_paged_decode_cache", "quantize_kv_rows",
            "write_block_kv_quant",
@@ -230,7 +242,7 @@ def quantize_kv_rows(vals):
 
 
 def paged_attention_reference(q, kv_pool, block_table, q_positions,
-                              k_scale=None, v_scale=None):
+                              k_scale=None, v_scale=None, window=None):
     """Pure-JAX paged attention: gather blocks by table, mask keys
     beyond each query's position, softmax in f32, weighted sum.
 
@@ -261,7 +273,12 @@ def paged_attention_reference(q, kv_pool, block_table, q_positions,
     REPEATED across each query-head group — pure copies, so this is
     bitwise-identical to running the dense math against a pool that
     physically stored each KV head H/H_kv times (the repeat-KV
-    equivalence the GQA tests pin)."""
+    equivalence the GQA tests pin).
+
+    `window` W (sliding-window attention, the window walk's spec): a
+    key is visible to a query at position i only where
+    i - key < W as well, which hides whatever a table column below the
+    window holds (a ring block since rewritten)."""
     d = q.shape[-1]
     h, hp = q.shape[1], kv_pool.shape[1]
     if hp > h or h % hp or kv_pool.shape[3] != 2 * d:
@@ -295,10 +312,7 @@ def paged_attention_reference(q, kv_pool, block_table, q_positions,
             gv = jnp.repeat(gv, rep, axis=1)
         s = jnp.einsum("bhcd,bhtd->bhct", q.astype(jnp.float32),
                        gk) / np.sqrt(d)
-        t = gk.shape[2]
-        key_pos = jnp.arange(t)
-        mask = (key_pos[None, None, None, :]
-                <= q_positions[:, None, :, None])
+        mask = _visible(gk.shape[2], q_positions, window)
         s = jnp.where(mask, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
         return jnp.einsum("bhct,bhtd->bhcd", p, gv)
@@ -307,12 +321,49 @@ def paged_attention_reference(q, kv_pool, block_table, q_positions,
         gk = jnp.repeat(gk, rep, axis=1)
         gv = jnp.repeat(gv, rep, axis=1)
     s = jnp.einsum("bhcd,bhtd->bhct", q, gk) / np.sqrt(d)
-    t = gk.shape[2]
-    key_pos = jnp.arange(t)
-    mask = key_pos[None, None, None, :] <= q_positions[:, None, :, None]
+    mask = _visible(gk.shape[2], q_positions, window)
     s = jnp.where(mask, s.astype(jnp.float32), NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(gv.dtype)
     return jnp.einsum("bhct,bhtd->bhcd", p, gv)
+
+
+def _visible(t, q_positions, window):
+    """(B, 1, C, T) bool: key position k is visible to the query at
+    position i where k <= i (and i - k < window, where one is given)."""
+    key_pos = jnp.arange(t)[None, None, None, :]
+    q_pos = q_positions[:, None, :, None]
+    mask = key_pos <= q_pos
+    if window is not None:
+        mask &= q_pos - key_pos < window
+    return mask
+
+
+def ring_blocks_for(window, chunk, block_size):
+    """Blocks a lane's ring must hold for a window layer: a step's walk
+    reads from the block holding its first query's position less
+    window - 1 to the block holding its last column, at most
+    ceil((window + chunk + block_size - 2) / block_size) of them, which
+    must all be distinct blocks of the ring; rounded up to whole walk
+    groups (`ops/pallas/paged.walk_group`). 4096 / 16 / 16 -> 264."""
+    from ..ops.pallas.paged import walk_group
+    need = -(-(int(window) + int(chunk) + int(block_size) - 2)
+             // int(block_size))
+    p = walk_group(block_size, need)
+    return -(-need // p) * p
+
+
+def ring_table(tables, ring_blocks):
+    """A window layer's table, derived from lane and position: column j
+    of lane s names block 1 + s * R + (j mod R) of the ring pool (block
+    0 is NULL), wherever the lane's own table holds a block there, and
+    NULL where it holds none (an idle lane, columns past the lane's
+    reservation). tables (S, M) int32 -> (S, M) int32."""
+    s, m = tables.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s, m), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (s, m), 1)
+    return jnp.where(tables != NULL_BLOCK,
+                     1 + lane * ring_blocks + col % ring_blocks,
+                     NULL_BLOCK).astype(jnp.int32)
 
 
 def paged_kernel_mode():
@@ -417,7 +468,8 @@ def _record_dispatch(kernel, reason=None, version=None, name=None):
         c.inc()                             # unlabeled aggregate
         if version or not name:
             # a walk over a block table has a generation; a kernel that
-            # walks none (`kda_chunk`) is counted by its name alone
+            # walks none (`kda_chunk`), or whose generation its name
+            # fixes (the window walk), by its name alone
             version = version or "v1"
             KERNEL_VERSIONS[version] = KERNEL_VERSIONS.get(version,
                                                            0) + 1
@@ -476,7 +528,8 @@ def _dispatch(supported, operands, reference, kernel,
 
 
 def paged_attention(q, kv_pool, block_table, q_positions,
-                    k_scale=None, v_scale=None, *, in_shard_map=False):
+                    k_scale=None, v_scale=None, *, in_shard_map=False,
+                    window=None):
     """Paged attention dispatcher — the frozen serving contract.
 
     Routes to the Pallas ragged paged attention kernel
@@ -499,14 +552,27 @@ def paged_attention(q, kv_pool, block_table, q_positions,
     operands falls back (reason unsupported_under_shard_map) instead of
     raising — a ValueError mid-shard_map-trace surfaces as transform
     internals, not as this dispatcher's message. Plain force-mode
-    misuse still raises loudly."""
+    misuse still raises loudly.
+
+    `window` W (a window layer: a key visible only where 0 <= i - key
+    < W) routes to v2's walk from the window's first group, traced as
+    `paged_window_attention`, or to the reference with the window's
+    mask; `block_table` is then the lane's ring (`ring_table`). That
+    walk's generation is fixed, so it counts under its name alone
+    (`KERNEL_NAMES`), as `kda_chunk` does."""
     def reference():
         return paged_attention_reference(q, kv_pool, block_table,
-                                         q_positions, k_scale, v_scale)
+                                         q_positions, k_scale, v_scale,
+                                         window=window)
 
     def kernel(mode):
         from ..ops.pallas.paged import (ragged_paged_attention,
                                         ragged_paged_attention_v2)
+        if window is not None:
+            return None, "paged_window_attention", lambda: \
+                ragged_paged_attention_v2(q, kv_pool, block_table,
+                                          q_positions, k_scale=k_scale,
+                                          v_scale=v_scale, window=window)
         version = _kernel_version_for(mode, kv_pool, block_table)
         fn = (ragged_paged_attention_v2 if version == "v2"
               else ragged_paged_attention)
@@ -770,6 +836,30 @@ class HostKVTier:
 # pool manager (host side)
 # ---------------------------------------------------------------------------
 
+def lane_owned(geometry):
+    """What the lanes own in a cache of this `geometry`, for a refusal's
+    message: ("window ring", "ROADMAP R3") where a layer is a window
+    layer's ring, ("state layer", "ROADMAP R5") where one keeps a state,
+    None where every layer's cache is the allocator's blocks."""
+    owned = [g for g in geometry or () if isinstance(g, dict)]
+    if any("window" in g or "ring_blocks" in g for g in owned):
+        return ("window ring", "ROADMAP R3")
+    return ("state layer", "ROADMAP R5") if owned else None
+
+
+def _layer_geometry(g):
+    """One layer's `geometry` entry, its numbers as ints: (rows, width)
+    of a block, a state layer's `{name: (shape a lane, dtype)}`, or a
+    window layer's `{"window": W, "ring_blocks": R}`."""
+    if not isinstance(g, dict):
+        return (int(g[0]), int(g[1]))
+    if "ring_blocks" in g:
+        return {"window": int(g["window"]),
+                "ring_blocks": int(g["ring_blocks"])}
+    return {name: (tuple(int(d) for d in shp), dt)
+            for name, (shp, dt) in g.items()}
+
+
 class PagedKVCache:
     """Device block pools (one fused K|V array per layer, `pools[i]["kv"]`
     of (num_blocks, H_kv, block_size, 2*head_dim)) + a host free list.
@@ -818,7 +908,17 @@ class PagedKVCache:
     layer's `{"state": ((H, dv, dk), float32), "conv": ((3, channels),
     None)}`). Its entry of `pools` is those arrays with a leading lane
     axis; `layer_shapes[i]` is None; the block rewriters refuse the
-    cache (`_refuse_state`)."""
+    cache (`_refuse_state`).
+
+    A WINDOW layer (sliding-window attention over W positions) gives
+    `{"window": W, "ring_blocks": R}`: its pool is a ring of R blocks a
+    lane, (1 + num_slots * R, H_kv, bs, 2 * head_dim) with block 0
+    NULL, in which position p of lane s lives in block 1 + s * R +
+    (p // bs mod R) (`ring_table`). The allocator never hands out a
+    ring block, so admission and `utilization` count the other layers'
+    blocks; `layer_shapes[i]` is None, `ring_layers` lists such layers,
+    `ring_bytes` counts their pools, and the block rewriters refuse the
+    cache as they refuse a state (`_refuse_state`)."""
 
     def __init__(self, num_layers, num_heads, head_dim, num_blocks,
                  block_size=16, dtype=jnp.float32, mesh=None, axis="tp",
@@ -886,28 +986,30 @@ class PagedKVCache:
         if geometry is None:
             geometry = [(self.num_kv_heads, 2 * self.head_dim)
                         ] * self.num_layers
-        self.geometry = [
-            {name: (tuple(int(d) for d in shp), dt)
-             for name, (shp, dt) in g.items()} if isinstance(g, dict)
-            else (int(g[0]), int(g[1])) for g in geometry]
+        self.geometry = [_layer_geometry(g) for g in geometry]
         if len(self.geometry) != self.num_layers:
             raise ValueError(
                 f"geometry names {len(self.geometry)} layers, the cache "
                 f"has {self.num_layers}")
-        # the layers whose cache is a state a lane, not rows a token
+        # the layers whose cache a LANE owns: a state a lane, not rows a
+        # token, or a window layer's ring of blocks a lane
+        self.ring_layers = [i for i, g in enumerate(self.geometry)
+                            if isinstance(g, dict) and "ring_blocks" in g]
         self.state_layers = [i for i, g in enumerate(self.geometry)
-                             if isinstance(g, dict)]
+                             if isinstance(g, dict)
+                             and i not in self.ring_layers]
         self.num_slots = int(num_slots) if num_slots else None
-        if self.state_layers and not self.num_slots:
+        if (self.state_layers or self.ring_layers) and not self.num_slots:
             raise ValueError(
-                "a state layer's pool has a lane axis: the cache must be "
-                "told the lane count (num_slots=), which the engine owns")
-        if self.state_layers and (self.quantized or mesh is not None):
+                "a state layer's pool and a window layer's ring have a "
+                "lane axis: the cache must be told the lane count "
+                "(num_slots=), which the engine owns")
+        owned = lane_owned(self.geometry)
+        if owned and (self.quantized or mesh is not None):
             raise NotImplementedError(
                 f"{'int8 pools' if self.quantized else 'a mesh'} with a "
-                f"state layer (a recurrent state a lane beside the paged "
-                f"cache): the state is float32 by design and is not "
-                f"sharded yet (ROADMAP R5)")
+                f"{owned[0]} (a lane's own cache beside the paged cache): "
+                f"it is kept dense and on one device ({owned[1]})")
         self.latent = any(g != (self.num_kv_heads, 2 * self.head_dim)
                           for g in self.geometry if not isinstance(g, dict))
         if self.latent and (self.quantized or mesh is not None):
@@ -949,11 +1051,14 @@ class PagedKVCache:
                 layer["v_scale"] = make(sshape, jnp.float32) + 1.0
             return layer
 
-        def make_state(arrays):
+        def make_lane_owned(g):
+            if "ring_blocks" in g:
+                return {"kv": make(self._ring_shape(g))}
             return {name: jnp.zeros((self.num_slots,) + shp, dt or dtype)
-                    for name, (shp, dt) in arrays.items()}
+                    for name, (shp, dt) in g.items()}
 
-        self.pools = [make_state(g) if shp is None else make_layer(shp)
+        self.pools = [make_lane_owned(g) if shp is None
+                      else make_layer(shp)
                       for g, shp in zip(self.geometry, self.layer_shapes)]
         # every jitted rewriter of the pools DONATES them (the engine's
         # fused and draft steps, cow_copy, adopt_block_from,
@@ -1001,7 +1106,8 @@ class PagedKVCache:
         size, never the dense equivalent — and GQA pools their true
         H_kv row count, never the H-head overcount."""
         return (self._pool_elems() * np.dtype(self.dtype).itemsize
-                + self.scale_bytes() + self.state_bytes())
+                + self.scale_bytes() + self.state_bytes()
+                + self.ring_bytes())
 
     def _pool_elems(self):
         return sum(int(np.prod(shp)) for shp in self.layer_shapes
@@ -1016,15 +1122,29 @@ class PagedKVCache:
             for i in self.state_layers
             for shp, dt in self.geometry[i].values())
 
+    def _ring_shape(self, g):
+        """A window layer's pool: the NULL block, then R blocks a lane,
+        lane after lane (`ring_table`)."""
+        return (1 + self.num_slots * g["ring_blocks"], self.num_kv_heads,
+                self.block_size, 2 * self.head_dim)
+
+    def ring_bytes(self, dtype=None):
+        """Bytes of the window layers' rings over all lanes; 0 without
+        one."""
+        return sum(int(np.prod(self._ring_shape(self.geometry[i])))
+                   * np.dtype(dtype or self.dtype).itemsize
+                   for i in self.ring_layers)
+
     def _refuse_state(self, what):
         """Everything that copies, shares or moves cache BY BLOCK
-        cannot carry a lane's state yet."""
-        if self.state_layers:
+        cannot carry what a lane owns (`lane_owned`) yet."""
+        owned = lane_owned(self.geometry)
+        if owned:
             raise NotImplementedError(
-                f"{what} with a state layer: a block id names rows of "
+                f"{what} with a {owned[0]}: a block id names rows of "
                 f"keys and values, and layers "
-                f"{self.state_layers} keep a recurrent state a lane "
-                f"that no block addresses (ROADMAP R5)")
+                f"{sorted(self.state_layers + self.ring_layers)} keep a "
+                f"cache a lane that no block id addresses ({owned[1]})")
 
     def scale_bytes(self):
         """Bytes of the (N, H_kv, bs) f32 scale pools across k+v and
@@ -1044,7 +1164,7 @@ class PagedKVCache:
         equivalent."""
         dt = dtype if dtype is not None else self.compute_dtype
         return (self._pool_elems() * np.dtype(dt).itemsize
-                + self.state_bytes())
+                + self.state_bytes() + self.ring_bytes(dt))
 
     def shard_pool_bytes(self):
         """Bytes ONE device commits to the pools: pool_bytes()/tp under

@@ -133,6 +133,27 @@ def test_paged_v2_compiles_at_the_gqa_cell_geometry(v5e, pool, c):
     assert len(calls) == 1 and "paged_attention_v2" in calls[0]
 
 
+@pytest.mark.parametrize("c", [16, 1], ids=["prefill", "decode"])
+def test_window_walk_compiles_at_the_window_cell_geometry(v5e, c):
+    """SmallThinker-21BA3B as `smallthinker-21b-a3b.mixed16k-closed-16`
+    serves its window layers: 16 lanes, 28 query heads on 4 KV heads of
+    128, bf16 blocks of 16 tokens, a lane's ring of 264 blocks walked
+    through a 1,024-column table from the window's first group. One
+    v2 call given the window, traced as `paged_window_attention`: the
+    windowed plan, the lane's first step that starts the carry, and the
+    window's mask, typed by Mosaic."""
+    from paddle_tpu.serving import kv_cache as kvc
+    s, h, hp, d, bs, m, w = 16, 28, 4, 128, 16, 1024, 4096
+    ring = kvc.ring_blocks_for(w, 16, bs)
+    assert ring == 264
+    shapes = [((s, h, c, d), jnp.bfloat16),
+              ((1 + s * ring, hp, bs, 2 * d), jnp.bfloat16),
+              ((s, m), jnp.int32), ((s, c), jnp.int32)]
+    calls = _compile(v5e, lambda *a: paged.ragged_paged_attention_v2(
+        *a, window=w, interpret=False), *shapes)
+    assert len(calls) == 1 and "paged_window_attention" in calls[0]
+
+
 @pytest.mark.parametrize("nested", [False, True],
                          ids=["gspmd", "in_callers_shard_map"])
 def test_flash_compiles_under_an_executor_mesh(v5e_2x2, nested,
@@ -281,14 +302,21 @@ def test_latent_walk_compiles_at_the_cells_geometry(v5e):
     assert len(calls) == 1 and "paged_latent_attention" in calls[0]
 
 
-def test_held_experts_kernel_compiles_at_the_cells_geometry(v5e):
-    """16 held experts of 2048 x 768 (gate|up side by side), the 256
-    columns of a 16 x 16 step."""
+@pytest.mark.parametrize("hid,e,activation", [(2048, 16, "silu"),
+                                               (2560, 64, "relu")],
+                         ids=["joyai_silu", "smallthinker_relu"])
+def test_held_experts_kernel_compiles_at_the_cells_geometry(
+        v5e, hid, e, activation):
+    """The 256 columns of a 16 x 16 step through held experts of
+    hid x 768 (gate|up side by side): JoyAI's 16 SiLU-gated of 2048,
+    SmallThinker's 64 ReLU-gated of 2560 (each arrives whole)."""
     from paddle_tpu.ops.pallas import moe
-    t, hid, inner, e = 256, 2048, 768, 16
+    t, inner = 256, 768
+    assert moe._inner_blocks(hid, inner, jnp.bfloat16) == 1
     calls = _compile(
         v5e, lambda x, sel, comb, gu, down: moe.moe_experts(
-            x, sel, comb, gu, down, interpret=False),
+            x, sel, comb, gu, down, interpret=False,
+            activation=activation),
         ((t, hid), jnp.bfloat16), ((t, e), jnp.bool_),
         ((t, e), jnp.float32), ((e, hid, 2 * inner), jnp.bfloat16),
         ((e, inner, hid), jnp.bfloat16))

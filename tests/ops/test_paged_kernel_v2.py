@@ -27,6 +27,8 @@ Contract (extends tests/ops/test_paged_kernel.py):
   lands a version label + the serving.kernel.version gauge.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -504,3 +506,119 @@ def test_v2_lazy_export():
     import paddle_tpu.ops.pallas as pk
     assert pk.ragged_paged_attention_v2 is \
         paged.ragged_paged_attention_v2
+
+
+# ---------------------------------------------------------------------------
+# the windowed walk over a ring a lane
+# ---------------------------------------------------------------------------
+
+def make_ring_case(lengths, c, window, dtype=jnp.float32, h=4, hp=2, d=8,
+                   bs=8, m=16, seed=0):
+    """Lanes that wrote positions 0 .. length + c - 1 (None: idle) into
+    a ring of R = ring_blocks_for(window, c, bs) blocks each, later
+    positions over earlier ones, the ring's never-written rows holding
+    1e4 (a stale key that a walk must not weigh). Returns (the walk's
+    operands over the ring, each lane's logical (K, V) by position, the
+    lanes' query positions)."""
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    ring = kvc.ring_blocks_for(window, c, bs)
+    pool = np.full((1 + b * ring, hp, bs, 2 * d), 1e4, np.float32)
+    tables = np.full((b, m), kvc.NULL_BLOCK, np.int32)
+    q_pos = np.zeros((b, c), np.int32)
+    keys = []
+    for i, length in enumerate(lengths):
+        if length is None:
+            keys.append(None)
+            continue
+        kv = rng.standard_normal((length + c, hp, 2 * d))
+        for p in range(length + c):
+            pool[1 + i * ring + (p // bs) % ring, :, p % bs] = kv[p]
+        tables[i, :-(-(length + c) // bs)] = 1 + rng.integers(
+            0, 999, -(-(length + c) // bs))
+        q_pos[i] = np.arange(length, length + c)
+        keys.append(kv)
+    q = rng.standard_normal((b, h, c, d))
+    ring_tbl = kvc.ring_table(jnp.asarray(tables), ring)
+    return ((jnp.asarray(q, dtype), jnp.asarray(pool, dtype), ring_tbl,
+             jnp.asarray(q_pos)), keys, q_pos)
+
+
+def _window_dense(q, keys, q_pos, window, h, hp, d):
+    """Attention of each query over ITS window of the logical keys,
+    float64: key j visible to position i iff 0 <= i - j < window."""
+    q = np.asarray(q, np.float64)
+    out = np.zeros(q.shape)
+    for b, kv in enumerate(keys):
+        if kv is None:
+            continue
+        for ci, i in enumerate(q_pos[b]):
+            lo = max(0, i - window + 1)
+            for head in range(h):
+                g = head // (h // hp)
+                k, v = kv[lo:i + 1, g, :d], kv[lo:i + 1, g, d:]
+                s = k @ q[b, head, ci] / np.sqrt(d)
+                p = np.exp(s - s.max())
+                out[b, head, ci] = p @ v / p.sum()
+    return out
+
+
+@pytest.mark.parametrize("c", [4, 1], ids=["prefill", "decode"])
+@pytest.mark.parametrize("window", [20, 8], ids=["w20", "w8"])
+def test_window_walk_matches_masked_dense_attention(window, c):
+    """A lane's ring holds only the last R blocks of its positions; the
+    windowed walk over it equals attention over each query's window of
+    the whole logical sequence, for chunks wholly inside the first
+    window, straddling its edge, just past it, and far past it with the
+    ring wrapped several times; an idle lane is an exact zero, and the
+    ring's stale rows (1e4) weigh nothing. The dispatcher's reference,
+    the same walk's spec, agrees."""
+    lengths = [None, 2, window - 2, window + 1, 61, 120 - c]
+    args, keys, q_pos = make_ring_case(lengths, c, window, seed=window + c)
+    got = np.asarray(jax.jit(functools.partial(
+        paged.ragged_paged_attention_v2, window=window))(*args))
+    want = _window_dense(args[0], keys, q_pos, window, 4, 2, 8)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert not got[0].any()
+    ref = np.asarray(kvc.paged_attention_reference(*args, window=window))
+    np.testing.assert_allclose(ref[1:], want[1:], rtol=1e-5, atol=1e-5)
+
+
+def test_window_plan_starts_each_lane_at_its_windows_first_group():
+    """16-key blocks, groups of 8 columns (128 keys), a window of 100:
+    a lane at 0-3 takes group 0 alone; at 300-303 its window reaches
+    back to 201 (column 12, group 1), so groups 1-2; at 1000-1003 to
+    901 (column 56, group 7): groups 7 only, one step, where the plain
+    walk takes 8."""
+    bs, m, p = 16, 64, 8
+    tables = jnp.ones((3, m), jnp.int32)
+    pos = jnp.asarray([[0, 1, 2, 3], [300, 301, 302, 303],
+                       [1000, 1001, 1002, 1003]], jnp.int32)
+    steps, plan = paged._plan_walk(tables, pos, bs, p, window=100)
+    lane, group, _fetch, live, groups = (np.asarray(a) for a in plan)
+    assert int(steps) == 4
+    np.testing.assert_array_equal(lane[:4], [0, 1, 1, 2])
+    np.testing.assert_array_equal(group[:4], [0, 1, 2, 7])
+    np.testing.assert_array_equal(groups, [1, 3, 8])
+    assert live[1, :12].sum() == 0 and live[1, 12:19].all()
+    assert live[2, :56].sum() == 0 and live[2, 56:63].all()
+    plain_steps, _ = paged._plan_walk(tables, pos, bs, p)
+    assert int(plain_steps) == 12
+
+
+def test_ring_layout_is_lane_and_position():
+    """Block 1 + s * R + (j mod R) of the ring pool holds column j of
+    lane s wherever the lane's table holds a block; NULL elsewhere. A
+    window of 4,096 at chunks of 16 over 16-token blocks takes 264
+    blocks a lane (33 walk groups)."""
+    tables = jnp.asarray([[5, 9, 2, 0, 0], [0, 0, 0, 0, 0],
+                          [3, 3, 3, 3, 3]], jnp.int32)
+    got = np.asarray(kvc.ring_table(tables, 2))
+    np.testing.assert_array_equal(got, [[1, 2, 1, 0, 0], [0] * 5,
+                                        [5, 6, 5, 6, 5]])
+    assert kvc.ring_blocks_for(4096, 16, 16) == 264
+    # R * bs covers the window, the chunk and a block the window's
+    # start falls inside
+    for w, c, bs in ((20, 4, 8), (8, 1, 8), (4096, 1, 16), (100, 16, 16)):
+        r = kvc.ring_blocks_for(w, c, bs)
+        assert r * bs >= w + c + bs - 2
