@@ -789,10 +789,25 @@ def latent_row_width(kv_lora_rank, rope_dim):
 def _latent_scratch_shapes(rows, width, value_width, p, bs, pool_dtype):
     """The latent walk's VMEM scratch: the group's keys in position
     order, and the online-softmax carry (running max, exp-sum, value
-    partial) of all heads' rows. Nothing depends on the table width."""
+    partial) of all heads' rows, the first two a lane tile wide
+    (`_lanes`). Nothing depends on the table width."""
     return [((p * bs, width), pool_dtype),
-            ((rows, 1), jnp.float32), ((rows, 1), jnp.float32),
+            ((rows, 128), jnp.float32), ((rows, 128), jnp.float32),
             ((rows, value_width), jnp.float32)]
+
+
+def _lanes(x, width):
+    """A carry of (rows, 128) whose lanes all hold the row's number, at
+    `width` lanes: the tile repeated where `width` is whole tiles, else
+    its first lane broadcast. Held as a (rows, 1) column, the running
+    max and exp-sum took as many vector registers as the whole tile and
+    a broadcast across lanes at each use: on a v5e a step of 512 query
+    rows took 2.7 us with columns, 1.7 us lane-wide (PERF.md section 6)."""
+    if width == x.shape[1]:
+        return x
+    if width % x.shape[1] == 0:
+        return jnp.concatenate([x] * (width // x.shape[1]), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
 def _latent_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
@@ -811,8 +826,10 @@ def _latent_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
     `value_width` lanes of the same VMEM rows: the pool is read once.
     The rows fold into a flash-style carry (m, l, acc: f32), as v2's
     do, with v2's two traps dodged the same way (probabilities come
-    from where(mask, exp, 0); an idle lane divides by 1). A lane that
-    feeds one token computes its first column's rows only."""
+    from where(mask, exp, 0); an idle lane divides by 1); m and l hold
+    a row's number in each of 128 lanes, so that they meet the scores
+    and the value partial lane for lane. A lane that feeds one token
+    computes its first column's rows only."""
     del fetch_ref, m                        # read by the index maps
     kv_refs, rest = rest[:p], rest[p:]
     o_ref, k_ref, m_ref, l_ref, acc_ref = rest
@@ -865,13 +882,14 @@ def _latent_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
                              1 - live_ref[b, j * p + i], dead)
         mask = (j * t + key <= q_pos) & (dead == 0)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:n, :]
+        m_prev = m_ref[:n, :]                               # (n, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        prob = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        prob = jnp.where(mask, jnp.exp(s - _lanes(m_new, t)), 0.0)
         l_ref[:n, :] = l_ref[:n, :] * corr + jnp.sum(prob, axis=-1,
                                                      keepdims=True)
-        acc_ref[:n, :] = acc_ref[:n, :] * corr + jnp.dot(
+        acc_ref[:n, :] = acc_ref[:n, :] * _lanes(
+            corr, value_width) + jnp.dot(
             prob.astype(keys.dtype), keys[:, :value_width],
             precision=_mxu_precision(keys.dtype),
             preferred_element_type=jnp.float32)
@@ -891,7 +909,7 @@ def _latent_kernel(lane_ref, group_ref, fetch_ref, live_ref, groups_ref,
 
     @pl.when(j == jnp.maximum(n_groups, 1) - 1)
     def _flush():
-        l = l_ref[...]
+        l = _lanes(l_ref[...], value_width)
         o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(
             o_ref.dtype)
 

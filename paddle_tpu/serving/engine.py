@@ -1437,9 +1437,12 @@ class GenerationServer:
         `walk_groups` is the share of the block
         table the paged kernel touches: it walks a lane's table in
         groups of `walk_group` columns and stops after the last one
-        that holds a token (the latent walk the same). A model whose
-        step counts (`step_counters`) adds the counts under its
-        names."""
+        that holds a token (the latent walk the same).
+        `walk_groups_whole` counts the live groups that lie wholly at or
+        below a lane's lowest fed position (its column 0): every query
+        of the lane sees every key of such a group, so its causal mask
+        is all true. A model whose step counts (`step_counters`) adds
+        the counts under its names."""
         from ..ops.pallas.paged import walk_group
         cols = plan.valid.sum(axis=1)
         lanes_qc = [[int(cols[sid]),
@@ -1456,6 +1459,9 @@ class GenerationServer:
                   "lanes_qc": lanes_qc,
                   "walk_groups_live": sum(-(-ctx // keys)
                                           for _q, ctx in lanes_qc),
+                  "walk_groups_whole": sum(
+                      (int(plan.positions[sid, 0]) + 1) // keys
+                      for sid in plan.slot_ids),
                   "walk_groups": len(lanes_qc) * -(-width // group)}
         record.update(zip((arg for arg, _metric in self._step_counters),
                           self._step_counts or ()))

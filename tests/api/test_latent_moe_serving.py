@@ -202,9 +202,11 @@ def test_absorbed_attention_equals_the_expanded_form():
 # ---------------------------------------------------------------------
 
 def _latent_case(contexts, c, heads=4, lora=32, rope=8, bs=8, m=12,
-                 dtype=jnp.float32, seed=0):
-    """Lanes at the given contexts (0: idle), the last `c` (or fewer)
-    positions of each as its queries."""
+                 dtype=jnp.float32, seed=0, fed=None, holes=()):
+    """Lanes at the given contexts (0: idle), the last `fed[i]` (by
+    default `c`, or fewer) positions of each as its queries and the rest
+    of its columns 0, as the scheduler pads them. A (lane, column) of
+    `holes` is NULL_BLOCK inside the lane's live range."""
     rng = np.random.RandomState(seed)
     w = pk.latent_row_width(lora, rope)
     b = len(contexts)
@@ -221,40 +223,138 @@ def _latent_case(contexts, c, heads=4, lora=32, rope=8, bs=8, m=12,
         if 0 < nb < m:
             table[i, nb] = 1 + i * m + nb
             pool[table[i, nb]] = np.nan
-        q_n = min(c, ctx)
+        q_n = min(c, ctx) if fed is None else fed[i]
         pos[i, :q_n] = np.arange(ctx - q_n, ctx)
-        pos[i, q_n:] = max(ctx - 1, 0)
+    for i, col in holes:
+        table[i, col] = NULL_BLOCK
     q = rng.randn(b, c, heads, w).astype(np.float32)
     q[..., lora + rope:] = 0.0
     return (jnp.asarray(q, dtype), jnp.asarray(pool, dtype),
             jnp.asarray(table), jnp.asarray(pos))
 
 
-@pytest.mark.parametrize("contexts,c", [
-    ((37, 0, 1, 96), 4),        # ragged, an idle lane, a context of one
-    ((5, 64, 17), 1),           # decode
-    ((96, 96), 16),             # a full table, a whole chunk
+def _whole_groups(table, pos, fed, bs):
+    """The groups of a lane's walk that every one of its `fed` queries
+    sees whole: all p columns hold a block, and the group's last key
+    lies at or below the lowest fed position, so the causal mask is all
+    true there. One count a lane."""
+    p = pk.walk_group(bs, table.shape[1])
+    t = p * bs
+    out = []
+    for i, n_fed in enumerate(fed):
+        lowest = int(np.min(pos[i, :n_fed])) if n_fed else -1
+        groups = [table[i, k:k + p] for k in range(0, table.shape[1], p)]
+        out.append(sum(len(g) == p and (g != NULL_BLOCK).all()
+                       and (j + 1) * t - 1 <= lowest
+                       for j, g in enumerate(groups)))
+    return out
+
+
+def _without_holes(table, pos, holes, bs):
+    """The table and positions the reference reads for a lane with a
+    NULL column inside its range: the column taken out and the queries
+    moved down a block, so that the keys past it keep their places
+    relative to every query (each hole lies below the lane's queries)."""
+    table, pos = np.array(table), np.array(pos)
+    for i, col in sorted(holes, key=lambda h: -h[1]):
+        table[i] = np.append(np.delete(table[i], col), NULL_BLOCK)
+        pos[i] -= bs
+    return jnp.asarray(table), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("contexts,c,case", [
+    ((37, 0, 1, 96), 4, {}),    # ragged, an idle lane, a context of one
+    ((5, 64, 17), 1, {}),       # decode
+    ((96, 96), 16, {}),         # a full table, a whole chunk
+    # the groups of 128 keys of a table of 16-token blocks (p = 8): a
+    # chunk past four whole groups, on the diagonal of the fifth
+    ((600,), 16, dict(bs=16, m=40, whole=[4])),
+    # chunks that straddle 384 and 128, so that two groups take the
+    # mask of each
+    ((392, 130), 16, dict(bs=16, m=40, whole=[2, 0])),
+    # a lowest query exactly at a group's last key (383), in a chunk
+    # and in a decode lane: that group is whole
+    ((399, 384), 16, dict(bs=16, m=40, fed=[16, 1], whole=[3, 3])),
+    # a partial last chunk: 11 fed columns, the other 5 at position 0
+    ((600, 133), 16, dict(bs=16, m=40, fed=[11, 5], whole=[4, 1])),
+    # a NULL column (keys 144-159) inside an otherwise whole range
+    ((600,), 16, dict(bs=16, m=40, holes=[(0, 9)], whole=[3])),
+    # a decode lane and an idle lane beside a chunk in one call
+    ((600, 0, 300), 16, dict(bs=16, m=40, fed=[16, 0, 1],
+                             whole=[4, 0, 2])),
 ])
-def test_latent_kernel_matches_reference_attention(contexts, c):
-    q, pool, table, pos = _latent_case(contexts, c)
+def test_latent_kernel_matches_reference_attention(contexts, c, case):
+    case = dict(case)
+    whole, holes = case.pop("whole", None), case.get("holes", ())
+    q, pool, table, pos = _latent_case(contexts, c, **case)
+    fed = case.get("fed") or [min(c, ctx) for ctx in contexts]
+    if whole is not None:
+        # the case covers the groups it says: whole ones and masked ones
+        assert _whole_groups(np.asarray(table), np.asarray(pos), fed,
+                             case["bs"]) == whole
     kw = dict(value_width=32, scale=1.0 / np.sqrt(24))
     got = np.asarray(pk.paged_latent_attention(q, pool, table, pos, **kw))
     assert np.isfinite(got).all()           # NULL / stale never read
     # the reference reads whatever the table names: give it zeros there
     clean = jnp.where(jnp.isnan(pool), 0.0, pool)
+    ref_table, ref_pos = _without_holes(table, pos, holes,
+                                        case.get("bs", 8))
     want = np.asarray(kvc.paged_latent_attention_reference(
-        q, clean, table, pos, **kw))
+        q, clean, ref_table, ref_pos, **kw))
     for i, ctx in enumerate(contexts):
         if ctx == 0:
             assert not got[i].any()         # an idle lane: exact zeros
             continue
-        live = min(c, ctx)
+        live = fed[i]
         np.testing.assert_allclose(got[i, :live], want[i, :live],
                                    atol=2e-5)
         if live == 1:
             # a lane that feeds one token: its padded columns' rows
             # are not computed
             assert not got[i, 1:].any()
+
+
+def test_iteration_record_counts_the_groups_every_query_sees_whole():
+    """`walk_groups_whole` in each `serving.iteration` record is the
+    count `_whole_groups` makes of the walk the record describes: two
+    lanes that prefill in chunks of 16 (each prompt's last chunk
+    partial) and then decode past 128, beside an idle third lane."""
+    from paddle_tpu.observability.tracing import get_recorder
+    cfg = latent_moe_tiny(n_routed_experts_held=4, expert_offset=8,
+                          max_position=320)
+    model = LatentMoEServingModel(init_params(cfg, 3), cfg)
+    rec = get_recorder()
+    rec.start()
+    srv = GenerationServer(model, num_slots=3, chunk=16, block_size=16,
+                           max_context=320, start=False)
+    rng = np.random.RandomState(4)
+    futs = [srv.submit(rng.randint(1, 256, size=n), max_new_tokens=k)
+            for n, k in ((150, 4), (120, 20))]
+    srv.run_until_idle()
+    rec.stop()
+    records = [e["args"] for e in rec.events()
+               if e.get("name") == "serving.iteration"]
+    rec.clear()
+    srv.close()
+    assert all(len(f.result().token_ids) > 0 for f in futs)
+    assert pk.walk_group(16, 320 // 16) == 8
+    qc = set()
+    for a in records:
+        table = np.full((len(a["lanes_qc"]), 20), NULL_BLOCK, np.int32)
+        pos = np.zeros((len(a["lanes_qc"]), 16), np.int32)
+        for i, (q_n, ctx) in enumerate(a["lanes_qc"]):
+            table[i, :-(-ctx // 16)] = 1 + i
+            pos[i, :q_n] = np.arange(ctx - q_n, ctx)
+        whole = _whole_groups(table, pos, [q for q, _ in a["lanes_qc"]], 16)
+        assert a["walk_groups_whole"] == sum(whole), a
+        assert a["walk_groups_whole"] <= a["walk_groups_live"]
+        qc.update((tuple(lane), w) for lane, w in zip(a["lanes_qc"], whole))
+    # chunks before and past the first group's last key, partial chunks
+    # on either side of it, and a decode lane at the edge (position 126,
+    # then 127 = the group's last key)
+    assert ((16, 128), 0) in qc and ((16, 144), 1) in qc
+    assert ((8, 120), 0) in qc and ((6, 150), 1) in qc
+    assert ((1, 127), 0) in qc and ((1, 128), 1) in qc
 
 
 def test_latent_kernel_bf16_pool_accumulates_in_float32():

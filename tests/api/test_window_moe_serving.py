@@ -349,6 +349,15 @@ STREAMS = {
     ("joyai", "auto"): {
         "greedy": ([241, 9, 40, 241, 9], -25.662625789642334),
         "sampled": ([108, 230, 172, 180, 14], -14.339096546173096)},
+    # a table of two groups of 128 key positions and a greedy prompt of
+    # 140 tokens: the latent walk's carry crosses from one group into
+    # the next in prefill and in decode, and chunks past 128 see their
+    # first group whole. Taken while the walk held its running max and
+    # exp-sum as (rows, 1) columns; the lane-wide carry streams the same
+    # bits
+    ("joyai_long", "auto"): {
+        "greedy": ([210, 1, 247, 233, 68], -25.523290634155273),
+        "sampled": ([108, 230, 172, 180, 14], -14.339096546173096)},
     ("solar", "auto"): {
         "greedy": ([254, 238, 111, 254, 99], -25.523123741149902),
         "sampled": ([242, 219, 195, 198, 233], -14.473012685775757)},
@@ -391,19 +400,25 @@ def _stream(family, mode):
     from paddle_tpu.serving import (LatentMoEServingModel,
                                     LinearMoEServingModel)
     from paddle_tpu.serving.decode_strategies import SamplingParams
+    max_context, prompt = 64, np.arange(1, 12)
     if family == "gpt":
         model = _tiny_gpt()
     elif family == "joyai":
         cfg = latent_moe_tiny(n_routed_experts_held=4, expert_offset=8)
         model = LatentMoEServingModel(latent_init(cfg, 1), cfg)
+    elif family == "joyai_long":
+        cfg = latent_moe_tiny(n_routed_experts_held=4, expert_offset=8,
+                              max_position=256)
+        model = LatentMoEServingModel(latent_init(cfg, 1), cfg)
+        max_context, prompt = 256, np.arange(1, 141)
     else:
         cfg = linear_moe_tiny(n_routed_experts_held=4, expert_offset=4)
         model = LinearMoEServingModel(linear_init(cfg, 1), cfg)
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("PADDLE_TPU_PAGED_KERNEL", mode)
         srv = _server(model, num_slots=2, chunk=4, block_size=8,
-                      max_context=64)
-        greedy = srv.submit(np.arange(1, 12), max_new_tokens=5)
+                      max_context=max_context)
+        greedy = srv.submit(prompt, max_new_tokens=5)
         sampled = srv.submit(
             np.arange(20, 29), max_new_tokens=5,
             sampling=SamplingParams(temperature=0.8, top_k=20, top_p=0.9,
