@@ -93,6 +93,7 @@ from .engine import GenerationFuture, GenerationServer, GPTServingModel
 from .latent_moe import LatentMoEServingModel
 from .linear_moe import LinearMoEServingModel
 from .smallthinker import SmallThinkerServingModel
+from .brumby import BrumbyServingModel
 from .spec_decode import SpecDecodeConfig
 from .replica import Replica
 from .router import (AdmissionPolicy, AdmissionRejected, FleetFuture,
@@ -113,7 +114,7 @@ __all__ = [
     "DeadlineExceeded", "RequestCancelled",
     "GenerationServer", "GenerationFuture", "GPTServingModel",
     "LatentMoEServingModel", "LinearMoEServingModel",
-    "SmallThinkerServingModel",
+    "SmallThinkerServingModel", "BrumbyServingModel",
     "Replica", "FleetRouter", "FleetFuture", "RouterPolicy",
     "AdmissionPolicy", "AdmissionRejected",
     "WorkerProxy", "make_subprocess_spawn", "spawn_worker",

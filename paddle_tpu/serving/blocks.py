@@ -16,8 +16,11 @@ window / mixture-of-experts family (`serving/smallthinker.py`) a
 fourth (grouped-query layers that are either windowed with half-split
 rotary positions over a ring a lane, or full with none, softmax-routed
 ReLU-gated experts whose router reads the attention's input, untied
-head). A new family adds entries here and a spec; the step, the
-scheduler and the cache manager do not change.
+head); the power-retention family (`serving/brumby.py`) a fifth (every
+layer power retention over a state a lane, with normalised q and k and
+half-split rotary positions, a dense gated MLP, untied head). A new
+family adds entries here and a spec; the step, the scheduler and the
+cache manager do not change.
 
 Every attention kind takes (ctx, hn, lp, layer_pools) and returns (the
 residual's addend, the layer's rewritten pools); every MLP kind takes
@@ -37,15 +40,15 @@ import jax.numpy as jnp
 
 from ..models.gpt import _ln
 from .kv_cache import (fuse_kv, kda_chunk, paged_attention,
-                       paged_latent_attention, write_block_kv,
-                       write_block_kv_quant)
+                       paged_latent_attention, power_retention_chunk,
+                       write_block_kv, write_block_kv_quant)
 from .moe import expert_share, step_stats
 
 __all__ = ["LayerSpec", "StepSpec", "StepContext", "NORMS", "ATTENTIONS",
            "MLPS", "STATE_ATTENTIONS", "STATE_STATS", "RING_ATTENTIONS",
            "WINDOW_STATS", "fold_counts", "state_counts", "window_counts",
-           "rms_norm", "rotary_angles", "rotate_interleaved",
-           "rotate_half", "short_conv"]
+           "rms_norm", "qk_rms_norm", "rotary_angles",
+           "rotate_interleaved", "rotate_half", "short_conv"]
 
 # one layer: the names of its norm, attention and MLP kinds
 LayerSpec = collections.namedtuple("LayerSpec", "norm attention mlp")
@@ -85,6 +88,14 @@ def rms_norm(x, scale, eps):
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
                             + eps)
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def qk_rms_norm(q, k, lp, eps):
+    """Queries and keys (..., heads, head_dim) RMS-normed a head, over
+    its head_dim channels, by the layer's `q_norm_s` and `k_norm_s`
+    (head_dim,) scales: float32 out."""
+    return (rms_norm(q.astype(jnp.float32), lp["q_norm_s"], eps),
+            rms_norm(k.astype(jnp.float32), lp["k_norm_s"], eps))
 
 
 def rotary_angles(pos, dim, theta):
@@ -303,16 +314,47 @@ def _attention_kda(ctx, hn, lp, pools):
     return (o @ lp["wo"]).astype(ctx.xdt), {"state": state, "conv": conv}
 
 
+def _attention_power(ctx, hn, lp, pools):
+    """Power retention over a STATE a lane, `pools["state"]` (S, H_kv,
+    D, head_dim) and `pools["norm"]` (S, H_kv, D) float32, D the rows of
+    the degree-2 expansion (`ops/pallas/retention.py`): `q = h wq`,
+    `[k | v] = h wkv`, q and k RMS-normed a head (`qk_rms_norm`) and
+    turned by half-split rotary angles over the whole head, q scaled by
+    1 / sqrt(head_dim); a log-gate a KV head `logsigmoid(h wa + ba)`;
+    `power_retention_chunk` moves the state by the lane's valid columns
+    and gives each column's normalised read of it, which `wo`
+    projects. A lane that starts a request starts from the zero state
+    HERE, in the step; a lane with no valid column keeps its state as
+    it was."""
+    s, c = ctx.s, ctx.c
+    spec = ctx.spec
+    heads, kv_heads, d = spec.heads, spec.kv_heads, spec.head_dim
+    xdt = hn.dtype
+    q = (hn @ lp["wq"]).reshape(s, c, heads, d)
+    kv = (hn @ lp["wkv"]).reshape(s, c, 2, kv_heads, d)
+    q, k = qk_rms_norm(q, kv[:, :, 0], lp, spec.norm_eps)
+    cos, sin = (x[:, :, None] for x in ctx.angles)
+    q = rotate_half(q, cos, sin) * (d ** -0.5)
+    k = rotate_half(k, cos, sin)
+    a = jax.nn.log_sigmoid((hn @ lp["wa"]).astype(jnp.float32)
+                           + lp["ba"].astype(jnp.float32))
+    o, state, norm = power_retention_chunk(
+        q.astype(xdt), k.astype(xdt), kv[:, :, 1], a, pools["state"],
+        pools["norm"], ctx.counts, ctx.starts)
+    o = o.reshape(s, c, heads * d).astype(xdt)
+    return (o @ lp["wo"]).astype(ctx.xdt), {"state": state, "norm": norm}
+
+
 ATTENTIONS = {
     "mha": _attention_mha, "latent": _attention_latent,
     "gqa_gated": functools.partial(_attention_gqa, gated=True),
     "gqa": _attention_gqa,
     "gqa_window": functools.partial(_attention_gqa, windowed=True),
-    "kda": _attention_kda}
+    "kda": _attention_kda, "power": _attention_power}
 
 # the kinds whose cache is a state a lane, and what a step counts of
 # them (`state_counts`)
-STATE_ATTENTIONS = ("kda",)
+STATE_ATTENTIONS = ("kda", "power")
 STATE_STATS = ("lane_calls", "columns", "resets")
 # the kinds whose cache is a ring a lane (`kv_cache.ring_table`), and
 # what a step counts of them (`window_counts`)

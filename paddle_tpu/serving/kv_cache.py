@@ -72,6 +72,11 @@ tables, the refcounts, COW, the wire or the host tier address, so every
 block rewriter here REFUSES a cache that has one (ROADMAP R5 says what
 each would need), and the byte counts add the lanes' arrays.
 `kda_chunk` is that layer's dispatcher, counted like the paged ones.
+A power-retention layer (`ops/pallas/retention.py`) is a state layer
+too, `{"state", "norm"}` a lane, and `power_retention_chunk` its
+dispatcher. A model may have no paged layer at all: the allocator then
+counts positions into blocks that no pool holds, and admission, the
+tables and the donation of `pools` are the same code.
 
 Window rings: a layer of sliding-window attention reads the last W
 positions of a lane and no others, so its keys and values live in a
@@ -136,7 +141,7 @@ __all__ = ["PagedKVCache", "HostKVTier", "PagedDecodeLayer",
            "paged_attention_reference", "gather_block_kv",
            "paged_latent_attention", "paged_latent_attention_reference",
            "ring_table", "ring_blocks_for",
-           "lane_owned", "kda_chunk",
+           "lane_owned", "kda_chunk", "power_retention_chunk",
            "gather_block_kv_pair", "gather_block_scales",
            "build_paged_decode_cache", "quantize_kv_rows",
            "write_block_kv_quant",
@@ -656,6 +661,35 @@ def kda_chunk(q, k, v, g, beta, state, counts, reset):
         and state.dtype == jnp.float32
         and q.dtype in (jnp.float32, jnp.bfloat16),
         f"for the chunked delta rule (q {q.shape} {q.dtype}, state "
+        f"{state.shape} {state.dtype})", reference, kernel)
+
+
+def power_retention_chunk(q, k, v, a, state, norm, counts, reset):
+    """A power-retention layer's dispatcher: one chunk of every lane's
+    columns against the lane's carried state and normaliser
+    (`ops/pallas/retention.power_retention_chunk`, whose docstring has
+    the shapes), through the same modes, labeled fallbacks and dispatch
+    counters as the paged walks. The kernel walks no block table, so it
+    has no generation: it counts under its name,
+    "power_retention_chunk", alone. Its reference is the same chunk in
+    plain `jax.numpy`."""
+    from ..ops.pallas import retention
+
+    def reference():
+        return retention.power_retention_chunk_reference(
+            q, k, v, a, state, norm, counts, reset)
+
+    def kernel(mode):
+        return None, "power_retention_chunk", lambda: \
+            retention.power_retention_chunk(q, k, v, a, state, norm,
+                                            counts, reset)
+
+    return _dispatch(
+        q.ndim == 4 and state.ndim == 4 and norm.ndim == 3
+        and state.dtype == norm.dtype == jnp.float32
+        and q.dtype in (jnp.float32, jnp.bfloat16)
+        and state.shape[2] % 128 == 0,
+        f"for power retention (q {q.shape} {q.dtype}, state "
         f"{state.shape} {state.dtype})", reference, kernel)
 
 

@@ -144,8 +144,8 @@ def test_chunked_prefill_then_decode_matches_reference_logits(dtype, tol):
 
 def test_the_spec_follows_gqa_layers_and_the_tables_hold_every_kind():
     assert set(blocks.ATTENTIONS) == {"mha", "latent", "gqa_gated", "gqa",
-                                      "gqa_window", "kda"}
-    assert blocks.STATE_ATTENTIONS == ("kda",)
+                                      "gqa_window", "kda", "power"}
+    assert blocks.STATE_ATTENTIONS == ("kda", "power")
     cfg = linear_moe_tiny(num_layers=6, gqa_layers=(1, 2))
     model = LinearMoEServingModel(init_params(cfg, 0), cfg)
     spec = model.step_spec()

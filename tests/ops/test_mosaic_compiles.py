@@ -357,6 +357,25 @@ def test_chunked_delta_rule_compiles_at_the_cells_geometry(v5e):
     assert len(calls) == 1 and "kda_chunk" in calls[0]
 
 
+def test_power_retention_compiles_at_the_cells_geometry(v5e):
+    """brumby-14b-pp5: 16 lanes x 16-token chunks, 40 query heads on 8
+    KV heads of 128, a float32 state of 8,320 rows and its normaliser a
+    lane, rewritten where they lie, 640 rows a grid step."""
+    from paddle_tpu.ops.pallas import retention
+    s, c, h, kv, d = 16, 16, 40, 8, 128
+    rows = retention.state_rows(d)
+    assert rows == 8320 and retention.tile_rows(rows) == 640
+    bf = jnp.bfloat16
+    calls = _compile(
+        v5e, lambda q, k, v, a, st, nm, cnt, rst:
+        retention.power_retention_chunk(q, k, v, a, st, nm, cnt, rst,
+                                        interpret=False),
+        ((s, c, h, d), bf), ((s, c, kv, d), bf), ((s, c, kv, d), bf),
+        ((s, c, kv), jnp.float32), ((s, kv, rows, d), jnp.float32),
+        ((s, kv, rows), jnp.float32), ((s,), jnp.int32), ((s,), jnp.bool_))
+    assert len(calls) == 1 and "power_retention_chunk" in calls[0]
+
+
 _BRANCH_ATTRS = ("branch_computations", "true_computation",
                  "false_computation")
 
