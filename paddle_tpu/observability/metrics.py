@@ -150,9 +150,15 @@ METRIC_SPECS = [
      "iterations that uploaded a guided-decoding mask: those with a "
      "guided lane (every other step reads the zero mask kept on the "
      "device)"),
+    ("serving.overlapped_iterations", "counter",
+     "iterations whose fused step was dispatched while the previous "
+     "one was not yet fetched: the host's plan, feed, fetch and commit "
+     "ran under the device (over serving.iterations, the overlapped "
+     "share)"),
     ("serving.step_ms", "histogram",
      "wall ms of one serving iteration from the step's feed to the end "
-     "of commit (plan() is not in it)"),
+     "of its commit (plan() is not in it; with a step in flight, the "
+     "next iteration's plan, feed and dispatch are)"),
     ("serving.valid_columns", "counter",
      "columns of the fused step's (slots, chunk) grid that carried a "
      "token"),

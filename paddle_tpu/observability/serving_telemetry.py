@@ -796,7 +796,7 @@ class ServingTelemetry:
         self._rec = recorder or get_recorder()
         self._req = {}
         self._lock = threading.Lock()
-        self._iter_deadline_cancels = 0
+        self._iter_deadline_cancels = {}    # iteration -> count
         self._storm_latched = False
         self._traced_local = 0      # THIS instance's emitted trees (the
         #                             registry counter aggregates
@@ -893,10 +893,12 @@ class ServingTelemetry:
             st.tokens += 1
 
     def on_deadline_cancel(self, rid, iteration):
-        # no lock: iteration-scoped counter, engine-thread only (reset
-        # in begin_iteration, incremented from _fail during the same
-        # thread's plan(), read in end_iteration)
-        self._iter_deadline_cancels += 1
+        # no lock: engine-thread only. Counted under the iteration whose
+        # plan decided it (with a step in flight the cancel lands at that
+        # step's commit, after the next iteration began) and read by
+        # that iteration's end_iteration
+        d = self._iter_deadline_cancels
+        d[iteration] = d.get(iteration, 0) + 1
 
     def on_finish(self, rid, iteration, outcome, reason=None, e2e_ms=None,
                   prompt_len=None, generated=None):
@@ -984,7 +986,11 @@ class ServingTelemetry:
 
     # -- engine iteration bracketing --------------------------------------
     def begin_iteration(self, iteration):
-        self._iter_deadline_cancels = 0     # engine-thread only
+        # engine-thread only: drop what an iteration that never ended
+        # (dropped by a fault) left behind
+        d = self._iter_deadline_cancels
+        for it in [it for it in d if it < iteration - 1]:
+            del d[it]
 
     def end_iteration(self, iteration, values=None, **flight_fields):
         """Record the iteration into the flight ring, roll the SLO
@@ -995,7 +1001,7 @@ class ServingTelemetry:
         fields in ITER_FIELDS order (deadline_cancels is appended
         here) — no per-iteration dicts. Keyword fields are the
         readable fallback for cold callers."""
-        cancels = self._iter_deadline_cancels
+        cancels = self._iter_deadline_cancels.pop(iteration, 0)
         if values is not None:
             self.flight.record_iteration(iteration, values + (cancels,))
         else:

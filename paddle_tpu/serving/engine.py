@@ -26,6 +26,7 @@ params (same math as gpt.build_kv_step, vectorized over the chunk
 axis, KV routed through serving.kv_cache.paged_attention/write).
 """
 
+import contextlib
 import itertools
 import math
 import threading
@@ -65,6 +66,20 @@ def _moved(leaf, arrays):
     arrays = [a for a in arrays if a is not None]
     return {**(leaf or {}), "arrays": len(arrays),
             "bytes": sum(a.nbytes for a in arrays)}
+
+
+class _Dispatched:
+    """One fused step from its plan to its commit: what the engine
+    holds of it while it may be in flight."""
+
+    __slots__ = ("plan", "it", "admitted", "t0", "out", "draft_logps",
+                 "fetched", "overlapped")
+
+    def __init__(self, plan, it, admitted):
+        self.plan, self.it, self.admitted = plan, it, admitted
+        self.t0 = None              # perf_counter at its feed
+        self.out = self.draft_logps = self.fetched = None
+        self.overlapped = False     # dispatched with a step in flight
 
 
 def _sample_rows(base, rng, temperature, do_top_k, top_p):
@@ -805,18 +820,39 @@ class GenerationServer:
             self._zero_mask = jnp.zeros(
                 (s, c, self._vocab) if spec is not None
                 else (s, self._vocab), jnp.float32)
+        # last: the lanes whose column 0 is the previous step's id
+        # (plan.from_prev), handed to the step as the device array that
+        # step returned, never fetched first (docs/serving.md "One step
+        # in flight")
+        fields.append(((s,), np.bool_))
         self._feed = layout = FeedLayout(fields)
 
-        def packed_step(pools, packed, *mask):
+        def packed_step(pools, packed, prev_ids, *mask):
             feeds = layout.unpack(packed)
-            return fused(pools, *feeds[:4], *mask, *feeds[4:])
+            tokens, from_prev = feeds[0], feeds[-1]
+            tokens = tokens.at[:, 0].set(
+                jnp.where(from_prev, prev_ids, tokens[:, 0]))
+            return fused(pools, tokens, *feeds[1:4], *mask, *feeds[4:-1])
 
         # argument 0 is the pools: the step rewrites them in place (XLA
         # aliases each pool's output to its input), so the call consumes
         # them and _dispatch_fused stores the new set before anyone can
         # look (docs/serving.md "Who owns the pools"). Only the pools:
-        # the resident zero mask is passed again every step
+        # the previous step's ids are both the next step's input and
+        # the host's copy for commit, and the resident zero mask is
+        # passed again every step
         self._fused = jax.jit(packed_step, donate_argnums=(0,))
+        # what a step takes for the previous ids where none is in
+        # flight: zeros, placed as the step places its ids (replicated
+        # over a mesh; on one device as uncommitted as every other
+        # argument), so that the jit's cache holds one entry whichever
+        # of the two it is handed
+        self._zero_ids = (
+            jax.device_put(np.zeros((s,), np.int32),
+                           jax.sharding.NamedSharding(
+                               mesh, jax.sharding.PartitionSpec()))
+            if mesh is not None else jnp.asarray(np.zeros((s,), np.int32)))
+        self._inflight = None           # the step dispatched, not fetched
         self._signatures = set()
         self._n_arguments = None        # _arguments(): read once
         # HBM ledger (observability/compile_insight.py): the serving
@@ -1039,6 +1075,9 @@ class GenerationServer:
             "pool_donations": reg.counter(
                 "serving.kv.pool_donations",
                 _help("serving.kv.pool_donations")),
+            "overlapped_iterations": reg.counter(
+                "serving.overlapped_iterations",
+                _help("serving.overlapped_iterations")),
         }
         # a model whose step counts something returns the counts as
         # one more output (`_fused_step_body`) and names them:
@@ -1270,164 +1309,265 @@ class GenerationServer:
 
     # -- serve loop --------------------------------------------------------
     def step(self):
-        """Run one scheduler iteration + fused device step. Returns
-        True if any lane did work. Public so tests can pump the engine
-        deterministically without the worker thread."""
+        """Run one scheduler iteration: plan it and dispatch its fused
+        step, then fetch and commit the step before it, which ran on
+        the device meanwhile (docs/serving.md "One step in flight").
+        Returns True if any lane did work. Public so tests can pump the
+        engine deterministically without the worker thread; every call
+        commits what the one before it dispatched, so a pump that stops
+        while work is left leaves one step in flight, which the next
+        call, `run_until_idle` or `close` commits."""
         with self._step_lock:
-            tel = self._tel
-            rec = get_recorder()
-            it0 = self._sched.iteration
-            # leaf spans (docs/serving.md): plan, feed, dispatch, fetch,
-            # commit, account tile the body of a non-idle iteration on
-            # this thread, so every idle gap of the device has a name
-            with rec.span("serving.plan", cat="serving",
-                          args=lambda: {"iteration": self._sched.iteration,
-                                        "idle": plan is None}):
-                if tel is not None:
-                    # before plan(): the iteration's deadline cancels
-                    # fire inside plan and must land on THIS iteration's
-                    # flight entry (plan() increments the counter if
-                    # non-idle)
-                    tel.begin_iteration(it0 + 1)
-                admitted0 = self._sched.counts["admitted"]
-                plan = self._sched.plan()
-                self._publish_gauges()
-            if plan is None:
-                it = self._sched.iteration
-                if self._chaos is not None and it > it0:
-                    # a poison keyed to a cancel/deadline-only
-                    # iteration (counted, but no lane ran) would be
-                    # popped by no one and silently lost — re-key it to
-                    # the next iteration instead
-                    poison_layer = self._chaos.serving_poison_at(it)
-                    if poison_layer is not None:
-                        self._chaos.poison_serving_at(it + 1,
-                                                      poison_layer)
-                if tel is not None and it > it0:
-                    # a cancel/deadline-only iteration (counted by the
-                    # scheduler, but no lane ran): the flight ring and
-                    # the deadline-storm detector must still see it
-                    tel.end_iteration(
-                        it, step_ms=0.0, lanes=[], emitting=[],
-                        prefill_tokens=0,
-                        admitted=self._sched.counts["admitted"]
-                        - admitted0,
-                        retired=[],
-                        queue_depth=self._sched.queue_depth,
-                        active_slots=self._sched.active_count,
-                        blocks_free=self.cache.num_free,
-                        blocks_in_use=self.cache.num_used,
-                        watermark_blocks=self._sched.watermark_blocks,
-                        lanes_detail=[],
-                        kernel={"mode": self._kernel_mode,
-                                "engaged": self._kernel_engaged})
-                return False
-            it = self._sched.iteration
-            # pre-step occupancy rides the plan (built inside plan()'s
-            # slot loop — no second scheduler-lock round-trip)
-            lanes = plan.lanes_detail
-            leaf = {"iteration": it} if rec.enabled else None
-            t0 = time.perf_counter()
-            with rec.span("serving.iteration", cat="serving",
-                          args=lambda: self._iteration_record(plan, it)):
-                with rec.span("serving.feed", cat="serving", args=leaf):
-                    if self._chaos is not None:
-                        self._apply_step_chaos(it, lanes)
-                    # speculative mode: the draft step runs EVERY
-                    # iteration (its KV must track prefill chunks too,
-                    # not just decode lanes) and its proposals land in
-                    # plan.tokens columns 1..q-1 before the fused step
-                    # verifies them: they are part of what is fed
-                    draft_logps = None
-                    if self._draft is not None:
-                        with rec.span("serving.draft", cat="serving",
-                                      args=leaf):
-                            draft_logps = self._run_draft(plan)
-                    with rec.span("serving.feed.build", cat="serving",
-                                  args=leaf):
-                        feeds = (plan.tokens, plan.positions, plan.valid,
-                                 plan.tables)
-                        if self._strategies:
-                            # the controls and the mask are DATA with
-                            # constant shapes — the signature set below
-                            # still collapses to one entry
-                            do_sample, temperature, top_k, top_p, keys = \
-                                plan.sample_ctl
-                            feeds += (keys, temperature, do_sample, top_k,
-                                      top_p)
-                        mask = (self._guided_mask(plan, it)
-                                if self._strategies else None)
-                        # a mask of None stands for the resident zeros
-                        host = (self._feed.pack(feeds),) + (
-                            (mask,) if self._strategies else ())
-                    with rec.span("serving.feed.upload", cat="serving",
-                                  args=lambda: _moved(leaf, host)):
-                        args = tuple(self._zero_mask if a is None
-                                     else jnp.asarray(a) for a in host)
-                    if mask is not None:
-                        self._m["mask_uploads"].inc()
-                    self._signatures.add(
-                        tuple((a.shape, str(a.dtype)) for a in args))
-                with rec.span("serving.dispatch", cat="serving",
-                              args=lambda: {
-                                  **(leaf or {}),
-                                  "arguments": self._arguments(args)}):
-                    out = self._dispatch_fused(args)
-                with rec.span("serving.fetch", cat="serving", args=leaf):
-                    nxt, logps, fed, rows = self._fetch_outputs(out, plan)
-            with rec.span("serving.commit", cat="serving", args=leaf):
-                # non-finite logits guard: one reduce on the hot path (a
-                # NaN/Inf anywhere makes the sum non-finite; idle lanes
-                # hold finite garbage); the per-slot triage only runs on
-                # a trip, BEFORE commit() streams garbage tokens to
-                # clients. math.isfinite on the extracted scalar beats
-                # np.isfinite's ufunc dispatch on this every-iteration
-                # path. The fail-stop is a safety feature and runs
-                # regardless of telemetry — only the flight-recorder
-                # dump needs it
-                if plan.slot_ids and \
-                        not math.isfinite(float(logps.sum())):
-                    if not np.all(np.isfinite(logps[plan.slot_ids])):
-                        self._on_engine_fault(plan, it, logps, lanes)
-                retired = self._sched.commit(plan, nxt, logps,
-                                             fed_logps=fed,
-                                             draft_logps=draft_logps,
-                                             rows=rows)
-            with rec.span("serving.account", cat="serving", args=leaf):
-                self._m["iterations"].inc()
-                if plan.sample_ctl[0].any():
-                    # the step's own predicate: it took its sampled
-                    # branch (`_sampled_where_asked`)
-                    self._m["sampled_iterations"].inc()
-                self._m["valid_columns"].inc(plan.valid_columns)
-                self._m["padded_columns"].inc(plan.padded_columns)
-                step_ms = (time.perf_counter() - t0) * 1e3
-                self._m["step_ms"].observe(step_ms)
-                self._publish_gauges()
-                if tel is not None:
-                    st = self._sched
-                    # hot path: one ITER_FIELDS-order tuple per
-                    # iteration (tuples of scalars are GC-untracked;
-                    # per-iteration dicts next to a ~0.25 ms fused step
-                    # kept promoting ring garbage into the older GC
-                    # generations)
-                    tel.end_iteration(it, (
-                        round(step_ms, 3),              # step_ms
-                        tuple(plan.slot_ids),           # lanes
-                        tuple(plan.emitting),           # emitting
-                        plan.prefill_tokens,
-                        st.counts["admitted"] - admitted0,
-                        tuple(r.request_id for r in retired),
-                        plan.queue_depth,
-                        len(plan.slot_ids),             # active_slots
-                        self.cache.num_free,            # blocks_free
-                        self.cache.num_used,            # blocks_in_use
-                        st.watermark_blocks,
-                        lanes,                          # lanes_detail
-                        self._kernel_info()))
-            return True
+            try:
+                return self._step()
+            except BaseException:
+                # what is in flight is dropped: a serial engine's step
+                # raised before its commit the same way
+                self._inflight = None
+                self._sched.forget_uncommitted()
+                raise
 
-    def _iteration_record(self, plan, it):
+    def _serial(self, plan):
+        """Whether the step after `plan` needs its outputs on the host
+        before its feed, so that `plan` is committed before the next
+        plan is made: a draft model (its step reads the committed
+        tokens), guided lanes (the mask follows the automaton), rows a
+        group reads (a fork or a beam re-rank rewrites tables and
+        tokens), a host tier (a preemption spills what a step writes),
+        or a fault."""
+        return (self._draft is not None or bool(plan.guided_lanes)
+                or plan.needs_rows or self.cache.host is not None
+                or self._fault is not None)
+
+    def _step(self):
+        tel = self._tel
+        rec = get_recorder()
+        prev = self._inflight
+        if prev is not None and not self._sched.plans_ahead():
+            # every lane ends with the step in flight: commit it, and
+            # plan after that, as a serial engine does
+            self._inflight = None
+            self._complete(prev)
+            return True
+        it0 = self._sched.iteration
+        # leaf spans (docs/serving.md): plan, feed, dispatch, fetch,
+        # commit, account tile the body of a non-idle iteration on this
+        # thread, so every idle gap of the device has a name
+        with rec.span("serving.plan", cat="serving",
+                      args=lambda: {"iteration": self._sched.iteration,
+                                    "idle": plan is None}):
+            if tel is not None:
+                # before plan(): the iteration's deadline cancels fire
+                # inside plan and must land on THIS iteration's flight
+                # entry (plan() increments the counter if non-idle)
+                tel.begin_iteration(it0 + 1)
+            admitted0 = self._sched.counts["admitted"]
+            plan = self._sched.plan()
+            self._publish_gauges()
+        if plan is None:
+            self._idle_iteration(it0, admitted0)
+            if prev is not None:
+                self._inflight = None
+                self._complete(prev)
+                return True
+            return False
+        it = self._sched.iteration
+        cur = _Dispatched(plan, it,
+                          self._sched.counts["admitted"] - admitted0)
+        # the previous step's ids: where this plan feeds a lane the
+        # token that step emits, the device hands it over
+        prev_ids = prev.out[1] if prev is not None else self._zero_ids
+        serial = self._serial(plan)
+        if prev is not None and serial:
+            # commit the step in flight before this feed: a lane whose
+            # request that commit ends is fed by this step alone, which
+            # frees it
+            self._inflight = None
+            self._complete(prev)
+            prev = None
+        # pre-step occupancy rides the plan (built inside plan()'s slot
+        # loop — no second scheduler-lock round-trip)
+        lanes = plan.lanes_detail
+        leaf = {"iteration": it} if rec.enabled else None
+        cur.overlapped = prev is not None
+        # the step this call fetches: the one in flight, or this one
+        # where the next plan needs its outputs first; none on the call
+        # that starts a run
+        done = prev if prev is not None else cur if serial else None
+        cur.t0 = time.perf_counter()
+        with self._iteration_span(done):
+            with rec.span("serving.feed", cat="serving", args=leaf):
+                if self._chaos is not None:
+                    self._apply_step_chaos(it, lanes)
+                # speculative mode: the draft step runs EVERY iteration
+                # (its KV must track prefill chunks too, not just decode
+                # lanes) and its proposals land in plan.tokens columns
+                # 1..q-1 before the fused step verifies them: they are
+                # part of what is fed
+                if self._draft is not None:
+                    with rec.span("serving.draft", cat="serving",
+                                  args=leaf):
+                        cur.draft_logps = self._run_draft(plan)
+                with rec.span("serving.feed.build", cat="serving",
+                              args=leaf):
+                    feeds = (plan.tokens, plan.positions, plan.valid,
+                             plan.tables)
+                    if self._strategies:
+                        # the controls and the mask are DATA with
+                        # constant shapes — the signature set below
+                        # still collapses to one entry
+                        do_sample, temperature, top_k, top_p, keys = \
+                            plan.sample_ctl
+                        feeds += (keys, temperature, do_sample, top_k,
+                                  top_p)
+                    feeds += (plan.from_prev,)
+                    mask = (self._guided_mask(plan, it)
+                            if self._strategies else None)
+                    # a mask of None stands for the resident zeros
+                    host = (self._feed.pack(feeds),) + (
+                        (mask,) if self._strategies else ())
+                with rec.span("serving.feed.upload", cat="serving",
+                              args=lambda: _moved(leaf, host)):
+                    up = tuple(self._zero_mask if a is None
+                               else jnp.asarray(a) for a in host)
+                args = (up[0], prev_ids) + up[1:]
+                if mask is not None:
+                    self._m["mask_uploads"].inc()
+                self._signatures.add(
+                    tuple((a.shape, str(a.dtype)) for a in args))
+            with rec.span("serving.dispatch", cat="serving",
+                          args=lambda: {
+                              **(leaf or {}),
+                              "arguments": self._arguments(args)}):
+                cur.out = self._dispatch_fused(args)
+            if cur.overlapped:
+                self._m["overlapped_iterations"].inc()
+            # one step in flight: the one just dispatched, unless the
+            # next plan needs its outputs first; the previous one is
+            # fetched while this one runs
+            self._inflight = None if serial else cur
+            if done is not None:
+                done.fetched = self._fetch(done)
+        if done is not None:
+            self._commit_and_account(done)
+        return True
+
+    def _iteration_span(self, done):
+        """`serving.iteration`: from the call's feed, where it feeds, to
+        the end of its fetch of `done`, whose record it carries; no span
+        on a call that fetches nothing."""
+        if done is None:
+            return contextlib.nullcontext()
+        return get_recorder().span(
+            "serving.iteration", cat="serving",
+            args=lambda: self._iteration_record(done))
+
+    def _idle_iteration(self, it0, admitted0):
+        """A plan() that came back None: where it still counted an
+        iteration (a cancel/deadline-only one, no lane ran), the chaos
+        plan and the flight ring must see it."""
+        tel = self._tel
+        it = self._sched.iteration
+        if self._chaos is not None and it > it0:
+            # a poison keyed to a cancel/deadline-only iteration would
+            # be popped by no one and silently lost — re-key it to the
+            # next iteration instead
+            poison_layer = self._chaos.serving_poison_at(it)
+            if poison_layer is not None:
+                self._chaos.poison_serving_at(it + 1, poison_layer)
+        if tel is not None and it > it0:
+            tel.end_iteration(
+                it, step_ms=0.0, lanes=[], emitting=[],
+                prefill_tokens=0,
+                admitted=self._sched.counts["admitted"] - admitted0,
+                retired=[],
+                queue_depth=self._sched.queue_depth,
+                active_slots=self._sched.active_count,
+                blocks_free=self.cache.num_free,
+                blocks_in_use=self.cache.num_used,
+                watermark_blocks=self._sched.watermark_blocks,
+                lanes_detail=[],
+                kernel={"mode": self._kernel_mode,
+                        "engaged": self._kernel_engaged})
+
+    def _complete(self, done):
+        """Fetch, commit and account a step dispatched earlier."""
+        with self._iteration_span(done):
+            done.fetched = self._fetch(done)
+        self._commit_and_account(done)
+
+    def _fetch(self, done):
+        rec = get_recorder()
+        leaf = {"iteration": done.it} if rec.enabled else None
+        with rec.span("serving.fetch", cat="serving", args=leaf):
+            return self._fetch_outputs(done.out, done.plan)
+
+    def _commit_and_account(self, done):
+        """commit() a fetched step's outputs and account its
+        iteration."""
+        tel = self._tel
+        rec = get_recorder()
+        plan, it = done.plan, done.it
+        nxt, logps, fed, rows = done.fetched
+        leaf = {"iteration": it} if rec.enabled else None
+        with rec.span("serving.commit", cat="serving", args=leaf):
+            # non-finite logits guard: one reduce on the hot path (a
+            # NaN/Inf anywhere makes the sum non-finite; idle lanes hold
+            # finite garbage); the per-slot triage only runs on a trip,
+            # BEFORE commit() streams garbage tokens to clients.
+            # math.isfinite on the extracted scalar beats np.isfinite's
+            # ufunc dispatch on this every-iteration path. The fail-stop
+            # is a safety feature and runs regardless of telemetry —
+            # only the flight-recorder dump needs it
+            if plan.slot_ids and not math.isfinite(float(logps.sum())):
+                if not np.all(np.isfinite(logps[plan.slot_ids])):
+                    # the step in flight read the same pools: wait for
+                    # it and drop it, nothing of it is committed
+                    self._drop_inflight()
+                    self._on_engine_fault(plan, it, logps,
+                                          plan.lanes_detail)
+            retired = self._sched.commit(plan, nxt, logps, fed_logps=fed,
+                                         draft_logps=done.draft_logps,
+                                         rows=rows)
+        with rec.span("serving.account", cat="serving", args=leaf):
+            self._m["iterations"].inc()
+            if plan.sample_ctl[0].any():
+                # the step's own predicate: it took its sampled branch
+                # (`_sampled_where_asked`)
+                self._m["sampled_iterations"].inc()
+            self._m["valid_columns"].inc(plan.valid_columns)
+            self._m["padded_columns"].inc(plan.padded_columns)
+            step_ms = (time.perf_counter() - done.t0) * 1e3
+            self._m["step_ms"].observe(step_ms)
+            self._publish_gauges()
+            if tel is not None:
+                st = self._sched
+                # hot path: one ITER_FIELDS-order tuple per iteration
+                # (tuples of scalars are GC-untracked; per-iteration
+                # dicts next to a ~0.25 ms fused step kept promoting
+                # ring garbage into the older GC generations)
+                tel.end_iteration(it, (
+                    round(step_ms, 3),              # step_ms
+                    tuple(plan.slot_ids),           # lanes
+                    tuple(plan.emitting),           # emitting
+                    plan.prefill_tokens,
+                    done.admitted,
+                    tuple(r.request_id for r in retired),
+                    plan.queue_depth,
+                    len(plan.slot_ids),             # active_slots
+                    self.cache.num_free,            # blocks_free
+                    self.cache.num_used,            # blocks_in_use
+                    st.watermark_blocks,
+                    plan.lanes_detail,              # lanes_detail
+                    self._kernel_info()))
+
+    def _drop_inflight(self):
+        """Wait for the step in flight, if any, and drop its outputs."""
+        done, self._inflight = self._inflight, None
+        if done is not None:
+            done.out[1].block_until_ready()
+
+    def _iteration_record(self, done):
         """The `serving.iteration` span's args, built only while a
         capture is live: what the fused step was handed. `lanes_qc` is
         each lane's (queries, context) — columns fed this iteration, and
@@ -1441,9 +1581,12 @@ class GenerationServer:
         `walk_groups_whole` counts the live groups that lie wholly at or
         below a lane's lowest fed position (its column 0): every query
         of the lane sees every key of such a group, so its causal mask
-        is all true. A model whose step counts (`step_counters`) adds
-        the counts under its names."""
+        is all true. `overlapped` is 1 where the step was dispatched
+        while the one before it was not yet fetched (the counter
+        `serving.overlapped_iterations`), else 0. A model whose step
+        counts (`step_counters`) adds the counts under its names."""
         from ..ops.pallas.paged import walk_group
+        plan, it = done.plan, done.it
         cols = plan.valid.sum(axis=1)
         lanes_qc = [[int(cols[sid]),
                      int(plan.positions[sid, cols[sid] - 1]) + 1]
@@ -1462,7 +1605,8 @@ class GenerationServer:
                   "walk_groups_whole": sum(
                       (int(plan.positions[sid, 0]) + 1) // keys
                       for sid in plan.slot_ids),
-                  "walk_groups": len(lanes_qc) * -(-width // group)}
+                  "walk_groups": len(lanes_qc) * -(-width // group),
+                  "overlapped": int(done.overlapped)}
         record.update(zip((arg for arg, _metric in self._step_counters),
                           self._step_counts or ()))
         return record
@@ -1572,8 +1716,7 @@ class GenerationServer:
         Child spans of `serving.fetch`: `serving.fetch.wait` until the
         ids are ready, `serving.fetch.copy` for the copies after it."""
         rec = get_recorder()
-        leaf = {"iteration": self._sched.iteration} if rec.enabled \
-            else None
+        leaf = {"iteration": plan.iteration} if rec.enabled else None
         with rec.span("serving.fetch.wait", cat="serving", args=leaf):
             if self._step_counters:
                 # the step's counts, its last output: their copy starts
@@ -1942,6 +2085,14 @@ class GenerationServer:
                                           deadline - time.monotonic()))
         elif drain:
             self.run_until_idle()
+        if self._inflight is not None and not (
+                self._worker is not None and self._worker.is_alive()):
+            # a step still in flight (close without drain, or a pump
+            # that stopped): its commit finds its lanes released
+            with self._step_lock:
+                if self._inflight is not None:
+                    done, self._inflight = self._inflight, None
+                    self._complete(done)
         self._publish_gauges()
         if self._exporter is not None:
             self._exporter.close()

@@ -119,14 +119,15 @@ class _Request:
 class _Slot:
     __slots__ = ("req", "blocks", "table", "pos", "admit_seq", "shared",
                  "keys", "registered", "cow_spares", "cow_copies",
-                 "tier", "hold")
+                 "tier", "hold", "ahead", "emits_ahead", "cols", "emit",
+                 "done", "ending")
 
     def __init__(self, req, blocks, table, admit_seq, shared=(),
                  keys=(), registered=0, cow_spares=(), tier="device"):
         self.req = req
         self.blocks = blocks                # every block to release
         self.table = table                  # np.int32 (max_blocks,)
-        self.pos = 0                        # next logical position to feed
+        self.pos = 0                        # next position, as committed
         self.admit_seq = admit_seq          # admission age (chaos targets)
         self.shared = list(shared)          # prefix-cache blocks in table
         self.keys = list(keys)              # chunk chain keys computed
@@ -141,10 +142,50 @@ class _Slot:
         # prefill: it owns its suffix reservation but plans no work
         # until the fork clears the hold (commit's _fork_group)
         self.hold = False
+        # what plans not yet committed feed this lane: the columns, and
+        # the tokens they emit (the engine dispatches the next step
+        # before it commits the last, docs/serving.md "One step in
+        # flight"); and of the newest plan alone, `cols` and `emit`
+        self.ahead = 0
+        self.emits_ahead = 0
+        self.cols = 0
+        self.emit = 0
+        # the request ended while a step not yet committed fed the lane:
+        # the slot is released at that step's commit
+        self.done = False
+        # (exception, count key) of a cancel or deadline that a plan
+        # decided while a step fed the lane: applied at its commit
+        self.ending = None
+
+    @property
+    def next_pos(self):
+        """The next position to feed: what is committed, and what the
+        plans not yet committed feed."""
+        return self.pos + self.ahead
 
     @property
     def prefilling(self):
-        return self.pos < len(self.req.prompt)
+        return self.next_pos < len(self.req.prompt)
+
+    @property
+    def exhausted(self):
+        """The plans not yet committed emit the request's last token."""
+        return (not self.prefilling and len(self.req.generated)
+                + self.emits_ahead >= self.req.max_new_tokens)
+
+    @property
+    def live(self):
+        """The lane is one that an engine which commits each step
+        before it plans the next would still hold after the newest
+        plan: not ended, and fed by that plan or with tokens left."""
+        return not (self.done or self.ending is not None
+                    or (not self.cols and self.exhausted))
+
+    @property
+    def plannable(self):
+        """A plan made now would feed this lane."""
+        return not (self.hold or self.done or self.ending is not None
+                    or self.exhausted)
 
 
 class _Preempted:
@@ -171,10 +212,13 @@ def _lane_tuple(sid, slot):
     serving_telemetry.LANE_FIELDS order — the flight dump's
     _expand_lanes zips these against that schema, so every producer
     must go through this helper (plan()'s slot loop and
-    lane_snapshot())."""
+    lane_snapshot()). Position and tokens are those the newest plan
+    started from, committed or not."""
     group = slot.req.group
-    return (sid, slot.req.rid, int(slot.pos), bool(slot.prefilling),
-            int(slot.admit_seq), len(slot.req.generated),
+    pos = slot.next_pos - slot.cols
+    return (sid, slot.req.rid, int(pos), pos < len(slot.req.prompt),
+            int(slot.admit_seq),
+            len(slot.req.generated) + slot.emits_ahead - slot.emit,
             int(slot.blocks[0]) if slot.blocks else None,
             len(slot.shared), int(slot.cow_copies), slot.tier,
             group.gid if group is not None else None,
@@ -190,18 +234,26 @@ class IterationPlan:
     mode, where the engine fills columns 1..q-1 with draft proposals
     before the fused step runs); 0 marks a prefill lane. `limits[s]` is
     the lane's reserved token horizon (prompt + max_new_tokens) — the
-    draft step's rollout must never write a position past it."""
+    draft step's rollout must never write a position past it.
+    `from_prev[s]` marks a decode lane whose token is the previous
+    step's output, not yet committed: the engine hands that step's ids
+    to this one, which takes column 0 from them. `slots` holds the slot
+    each of `slot_ids` names, for commit() to tell it from a slot
+    admitted in its place. `iteration` is the scheduler's count that
+    planned it."""
 
     __slots__ = ("tokens", "positions", "valid", "tables", "slot_ids",
                  "emitting", "prefill_tokens", "decode_cols", "limits",
                  "lanes_detail", "queue_depth", "sample_ctl",
-                 "guided_lanes", "needs_rows", "valid_columns")
+                 "guided_lanes", "needs_rows", "valid_columns",
+                 "from_prev", "slots", "iteration")
 
     def __init__(self, tokens, positions, valid, tables, slot_ids,
                  emitting, prefill_tokens, decode_cols=None,
                  limits=None, lanes_detail=None, queue_depth=None,
                  sample_ctl=None, guided_lanes=None, needs_rows=False,
-                 valid_columns=0):
+                 valid_columns=0, from_prev=None, slots=None,
+                 iteration=None):
         self.tokens = tokens                # (S, C) int32
         self.positions = positions          # (S, C) int32
         self.valid = valid                  # (S, C) bool
@@ -230,6 +282,9 @@ class IterationPlan:
         # columns of the (S, C) grid that carry a token this iteration;
         # the rest of the grid is padding the fused step computes anyway
         self.valid_columns = valid_columns
+        self.from_prev = from_prev          # (S,) bool
+        self.slots = slots
+        self.iteration = iteration
 
     @property
     def padded_columns(self):
@@ -557,8 +612,11 @@ class ContinuousBatchingScheduler:
         # (admission order, NOT slot order — freed slots get reused)
         if self._chaos is not None:
             for idx in self._chaos.serving_cancels_at(self.iteration):
+                # a lane the step in flight finishes is gone once it is
+                # committed: it is not active here
                 active = [s.req.rid for s in sorted(
-                    (s for s in self._slots if s is not None),
+                    (s for s in self._slots if s is not None
+                     and not s.done and not s.exhausted),
                     key=lambda s: s.admit_seq)]
                 if idx < len(active):
                     self._cancel_rids.add(active[idx])
@@ -575,9 +633,8 @@ class ContinuousBatchingScheduler:
                                  "cancelled")
             for sid, slot in enumerate(self._slots):
                 if slot is not None and slot.req.rid in rids:
-                    self._fail(slot.req, RequestCancelled(
+                    self._end_lane(sid, RequestCancelled(
                         f"request {slot.req.rid} cancelled"), "cancelled")
-                    self._release_slot(sid)
         self._drop_queued(
             lambda r: r.deadline is not None and now > r.deadline,
             lambda r: DeadlineExceeded(
@@ -593,11 +650,70 @@ class ContinuousBatchingScheduler:
                 continue
             dl = slot.req.deadline
             if dl is not None and now > dl:
-                self._fail(slot.req, DeadlineExceeded(
+                self._end_lane(sid, DeadlineExceeded(
                     f"request {slot.req.rid} deadline passed after "
-                    f"{len(slot.req.generated)} tokens"),
-                    "deadline_cancels")
-                self._release_slot(sid)
+                    f"{len(slot.req.generated) + slot.emits_ahead} "
+                    f"tokens"), "deadline_cancels")
+
+    def _end_lane(self, sid, exc, count_key):
+        """A cancel or deadline ends the request in slot `sid`. Where a
+        step not yet committed feeds the lane, the request ends at that
+        step's commit, after the tokens it emits, as if it had been
+        committed before this plan: unless that step emits its last
+        token, when the request finishes there and this is a no-op."""
+        slot = self._slots[sid]
+        if slot.done:
+            return
+        if slot.ahead:
+            if not slot.exhausted and slot.ending is None:
+                slot.ending = (exc, count_key)
+            return
+        self._fail(slot.req, exc, count_key)
+        self._release_slot(sid)
+
+    def _retire_lane(self, sid):
+        """Free slot `sid` of a request that has ended, or, where a step
+        not yet committed still writes its blocks (or its state or ring
+        lane), mark it done: that step's commit frees it. No other lane
+        is handed what the device has yet to write."""
+        slot = self._slots[sid]
+        if slot.ahead:
+            slot.done = True
+        else:
+            self._release_slot(sid)
+
+    def forget_uncommitted(self):
+        """The steps planned and not committed will never be: every
+        lane goes back to what is committed (a lane they fed is fed
+        again), and a slot that waited for them is freed."""
+        with self._lock:
+            for sid, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                slot.ahead = slot.emits_ahead = slot.cols = slot.emit = 0
+                if slot.done:
+                    self._release_slot(sid)
+                elif slot.ending is not None:
+                    exc, count_key = slot.ending
+                    slot.ending = None
+                    self._fail(slot.req, exc, count_key)
+                    self._release_slot(sid)
+
+    def plans_ahead(self):
+        """Whether to plan now, before the step in flight is committed:
+        False where no lane would be fed (every lane is done, held,
+        ending or fed its last token by that step), or where a request
+        waits in the queue for a slot, none is free, and that step
+        feeds some lane its last token. The engine then commits that
+        step first and plans as an engine that commits each step before
+        the next plan does, so that the waiting request is admitted
+        into the slot that commit frees at the same iteration."""
+        with self._lock:
+            if self._queue and all(s is not None for s in self._slots) \
+                    and any(s.exhausted and not s.done
+                            for s in self._slots):
+                return False
+            return any(s is not None and s.plannable for s in self._slots)
 
     def _admit(self, now):
         self._try_resume(now)
@@ -1034,7 +1150,7 @@ class ContinuousBatchingScheduler:
         deterministic tests drive without arranging real divergence.
         Returns True when a copy happened."""
         bs = self._cache.block_size
-        bi = slot.pos // bs
+        bi = slot.next_pos // bs
         if bi >= slot.table.size or int(slot.table[bi]) == 0:
             return False
         group = slot.req.group
@@ -1116,7 +1232,7 @@ class ContinuousBatchingScheduler:
                     for slot in self._slots:
                         if forced >= k_storm:
                             break
-                        if slot is None or slot.hold \
+                        if slot is None or not slot.plannable \
                                 or slot.req.group is None \
                                 or slot.prefilling:
                             continue
@@ -1128,14 +1244,15 @@ class ContinuousBatchingScheduler:
 
             def _plan_cols(slot):
                 if slot.prefilling:
-                    return min(c, len(slot.req.prompt) - slot.pos)
+                    return min(c, len(slot.req.prompt) - slot.next_pos)
                 sp = slot.req.sampling
                 if self.spec_k and not (sp is not None and sp.do_sample):
                     # sampled lanes stay 1-column: draft acceptance is
                     # defined against the target's deterministic choice
                     return max(1, min(self.spec_k + 1, c,
                                       slot.req.max_new_tokens
-                                      - len(slot.req.generated)))
+                                      - len(slot.req.generated)
+                                      - slot.emits_ahead))
                 return 1
 
             # lazy-mode growth PRE-PASS: every lane's block needs are
@@ -1164,36 +1281,48 @@ class ContinuousBatchingScheduler:
             top_k_arr = np.zeros((s,), np.int32)
             top_p_arr = np.full((s,), 2.0, np.float32)
             rng_keys = np.zeros((s, 2), np.uint32)
+            from_prev = np.zeros((s,), bool)
             guided_lanes = []
             needs_rows = False
+            planned = []
             for sid, slot in enumerate(self._slots):
+                if slot is not None:
+                    slot.cols = slot.emit = 0
                 # held slots are fork-group followers parked until the
                 # leader's prefill completes — they own suffix blocks
-                # but have no tokens to run yet
-                if slot is None or sid in starved or slot.hold:
+                # but have no tokens to run yet; a done, ending or
+                # exhausted lane has nothing left to feed
+                if slot is None or sid in starved or not slot.plannable:
                     continue
                 slot_ids.append(sid)
+                planned.append(slot)
                 req = slot.req
+                start = slot.next_pos
                 limits[sid] = len(req.prompt) + req.max_new_tokens
                 if lanes is not None:
                     lanes.append(_lane_tuple(sid, slot))
                 n = _plan_cols(slot)        # == the pre-pass's count
                 valid_columns += n
                 if slot.prefilling:
-                    tokens[sid, :n] = req.prompt[slot.pos:slot.pos + n]
+                    tokens[sid, :n] = req.prompt[start:start + n]
                     prefill_tokens += n
                     if self._tel is not None:
                         self._tel.on_prefill_chunk(req.rid,
                                                    self.iteration, n)
-                    if slot.pos + n == len(req.prompt):
+                    if start + n == len(req.prompt):
                         emitting.add(sid)
                 else:
                     # decode lane: 1 column in plain mode; in spec mode
                     # q = min(k+1, chunk, remaining) verify columns —
                     # the engine fills 1..q-1 with draft proposals, and
-                    # commit() accepts 1..q of the per-column outputs
+                    # commit() accepts 1..q of the per-column outputs.
+                    # Its token is the last one emitted: where a step
+                    # not yet committed emits it, the device has it
                     decode_cols[sid] = n
-                    tokens[sid, 0] = req.generated[-1]
+                    if slot.emits_ahead:
+                        from_prev[sid] = True
+                    else:
+                        tokens[sid, 0] = req.generated[-1]
                     emitting.add(sid)
                 group = req.group
                 sp = req.sampling
@@ -1208,7 +1337,7 @@ class ContinuousBatchingScheduler:
                     top_p_arr[sid] = (sp.top_p if sp.top_p is not None
                                       else 2.0)
                     rng_keys[sid] = fold_key(sp.seed, req.lane,
-                                             slot.pos + n - 1)
+                                             start + n - 1)
                 if req.guided is not None and sid in emitting:
                     guided_lanes.append((sid, req))
                     self._count("guided.masked_steps")
@@ -1220,10 +1349,13 @@ class ContinuousBatchingScheduler:
                 # a shared block is never written in place: copy (to a
                 # reserved spare) + repoint BEFORE the table row is
                 # captured into the plan
-                self._maybe_cow(slot, slot.pos, n)
+                self._maybe_cow(slot, start, n)
                 tables[sid] = slot.table
-                positions[sid, :n] = np.arange(slot.pos, slot.pos + n)
+                positions[sid, :n] = np.arange(start, start + n)
                 valid[sid, :n] = True
+                slot.cols, slot.emit = n, int(sid in emitting)
+                slot.ahead += n
+                slot.emits_ahead += slot.emit
             if not slot_ids:
                 return None
             self._count("prefill_tokens", prefill_tokens)
@@ -1236,7 +1368,9 @@ class ContinuousBatchingScheduler:
                 sample_ctl=(do_sample, temperature, top_k_arr,
                             top_p_arr, rng_keys),
                 guided_lanes=tuple(guided_lanes),
-                needs_rows=needs_rows, valid_columns=valid_columns)
+                needs_rows=needs_rows, valid_columns=valid_columns,
+                from_prev=from_prev, slots=planned,
+                iteration=self.iteration)
 
     def _accept(self, plan, sid, ids, logps, fed_logps, draft_logps):
         """One decode lane's committed (token, logp) list + position
@@ -1304,64 +1438,94 @@ class ContinuousBatchingScheduler:
         log-prob rows — (S, V) plain or (S, C, V) per-column — that the
         host-side group strategies consume: fork-time sampling/beam
         seeding and per-iteration beam re-ranking. Returns the list of
-        GenerationResults retired this iteration."""
+        GenerationResults retired this iteration.
+
+        A lane whose request ended at an earlier commit (EOS, found only
+        once that step was fetched) while this step fed it is done: its
+        outputs here are discarded, and its slot freed once no step not
+        yet committed feeds it. A lane a cancel or deadline ended while
+        this step was in flight ends after the tokens this step
+        emits."""
         retired = []
         next_ids = np.asarray(next_ids)
         next_logps = np.asarray(next_logps)
         with self._lock:
             now = self.now()
+            for sid, slot in zip(plan.slot_ids, plan.slots):
+                if self._slots[sid] is slot:
+                    slot.ahead -= int(plan.valid[sid].sum())
+                    slot.emits_ahead -= int(sid in plan.emitting)
             # beam groups re-rank across their K lanes BEFORE the
             # per-lane loop: divergence remaps block tables and rewrites
             # lane streams, so the generic path below only applies the
             # pre-computed per-lane commits
             beam_overrides = self._commit_beam_groups(plan, rows)
-            for sid in plan.slot_ids:
-                slot = self._slots[sid]
-                if slot is None:        # raced with a cancel mid-step
+            for sid, slot in zip(plan.slot_ids, plan.slots):
+                if self._slots[sid] is not slot:
+                    continue    # released under the step (cancel_all)
+                if slot.done:
+                    if not slot.ahead:
+                        self._release_slot(sid)
                     continue
-                req = slot.req
-                group = req.group
-                q = int(plan.decode_cols[sid]) if plan.decode_cols \
-                    is not None else 0
-                if q == 0:
-                    # prefill lane: advance by the chunk fed; register
-                    # freshly-completed full prompt chunks into the
-                    # prefix index; emit only when the prompt finished
-                    n = int(plan.valid[sid].sum())
-                    slot.pos += n
-                    self._register_chunks(slot)
-                    if sid not in plan.emitting:
-                        continue
-                    if group is not None and not group.prefilled:
-                        # leader prefill complete: fork the group (K-1
-                        # table aliases of the prompt blocks) and emit
-                        # every lane's first token host-side
-                        retired.extend(self._fork_group(
-                            group, sid, slot, plan, rows,
-                            next_ids, next_logps, n, now))
-                        continue
-                    commits = [(int(next_ids[sid, n - 1]),
-                                float(next_logps[sid, n - 1]))]
-                elif group is not None and group.kind == "beam":
-                    override = beam_overrides.get(sid)
-                    if override is None:
-                        continue    # group skipped this step (see above)
-                    commits, advance = override
-                    slot.pos += advance
-                else:
-                    commits, advance = self._accept(
-                        plan, sid, next_ids, next_logps, fed_logps,
-                        draft_logps)
-                    slot.pos += advance
-                finished = None
-                for tok, lp in commits:
-                    finished = self._emit_token(req, tok, lp, now)
-                    if finished is not None:
-                        break       # later accepted tokens discarded
+                finished = self._commit_lane(
+                    plan, sid, slot, beam_overrides, next_ids,
+                    next_logps, fed_logps, draft_logps, rows, now,
+                    retired)
                 if finished is not None:
-                    retired.append(self._finish(req, finished))
-                    self._release_slot(sid)
+                    retired.append(self._finish(slot.req, finished))
+                    self._retire_lane(sid)
+                elif slot.ending is not None and \
+                        self._slots[sid] is slot and not slot.done:
+                    exc, count_key = slot.ending
+                    slot.ending = None
+                    self._fail(slot.req, exc, count_key)
+                    self._retire_lane(sid)
         return retired
+
+    def _commit_lane(self, plan, sid, slot, beam_overrides, next_ids,
+                     next_logps, fed_logps, draft_logps, rows, now,
+                     retired):
+        """One lane's part of commit(): advance its position, emit what
+        it chose. Returns the finish reason or None."""
+        req = slot.req
+        group = req.group
+        q = int(plan.decode_cols[sid]) if plan.decode_cols \
+            is not None else 0
+        if q == 0:
+            # prefill lane: advance by the chunk fed; register
+            # freshly-completed full prompt chunks into the prefix
+            # index; emit only when the prompt finished
+            n = int(plan.valid[sid].sum())
+            slot.pos += n
+            self._register_chunks(slot)
+            if sid not in plan.emitting:
+                return None
+            if group is not None and not group.prefilled:
+                # leader prefill complete: fork the group (K-1 table
+                # aliases of the prompt blocks) and emit every lane's
+                # first token host-side
+                retired.extend(self._fork_group(
+                    group, sid, slot, plan, rows, next_ids, next_logps,
+                    n, now))
+                return None
+            commits = [(int(next_ids[sid, n - 1]),
+                        float(next_logps[sid, n - 1]))]
+        elif group is not None and group.kind == "beam":
+            override = beam_overrides.get(sid)
+            if override is None:
+                return None     # group skipped this step (see above)
+            commits, advance = override
+            slot.pos += advance
+        else:
+            commits, advance = self._accept(
+                plan, sid, next_ids, next_logps, fed_logps, draft_logps)
+            slot.pos += advance
+        finished = None
+        for tok, lp in commits:
+            finished = self._emit_token(req, tok, lp, now)
+            if finished is not None:
+                break       # later accepted tokens discarded
+        return finished
 
     def _emit_token(self, req, tok, lp, now):
         """Record ONE committed token on `req`: score/stream/telemetry
@@ -1484,7 +1648,7 @@ class ContinuousBatchingScheduler:
             finished = self._emit_token(lane_req, tok, lp, now)
             if finished is not None:
                 retired.append(self._finish(lane_req, finished))
-                self._release_slot(fsid)
+                self._retire_lane(fsid)
         return retired
 
     def _commit_beam_groups(self, plan, rows):
@@ -1629,7 +1793,9 @@ class ContinuousBatchingScheduler:
         the NaN could never propagate."""
         with self._lock:
             for slot in self._slots:
-                if slot is None or slot.pos < 1:
+                # the position the newest plan feeds from
+                if slot is None or not slot.live \
+                        or slot.next_pos - slot.cols < 1:
                     continue
                 if np.array_equal(slot.req.prompt, prompt):
                     return int(slot.table[0])
@@ -1648,7 +1814,7 @@ class ContinuousBatchingScheduler:
         with self._lock:
             return tuple(_lane_tuple(sid, slot)
                          for sid, slot in enumerate(self._slots)
-                         if slot is not None)
+                         if slot is not None and slot.live)
 
     def stats(self):
         with self._lock:

@@ -140,11 +140,15 @@ def test_ids_and_logps_are_those_of_the_unpacked_feeds(tiny_gpt, run):
         fed.append([np.array(a) for a in feeds])
         return pack(feeds)
 
-    def comparing(pools, packed, mask):
+    def comparing(pools, packed, prev_ids, mask):
         host = fed[-1]
-        ref = plain(jax.tree_util.tree_map(jnp.copy, pools), *host[:4],
-                    np.asarray(mask), *host[4:])
-        out = served(pools, packed, mask)
+        # a lane whose token the step before holds takes it from there
+        tokens = host[0].copy()
+        from_prev = host[-1]
+        tokens[from_prev, 0] = np.asarray(prev_ids)[from_prev]
+        ref = plain(jax.tree_util.tree_map(jnp.copy, pools), tokens,
+                    *host[1:4], np.asarray(mask), *host[4:-1])
+        out = served(pools, packed, prev_ids, mask)
         assert len(out) == len(ref)
         compared.append([(_bits(o), _bits(r))
                          for o, r in zip(out[1:], ref[1:])])
@@ -163,6 +167,9 @@ def test_ids_and_logps_are_those_of_the_unpacked_feeds(tiny_gpt, run):
     if run == "sampled":
         # the controls reached the step: the lanes drew their tokens
         assert any(h[6].any() for h in fed)
+    # a step left in flight handed its ids to the next, except where a
+    # draft model keeps every step's outputs on the host first
+    assert any(h[-1].any() for h in fed) == (run != "per_column")
     srv.close()
 
 

@@ -476,11 +476,18 @@ def test_leaf_spans_tile_every_iteration(tiny_gpt):
         assert len({e["tid"] for e in leaves}) == 1
         for a, b in zip(leaves, leaves[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 0.002, (a, b)
-        # serving.iteration: from the start of feed to the end of fetch
-        # (the span engine.iter_ms_p50 reads; its extent must not move)
+        # serving.iteration (the span engine.iter_ms_p50 reads): from
+        # the start of the feed of the call that fetched this iteration
+        # (with a step in flight the next iteration's feed; none on a
+        # call that only commits) to the end of this iteration's fetch
         whole, feed, fetch = iters[it], leaves[1], leaves[3]
         assert whole["tid"] == feed["tid"]
-        assert 0 <= feed["ts"] - whole["ts"] < 200
+        first = min((e for e in events if e["name"] == "serving.feed"
+                     and whole["ts"] - 0.002 <= e["ts"]
+                     <= whole["ts"] + whole["dur"]),
+                    key=lambda e: e["ts"], default=fetch)
+        assert 0 <= first["ts"] - whole["ts"] < 200
+        assert first["args"]["iteration"] in (it, it + 1)
         assert 0 <= (whole["ts"] + whole["dur"]) \
             - (fetch["ts"] + fetch["dur"]) + 0.002 < 200
         # the iteration record: the (S, C) grid and who filled it
@@ -523,8 +530,9 @@ def test_feed_and_fetch_split_into_child_spans(tiny_gpt):
     m = srv._sched.max_blocks
     # one int32 buffer: tokens, positions, valid (S, C) and tables
     # (S, M); the rng keys (S, 2); temperature, do_sample, top_k, top_p
-    # one element a lane each
-    fed = 4 * (3 * s * c + s * m + 2 * s + 4 * s)
+    # and the lanes whose token the step before holds, one element a
+    # lane each
+    fed = 4 * (3 * s * c + s * m + 2 * s + 5 * s)
     assert delta["serving.feed.mask_uploads"] == 0
     import jax
     pools = jax.tree_util.tree_leaves(srv.cache.pools)
@@ -549,9 +557,10 @@ def test_feed_and_fetch_split_into_child_spans(tiny_gpt):
         assert upload == {"iteration": it, "arrays": 1, "bytes": fed}
         copy = spans["serving.fetch.copy"][0]["args"]
         assert copy == {"iteration": it, "arrays": 2, "bytes": 2 * 4 * s}
+        # the pools, the packed feed, the step before's ids, the mask
         (dispatch,) = spans["serving.dispatch"]
         assert dispatch["args"] == {"iteration": it,
-                                    "arguments": len(pools) + 2}
+                                    "arguments": len(pools) + 3}
         assert spans["serving.fetch.wait"][0]["args"] == {"iteration": it}
 
 

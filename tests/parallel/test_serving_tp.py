@@ -190,9 +190,11 @@ def test_tp2_fused_step_compiles_collectives_and_sharded_pools(trained):
     cfg, params = trained
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     srv = _server(params, cfg, mesh=mesh)
-    # the feed: tokens, positions, valid and tables in one int32 buffer
+    # the feed: tokens, positions, valid and tables in one int32 buffer;
+    # the ids of the step before (none in flight: zeros)
     packed = jnp.zeros((srv._feed.size,), jnp.int32)
-    text = srv._fused.lower(srv.cache.pools, packed).compile().as_text()
+    text = srv._fused.lower(srv.cache.pools, packed,
+                            srv._zero_ids).compile().as_text()
     assert "all-reduce" in text or "all_reduce" in text, \
         "tp fused step compiled without any all-reduce"
     kp = srv.cache.pools[0]["kv"]
