@@ -17,8 +17,12 @@ class AutoMixedPrecisionLists:
     (white), ops kept fp32 (black)."""
 
     def __init__(self, custom_white_list=None, custom_black_list=None):
+        # the fused attention ops are Fluid's matmul pairs (Q.K^T, P.V;
+        # fluid.nets.scaled_dot_product_attention), which it white-lists
         self.white_list = {"matmul", "mul", "conv2d", "conv3d",
-                           "depthwise_conv2d", "conv2d_transpose"}
+                           "depthwise_conv2d", "conv2d_transpose",
+                           "multihead_attention",
+                           "scaled_dot_product_attention"}
         self.black_list = {"softmax_with_cross_entropy", "cross_entropy",
                            "mean", "reduce_mean", "layer_norm", "batch_norm",
                            "exp", "log", "softmax"}

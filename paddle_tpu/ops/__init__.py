@@ -16,6 +16,9 @@ from ..observability import get_recorder
 from ..observability.metrics import global_registry
 
 _REGISTRY = {}
+# op type -> the input slots AMP leaves in their own type (register's
+# amp_keeps)
+_AMP_KEEPS = {}
 
 # trace-time dispatch counter (run_op only executes while the Executor
 # traces a program, never on the cached per-step hot path)
@@ -50,10 +53,14 @@ class TensorArray(list):
     multi-output list (zipped across output names)."""
 
 
-def register(*names):
+def register(*names, amp_keeps=()):
+    """Register an op impl under `names`. amp_keeps: input slots that are
+    no matmul operand (a bias that is added), which keep their type when
+    amp.cast_model_to_bf16 tags the op."""
     def deco(fn):
         for n in names:
             _REGISTRY[n] = fn
+            _AMP_KEEPS[n] = frozenset(amp_keeps)
         return fn
     return deco
 
@@ -85,21 +92,24 @@ class OpContext:
         self.is_test = is_test or bool(op.attrs.get("is_test", False))
 
     # -- inputs -------------------------------------------------------------
-    def _maybe_amp(self, v):
+    def _maybe_amp(self, slot, v):
         # White-listed ops tagged by amp.cast_model_to_bf16 consume bf16 on
         # the MXU; params/grads stay fp32 outside (master weights).
         amp = self.op.attrs.get("__amp_dtype__")
-        if amp and hasattr(v, "dtype") and str(v.dtype) in ("float32", "float64"):
+        if (amp and slot not in _AMP_KEEPS.get(self.op.type, ())
+                and hasattr(v, "dtype")
+                and str(v.dtype) in ("float32", "float64")):
             import jax.numpy as jnp
             return v.astype(jnp.dtype(amp))
         return v
 
     def in_list(self, slot):
-        return [self._maybe_amp(self.env[n]) for n in self.op.input(slot)]
+        return [self._maybe_amp(slot, self.env[n])
+                for n in self.op.input(slot)]
 
     def in_(self, slot, default=None):
         names = self.op.input(slot)
-        return self._maybe_amp(self.env[names[0]]) if names else default
+        return self._maybe_amp(slot, self.env[names[0]]) if names else default
 
     def has_in(self, slot):
         return bool(self.op.input(slot))

@@ -180,7 +180,7 @@ def dot_product_attention(q, k, v, bias=None, scale=None, causal=False,
     return _xla_attention(q, k, v, bias=bias, scale=scale, causal=causal)
 
 
-@register("scaled_dot_product_attention")
+@register("scaled_dot_product_attention", amp_keeps=("Bias",))
 def scaled_dot_product_attention_op(ctx):
     """Q/K/V: (B, H, T, D). Optional Bias broadcastable to (B, H, Tq, Tk);
     optional SegmentIds (B, T) for packed-sequence attention."""
@@ -193,11 +193,16 @@ def scaled_dot_product_attention_op(ctx):
     return {"Out": out}
 
 
-@register("multihead_attention")
+@register("multihead_attention",
+          amp_keeps=("BQ", "BK", "BV", "BO", "Bias"))
 def multihead_attention_op(ctx):
     """Fused projections + attention. Inputs: Query (B, Tq, M),
     Key/Value (B, Tk, M), packed weights WQ/WK/WV (M, M), WO (M, M),
-    optional biases and attention Bias. num_heads attr splits M."""
+    optional biases and attention Bias. num_heads attr splits M.
+
+    Under AMP the matmul operands (Query/Key/Value, the weights and the
+    attention's q/k/v) are bf16; the projection biases and the attention
+    Bias are added in f32, as fc adds its bias to `mul`'s product."""
     q_in = ctx.in_("Query")
     k_in = ctx.in_("Key")
     v_in = ctx.in_("Value")
@@ -218,9 +223,10 @@ def multihead_attention_op(ctx):
         b_, t, m = x.shape
         return x.reshape(b_, t, n_heads, m // n_heads).transpose(0, 2, 1, 3)
 
-    q = split_heads(proj(q_in, wq, bq))
-    k = split_heads(proj(k_in, wk, bk))
-    v = split_heads(proj(v_in, wv, bv))
+    # the attention's operands take the weights' type (bf16 under AMP)
+    q = split_heads(proj(q_in, wq, bq).astype(wq.dtype))
+    k = split_heads(proj(k_in, wk, bk).astype(wk.dtype))
+    v = split_heads(proj(v_in, wv, bv).astype(wv.dtype))
     seg = ctx.in_("SegmentIds")
     o = dot_product_attention(q, k, v, bias=bias,
                               causal=bool(ctx.attr("causal", False)),

@@ -14,6 +14,11 @@ is the TPU-native replacement:
 * additive bias (padding masks, relative-position biases) is applied INSIDE
   the kernels. A (B, 1, 1, Tk) padding bias — the BERT/ERNIE hot path —
   stays O(T) end to end.
+* the MXU sees the operands' own type: bf16 q/k/v (what the AMP pass
+  gives the attention ops, as Fluid's AMP gives its matmul ops) enter
+  every product as bf16 tiles with f32 accumulation, while the running
+  max, exp-sum, logsumexp, delta and the bias add stay f32. Any other
+  type computes in f32 throughout.
 
 Off-TPU the same kernels run under the Pallas interpreter so the CPU test
 suite exercises the real kernel code, not a shadow path.
@@ -24,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .paged import _mxu_precision
 
 NEG_INF = -1e30
 
@@ -40,10 +47,35 @@ LSE_LANES = 8
 # benchmark's training runner assert the flash path actually engaged
 # (VERDICT r1 weak #7).
 TRACE_COUNT = 0
+# The same count by the type the kernels' tiles enter the MXU in
+# (_mxu_dtype's name): tests read which arithmetic a caller reached.
+TRACE_COUNT_BY_DTYPE = {}
+
+# dimension numbers of a tile product: x @ y, x @ y.T, x.T @ y
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
 
 
 def _interpret():
     return jax.default_backend() != "tpu"
+
+
+def _mxu_dtype(*operands):
+    """The type a kernel's tiles enter the MXU in: bf16 where every
+    operand (q, k, v) is bf16, else f32. Chosen from the operands alone,
+    so an f32 caller keeps the f32 arithmetic it always had."""
+    if all(x.dtype == jnp.bfloat16 for x in operands):
+        return jnp.bfloat16
+    return jnp.float32
+
+
+def _dot(x, y, dims=_NN):
+    """One tile product on the MXU, accumulated in f32; x and y are of
+    the kernel's _mxu_dtype."""
+    return jax.lax.dot_general(x, y, dims,
+                               precision=_mxu_precision(x.dtype),
+                               preferred_element_type=jnp.float32)
 
 
 def _pad_to(x, axis, mult):
@@ -171,7 +203,10 @@ def _fwd_kernel(*refs, scale, causal, block_k, q_len, kv_len,
     b_ref = refs.pop(0) if has_bias else None
     qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
     o_ref, lse_ref = refs
-    q = q_ref[0].astype(jnp.float32) * scale
+    cdt = _mxu_dtype(q_ref, k_ref, v_ref)
+    q = q_ref[0].astype(cdt)
+    if cdt == jnp.float32:
+        q = q * scale       # f32 folds the scale into q, as it always has
     block_q, d = q.shape
     q0 = pl.program_id(1) * block_q
     num_kb = pl.cdiv(kv_len, block_k)
@@ -189,11 +224,11 @@ def _fwd_kernel(*refs, scale, causal, block_k, q_len, kv_len,
 
         def compute(carry):
             acc, m_prev, l_prev = carry
-            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
+            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(cdt)
+            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(cdt)
+            s = _dot(q, k_blk, _NT)
+            if cdt != jnp.float32:
+                s = s * scale   # bf16 scales the f32 scores, not q
             if b_ref is not None:
                 if bias_per_q:
                     bblk = b_ref[0, :, pl.ds(kb * block_k, block_k)]
@@ -206,8 +241,7 @@ def _fwd_kernel(*refs, scale, causal, block_k, q_len, kv_len,
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
             l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-            acc = acc * alpha + jnp.dot(p, v_blk,
-                                        preferred_element_type=jnp.float32)
+            acc = acc * alpha + _dot(p.astype(cdt), v_blk)
             return acc, m_new, l_new
 
         return _seg_gate(qseg, kseg, compute, carry)
@@ -328,11 +362,14 @@ def _fwd_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_kb,
     qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     kb = pl.program_id(2)
-    q = q_ref[0].astype(jnp.float32) * scale
+    cdt = _mxu_dtype(q_ref, k_ref, v_ref)
+    q = q_ref[0].astype(cdt)
+    if cdt == jnp.float32:
+        q = q * scale       # as _fwd_kernel: f32 folds the scale into q
     block_q, d = q.shape
     q0 = pl.program_id(1) * block_q
-    k_blk = k_ref[0].astype(jnp.float32)              # (block_k, d)
-    v_blk = v_ref[0].astype(jnp.float32)
+    k_blk = k_ref[0].astype(cdt)                      # (block_k, d)
+    v_blk = v_ref[0].astype(cdt)
     block_k = k_blk.shape[0]
 
     @pl.when(kb == 0)
@@ -342,7 +379,9 @@ def _fwd_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_kb,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _step():
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
+        s = _dot(q, k_blk, _NT)
+        if cdt != jnp.float32:
+            s = s * scale
         if b_ref is not None:
             bblk = b_ref[0] if bias_per_q else b_ref[0, 0:1]
             s = s + bblk.astype(jnp.float32)
@@ -356,8 +395,7 @@ def _fwd_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_kb,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(cdt), v_blk)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -470,8 +508,9 @@ def _dq_kernel(*refs, scale, causal, block_k, q_len, kv_len,
     b_ref = refs.pop(0) if has_bias else None
     qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
     lse_ref, dlt_ref, do_ref, dq_ref = refs
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    cdt = _mxu_dtype(q_ref, k_ref, v_ref)
+    q = q_ref[0].astype(cdt)
+    do = do_ref[0].astype(cdt)
     lse = lse_ref[0][:, 0:1]
     dlt = dlt_ref[0][:, 0:1]
     block_q, d = q.shape
@@ -489,12 +528,9 @@ def _dq_kernel(*refs, scale, causal, block_k, q_len, kv_len,
                 if has_seg else None)
 
         def compute(acc):
-            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            s = jnp.dot(q, k_blk.T,
-                        preferred_element_type=jnp.float32) * scale
+            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(cdt)
+            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(cdt)
+            s = _dot(q, k_blk, _NT) * scale
             if b_ref is not None:
                 if bias_per_q:
                     bblk = b_ref[0, :, pl.ds(kb * block_k, block_k)]
@@ -504,10 +540,9 @@ def _dq_kernel(*refs, scale, causal, block_k, q_len, kv_len,
             s = _mask(s, q0, block_q, kb, block_k, q_len, kv_len, causal,
                       qseg=qseg, kseg=kseg)
             p = jnp.exp(s - lse)
-            dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
+            dp = _dot(do, v_blk, _NT)
             ds = p * (dp - dlt)
-            return acc + jnp.dot(ds, k_blk,
-                                 preferred_element_type=jnp.float32)
+            return acc + _dot(ds.astype(cdt), k_blk)
 
         return _seg_gate(qseg, kseg, compute, acc)
 
@@ -524,8 +559,9 @@ def _dkv_kernel(*refs, scale, causal, block_q, q_len, kv_len,
     b_ref = refs.pop(0) if has_bias else None
     qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
     lse_ref, dlt_ref, do_ref, dk_ref, dv_ref = refs
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+    cdt = _mxu_dtype(q_ref, k_ref, v_ref)
+    k = k_ref[0].astype(cdt)
+    v = v_ref[0].astype(cdt)
     block_k, d = k.shape
     kb = pl.program_id(1)
     num_qb = pl.cdiv(q_len, block_q)
@@ -543,14 +579,11 @@ def _dkv_kernel(*refs, scale, causal, block_q, q_len, kv_len,
 
         def compute(carry):
             dk_acc, dv_acc = carry
-            q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(
-                jnp.float32)
-            do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(
-                jnp.float32)
+            q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(cdt)
+            do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(cdt)
             lse_blk = lse_ref[0, pl.ds(qb * block_q, block_q), 0:1]
             dlt_blk = dlt_ref[0, pl.ds(qb * block_q, block_q), 0:1]
-            s = jnp.dot(q_blk, k.T,
-                        preferred_element_type=jnp.float32) * scale
+            s = _dot(q_blk, k, _NT) * scale
             if b_ref is not None:
                 if bias_per_q:
                     bblk = b_ref[0, pl.ds(qb * block_q, block_q), :]
@@ -560,12 +593,10 @@ def _dkv_kernel(*refs, scale, causal, block_q, q_len, kv_len,
             s = _mask(s, qb * block_q, block_q, kb, block_k, q_len, kv_len,
                       causal, qseg=qseg_blk, kseg=kseg)
             p = jnp.exp(s - lse_blk)
-            dv_acc = dv_acc + jnp.dot(p.T, do_blk,
-                                      preferred_element_type=jnp.float32)
-            dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
+            dv_acc = dv_acc + _dot(p.astype(cdt), do_blk, _TN)
+            dp = _dot(do_blk, v, _NT)
             ds = p * (dp - dlt_blk)
-            dk_acc = dk_acc + jnp.dot(ds.T, q_blk,
-                                      preferred_element_type=jnp.float32)
+            dk_acc = dk_acc + _dot(ds.astype(cdt), q_blk, _TN)
             return dk_acc, dv_acc
 
         return _seg_gate(qseg_blk, kseg, compute, carry)
@@ -587,14 +618,15 @@ def _dq_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_kb,
     qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
     lse_ref, dlt_ref, do_ref, dq_ref, acc_ref = refs
     kb = pl.program_id(2)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    cdt = _mxu_dtype(q_ref, k_ref, v_ref)
+    q = q_ref[0].astype(cdt)
+    do = do_ref[0].astype(cdt)
     lse = lse_ref[0][:, 0:1]
     dlt = dlt_ref[0][:, 0:1]
     block_q, d = q.shape
     q0 = pl.program_id(1) * block_q
-    k_blk = k_ref[0].astype(jnp.float32)
-    v_blk = v_ref[0].astype(jnp.float32)
+    k_blk = k_ref[0].astype(cdt)
+    v_blk = v_ref[0].astype(cdt)
     block_k = k_blk.shape[0]
 
     @pl.when(kb == 0)
@@ -602,7 +634,7 @@ def _dq_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_kb,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _step():
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k_blk, _NT) * scale
         if b_ref is not None:
             bblk = b_ref[0] if bias_per_q else b_ref[0, 0:1]
             s = s + bblk.astype(jnp.float32)
@@ -610,10 +642,9 @@ def _dq_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_kb,
                   qseg=qs_ref[0][:, 0] if has_seg else None,
                   kseg=ks_ref[0][:, 0] if has_seg else None)
         p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
+        dp = _dot(do, v_blk, _NT)
         ds = p * (dp - dlt)
-        acc_ref[...] += jnp.dot(ds, k_blk,
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += _dot(ds.astype(cdt), k_blk)
 
     _tile_guard(
         _kb_visible(kb, block_k, q0, block_q, q_len, kv_len)
@@ -638,11 +669,12 @@ def _dkv_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_qb,
     lse_ref, dlt_ref, do_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
     kb = pl.program_id(1)
     qb = pl.program_id(2)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+    cdt = _mxu_dtype(q_ref, k_ref, v_ref)
+    k = k_ref[0].astype(cdt)
+    v = v_ref[0].astype(cdt)
     block_k, d = k.shape
-    q_blk = q_ref[0].astype(jnp.float32)
-    do_blk = do_ref[0].astype(jnp.float32)
+    q_blk = q_ref[0].astype(cdt)
+    do_blk = do_ref[0].astype(cdt)
     lse_blk = lse_ref[0][:, 0:1]
     dlt_blk = dlt_ref[0][:, 0:1]
     block_q = q_blk.shape[0]
@@ -653,7 +685,7 @@ def _dkv_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_qb,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _step():
-        s = jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32) * scale
+        s = _dot(q_blk, k, _NT) * scale
         if b_ref is not None:
             bblk = b_ref[0] if bias_per_q else b_ref[0, 0:1]
             s = s + bblk.astype(jnp.float32)
@@ -662,12 +694,10 @@ def _dkv_kernel_kgrid(*refs, scale, causal, q_len, kv_len, num_qb,
                   qseg=qs_ref[0][:, 0] if has_seg else None,
                   kseg=ks_ref[0][:, 0] if has_seg else None)
         p = jnp.exp(s - lse_blk)
-        dv_acc[...] += jnp.dot(p.T, do_blk,
-                               preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
+        dv_acc[...] += _dot(p.astype(cdt), do_blk, _TN)
+        dp = _dot(do_blk, v, _NT)
         ds = p * (dp - dlt_blk)
-        dk_acc[...] += jnp.dot(ds.T, q_blk,
-                               preferred_element_type=jnp.float32)
+        dk_acc[...] += _dot(ds.astype(cdt), q_blk, _TN)
 
     # causal guard is _first_visible_qb in scalar form
     _tile_guard(
@@ -922,7 +952,8 @@ def _dbias_xla(q, k, v, bias, lse, do, delta, scale, causal,
     dS = P * (dP - delta). O(T^2) — but this expression is only kept alive
     by XLA when something downstream actually differentiates w.r.t. the
     bias (padding masks built from feed data are DCE'd away)."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
     s = s + bias.astype(jnp.float32)
     tq, tk = s.shape[-2], s.shape[-1]
     if causal:
@@ -933,8 +964,8 @@ def _dbias_xla(q, k, v, bias, lse, do, delta, scale, causal,
         same = segq[:, None, :, None] == segk[:, None, None, :]
         s = jnp.where(same, s, NEG_INF)
     p = jnp.exp(s - lse[..., None])
-    dp = jnp.einsum("bhqd,bhkd->bhqk", do.astype(jnp.float32),
-                    v.astype(jnp.float32))
+    dp = jnp.einsum("bhqd,bhkd->bhqk", do, v,
+                    preferred_element_type=jnp.float32)
     ds = p * (dp - delta[..., None])
     # Reduce over the dims the bias was broadcast along.
     axes = tuple(i for i in range(4) if bias.shape[i] == 1 and ds.shape[i] > 1)
@@ -1036,17 +1067,33 @@ def _canonical_bias(bias, b, h, tq, tk):
     return bias
 
 
-def default_blocks():
-    """(block_q, block_k) defaults: 128 each, overridable without code
-    edits via PADDLE_TPU_FLASH_BLOCK_Q / _K. A bad value fails HERE
-    naming the variable — raising mid-kernel would silently drop
-    attention to the O(T^2) fallback (the r1 weak-#7 failure mode)."""
+# Default block sizes, largest first. A grid step of these kernels costs
+# about a microsecond of fixed work on a v5e whatever its tile, so one
+# call at (16, 16, 512, 64) took 4.9 ms in 128-blocks and 1.1 ms in one
+# 512-block (PERF.md section 6).
+_BLOCKS = (512, 256, 128)
+
+
+def _default_block(n):
+    """The largest of _BLOCKS that pads an axis of n to no more than
+    128-blocks do."""
+    floor = _padded_len(n, _BLOCKS[-1])
+    return next(b for b in _BLOCKS if _padded_len(n, b) == floor)
+
+
+def default_blocks(tq, tk):
+    """(block_q, block_k) for tq queries and tk keys: _default_block of
+    each axis, overridable without code edits via
+    PADDLE_TPU_FLASH_BLOCK_Q / _K. A bad value fails HERE naming the
+    variable — raising mid-kernel would silently drop attention to the
+    O(T^2) fallback (the r1 weak-#7 failure mode)."""
     import os
     out = []
-    for name in ("PADDLE_TPU_FLASH_BLOCK_Q", "PADDLE_TPU_FLASH_BLOCK_K"):
+    for name, n in (("PADDLE_TPU_FLASH_BLOCK_Q", tq),
+                    ("PADDLE_TPU_FLASH_BLOCK_K", tk)):
         raw = os.environ.get(name)
         if raw is None:
-            out.append(128)
+            out.append(_default_block(n))
             continue
         try:
             v = int(raw)
@@ -1110,11 +1157,13 @@ def flash_attention_with_lse(q, k, v, bias=None, scale=None, causal=False,
     """Variant returning (out, logsumexp (B,H,Tq) fp32) — the building block
     for ring attention's cross-device online combine. Fully differentiable
     (the lse cotangent rides the same Pallas backward kernels)."""
-    dq, dk = default_blocks()
+    dq, dk = default_blocks(q.shape[2], k.shape[2])
     block_q = dq if block_q is None else block_q
     block_k = dk if block_k is None else block_k
     global TRACE_COUNT
     TRACE_COUNT += 1
+    mxu = jnp.dtype(_mxu_dtype(q, k, v)).name
+    TRACE_COUNT_BY_DTYPE[mxu] = TRACE_COUNT_BY_DTYPE.get(mxu, 0) + 1
     d = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     segq, segk = _canonical_seg(segment_ids, q.shape[0], q.shape[2],

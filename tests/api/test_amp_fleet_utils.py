@@ -52,6 +52,41 @@ def test_amp_bf16_cast_tags_matmul_ops():
     assert np.isfinite(out).all()
 
 
+def test_amp_bf16_cast_tags_attention_ops_and_keeps_bias_f32(monkeypatch):
+    """Both fused attention ops are on the white list, as Fluid's matmul
+    pairs are: their q/k/v reach the attention bf16, while the additive
+    attention Bias keeps float32."""
+    from paddle_tpu.ops import attention_ops
+
+    seen = []
+    real = attention_ops.dot_product_attention
+
+    def spy(q, k, v, bias=None, **kw):
+        seen.append((q.dtype, k.dtype, v.dtype, bias.dtype))
+        return real(q, k, v, bias=bias, **kw)
+
+    monkeypatch.setattr(attention_ops, "dot_product_attention", spy)
+    x = layers.data("x", shape=[6, 16], dtype="float32")
+    mask = layers.data("mask", shape=[1, 1, 6], dtype="float32")
+    h = layers.multi_head_attention(x, num_heads=2, attn_bias=mask)
+    h = layers.scaled_dot_product_attention(h, h, h, num_heads=2,
+                                            attn_bias=mask)
+    loss = layers.mean(h)
+    main = fluid.default_main_program()
+    amp.cast_model_to_bf16(main)
+    tagged = {op.type for op in main.global_block().ops
+              if op.attrs.get("__amp_dtype__") == "bfloat16"}
+    assert {"multihead_attention", "scaled_dot_product_attention"} <= tagged
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    feed = {"x": np.random.RandomState(0).rand(3, 6, 16).astype(np.float32),
+            "mask": np.zeros((3, 1, 1, 6), np.float32)}
+    out, = exe.run(feed=feed, fetch_list=[loss])
+    assert np.isfinite(out).all()
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    assert seen == [(bf16, bf16, bf16, f32)] * 2
+
+
 # ---------------------------------------------------------------- fleet
 def test_fleet_facade_dp_training():
     from paddle_tpu.parallel import fleet as fleet_mod

@@ -399,3 +399,124 @@ def test_flash_segment_skip_tiles_grads(kgrid, causal, monkeypatch):
     for a, b_ in zip(gf, go):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=3e-5, rtol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# bf16 operands: bf16 tiles into every product, f32 statistics
+# ---------------------------------------------------------------------------
+
+# The kernels round P and dS to bf16 before their products and the
+# results to bf16 on the way out, each a relative 2^-8 (eps / 2); every
+# product of two bf16 tiles is exact in the f32 accumulator. Against
+# f32 attention over the SAME bf16 operands the error is then a few
+# half-eps of the largest value: 0.5 eps measured here, held at 2 eps.
+_BF16_TOL = 2 * float(jnp.finfo(jnp.bfloat16).eps)
+
+
+def _bf16_case(case):
+    """A small BERT-like call: (2, 4, 96, 32) bf16 operands, blocks of
+    32 (three along each axis), and a (B, 1, 1, T) padding bias, a
+    causal mask or packed segments."""
+    b, h, t, d = 2, 4, 96, 32
+    q, k, v = (_rand((b, h, t, d), s).astype(jnp.bfloat16)
+               for s in (40, 41, 42))
+    kw = {"scale": d ** -0.5, "block_q": 32, "block_k": 32}
+    if case == "padding":
+        bias = np.zeros((b, 1, 1, t), np.float32)
+        bias[0, ..., 70:] = -1e9         # row 0 has 70 real tokens
+        kw["bias"] = jnp.asarray(bias)
+    elif case == "causal":
+        kw["causal"] = True
+    else:
+        seg = np.ones((b, t), np.int32)
+        seg[0, 40:80], seg[0, 80:] = 2, 0
+        seg[1, 50:] = 2
+        kw["segment_ids"] = jnp.asarray(seg)
+    return q, k, v, kw
+
+
+def _f32_oracle(q, k, v, kw):
+    bias = kw.get("bias")
+    if "segment_ids" in kw:
+        seg = kw["segment_ids"]
+        bias = flash.segment_mask_bias(seg, seg)
+    with jax.default_matmul_precision("highest"):
+        return flash._xla_ref(q, k, v, kw["scale"], kw.get("causal", False),
+                              bias=bias)
+
+
+def _assert_bf16_close(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=_BF16_TOL,
+                               atol=_BF16_TOL * np.abs(want).max())
+
+
+@pytest.mark.parametrize("case", ["padding", "causal", "segments"])
+@pytest.mark.parametrize("kgrid", ["0", "1"], ids=["loop", "kgrid"])
+def test_flash_bf16_matches_f32_attention_of_the_same_operands(
+        kgrid, case, monkeypatch):
+    """bf16 q/k/v take the bf16-tile arithmetic in every kernel (loop
+    and kgrid), and the output and dq/dk/dv agree with f32 attention
+    over the same bf16-rounded operands to bf16's own rounding."""
+    monkeypatch.setenv("PT_FLASH_KGRID", kgrid)
+    q, k, v, kw = _bf16_case(case)
+    up = [x.astype(jnp.float32) for x in (q, k, v)]
+    before = flash.TRACE_COUNT_BY_DTYPE.get("bfloat16", 0)
+    got = flash.flash_attention(q, k, v, **kw)
+    assert flash.TRACE_COUNT_BY_DTYPE["bfloat16"] == before + 1
+    assert got.dtype == jnp.bfloat16
+    _assert_bf16_close(got, _f32_oracle(*up, kw))
+
+    def f_loss(q, k, v):
+        o = flash.flash_attention(q, k, v, **kw)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+
+    def o_loss(q, k, v):
+        return jnp.sum(jnp.sin(_f32_oracle(q, k, v, kw)))
+
+    gf = jax.grad(f_loss, argnums=(0, 1, 2))(q, k, v)
+    go = jax.grad(o_loss, argnums=(0, 1, 2))(*up)
+    for a, b_ in zip(gf, go):
+        assert a.dtype == jnp.bfloat16
+        _assert_bf16_close(a, b_)
+
+
+@pytest.mark.parametrize("dtypes,mxu", [
+    (("float32",) * 3, "float32"),
+    (("bfloat16",) * 3, "bfloat16"),
+    (("bfloat16", "float32", "float32"), "float32"),
+], ids=["f32", "bf16", "mixed"])
+def test_flash_arithmetic_follows_the_operands_dtype(dtypes, mxu):
+    """The tiles enter the MXU as bf16 only where q, k and v all are;
+    any mix takes the f32 arithmetic, and all-f32 operands keep its f32
+    tolerance against the oracle (a mix returns q's bf16)."""
+    q, k, v = (_rand((1, 2, 48, 16), s).astype(dt)
+               for s, dt in zip((50, 51, 52), dtypes))
+    before = dict(flash.TRACE_COUNT_BY_DTYPE)
+    got = flash.flash_attention(q, k, v, block_q=16, block_k=16)
+    counts = {name: flash.TRACE_COUNT_BY_DTYPE.get(name, 0)
+              - before.get(name, 0) for name in ("float32", "bfloat16")}
+    assert counts == {mxu: 1, ({"float32", "bfloat16"} - {mxu}).pop(): 0}
+    if "bfloat16" not in dtypes:
+        want = flash._xla_ref(q, k, v, 1.0 / 16 ** 0.5, False)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_flash_bf16_bias_gradient_matches_f32():
+    """A learned bias differentiated under bf16 operands: its cotangent
+    keeps f32 scores, so it agrees with f32 attention of the same
+    operands. Scores of some tens (q x 8) make a bf16-rounded score
+    visible: it moves exp(s) by eps/2 x |s|, and read 6.3 eps here."""
+    b, h, t, d = 1, 2, 48, 16
+    q, k, v = (_rand((b, h, t, d), s) for s in (60, 61, 62))
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q * 8, k, v))
+    bias = _rand((1, h, t, t), 63) * 2.0
+    up = [x.astype(jnp.float32) for x in (q, k, v)]
+    gf = jax.grad(lambda bb: jnp.sum(jnp.sin(flash.flash_attention(
+        q, k, v, bias=bb, block_q=16, block_k=16).astype(jnp.float32))))(
+            bias)
+    go = jax.grad(lambda bb: jnp.sum(jnp.sin(_f32_oracle(
+        *up, {"scale": d ** -0.5, "bias": bb}))))(bias)
+    _assert_bf16_close(gf, go)

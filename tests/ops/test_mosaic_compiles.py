@@ -42,20 +42,41 @@ def _compile(dev, fn, *shapes):
     return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
 
 
-@pytest.mark.parametrize("kgrid", ["0", "1"], ids=["loop", "kgrid"])
-def test_flash_fwd_bwd_compile_at_gpt_geometry(v5e, kgrid, monkeypatch):
+def _assert_flash_fwd_bwd_compile(dev, kgrid, monkeypatch, qkv, bias=None,
+                                  causal=False):
+    """jax.grad of flash attention over q/k/v of `qkv` (shape, dtype),
+    with an f32 `bias` shape if given, compiles its three kernels under
+    their names."""
     monkeypatch.setattr(flash, "_interpret", lambda: False)
     monkeypatch.setenv("PT_FLASH_KGRID", kgrid)
 
-    def grads(q, k, v):
+    def grads(q, k, v, *b):
         return jax.grad(lambda *a: flash.flash_attention(
-            *a, causal=True).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
+            *a, bias=b[0] if b else None, causal=causal).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    calls = _compile(v5e, grads, *[((1, 12, 1024, 64), jnp.float32)] * 3)
+    shapes = [qkv] * 3 + ([(bias, jnp.float32)] if bias else [])
+    calls = _compile(dev, grads, *shapes)
     suffix = "_kgrid" if kgrid == "1" else ""
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert any(f"{name}{suffix})" in c for c in calls), (name, calls)
+
+
+@pytest.mark.parametrize("kgrid", ["0", "1"], ids=["loop", "kgrid"])
+def test_flash_fwd_bwd_compile_at_gpt_geometry(v5e, kgrid, monkeypatch):
+    _assert_flash_fwd_bwd_compile(v5e, kgrid, monkeypatch,
+                                  ((1, 12, 1024, 64), jnp.float32),
+                                  causal=True)
+
+
+@pytest.mark.parametrize("kgrid", ["0", "1"], ids=["loop", "kgrid"])
+def test_flash_bf16_fwd_bwd_compile_at_bert_geometry(v5e, kgrid,
+                                                     monkeypatch):
+    """The training cell's call: bf16 q/k/v of (16, 16, 512, 64) under
+    AMP, a (B, 1, 1, T) f32 padding bias, bf16 tiles into the MXU."""
+    _assert_flash_fwd_bwd_compile(v5e, kgrid, monkeypatch,
+                                  ((16, 16, 512, 64), jnp.bfloat16),
+                                  bias=(16, 1, 1, 512))
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])

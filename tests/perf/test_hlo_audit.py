@@ -98,6 +98,50 @@ def test_bf16_cast_leaves_no_f32_dots():
         f"cast_model_to_bf16 (half MXU rate on TPU): {f32[:3]}")
 
 
+# the operand types of a lowered dot_general: `: (tensor<..>, tensor<..>)`
+_DOT_OPERANDS = re.compile(r": \((tensor<[^>]*>), (tensor<[^>]*>)\)")
+
+
+@pytest.mark.parametrize("flash_path", ["0", "1"], ids=["xla", "flash"])
+def test_bf16_cast_leaves_no_f32_dots_in_bert(flash_path, monkeypatch):
+    """(b) for a two-layer BERT from models/bert.py, whose attention is
+    the fused multihead_attention op: after the cast no dot of the step
+    takes an f32 operand, on the XLA attention path or through the flash
+    kernels (interpreted here, so their tile products are in the text).
+    The f32 a dot returns is its accumulator, not an operand."""
+    from paddle_tpu import amp
+    from paddle_tpu.models import bert
+    from paddle_tpu.ops.pallas import flash
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_FLASH", flash_path)
+    cfg = bert.bert_tiny()
+    cfg.num_hidden_layers = 2
+    seq_len = 64
+    main, startup = framework.Program(), framework.Program()
+    with framework.program_guard(main, startup):
+        _feeds, loss, _mlm, _acc = bert.build_pretrain_net(
+            cfg, seq_len=seq_len)
+        fluid.optimizer.AdamOptimizer(1e-3).minimize(loss)
+    amp.cast_model_to_bf16(main)
+    scope = Scope()
+    exe = fluid.Executor()
+    traces0 = flash.TRACE_COUNT_BY_DTYPE.get("bfloat16", 0)
+    with scope_guard(scope):
+        exe.run(startup)
+        out, = exe.run(main, feed=bert.make_pretrain_feed(cfg, seq_len, 2),
+                       fetch_list=[loss])
+    assert np.isfinite(out).all()
+    if flash_path == "1":
+        assert flash.TRACE_COUNT_BY_DTYPE["bfloat16"] - traces0 == 2
+    dots = _DOT_GENERAL.findall(exe.last_lowered_text())
+    assert dots, "no dots at all — the audit net lost its matmuls"
+    f32 = [d for d in dots
+           if any(t.endswith("xf32>") for t in _DOT_OPERANDS.search(d).groups())]
+    assert not f32, (
+        f"{len(f32)} of {len(dots)} dots take f32 operands after "
+        f"cast_model_to_bf16 (half MXU rate on TPU): {f32[:3]}")
+
+
 def _dot_count(policy):
     main, startup = framework.Program(), framework.Program()
     with framework.program_guard(main, startup):
