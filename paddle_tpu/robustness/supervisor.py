@@ -729,7 +729,8 @@ def make_checkpoint_spawn(manager, cfg, scope_factory=None,
     a crash-loop, exactly like a failed spawn."""
     from ..core.executor import Executor, Scope
     from ..models.gpt import load_params
-    from ..serving.engine import GenerationServer, GPTServingModel
+    from ..serving.engine import GenerationServer
+    from ..serving.model import GPTServingModel
     from .checkpoint_manager import CheckpointError
 
     def spawn(index):

@@ -27,6 +27,18 @@ deadline. This package is the TPU-native answer:
                   fused step runs under shard_map (one psum per
                   sub-block, scheduler state replicated on the host —
                   docs/serving.md "Serving on a mesh");
+- model.py      — ServingModel, the engine's view of a model: a family
+                  is a config, `init_params` and a spec, from which
+                  the facts, the cache's geometry and the names of the
+                  step's counts are derived; GPTServingModel adds int8
+                  weights and the tensor-parallel step. The families:
+                  latent_moe.py, linear_moe.py, smallthinker.py,
+                  brumby.py;
+- step.py / blocks.py — the ONE fused step every model runs, and the
+                  kinds it is made of (norms, attentions with their
+                  cache geometry, MLPs) layer by layer
+                  (docs/serving.md "Model families: the block is a
+                  spec");
 - prefix_cache.py — cross-request KV block sharing: content-hash-chain
                   index over prompt chunks, refcounted blocks,
                   copy-on-write on divergence, LRU eviction under
@@ -89,7 +101,8 @@ from .decode_strategies import (BeamHypothesis, BeamParams, GroupFuture,
                                 GroupResult, SamplingParams)
 from .guided import (ChoiceConstraint, Constraint, JsonConstraint,
                      RegexConstraint)
-from .engine import GenerationFuture, GenerationServer, GPTServingModel
+from .model import GPTServingModel, ServingModel
+from .engine import GenerationFuture, GenerationServer
 from .latent_moe import LatentMoEServingModel
 from .linear_moe import LinearMoEServingModel
 from .smallthinker import SmallThinkerServingModel
@@ -112,7 +125,8 @@ __all__ = [
     "JsonConstraint",
     "ContinuousBatchingScheduler", "GenerationResult",
     "DeadlineExceeded", "RequestCancelled",
-    "GenerationServer", "GenerationFuture", "GPTServingModel",
+    "GenerationServer", "GenerationFuture", "ServingModel",
+    "GPTServingModel",
     "LatentMoEServingModel", "LinearMoEServingModel",
     "SmallThinkerServingModel", "BrumbyServingModel",
     "Replica", "FleetRouter", "FleetFuture", "RouterPolicy",

@@ -1,7 +1,9 @@
 """What a decoder block is made of, as the ONE fused step
-(`engine._fused_step_body`) reads it: a `StepSpec` names, layer by
-layer, the norm, the attention with its cache geometry, and the MLP;
-the tables below hold each kind's arithmetic over (S, C) ragged lanes.
+(`step._fused_step_body`) reads it: a `StepSpec` names, layer by
+layer, the norm, the attention and the MLP; the tables below hold each
+kind's arithmetic over (S, C) ragged lanes, what each attention kind
+keeps in the cache (`cache_geometry`) and the names of what a step
+counts (`step_counters`).
 
 `gpt2-xl` is one spec (LayerNorm, learned positions, multi-head
 attention over a K-beside-V pool, GELU MLP, head tied to the
@@ -19,8 +21,10 @@ ReLU-gated experts whose router reads the attention's input, untied
 head); the power-retention family (`serving/brumby.py`) a fifth (every
 layer power retention over a state a lane, with normalised q and k and
 half-split rotary positions, a dense gated MLP, untied head). A new
-family adds entries here and a spec; the step, the scheduler and the
-cache manager do not change.
+family is a config, its `init_params` and a spec
+(`model.ServingModel`); a new kind adds its entries here. The step, the
+engine, the scheduler and the cache manager do not change unless a
+cache's lifetime is new.
 
 Every attention kind takes (ctx, hn, lp, layer_pools) and returns (the
 residual's addend, the layer's rewritten pools); every MLP kind takes
@@ -39,15 +43,18 @@ import jax
 import jax.numpy as jnp
 
 from ..models.gpt import _ln
+from ..ops.pallas.paged import latent_row_width
+from ..ops.pallas.retention import state_rows
 from .kv_cache import (fuse_kv, kda_chunk, paged_attention,
                        paged_latent_attention, power_retention_chunk,
                        write_block_kv, write_block_kv_quant)
-from .moe import expert_share, step_stats
+from .moe import MOE_STATS, expert_share, step_stats
 
 __all__ = ["LayerSpec", "StepSpec", "StepContext", "NORMS", "ATTENTIONS",
-           "MLPS", "STATE_ATTENTIONS", "STATE_STATS", "RING_ATTENTIONS",
-           "WINDOW_STATS", "fold_counts", "state_counts", "window_counts",
-           "rms_norm", "qk_rms_norm", "rotary_angles",
+           "MLPS", "CACHE_GEOMETRY", "STATE_ATTENTIONS", "STATE_STATS",
+           "RING_ATTENTIONS", "WINDOW_STATS", "cache_geometry",
+           "step_counters", "fold_counts", "state_counts",
+           "window_counts", "rms_norm", "qk_rms_norm", "rotary_angles",
            "rotate_interleaved", "rotate_half", "short_conv"]
 
 # one layer: the names of its norm, attention and MLP kinds
@@ -352,10 +359,53 @@ ATTENTIONS = {
     "gqa_window": functools.partial(_attention_gqa, windowed=True),
     "kda": _attention_kda, "power": _attention_power}
 
+
+def _kv_pool(spec):
+    return (spec.kv_heads, 2 * spec.head_dim)
+
+
+def _latent_pool(spec):
+    return (1, latent_row_width(spec.dims["kv_lora_rank"],
+                                spec.dims["qk_rope"]))
+
+
+def _kda_state(spec):
+    dm = spec.dims
+    heads, dk, dv = dm["kda_heads"], dm["kda_key_dim"], dm["kda_value_dim"]
+    return {"state": ((heads, dv, dk), jnp.float32),
+            "conv": ((dm["conv_taps"] - 1, heads * (2 * dk + dv)), None)}
+
+
+def _power_state(spec):
+    rows = state_rows(spec.head_dim)
+    return {"state": ((spec.kv_heads, rows, spec.head_dim), jnp.float32),
+            "norm": ((spec.kv_heads, rows), jnp.float32)}
+
+
+# what a layer of each attention kind keeps in the cache, as
+# `PagedKVCache(geometry=)` takes it: a paged block's (rows, width) —
+# K beside V a KV head, or a latent layer's one row a token — or what a
+# lane owns: a window (`{"window": W}`, which the engine sizes into a
+# ring of blocks) or a state's arrays as (shape, dtype), None being the
+# cache's dtype
+CACHE_GEOMETRY = {
+    "mha": _kv_pool, "gqa": _kv_pool, "gqa_gated": _kv_pool,
+    "latent": _latent_pool,
+    "gqa_window": lambda spec: {"window": spec.dims["window"]},
+    "kda": _kda_state, "power": _power_state}
+
+
+def cache_geometry(spec):
+    """A model's cache, layer by layer (`CACHE_GEOMETRY`)."""
+    return [CACHE_GEOMETRY[layer.attention](spec) for layer in spec.layers]
+
+
 # the kinds whose cache is a state a lane, and what a step counts of
-# them (`state_counts`)
+# them (`state_counts`); the prefix each kind gives the first two
+# counts' names
 STATE_ATTENTIONS = ("kda", "power")
 STATE_STATS = ("lane_calls", "columns", "resets")
+STATE_COUNTER_PREFIX = {"kda": "kda_", "power": "retention_"}
 # the kinds whose cache is a ring a lane (`kv_cache.ring_table`), and
 # what a step counts of them (`window_counts`)
 RING_ATTENTIONS = ("gqa_window",)
@@ -425,6 +475,33 @@ def _mlp_experts(ctx, hn, lp, attn_in):
 MLPS = {"gelu": _mlp_gelu, "gated": _mlp_gated, "experts": _mlp_experts}
 
 # a step's counts from those of its layers that count: one kind counts
-# today, the expert layer its routing (`moe.MOE_STATS`), and a model of
-# that kind names the vector's entries (`step_counters`)
+# today, the expert layer its routing (`moe.MOE_STATS`)
+COUNTING_MLPS = ("experts",)
 fold_counts = step_stats
+# the counts that also feed a registry counter of the same name
+_MOE_FED = ("assignments", "assignments_held")
+
+
+def step_counters(spec):
+    """The names of the count vector a step of `spec` returns last, in
+    the order `step._fused_step_body` appends them: for each count (its
+    name in the iteration record, the registry counter it feeds or
+    None). The expert layers' routing; the state layers' (`kda_` or
+    `retention_`, as the kind says); the window layers'. Empty for a
+    spec with no layer that counts."""
+    attentions = [layer.attention for layer in spec.layers]
+    names = ()
+    if any(layer.mlp in COUNTING_MLPS for layer in spec.layers):
+        names += tuple(
+            ("moe_" + name,
+             f"serving.moe.{name}" if name in _MOE_FED else None)
+            for name in MOE_STATS)
+    state = [kind for kind in attentions if kind in STATE_ATTENTIONS]
+    if state:
+        prefix = STATE_COUNTER_PREFIX[state[0]]
+        names += ((prefix + "lane_calls", None),
+                  (prefix + "columns", "serving.state.columns"),
+                  ("state_resets", "serving.state.resets"))
+    if any(kind in RING_ATTENTIONS for kind in attentions):
+        names += tuple(("window_" + name, None) for name in WINDOW_STATS)
+    return names

@@ -21,9 +21,9 @@ decoding is on (spec_decode.py) — the whole server lifetime compiles
 at most two step functions. The small feeds reach that step as one
 packed int32 buffer, unpacked inside the jitted call (feed.py).
 
-The model side is pluggable; GPTServingModel adapts models/gpt.py
-params (same math as gpt.build_kv_step, vectorized over the chunk
-axis, KV routed through serving.kv_cache.paged_attention/write).
+The model is a `ServingModel` (model.py): the engine reads its facts,
+its cache geometry, the names of its step's counts and its fused step
+(step.py), and nothing else of it.
 """
 
 import contextlib
@@ -38,22 +38,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import _cast_params, load_params
 from ..observability import _help
 from ..observability.metrics import global_registry
 from ..observability.tracing import get_recorder
 from . import kv_cache as _kvc
-from .blocks import (ATTENTIONS, MLPS, NORMS, RING_ATTENTIONS,
-                     STATE_ATTENTIONS, LayerSpec, StepContext, StepSpec,
-                     fold_counts, rotary_angles, state_counts,
-                     window_counts)
-from .decode_strategies import (GroupFuture, RequestGroup,
-                                SamplingParams, gumbel_noise)
+from .decode_strategies import GroupFuture, RequestGroup, SamplingParams
 from .feed import FeedLayout
-from .kv_cache import NEG_INF, NULL_BLOCK, PagedKVCache
+from .kv_cache import NEG_INF, PagedKVCache
 from .scheduler import ContinuousBatchingScheduler, RequestCancelled, _Request
 
-__all__ = ["GenerationServer", "GenerationFuture", "GPTServingModel"]
+__all__ = ["GenerationServer", "GenerationFuture"]
 
 # HBM-ledger component ids ("serving0", ...): monotonic, never recycled
 _SERVER_SEQ = itertools.count()
@@ -80,469 +74,6 @@ class _Dispatched:
         self.t0 = None              # perf_counter at its feed
         self.out = self.draft_logps = self.fetched = None
         self.overlapped = False     # dispatched with a step in flight
-
-
-def _sample_rows(base, rng, temperature, do_top_k, top_p):
-    """Stochastic token choice over (S, V) log-prob rows INSIDE the one
-    fused step: temperature scale, top-k / nucleus filtering
-    (inference.decoding._filter_logits semantics), Gumbel-argmax draw
-    from per-lane counter keys. Every control is DATA — (S,) arrays, 0
-    meaning top-k off and 2.0 meaning top-p off — so sampled, greedy,
-    and mixed batches all share one jit signature. Returns
-    (sampled ids (S,), their logp under the filtered distribution)."""
-    s, v = base.shape
-    scaled = base / jnp.maximum(temperature, 1e-6)[:, None]
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    k_eff = jnp.clip(jnp.where(do_top_k > 0, do_top_k, v), 1, v)
-    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], -1)
-    filt = jnp.where(scaled < kth, jnp.float32(NEG_INF), scaled)
-    # nucleus over the top-k survivors (softmax subtracts the row max,
-    # so the NEG_INF entries contribute exp(-huge) = 0, never NaN)
-    sd = -jnp.sort(-filt, axis=-1)
-    cum = jnp.cumsum(jax.nn.softmax(sd, axis=-1), axis=-1)
-    keep = jnp.concatenate([jnp.ones((s, 1), bool),
-                            cum[:, :-1] < top_p[:, None]], axis=-1)
-    thresh = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1,
-                     keepdims=True)
-    filt = jnp.where(filt < thresh, jnp.float32(NEG_INF), filt)
-    samp = jnp.argmax(filt + gumbel_noise(rng, v, xp=jnp), axis=-1)
-    samp_lp = jnp.take_along_axis(
-        jax.nn.log_softmax(filt, axis=-1), samp[:, None], -1)[:, 0]
-    return samp.astype(jnp.int32), samp_lp
-
-
-def _sampled_where_asked(logp, nxt, chosen, rng, temperature, do_sample,
-                         top_k, top_p):
-    """The step's (ids, their logp) from the greedy `(nxt, chosen)`: a
-    lane whose `do_sample` is set takes `_sample_rows`' draw over its
-    `logp` row instead. The draw (two sorts of the whole vocabulary, a
-    nucleus sum, the noise) runs under ONE device-side branch on
-    `do_sample.any()`, so a step whose lanes are all greedy pays for
-    none of it; the predicate is data the step already takes, so there
-    is still one executable. Over per-column `(S, C, ...)` inputs the
-    draw is column 0's. Everything a branch reads is an operand."""
-    per_column = logp.ndim == 3
-
-    def sampled(logp, nxt, chosen, rng, temperature, do_sample, top_k,
-                top_p):
-        samp, samp_lp = _sample_rows(logp[:, 0] if per_column else logp,
-                                     rng, temperature, top_k, top_p)
-        if per_column:
-            nxt = nxt.at[:, 0].set(jnp.where(do_sample, samp, nxt[:, 0]))
-            chosen = chosen.at[:, 0].set(
-                jnp.where(do_sample, samp_lp, chosen[:, 0]))
-        else:
-            nxt = jnp.where(do_sample, samp, nxt)
-            chosen = jnp.where(do_sample, samp_lp, chosen)
-        return nxt, chosen
-
-    def greedy(logp, nxt, chosen, *controls):
-        return nxt, chosen
-
-    return jax.lax.cond(jnp.any(do_sample), sampled, greedy, logp,
-                        nxt.astype(jnp.int32), chosen, rng, temperature,
-                        do_sample, top_k, top_p)
-
-
-def _fused_step_body(params, spec, block_size, reduce_fn, pools, tokens,
-                     positions, valid, tables, per_column=False,
-                     sampling=False, mask=None, rng=None,
-                     temperature=None, do_sample=None, top_k=None,
-                     top_p=None, in_shard_map=False):
-    """The ONE fused prefill/decode step body, over (S, C) ragged lanes
-    with paged KV, for every model family: `spec` (`blocks.StepSpec`)
-    says, layer by layer, which norm, which attention over which cache
-    geometry and which MLP a block is made of, how positions enter and
-    whether the head is tied; `blocks.py` holds each kind's
-    arithmetic. The skeleton around the layers is the same for all:
-    embedding, write targets (masked lanes to the NULL block), the
-    layer loop with its two residual adds, the final norm, the
-    last-column gather, log-softmax in float32, and the greedy and
-    sampled tails.
-
-    It is shared by the single-device and tensor-parallel fused steps
-    exactly like gpt._prefill_forward: `spec.heads` is the QUERY head
-    count THIS caller sees (H, or H/tp inside shard_map over
-    head-sharded params and pools), `spec.kv_heads` the KV head count
-    (equal for MHA; H_kv or H_kv/tp for grouped-query attention, where
-    wk/wv project to kv_heads * head_dim columns and the
-    paged_attention dispatcher groups the query heads onto the shared
-    KV heads), and `reduce_fn`
-    finishes the row-parallel o-proj / ffn-down contractions (identity
-    single-device; one psum per sub-block under tp — the partial sums
-    those matmuls leave are the ONLY cross-shard state the step has);
-    `in_shard_map` is that same fact handed on to the paged_attention
-    dispatcher, which cannot see it from inside the trace.
-
-    `per_column=False` (plain serving): each lane's LAST valid column
-    is gathered before the lm-head projection — one (S, H) @ (H, V)
-    gemm, returns (pools, next_ids (S,), next_logps (S,)).
-    `per_column=True` (speculative verify): every column is projected —
-    (S*C, H) @ (H, V) — and a third `fed_logps` output carries the
-    target logp of each NEXT fed column's token (the draft under
-    verification; rejection-mode acceptance needs p_target(draft)).
-    Rows of the wide gemm are independent dot products, so a column's
-    outputs are bitwise the last-column gather's (the spec parity tests
-    pin this); plain servers keep the narrow gemm — C x fewer lm-head
-    FLOPs on the decode hot path.
-
-    A spec with layers that count (an MLP kind that returns counts
-    beside its addend: the expert layer its routing) returns ONE more
-    output, last: the step's counts, an int32 vector folded over those
-    layers (`blocks.fold_counts`), which the model names
-    (`step_counters`). A spec with state layers (`STATE_ATTENTIONS`)
-    appends theirs (`blocks.state_counts`) to that vector, and one with
-    window layers (`RING_ATTENTIONS`) theirs (`blocks.window_counts`).
-
-    A window layer's pool is the lanes' rings: its table comes from
-    lane and position (`kv_cache.ring_table`, R read off the first such
-    layer's pool), computed once for all such layers. Each MLP kind is
-    handed what its layer's attention read, for a router placed before
-    the attention.
-
-    Quantized serving (ISSUE 14) rides the same body: a layer dict
-    carrying "k_scale"/"v_scale" pools takes the quantize-at-write path
-    and hands the scales to the paged_attention dispatcher (which fuses
-    the dequant into the Pallas kernel's gather); a layer dict carrying
-    "<w>@q8"/"<w>@scale" weight entries (GPTServingModel.quantize_int8)
-    gets its matmul weight dequantized INLINE — int8 codes times the
-    per-output-channel f32 scale, cast to the activation dtype — so the
-    step reads half the weight bytes from HBM and the jit signature
-    budget is untouched (the dequant is part of the one compiled
-    step, not a second executable)."""
-    s, c = tokens.shape
-    wdt = params["word_emb"].dtype     # activation/compute dtype
-
-    def w(container, name):
-        # int8 weight entry -> inline dequant; plain entry -> as-is
-        q8 = container.get(name + "@q8")
-        if q8 is None:
-            return container[name]
-        return (q8.astype(jnp.float32)
-                * container[name + "@scale"]).astype(wdt)
-
-    pos = jnp.where(valid, positions, 0)
-    x = params["word_emb"][tokens]
-    angles = None
-    if spec.positions == "learned":
-        x = x + params["pos_emb"][pos]
-    elif spec.positions == "rotary":
-        angles = rotary_angles(pos, spec.dims["qk_rope"],
-                               spec.dims["rope_theta"])
-    # write targets: masked lanes route to the NULL block
-    bidx = jnp.take_along_axis(tables, pos // block_size, axis=1)
-    bidx = jnp.where(valid, bidx, NULL_BLOCK)
-    off = jnp.where(valid, pos % block_size, 0)
-    # what a state layer reads: a lane's valid columns are a prefix, and
-    # a lane whose first one holds position 0 starts a request
-    lane_cols = jnp.sum(valid, axis=1, dtype=jnp.int32)
-    starts = valid[:, 0] & (positions[:, 0] == 0)
-    ring = ring_bidx = None
-    ring_layers = [i for i, layer in enumerate(spec.layers)
-                   if layer.attention in RING_ATTENTIONS]
-    if ring_layers:
-        ring = _kvc.ring_table(
-            tables, (pools[ring_layers[0]]["kv"].shape[0] - 1) // s)
-        ring_bidx = jnp.where(
-            valid, jnp.take_along_axis(ring, pos // block_size, axis=1),
-            NULL_BLOCK)
-    ctx = StepContext(spec, s, c, x.dtype, pos, bidx, off, valid, tables,
-                      reduce_fn, in_shard_map, w, angles, lane_cols,
-                      starts, ring, ring_bidx)
-    new_pools, counts = [], []
-    for i, layer in enumerate(spec.layers):
-        lp = params[f"l{i}"]
-        norm = NORMS[layer.norm]
-        hn = norm(spec, x, lp, "ln1")
-        a, layer_pools = ATTENTIONS[layer.attention](ctx, hn, lp,
-                                                     pools[i])
-        x = x + a
-        f, stats = MLPS[layer.mlp](ctx, norm(spec, x, lp, "ln2"), lp, hn)
-        x = x + f
-        new_pools.append(layer_pools)
-        if stats is not None:
-            counts.append(stats)
-    x = NORMS[spec.layers[-1].norm](spec, x, params, "lnf")
-    head = params["word_emb"].T if spec.tied_head else params["head"]
-    out = _step_tail(x, head, tokens, valid, new_pools, per_column,
-                     sampling, mask, rng, temperature, do_sample, top_k,
-                     top_p)
-    state_layers = sum(layer.attention in STATE_ATTENTIONS
-                       for layer in spec.layers)
-    vectors = ([fold_counts(counts)] if counts else []) + (
-        [state_counts(ctx, state_layers)] if state_layers else []) + (
-        [window_counts(ctx, len(ring_layers))] if ring_layers else [])
-    if vectors:
-        out += (jnp.concatenate(vectors),)
-    return out
-
-
-def _step_tail(x, head, tokens, valid, new_pools, per_column, sampling,
-               mask, rng, temperature, do_sample, top_k, top_p):
-    """From the final-normed residual to the step's outputs: the head
-    over each lane's last valid column (or every column), log-softmax
-    in float32, the greedy choice and, where compiled in, the sampled
-    one."""
-    s, c = tokens.shape
-    if not per_column:
-        # next token comes from each lane's LAST valid column only
-        last = jnp.clip(valid.sum(1) - 1, 0, c - 1)
-        xl = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        logits = xl @ head
-        logitsf = logits.astype(jnp.float32)
-        if sampling:
-            # guided-decoding constraint mask (S, V): additive 0 /
-            # NEG_INF rows, all-zero for unconstrained lanes — data,
-            # never shape, so the one-signature invariant holds
-            logitsf = logitsf + mask
-        logp = jax.nn.log_softmax(logitsf)
-        nxt = jnp.argmax(logp, axis=-1)
-        chosen = jnp.take_along_axis(logp, nxt[:, None], -1)[:, 0]
-        if not sampling:
-            return new_pools, nxt.astype(jnp.int32), chosen
-        nxt, chosen = _sampled_where_asked(
-            logp, nxt, chosen, rng, temperature, do_sample, top_k, top_p)
-        # 4th output: the full logp rows — fork-time host sampling and
-        # beam re-ranking read these (the host transfer is paid only
-        # when the plan says a group needs them)
-        return new_pools, nxt, chosen, logp
-    logits = (x.reshape(s * c, -1) @ head).reshape(
-        s, c, head.shape[1])
-    logitsf = logits.astype(jnp.float32)
-    if sampling:
-        logitsf = logitsf + mask        # (S, C, V) per-column masks
-    logp = jax.nn.log_softmax(logitsf)
-    nxt = jnp.argmax(logp, axis=-1)                         # (S, C)
-    chosen = jnp.take_along_axis(logp, nxt[..., None], -1)[..., 0]
-    # target logp of the NEXT FED column's token — the draft under
-    # verification at this column; rejection-sampled acceptance needs
-    # p_target(draft). The last column's value wraps and is meaningless.
-    nt = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-    fed = jnp.take_along_axis(logp, nt[..., None], -1)[..., 0]
-    if not sampling:
-        return new_pools, nxt.astype(jnp.int32), chosen, fed
-    # sampled lanes run 1-column (the scheduler plans no drafts for
-    # them), so the stochastic draw applies to column 0 only
-    nxt, chosen = _sampled_where_asked(
-        logp, nxt, chosen, rng, temperature, do_sample, top_k, top_p)
-    return new_pools, nxt, chosen, fed, logp
-
-
-def single_device_step(params, spec, block_size, per_column=False,
-                       sampling=False):
-    """The fused step of one device over `params` and `spec`, as
-    `GenerationServer` jits it: (pools, tokens, positions, valid,
-    tables[, mask, rng, temperature, do_sample, top_k, top_p]) ->
-    `_fused_step_body`'s outputs. What every family's
-    `build_fused_step` returns without a mesh."""
-    controls = ("mask", "rng", "temperature", "do_sample", "top_k",
-                "top_p") if sampling else ()
-
-    def fused(pools, tokens, positions, valid, tables, *ctl):
-        return _fused_step_body(
-            params, spec, block_size, lambda z: z, pools, tokens,
-            positions, valid, tables, per_column=per_column,
-            sampling=sampling, **dict(zip(controls, ctl, strict=True)))
-
-    return fused
-
-
-class GPTServingModel:
-    """models/gpt.py parameters behind the engine's model interface:
-    config facts + `build_fused_step(block_size, mesh=None)`. The step
-    math is build_kv_step's, re-expressed over (S, C) ragged lanes with
-    paged KV — tests pin the two token-for-token. With a mesh the SAME
-    body runs under shard_map: params in the Megatron serving layout
-    (gpt.gpt_tp_shardings), pools head-sharded, one psum per sub-block
-    (attention o-proj + ffn down-projection)."""
-
-    def __init__(self, params, cfg, dtype=None):
-        self.params = _cast_params(params, dtype)
-        self.cfg = cfg
-        self.num_layers = cfg.num_layers
-        self.num_heads = cfg.num_heads
-        # GQA: cfg.kv_heads < num_heads shares each KV head across a
-        # group of query heads; None/absent means MHA (H_kv == H)
-        self.num_kv_heads = getattr(cfg, "kv_heads", None) or cfg.num_heads
-        if self.num_heads % self.num_kv_heads:
-            raise ValueError(
-                f"kv_heads={self.num_kv_heads} must divide "
-                f"num_heads={self.num_heads}: grouped-query attention "
-                f"needs an integral query-head group per KV head")
-        self.head_dim = cfg.hidden_size // cfg.num_heads
-        self.max_position = cfg.max_position
-        self.kv_dtype = dtype or jnp.float32
-        self._int8_weights = 0
-
-    @classmethod
-    def from_scope(cls, scope, cfg, dtype=None):
-        return cls(load_params(scope, cfg), cfg, dtype=dtype)
-
-    # int8 weight entries a quantize_int8'd layer dict carries in place
-    # of each matmul weight (the fused step dequantizes inline)
-    INT8_WEIGHT_NAMES = ("wq", "wk", "wv", "wo", "f0w", "f1w")
-
-    def quantize_int8(self):
-        """Per-output-channel absmax int8 quantization of every layer's
-        matmul weights (the AnalysisConfig.enable_int8 weight side):
-        each (in, out) weight w becomes w@q8 int8 codes + w@scale f32
-        (1, out) — absmax over the input axis, the reference PTQ
-        convention for mul/matmul Y operands (quant/ptq.py). The fused
-        step dequantizes inline (codes * scale -> activation dtype), so
-        HBM reads halve for these weights and the one-signature-per-
-        lifetime budget is untouched. Embeddings, biases and layernorms
-        stay float: the word embedding doubles as the lm head (rounding
-        it distorts every logit for <2% of the byte win), the rest are
-        O(hidden) vectors. Idempotent; returns self."""
-        if self._int8_weights:
-            return self
-        from ..observability import _help
-        from ..observability.metrics import global_registry
-        # rebind a fresh top-level dict BEFORE rewriting layers: the
-        # constructor may hold the caller's own params dict (dtype=None
-        # skips the cast-copy), and quantization must never mutate a
-        # tree the caller still serves dense elsewhere
-        self.params = dict(self.params)
-        n = 0
-        for i in range(self.cfg.num_layers):
-            lp = dict(self.params[f"l{i}"])
-            for name in self.INT8_WEIGHT_NAMES:
-                wf = lp.pop(name).astype(jnp.float32)
-                absmax = jnp.max(jnp.abs(wf), axis=0, keepdims=True)
-                scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-                lp[name + "@q8"] = jnp.clip(
-                    jnp.round(wf / scale), -127, 127).astype(jnp.int8)
-                lp[name + "@scale"] = scale          # (1, out) f32
-                n += 1
-            self.params[f"l{i}"] = lp
-        self._int8_weights = n
-        global_registry().counter(
-            "inference.int8.weights",
-            _help("inference.int8.weights")).inc(n)
-        return self
-
-    @property
-    def int8_weights(self):
-        """Quantized weight-tensor count (0 = dense weights)."""
-        return self._int8_weights
-
-    def step_spec(self, heads=None, kv_heads=None):
-        """This family's block as the fused step reads it
-        (`blocks.StepSpec`): LayerNorm with bias, learned positions,
-        multi-head attention with biased projections over a K-beside-V
-        pool, erf-GELU MLP, head tied to the embedding. `heads` /
-        `kv_heads` override the counts for a caller inside a
-        shard_map."""
-        return StepSpec(
-            layers=(LayerSpec("layer_norm", "mha", "gelu"),
-                    ) * self.num_layers,
-            positions="learned", tied_head=True,
-            heads=heads or self.num_heads,
-            kv_heads=kv_heads or self.num_kv_heads,
-            head_dim=self.head_dim, norm_eps=None, dims=None)
-
-    def build_fused_step(self, block_size, mesh=None, axis="tp",
-                         per_column=False, kv_quantized=False,
-                         sampling=False):
-        params, cfg = self.params, self.cfg
-
-        if mesh is not None and self._int8_weights:
-            raise NotImplementedError(
-                "int8 weights under a mesh are not supported yet — the "
-                "tp shard rules name the dense weight keys; run int8-"
-                "weight servers single-device (int8 KV pools DO shard; "
-                "docs/serving.md)")
-        if mesh is not None and sampling:
-            raise NotImplementedError(
-                "the sampling/guided step under a mesh is not "
-                "supported yet — run fork-group servers single-device "
-                "(replicating the mask/rng feeds through shard_map is "
-                "follow-up work, docs/serving.md)")
-        if mesh is None:
-            return single_device_step(params, self.step_spec(),
-                                      block_size, per_column, sampling)
-        if per_column:
-            raise NotImplementedError(
-                "per-column outputs (speculative verify) are not "
-                "supported under a mesh yet")
-
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-        from ..models.gpt import gpt_tp_shardings
-
-        tp = mesh.shape[axis]
-        if self.num_heads % tp or cfg.inner_size % tp:
-            raise ValueError(
-                f"tp={tp} must divide both num_heads={self.num_heads} "
-                f"and inner_size={cfg.inner_size}")
-        if self.num_kv_heads % tp:
-            raise ValueError(
-                f"tp={tp} must divide kv_heads={self.num_kv_heads}: "
-                f"the KV pools (and wk/wv columns) shard on the KV "
-                f"head axis, so each device needs a whole number of "
-                f"KV-head groups")
-        h_loc = self.num_heads // tp
-        kv_loc = self.num_kv_heads // tp
-        shardings = gpt_tp_shardings(cfg, mesh, axis)
-        sharded = jax.device_put(params, shardings)
-        # rebind to the sharded copy so THIS model holds no reference
-        # to the unsharded source tree — the caller can free theirs and
-        # halve the footprint (at the HBM edge that's the difference
-        # between fitting and OOM). Shape/dtype consumers
-        # (param_bytes*, the ledger) are unaffected; a later
-        # single-device build_fused_step on this instance would close
-        # over sharded arrays, so use one model per server layout.
-        self.params = sharded
-        del params
-
-        local_spec = self.step_spec(heads=h_loc, kv_heads=kv_loc)
-
-        def local(lp_all, pools, tokens, positions, valid, tables):
-            return _fused_step_body(
-                lp_all, local_spec, block_size,
-                lambda z: jax.lax.psum(z, axis),
-                pools, tokens, positions, valid, tables,
-                in_shard_map=True)
-
-        param_specs = jax.tree_util.tree_map(
-            lambda ns: ns.spec, shardings)
-        layer_spec = {"kv": P(None, axis, None, None)}
-        if kv_quantized:
-            # the (N, H, bs) scale pools shard on the SAME head axis as
-            # their code pools — a shard's rows carry their own scales
-            layer_spec["k_scale"] = P(None, axis, None)
-            layer_spec["v_scale"] = P(None, axis, None)
-        pool_specs = [dict(layer_spec) for _ in range(cfg.num_layers)]
-        rep = P()
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(param_specs, pool_specs, rep, rep,
-                                 rep, rep),
-                       out_specs=(pool_specs, rep, rep),
-                       check_vma=False)
-
-        def fused(pools, tokens, positions, valid, tables):
-            return fn(sharded, pools, tokens, positions, valid, tables)
-
-        return fused
-
-    def param_bytes_per_device(self, mesh=None, axis="tp"):
-        """Bytes of the parameter tree ONE device holds under the
-        serving layout: sharded leaves (spec mentions `axis`) split by
-        tp, replicated leaves count full — the HBM ledger's per-device
-        unit. Without a mesh: the whole tree."""
-        from ..observability.compile_insight import array_nbytes
-        leaves = jax.tree_util.tree_leaves(self.params)
-        if mesh is None:
-            return sum(array_nbytes(a) for a in leaves)
-        from ..models.gpt import gpt_tp_shardings
-        tp = int(mesh.shape[axis])
-        # tree_map over BOTH trees so a params/shardings structure
-        # divergence fails loudly instead of zip-truncating silently
-        per_leaf = jax.tree_util.tree_map(
-            lambda a, ns: array_nbytes(a)
-            // (tp if axis in tuple(ns.spec) else 1),
-            self.params, gpt_tp_shardings(self.cfg, mesh, axis))
-        return sum(jax.tree_util.tree_leaves(per_leaf))
 
 
 class GenerationFuture(Future):
@@ -603,41 +134,25 @@ class GenerationServer:
                 f"mesh_axis {mesh_axis!r} is not a mesh axis (mesh has "
                 f"{mesh.axis_names}) — pass mesh_axis=<the axis name>")
         tp = int(mesh.shape[mesh_axis]) if mesh is not None else 1
-        # validate divisibility BEFORE anything allocates (pools,
-        # scheduler, telemetry): build_fused_step re-checks for direct
-        # callers, but by then the device pools already exist
-        inner = getattr(getattr(model, "cfg", None), "inner_size", None)
-        if mesh is not None and inner is not None and inner % tp:
-            raise ValueError(
-                f"tp={tp} must divide both num_heads={model.num_heads} "
-                f"and inner_size={inner}")
-        # GQA geometry, also before allocation: H % H_kv for any model
-        # (GPTServingModel re-checks for direct construction) and
-        # H_kv % tp under a mesh (the pools shard the KV head axis)
-        kv_heads = getattr(model, "num_kv_heads", model.num_heads)
+        # the GQA geometry, before anything allocates (pools,
+        # scheduler, telemetry): H % H_kv (GPTServingModel checks its
+        # own at construction)
+        kv_heads = model.num_kv_heads
         if model.num_heads % kv_heads:
             raise ValueError(
                 f"kv_heads={kv_heads} must divide "
                 f"num_heads={model.num_heads}: grouped-query attention "
                 f"needs an integral query-head group per KV head")
-        if mesh is not None and kv_heads % tp:
-            raise ValueError(
-                f"tp={tp} must divide kv_heads={kv_heads}: the KV "
-                f"pools shard on the KV head axis (with GQA that is "
-                f"H_kv={kv_heads}, not the {model.num_heads} query "
-                f"heads)")
         # a model with a layer whose cache a lane owns (a recurrent
         # state, or a window layer's ring of blocks, beside the paged
         # cache): everything that copies, shares or moves cache by
         # block cannot carry it yet. A window layer's ring is sized
         # here, where the chunk is known (`kv_cache.ring_blocks_for`)
-        geometry = getattr(model, "kv_geometry", None)
-        if geometry is not None:
-            geometry = [
-                dict(g, ring_blocks=_kvc.ring_blocks_for(
-                    g["window"], chunk, self.block_size))
-                if isinstance(g, dict) and "window" in g else g
-                for g in geometry]
+        geometry = [
+            dict(g, ring_blocks=_kvc.ring_blocks_for(
+                g["window"], chunk, self.block_size))
+            if isinstance(g, dict) and "window" in g else g
+            for g in model.kv_geometry]
         # (what a lane owns, where ROADMAP says what is left) or None
         self._lane_owned = _kvc.lane_owned(geometry)
         if self._lane_owned:
@@ -660,6 +175,11 @@ class GenerationServer:
                         f"{what} is not supported for a model with a "
                         f"{self._lane_owned[0]} ({type(model).__name__}):"
                         f" {self._lane_owned[1]}")
+        # what a mesh needs of the model (or its refusal by name), also
+        # before allocation: build_fused_step re-checks for direct
+        # callers, but by then the device pools already exist
+        if mesh is not None:
+            model.check_mesh(mesh, mesh_axis)
         max_context = int(max_context or model.max_position)
         if max_context > model.max_position:
             raise ValueError(
@@ -756,7 +276,7 @@ class GenerationServer:
                 dm.num_layers, dm.num_heads, dm.head_dim,
                 self.cache.num_blocks, block_size=self.block_size,
                 dtype=dm.kv_dtype, kv_dtype=kv_dtype,
-                num_kv_heads=getattr(dm, "num_kv_heads", dm.num_heads))
+                num_kv_heads=dm.num_kv_heads)
             self.cache.attach_sibling(self._draft_cache)
             from .spec_decode import build_draft_step
             self._draft = jax.jit(build_draft_step(
@@ -767,40 +287,19 @@ class GenerationServer:
         # draft pools too (a parked spec request keeps its draft KV).
         if host_kv_blocks:
             self.cache.enable_host_tier(int(host_kv_blocks))
-        # mesh/per_column kwargs only when needed: a custom model
-        # implementing the original build_fused_step(block_size) keeps
-        # working for plain single-device serving. Speculative servers
-        # are the ONLY ones that pay the per-column lm-head projection
-        # (C x the narrow gemm) — plain decode reads one column per
-        # lane, so it keeps the last-column gather.
-        # decode strategies (ISSUE 20): single-device servers whose
-        # model's build_fused_step grew the `sampling` kwarg get the
-        # in-step sampling/guided-mask path — and with it fork groups
-        # (submit(n=K) / beam=) and guided decoding. Feature-detected so
-        # custom models with the original signature keep working; the
-        # vocab size must be readable for the mask rows.
-        import inspect
-        self._vocab = getattr(getattr(model, "cfg", None),
-                              "vocab_size", None)
-        self._strategies = (
-            mesh is None and self._vocab is not None
-            and "sampling" in inspect.signature(
-                model.build_fused_step).parameters)
-        if mesh is not None:
-            mesh_kw = {"mesh": mesh, "axis": mesh_axis}
-            if self.cache.quantized:
-                # only passed when needed, so a custom model with the
-                # pre-quantization build_fused_step signature keeps
-                # working for dense mesh serving
-                mesh_kw["kv_quantized"] = True
-            fused = model.build_fused_step(self.block_size, **mesh_kw)
-        else:
-            step_kw = {}
-            if spec is not None:
-                step_kw["per_column"] = True
-            if self._strategies:
-                step_kw["sampling"] = True
-            fused = model.build_fused_step(self.block_size, **step_kw)
+        # decode strategies: a single-device server's step compiles in
+        # the sampling / guided-mask tail, and with it fork groups
+        # (submit(n=K) / beam=) and guided decoding; the mask rows are
+        # the vocabulary wide. Speculative servers are the ONLY ones
+        # that pay the per-column lm-head projection (C x the narrow
+        # gemm) — plain decode reads one column per lane, so it keeps
+        # the last-column gather.
+        self._vocab = model.cfg.vocab_size
+        self._strategies = mesh is None
+        fused = model.build_fused_step(
+            self.block_size, mesh=mesh, axis=mesh_axis,
+            per_column=spec is not None, sampling=self._strategies,
+            kv_quantized=self.cache.quantized)
         # the feed (feed.py): tokens, positions, valid, tables and the
         # sampling controls cross as ONE packed int32 buffer, unpacked
         # inside the step in the model step's argument order. The
@@ -854,7 +353,6 @@ class GenerationServer:
             if mesh is not None else jnp.asarray(np.zeros((s,), np.int32)))
         self._inflight = None           # the step dispatched, not fetched
         self._signatures = set()
-        self._n_arguments = None        # _arguments(): read once
         # HBM ledger (observability/compile_insight.py): the serving
         # side of get_stats()["memory"] / the /memory endpoint — block
         # pools + model params as resident rows, plus a static peak
@@ -895,10 +393,10 @@ class GenerationServer:
             kv_detail["scale_bytes"] = self.cache.scale_bytes()
             kv_detail["dense_equiv_bytes"] = \
                 self.cache.dense_pool_bytes()
+        param_dev_bytes = model.param_bytes_per_device(mesh, mesh_axis)
         if mesh is None:
             led.register(self._ledger_id, "kv_pool", "kv_cache",
                          kv_bytes, detail=kv_detail)
-            param_dev_bytes = param_bytes
         else:
             for i, dev in enumerate(mesh.devices.flat):
                 led.register(
@@ -907,10 +405,6 @@ class GenerationServer:
                     detail=dict(kv_detail, device=str(dev),
                                 mesh_index=i, axis=mesh_axis,
                                 heads_local=kv_heads // tp))
-            param_dev_bytes = param_bytes
-            if hasattr(model, "param_bytes_per_device"):
-                param_dev_bytes = model.param_bytes_per_device(
-                    mesh, mesh_axis)
         # host tier: its own row under the NON-resident "host_ram"
         # kind — host RAM is real memory the fleet sizes against, but
         # it must never inflate the per-device HBM totals the resident
@@ -1080,10 +574,10 @@ class GenerationServer:
                 _help("serving.overlapped_iterations")),
         }
         # a model whose step counts something returns the counts as
-        # one more output (`_fused_step_body`) and names them:
+        # one more output (`step._fused_step_body`) and names them:
         # `step_counters`, a (name in the iteration record, registry
         # counter it feeds or None) a count
-        self._step_counters = tuple(getattr(model, "step_counters", ()))
+        self._step_counters = model.step_counters
         self._step_counts = None
         self._counters_fed = [
             (i, reg.counter(metric, _help(metric)))
@@ -1179,9 +673,8 @@ class GenerationServer:
         if wants and not self._strategies:
             raise NotImplementedError(
                 "decode strategies (sampling/n>1/beam/guided) need the "
-                "strategies fused step: single-device serving with a "
-                "model whose build_fused_step accepts `sampling` "
-                "(mesh servers are follow-up work, docs/serving.md)")
+                "strategies fused step: single-device serving (mesh "
+                "servers are follow-up work, docs/serving.md)")
         if guided is not None and eos_id is None:
             raise ValueError(
                 "guided decoding requires eos_id (constraint "
@@ -1435,10 +928,7 @@ class GenerationServer:
                     self._m["mask_uploads"].inc()
                 self._signatures.add(
                     tuple((a.shape, str(a.dtype)) for a in args))
-            with rec.span("serving.dispatch", cat="serving",
-                          args=lambda: {
-                              **(leaf or {}),
-                              "arguments": self._arguments(args)}):
+            with rec.span("serving.dispatch", cat="serving", args=leaf):
                 cur.out = self._dispatch_fused(args)
             if cur.overlapped:
                 self._m["overlapped_iterations"].inc()
@@ -1695,20 +1185,6 @@ class GenerationServer:
                 if k > n0.get(n, 0))) or None
         self._check_kernel_engagement()
         return out
-
-    def _arguments(self, args):
-        """How many arrays the fused step's executable takes: the
-        pools' leaves, the feeds, and the weights JAX hoists into
-        arguments under JAX_USE_SIMPLIFIED_JAXPR_CONSTANTS. Read once,
-        while a capture is live, from the compiled step that the first
-        call left in JAX's caches (no second trace or compile)."""
-        if self._n_arguments is None:
-            with self.cache.pools_lock:
-                traced = self._fused.trace(self.cache.pools, *args)
-            self._n_arguments = len(traced.lower().compile()
-                                    .runtime_executable()
-                                    .get_parameter_layouts())
-        return self._n_arguments
 
     def _fetch_outputs(self, out, plan):
         """Bring the step's host-side outputs over (this waits for the
@@ -2198,7 +1674,7 @@ class GenerationServer:
                 "scale_bytes": self.cache.scale_bytes(),
                 "dense_equiv_bytes": db,
                 "bytes_ratio_vs_dense": round(pb / db, 4),
-                "int8_weights": getattr(self.model, "int8_weights", 0),
+                "int8_weights": self.model.int8_weights,
             }
         else:
             st["kv_quant"] = None

@@ -52,17 +52,17 @@ resampling (which needs the full target distribution on the host) —
 a documented deviation, see docs/serving.md. Greedy mode is exact.
 """
 
-import jax
 import jax.numpy as jnp
+
+from .step import _fused_step_body
 
 __all__ = ["SpecDecodeConfig", "build_draft_step"]
 
 
 class SpecDecodeConfig:
-    """Engine-facing spec-decode settings: the draft model (any object
-    with the GPTServingModel interface — params/cfg/num_layers/
-    num_heads/head_dim/kv_dtype/step_spec()), k proposals per iteration, the
-    acceptance mode, and the rejection-mode RNG seed."""
+    """Engine-facing spec-decode settings: the draft model (a
+    `ServingModel` whose cache is paged by block), k proposals per
+    iteration, the acceptance mode, and the rejection-mode RNG seed."""
 
     def __init__(self, draft_model, k=3, mode="greedy", seed=0):
         if k < 1:
@@ -80,7 +80,6 @@ def build_draft_step(model, block_size, k):
     """One compiled draft step (see module docstring): sync pass over
     the plan feed + k-1 rollout micro-steps, all inside one jit so the
     server lifetime holds exactly one draft signature."""
-    from .engine import _fused_step_body
     params, spec = model.params, model.step_spec()
 
     def _ident(z):

@@ -440,7 +440,8 @@ def build_server(spec):
     from ..robustness.chaos import ChaosInjector
     from ..robustness.checkpoint_manager import (CheckpointError,
                                                  CheckpointManager)
-    from .engine import GenerationServer, GPTServingModel
+    from .engine import GenerationServer
+    from .model import GPTServingModel
 
     cfg = gpt.GPTConfig(**spec["cfg"])
     main_p, startup = framework.Program(), framework.Program()

@@ -439,7 +439,8 @@ def test_ledger_kv_pool_bytes_and_retire_on_close(serving_params, dtype):
     """Satellite: pool-bytes accounting — the ledger's kv_cache row
     equals num_blocks*H*block_size*D*itemsize summed over layers for
     BOTH k and v pools, f32 and bf16; gauges retire on close."""
-    from paddle_tpu.serving.engine import GenerationServer, GPTServingModel
+    from paddle_tpu.serving.engine import GenerationServer
+    from paddle_tpu.serving.model import GPTServingModel
     cfg, params = serving_params
     srv = GenerationServer(GPTServingModel(params, cfg, dtype=dtype),
                            num_slots=2, block_size=8, max_context=32,
@@ -475,7 +476,8 @@ def test_ledger_retires_on_fault_stopped_close(serving_params):
     """PR 7's fault-stop path: _on_engine_fault closes without the
     normal teardown; the close()-after-fault early-return branch must
     still retire the ledger rows."""
-    from paddle_tpu.serving.engine import GenerationServer, GPTServingModel
+    from paddle_tpu.serving.engine import GenerationServer
+    from paddle_tpu.serving.model import GPTServingModel
     cfg, params = serving_params
     srv = GenerationServer(GPTServingModel(params, cfg), num_slots=2,
                            block_size=8, max_context=32, chunk=2,

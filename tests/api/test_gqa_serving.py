@@ -274,8 +274,8 @@ def test_gqa_bad_geometry_raises_at_construction(tiny_gpt):
     model = GPTServingModel(params, cfg)
     shim = Shim()
     shim.__dict__.update(model.__dict__)
-    shim.__class__ = type("ShimModel", (GPTServingModel,), {})
-    shim.num_kv_heads = 3
+    shim.__class__ = type("ShimModel", (GPTServingModel,),
+                          {"num_kv_heads": 3})
     with pytest.raises(ValueError, match="must divide num_heads"):
         _server(shim)
 

@@ -3,9 +3,9 @@
 Tier-1 (`serving` marker, CPU, no sleeps). The contract under test:
 
 - the compiled fused step aliases every pool leaf's output to its input
-  and nothing else, in each variant `build_fused_step` returns: plain,
-  `sampling`, `per_column`, int8 KV with its scale pools, the
-  `shard_map` body on a mesh;
+  and nothing else, in each variant `build_fused_step` returns: plain
+  (jitted apart, as no server builds it), `sampling`, `per_column`, int8
+  KV with its scale pools, the `shard_map` body on a mesh;
 - a step consumes the pools it was handed (the array that was
   `pools[0]["kv"]` is deleted after it) and counts it:
   `serving.kv.pool_donations` moves with `serving.iterations`;
@@ -79,19 +79,29 @@ ALIAS = re.compile(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)")
 # ---------------------------------------------------------------------------
 
 def _plain(params, cfg):
-    class Original(GPTServingModel):
-        # the pre-strategies signature: the server then builds the
-        # plain (pools, ids, logps) step
-        def build_fused_step(self, block_size, mesh=None, axis="tp"):
-            return super().build_fused_step(block_size, mesh=mesh,
-                                            axis=axis)
-    return GenerationServer(Original(params, cfg), num_slots=3,
-                            block_size=8, max_context=64, chunk=4,
-                            start=False)
+    """The model's own plain step, (pools, ids, logps), which no server
+    builds (every single-device server compiles the strategies' tail
+    in): jitted with the pools donated, as the server jits its packed
+    step, over a cache of the server's geometry. Returns the compiled
+    text and the pools' shapes."""
+    model = GPTServingModel(params, cfg)
+    cache = PagedKVCache(model.num_layers, model.num_heads,
+                         model.head_dim, 25, block_size=8)
+    fused = jax.jit(model.build_fused_step(8), donate_argnums=(0,))
+    s, c, m = 3, 4, 8
+    feeds = (np.zeros((s, c), np.int32), np.zeros((s, c), np.int32),
+             np.zeros((s, c), np.bool_), np.zeros((s, m), np.int32))
+    k0 = cache.pools[0]["kv"]
+    out = fused(cache.pools, *feeds)
+    assert len(out) == 3
+    # the step took the pools it was handed
+    assert k0.is_deleted()
+    assert not out[0][0]["kv"].is_deleted()
+    return (fused.lower(out[0], *feeds).compile().as_text(),
+            [tuple(a.shape) for a in jax.tree_util.tree_leaves(out[0])])
 
 
 VARIANTS = {
-    "plain": _plain,
     "sampling": lambda p, c: _server(p, c),
     "per_column": lambda p, c: _server(
         p, c, spec=SpecDecodeConfig(GPTServingModel(p, c), k=3)),
@@ -101,15 +111,30 @@ VARIANTS = {
 }
 
 
-@pytest.mark.parametrize("variant", list(VARIANTS))
+def _assert_aliases(text, shapes):
+    """The compiled module's outputs 0..n-1 are the pools, written into
+    parameters 0..n-1, which are the pools (of `shapes`); no other
+    pair."""
+    header = text[:text.index("\n")]
+    pairs = sorted((int(o), int(p)) for o, p in ALIAS.findall(header))
+    assert pairs == [(i, i) for i in range(len(shapes))], header[:2000]
+    layout = header[header.index("entry_computation_layout={(") + 27:]
+    for want, got in zip(shapes, layout.split("}, ")):
+        assert "[" + ",".join(map(str, want)) + "]" in got, (want, got)
+
+
+@pytest.mark.parametrize("variant", ["plain", *VARIANTS])
 def test_fused_step_aliases_every_pool_leaf_and_consumes_them(
         tiny_gpt, variant):
     cfg, params = tiny_gpt
-    srv = VARIANTS[variant](params, cfg)
     n_leaves = (3 if variant == "int8_kv" else 1) * cfg.num_layers
-    assert len(_leaves(srv.cache)) == n_leaves
     if variant == "plain":
-        assert not srv._strategies
+        text, shapes = _plain(params, cfg)
+        assert len(shapes) == n_leaves
+        _assert_aliases(text, shapes)
+        return
+    srv = VARIANTS[variant](params, cfg)
+    assert len(_leaves(srv.cache)) == n_leaves
     calls = []
     fused = srv._fused
 
@@ -136,18 +161,11 @@ def test_fused_step_aliases_every_pool_leaf_and_consumes_them(
     assert don1 - don0 == it1 - it0 == srv.get_stats()["iteration"]
     assert srv.get_stats()["fused_step_signatures"] == 1
 
-    # the compiled module: outputs 0..n-1 are the pools, written into
-    # parameters 0..n-1, which are the pools; no other pair
     text = fused.lower(srv.cache.pools, *calls[0]).compile().as_text()
-    header = text[:text.index("\n")]
-    pairs = sorted((int(o), int(p)) for o, p in ALIAS.findall(header))
-    assert pairs == [(i, i) for i in range(n_leaves)], header[:2000]
-    layout = header[header.index("entry_computation_layout={(") + 27:]
     shapes = [tuple(a.shape) for a in _leaves(srv.cache)]
     if variant == "tp_mesh":        # each device holds half the heads
         shapes = [(n, h // 2, *rest) for n, h, *rest in shapes]
-    for want, got in zip(shapes, layout.split("}, ")):
-        assert "[" + ",".join(map(str, want)) + "]" in got, (want, got)
+    _assert_aliases(text, shapes)
     srv.close()
 
 

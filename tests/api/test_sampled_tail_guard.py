@@ -1,5 +1,5 @@
 """The fused step's sampled tail sits under ONE device-side branch on
-`do_sample.any()` (`engine.py:_sampled_where_asked`): a step whose lanes
+`do_sample.any()` (`step.py:_sampled_where_asked`): a step whose lanes
 are all greedy runs no sort, no nucleus sum and no noise, and every
 step's outputs are bit for bit those of the unguarded arithmetic, which
 this file keeps as its reference.
@@ -29,7 +29,7 @@ from paddle_tpu.observability.metrics import global_registry
 from paddle_tpu.observability.tracing import get_recorder
 from paddle_tpu.serving import (GenerationServer, GPTServingModel,
                                 SamplingParams, SpecDecodeConfig)
-from paddle_tpu.serving import engine
+from paddle_tpu.serving import step
 from paddle_tpu.serving.decode_strategies import fold_key
 from paddle_tpu.serving.kv_cache import NEG_INF
 
@@ -48,7 +48,7 @@ def _unguarded_tail(x, head, tokens, valid, per_column, mask, rng,
         logp = jax.nn.log_softmax((xl @ head).astype(jnp.float32) + mask)
         nxt = jnp.argmax(logp, axis=-1)
         chosen = jnp.take_along_axis(logp, nxt[:, None], -1)[:, 0]
-        samp, samp_lp = engine._sample_rows(logp, rng, temperature,
+        samp, samp_lp = step._sample_rows(logp, rng, temperature,
                                             top_k, top_p)
         nxt = jnp.where(do_sample, samp, nxt).astype(jnp.int32)
         chosen = jnp.where(do_sample, samp_lp, chosen)
@@ -59,7 +59,7 @@ def _unguarded_tail(x, head, tokens, valid, per_column, mask, rng,
     chosen = jnp.take_along_axis(logp, nxt[..., None], -1)[..., 0]
     nt = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
     fed = jnp.take_along_axis(logp, nt[..., None], -1)[..., 0]
-    samp, samp_lp = engine._sample_rows(logp[:, 0], rng, temperature,
+    samp, samp_lp = step._sample_rows(logp[:, 0], rng, temperature,
                                         top_k, top_p)
     nxt = nxt.at[:, 0].set(jnp.where(do_sample, samp, nxt[:, 0]))
     chosen = chosen.at[:, 0].set(
@@ -105,11 +105,11 @@ def test_guarded_tail_is_bitwise_the_unguarded_one(lanes, use_top_k,
 
 
 def both_tails(per_column):
-    """(guarded, unguarded), jitted: `engine._step_tail` as it is and
+    """(guarded, unguarded), jitted: `step._step_tail` as it is and
     this file's reference, over the same arguments."""
     def guarded(x, head, tokens, valid, *ctl):
-        return engine._step_tail(x, head, tokens, valid, [], per_column,
-                                 True, *ctl)[1:]
+        return step._step_tail(x, head, tokens, valid, [], per_column,
+                               True, *ctl)[1:]
 
     def unguarded(x, head, tokens, valid, *ctl):
         return _unguarded_tail(x, head, tokens, valid, per_column, *ctl)

@@ -435,12 +435,12 @@ def test_the_sampling_tails_sorts_sit_in_a_conditional(v5e, per_column):
     whose lanes are all greedy does not run them (PERF.md section 6,
     PR 35). A later edit that hoists the draw out of the branch, or a
     compiler pass that does, fails here."""
-    from paddle_tpu.serving import engine
+    from paddle_tpu.serving import step
     s, c, hid, v = 16, (4 if per_column else 16), 1600, 50257
 
     def tail(x, head, tokens, valid, *ctl):
-        return engine._step_tail(x, head, tokens, valid, [], per_column,
-                                 True, *ctl)[1:]
+        return step._step_tail(x, head, tokens, valid, [], per_column,
+                               True, *ctl)[1:]
 
     shapes = (((s, c, hid), jnp.bfloat16), ((hid, v), jnp.bfloat16),
               ((s, c), jnp.int32), ((s, c), jnp.bool_),
